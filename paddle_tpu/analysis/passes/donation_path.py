@@ -1,8 +1,9 @@
 """donation-path: raw `donate_argnums` outside the gauntlet-gated store.
 
 PR 8 established that re-applying donation to store-served (exported →
-deserialized) executables intermittently heap-corrupts on jaxlib
-0.4.36; ISSUE 13's donation gauntlet therefore made the ProgramStore
+deserialized) executables intermittently heap-corrupted on the jaxlib
+of that time (0.4.36); ISSUE 13's donation gauntlet therefore made the
+ProgramStore
 the single donation owner: callers declare `donate_argnums` to
 `wrap_jit`, the DIRECT compile path donates as declared (the safe
 case), and the export path re-applies donation only on a probe-safe
@@ -73,6 +74,7 @@ class DonationPathPass(AnalysisPass):
                 f'donation gauntlet — route it through '
                 f'`ProgramStore.wrap_jit(..., donate_argnums=...)` so '
                 f'the probe verdict, corruption sentinels, and '
-                f'quarantine govern it (store-served donated '
-                f'executables heap-corrupt on jaxlib 0.4.36)'))
+                f'quarantine govern it (whether store-served donated '
+                f'executables corrupt on the installed runtime is the '
+                f'gauntlet\'s verdict, not the call site\'s)'))
         return findings

@@ -24,7 +24,7 @@ from typing import Any, Callable, List, Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
-from ._compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..nn.layer import Layer

@@ -17,10 +17,8 @@ import functools
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
 import jax
-# submodule import: jax.export is not an attribute of the jax module
-# object on older jax (0.4.x), but the submodule itself is importable
-from jax import export as _jax_export
 import jax.numpy as jnp
+from jax import export as _jax_export
 import numpy as np
 
 from .. import autograd, framework
@@ -328,25 +326,30 @@ class TrainStep:
                            else jnp.asarray(v), labels,
                            is_leaf=lambda v: isinstance(v, Tensor)))
 
-    def memory_analysis(self, inputs, labels):
-        """XLA's CompiledMemoryStats for the step at these batch shapes
-        (peak_memory_in_bytes, temp/argument/output sizes). The AOT
-        lower().compile() hits the jit cache, so after the step has run
-        once this costs no recompile."""
+    def lower(self, inputs, labels):
+        """The step lowered at these batch shapes (`jax.stages.Lowered`):
+        `.as_text()` is the StableHLO the compiler is given — where
+        chip_smoke.py counts the Mosaic custom calls — and `.compile()`
+        answers AOT introspection. Traces and lowers; runs nothing."""
         params, frozen, buffers = functional_state(self.layer)
         key = jax.random.fold_in(self._step_key_root, 0)
         if self._offload:
-            # offload path: HBM peak is the grad step (slots stream
-            # through one leaf at a time and never sit in HBM)
+            # offload path: the jitted program is the grad step (slots
+            # stream through one leaf at a time and never sit in HBM)
             return self._jitted_grads.lower(
                 params, buffers, frozen, key,
-                self._as_batch(inputs, labels)).compile().memory_analysis()
+                self._as_batch(inputs, labels))
         if self._opt_state is None:
             self._opt_state = self.optimizer.init_state(params)
         lr = jnp.asarray(self.optimizer.get_lr(), jnp.float32)
         return self._jitted.lower(
             params, self._opt_state, buffers, frozen, key, lr,
-            self._as_batch(inputs, labels)).compile().memory_analysis()
+            self._as_batch(inputs, labels))
+
+    def memory_analysis(self, inputs, labels):
+        """XLA's CompiledMemoryStats for the step at these batch shapes
+        (peak_memory_in_bytes, temp/argument/output sizes)."""
+        return self.lower(inputs, labels).compile().memory_analysis()
 
     def __call__(self, inputs, labels):
         # the span is the goodput ledger's `step_compute` source (first

@@ -1011,17 +1011,20 @@ def _fused_softmax_ce(logits2d, safe_labels, valid):
     from ..ops import pallas as _pallas
     if (_pallas.pallas_ce_enabled() and logits2d.shape[-1] >= 8192
             and logits2d.shape[-1] % 128 == 0):
-        try:
-            from ..ops import pallas_kernels as _pk
-            per = _pk.softmax_cross_entropy(logits2d, safe_labels)
-            return jnp.where(valid, per, 0.0)
-        except Exception as e:
-            # trace-time failure only — a Mosaic compile/runtime error
-            # inside an outer jit is NOT catchable here and will surface
-            # to the caller (use PADDLE_TPU_DISABLE_PALLAS_CE then)
-            import warnings
-            warnings.warn(f'pallas fused CE unavailable, using the XLA '
-                          f'path: {type(e).__name__}: {e}')
+        # the shape conditions above are the whole selection: a kernel
+        # error on this side propagates (ops/pallas.py says why)
+        from jax.sharding import PartitionSpec as P
+        from ..ops import pallas_kernels as _pk
+
+        def specs(mesh):
+            # rows are batch-major, so the dp batch split is a row split;
+            # the vocab dim stays whole (one lse per row)
+            rows = _pallas.mesh_axis(mesh, 'dp', logits2d.shape[0])
+            return (P(rows, None), P(rows)), P(rows)
+
+        per = _pallas.on_mesh(_pk.softmax_cross_entropy,
+                              (logits2d, safe_labels), specs)
+        return jnp.where(valid, per, 0.0)
     return _fused_softmax_ce_xla(logits2d, safe_labels, valid)
 
 

@@ -71,6 +71,9 @@ EVENT_SCHEMA: Dict[str, str] = {
                          'donation-safe',
     'donation_probe_failed': 'probe found corruption/crash; store runs '
                              'undonated',
+    'donation_no_verdict': 'no verdict recorded and this process holds '
+                           'the chip, so no probe was spawned; store '
+                           'runs undonated',
     'donation_enabled': 'store-served programs re-apply donate_argnums '
                         '(sentinel-guarded)',
     'donation_quarantined': 'corruption sentinel tripped; donation off '

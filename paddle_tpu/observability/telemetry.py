@@ -66,11 +66,16 @@ def _on_jax_event(name: str, **kw):
     assert that difference is zero."""
     if not _metrics.enabled():
         return
-    if name.endswith('cache_hits'):
+    if name.endswith('/compilation_cache/cache_hits'):
         _metrics.get_registry().counter(
             'paddle_jit_cache_hits_total',
             'XLA backend compiles served from the persistent '
             'compilation cache').inc()
+    elif name.endswith('/compilation_cache/cache_misses'):
+        _metrics.get_registry().counter(
+            'paddle_jit_cache_misses_total',
+            'XLA backend compiles that missed the persistent '
+            'compilation cache and were written to it').inc()
 
 
 def _dispatch_collector(reg: '_metrics.MetricsRegistry'):

@@ -1,11 +1,17 @@
-"""Hot fused ops: TPU pallas kernels with XLA fallbacks.
+"""Hot fused ops: TPU pallas kernels, or XLA where a kernel does not apply.
 
 Upstream analogue: the reference's hand-fused CUDA kernels
 (paddle/phi/kernels/fusion/gpu/*, flash-attn integration). Here the
 default path is plain jax — XLA already fuses normalization chains into
 adjacent matmuls — and the pallas kernels (ops/pallas_kernels.py) take
-over on real TPU backends for the attention inner loop, where manual
+over on TPU backends for the attention inner loop, where manual
 VMEM blocking beats the XLA-generated schedule.
+
+Which path runs is decided by explicit conditions on the backend and
+the shapes, never by a caught exception: on a TPU a kernel that fails
+to lower, compile or run is an error the caller sees (a silent XLA
+stand-in made every green run ambiguous — chip_smoke.py counts the
+Mosaic custom calls in the compiled step for the same reason).
 
 All functions in this module operate on raw jax arrays (they are called
 from inside apply_op bodies / jitted train steps).
@@ -18,16 +24,42 @@ import os
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 
 @functools.lru_cache(None)
 def _pallas_enabled() -> bool:
     if os.environ.get('PADDLE_TPU_DISABLE_PALLAS'):
         return False
-    try:
-        return jax.default_backend() == 'tpu'
-    except Exception:  # paddle-lint: disable=swallowed-exception -- backend probe during import; no backend means no TPU
-        return False
+    return jax.default_backend() == 'tpu'
+
+
+def on_mesh(kernel, args, specs):
+    """Call a Mosaic `kernel(*args)` so that it also works under an
+    active fleet mesh. GSPMD cannot partition a `pallas_call` (an opaque
+    custom call): left bare inside `fleet.DistTrainStep`'s one GSPMD jit
+    it is refused or fed gathered operands. So when a multi-device mesh
+    is active the call is made per shard through `shard_map`;
+    `specs(mesh) -> (in_specs, out_specs)` names the mesh axes that shard
+    the batch and head dims. With no mesh, one device, or inside someone
+    else's `shard_map` (the pipeline schedule — operands are per-shard
+    already) the kernel is called as is."""
+    from ..distributed import env
+    if not env.has_mesh():
+        return kernel(*args)
+    mesh = env.get_mesh(auto_init=False)
+    if mesh.size == 1 or jax.sharding.get_abstract_mesh().manual_axes:
+        return kernel(*args)
+    in_specs, out_specs = specs(mesh)
+    return jax.shard_map(kernel, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)(*args)
+
+
+def mesh_axis(mesh, axis, n):
+    """`axis` if the mesh has it and it divides a dim of size `n`, else
+    None (the dim stays whole on every shard)."""
+    size = mesh.shape.get(axis)
+    return axis if size and n % size == 0 else None
 
 
 @functools.lru_cache(None)
@@ -84,7 +116,8 @@ def flash_attention(q, k, v, mask=None, causal=False, dropout_p=0.0,
     """Dispatch: pallas flash kernel on TPU (no mask/dropout path), XLA
     softmax-attention otherwise. The pallas path never materializes the
     [B, H, Sq, Sk] logits — the difference between fitting seq 2048
-    training on one chip and OOMing."""
+    training on one chip and OOMing. The conditions below are the whole
+    selection: a kernel error on the pallas side propagates."""
     h, kvh = q.shape[2], k.shape[2]
     # causal requires sq == sk: the pallas kernel's causal mask is
     # top-left aligned while _attention_xla's is bottom-right aligned —
@@ -93,14 +126,17 @@ def flash_attention(q, k, v, mask=None, causal=False, dropout_p=0.0,
             and q.shape[1] % 128 == 0 and k.shape[1] % 128 == 0
             and (not causal or q.shape[1] == k.shape[1])
             and h % kvh == 0 and q.shape[-1] >= 64):
-        try:
-            from . import pallas_kernels
-            return pallas_kernels.flash_attention(q, k, v, causal=causal)
-        except Exception:
-            # fall back to XLA on any kernel/shape issue — counted, so a
-            # bench that thinks it raced the pallas kernel can prove the
-            # kernel actually ran
-            from ..observability import count_suppressed
-            count_suppressed('pallas.flash_fallback')
+        from . import pallas_kernels
+
+        def specs(mesh):
+            # batch over dp, heads over mp (kvh divides h, so an axis
+            # that splits the kv heads splits the q heads too)
+            qkv = P(mesh_axis(mesh, 'dp', q.shape[0]), None,
+                    mesh_axis(mesh, 'mp', kvh), None)
+            return (qkv, qkv, qkv), qkv
+
+        return on_mesh(
+            functools.partial(pallas_kernels.flash_attention, causal=causal),
+            (q, k, v), specs)
     return _attention_xla(q, k, v, mask=mask, causal=causal,
                          dropout_p=dropout_p, dropout_key=dropout_key)

@@ -16,11 +16,13 @@ automatically when the store is persistent).
 """
 from . import donation
 from .store import (ProgramDeserializeError, ProgramStore, StoredJit,
-                    backend_fingerprint, code_token, configure,
-                    describe_statics, get_store, store_key)
+                    backend_fingerprint, code_token, compile_cache_dir,
+                    configure, describe_statics, ensure_compile_cache,
+                    get_store, store_key)
 
 __all__ = [
     'ProgramDeserializeError', 'ProgramStore', 'StoredJit',
-    'backend_fingerprint', 'code_token', 'configure', 'describe_statics',
-    'donation', 'get_store', 'store_key',
+    'backend_fingerprint', 'code_token', 'compile_cache_dir', 'configure',
+    'describe_statics', 'donation', 'ensure_compile_cache', 'get_store',
+    'store_key',
 ]

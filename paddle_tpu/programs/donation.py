@@ -3,11 +3,13 @@ programs.
 
 PR 8 discovered that store-served executables (jax.export StableHLO
 payloads re-compiled through ``jax.jit(exported.call)``) intermittently
-HEAP-CORRUPT when donation is re-applied on jaxlib 0.4.36 — segfaults
-and garbage losses on roughly half of 14-run gauntlets. The store has
-run every persisted program UNDONATED since: memory-safe, but every
-serving pool op paid a full pool-buffer round trip and donated train
-state transiently 2x-buffered (the ROADMAP "Kill the copy" tax).
+HEAP-CORRUPTED when donation was re-applied on jaxlib 0.4.36, the
+runtime of that time — segfaults and garbage losses on roughly half of
+14-run gauntlets. The store ran every persisted program UNDONATED
+after that: memory-safe, but every serving pool op paid a full
+pool-buffer round trip and donated train state transiently 2x-buffered.
+What the runtime installed today does is the probe's answer, recorded
+per backend fingerprint (CHANGES.md PR 21 has jaxlib 0.9.0's).
 
 This module replaces the hardcoded posture with a *probe*: at
 ProgramStore init (when a persistent directory is configured) a
@@ -33,11 +35,15 @@ recompiled undonated, ``donation_quarantined`` emitted (a
 flight-recorder trigger) — and the triggering call re-runs undonated,
 so a garbage value is never surfaced.
 
-Deployment note (single-client accelerators): on a TPU the probe child
-cannot attach while the parent holds the device — the probe then times
-out and the verdict conservatively lands ``corrupting``. Record the
-verdict BEFORE launching instead: ``python -m paddle_tpu.programs.donation
-<store_dir>`` runs the gauntlet standalone and commits the verdict the
+One client per chip: a TPU belongs to one process at a time, so a probe
+child spawned from the process that holds the chip cannot attach — it
+would hang to its timeout and record a false ``corrupting``. When the
+parent's backend is a TPU and no verdict is recorded, ``resolve_posture``
+therefore does NOT spawn: it warns, emits ``donation_no_verdict`` and
+stays undonated. Record the verdict BEFORE launching instead:
+``python -m paddle_tpu.programs.donation <store_dir>`` runs the gauntlet
+standalone (its child takes the chip first; the parent reads the
+fingerprint only after the child has exited) and commits the verdict the
 next ProgramStore init will read. ``FLAGS_donation=on|off`` overrides
 the probe entirely (``on`` still honors a recorded quarantine).
 
@@ -54,6 +60,7 @@ import os
 import subprocess
 import sys
 import time
+import warnings
 from typing import Any, Dict, Optional
 
 from .. import flags as _flags
@@ -193,9 +200,7 @@ def run_probe(runs: Optional[int] = None,
                               timeout=timeout, env=env)
     except subprocess.TimeoutExpired:
         verdict.update(verdict='corrupting',
-                       reason=f'probe timed out after {timeout}s '
-                              f'(single-client device? see the module '
-                              f'docstring runbook)')
+                       reason=f'probe timed out after {timeout}s')
         verdict['seconds'] = round(time.perf_counter() - t0, 3)
         return verdict
     except Exception as exc:
@@ -329,6 +334,20 @@ def resolve_posture(directory: Optional[str],
             out['reason'] = 'no persistent store (nothing store-served)'
             _posture_gauge(0.0)
             return out
+        if fingerprint.get('backend') == 'tpu':
+            # this process holds the chip and a chip has one client: a
+            # probe child could never attach (see the module docstring)
+            out.update(source='no_verdict', reason=(
+                f'no verdict — run `python -m paddle_tpu.programs.'
+                f'donation {directory}` first'))
+            warnings.warn(
+                f'donation stays OFF for store-served programs: '
+                f'{out["reason"]} (the probe needs the chip this '
+                f'process holds)', RuntimeWarning, stacklevel=2)
+            _obs.emit('donation_no_verdict', token=token,
+                      directory=directory)
+            _posture_gauge(0.0)
+            return out
         with _probe_lock:
             recorded = load_verdict(directory, token) \
                 or _PROC_VERDICTS.get(token)
@@ -447,10 +466,12 @@ def main(argv=None):
         return 0
     directory = argv[0]
     runs = int(argv[1]) if len(argv) > 1 else None
+    # probe FIRST: reading the fingerprint initializes this process's
+    # backend, and on a one-client chip the child could then not attach
+    verdict = run_probe(runs=runs)
     from .store import backend_fingerprint
     fp = backend_fingerprint()
     token = fingerprint_token(fp)
-    verdict = run_probe(runs=runs)
     verdict['fingerprint'] = fp
     record_verdict(directory, token, verdict)
     print(json.dumps({'token': token, **verdict}, indent=1, default=str))

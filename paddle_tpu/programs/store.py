@@ -16,25 +16,28 @@ replicas of one model compile the decode block once), and — when a
 store directory is configured — persists each executable so the next
 process *loads* instead of compiling.
 
-Persistence is two complementary layers under one store directory:
+Persistence is two complementary layers:
 
   'stablehlo'   `jax.export` bytes (the serialization `jit.save` already
                 uses) — removes Python tracing from the restart path.
                 The cold path compiles THROUGH the exported program
                 (`jax.jit(exported.call)`, donation re-applied), so the
                 cold and warm processes compile the identical module.
-  <dir>/xla     jax's persistent compilation cache, pointed inside the
-                store directory — serves the compiled executable BYTES
-                on the warm path, so re-compiling the deserialized
-                module is a cache read, not an XLA compile. The
-                warm-restart tier-1 guard asserts every
+  compile cache jax's persistent compilation cache, at the directory
+                `ensure_compile_cache` resolves
+                (`JAX_COMPILATION_CACHE_DIR`, else
+                `<checkout>/.jax_cache`) — serves the compiled
+                executable BYTES on the warm path, so re-compiling the
+                deserialized module is a cache read, not an XLA
+                compile. The warm-restart tier-1 guard asserts every
                 `paddle_jit_compiles_total` tick in the warm window is
                 matched by a `paddle_jit_cache_hits_total` tick (zero
                 real compiles).
 
 (`jax.experimental.serialize_executable` — pickling the PjRt executable
 itself — was evaluated first and rejected: deserialized donated
-executables intermittently corrupt the heap on this jaxlib. The
+executables intermittently corrupted the heap on jaxlib 0.4.36, the
+runtime it was evaluated on. The
 export+cache pair reaches the same zero-compile warm restart through
 two independently hardened upstream paths.)
 
@@ -226,14 +229,11 @@ def _leaf_sig(leaf):
 def _mesh_token() -> str:
     """Active fleet mesh topology (axis names/sizes), part of the key so
     re-meshed programs never collide with their pre-resize ancestors."""
-    try:
-        from ..distributed import fleet
-        mesh = fleet.get_mesh()
-        if mesh is None:
-            return ''
-        return repr(tuple(zip(mesh.axis_names, mesh.devices.shape)))
-    except Exception:  # paddle-lint: disable=swallowed-exception -- mesh token probe; empty token means no mesh
+    from ..distributed import env
+    if not env.has_mesh():
         return ''
+    mesh = env.get_mesh(auto_init=False)
+    return repr(tuple(zip(mesh.axis_names, mesh.devices.shape)))
 
 
 def store_key(name: str, fn_token: str, statics_token: str, args) -> str:
@@ -284,9 +284,10 @@ def _compile_exported(exported, donate_argnums=(), donated=False):
     read, not an XLA compile.
 
     Donation: re-applying `donate_argnums` on the wrapper jit here is
-    the exact operation that intermittently corrupts the heap on jaxlib
-    0.4.36 (PR 8's fault-injection gauntlet: segfaults/garbage losses
-    ~50% of runs; stable 12/12 without) — so it happens ONLY when the
+    the exact operation that intermittently corrupted the heap on
+    jaxlib 0.4.36 (PR 8's fault-injection gauntlet on the runtime of
+    that time: segfaults/garbage losses ~50% of runs; stable 12/12
+    without) — so it happens ONLY when the
     donation gauntlet classified the installed runtime 'safe'
     (`donated=True`, probe-verified or operator-forced, and sentinel-
     guarded by the caller for its first K invocations). Otherwise the
@@ -394,33 +395,27 @@ class ProgramStore:
     def configure(self, directory: Optional[str]):
         """Point the store at a directory ('' / None disables the
         persistent tier; the in-memory tier is unaffected). Enabling
-        also points jax's persistent compilation cache at
-        `<directory>/xla` — the second half of the warm-restart path:
-        our manifests carry the traced program, the XLA cache carries
-        its compiled bytes."""
+        also turns on jax's persistent compilation cache — the second
+        half of the warm-restart path: our manifests carry the traced
+        program, the XLA cache carries its compiled bytes. WHERE that
+        cache lives is `ensure_compile_cache`'s decision, never the
+        store's: a store directory that moves must not move (and so
+        empty) the compile cache."""
         self._dir = directory if directory else ''
-        try:
-            if directory:
-                os.makedirs(directory, exist_ok=True)
-                jax.config.update('jax_compilation_cache_dir',
-                                  os.path.join(directory, 'xla'))
-                # cache every program, however small/fast: the
-                # zero-compile warm guard covers incidental converts too
-                jax.config.update(
-                    'jax_persistent_cache_min_compile_time_secs', 0.0)
-                jax.config.update(
-                    'jax_persistent_cache_min_entry_size_bytes', 0)
-            else:
-                jax.config.update('jax_compilation_cache_dir', None)
-            # jax memoizes "is the cache used" at the FIRST compile of
-            # the process — a store configured after any compile would
-            # silently never cache. Reset so the next compile re-reads
-            # the (re)configured directory.
-            from jax._src import compilation_cache as _cc
-            _cc.reset_cache()
-        except Exception:  # paddle-lint: disable=swallowed-exception -- older jax without cc reset knobs still gets the stablehlo tier
-            pass   # an older jax without these knobs still gets the
-            # stablehlo tier (warm restarts then skip tracing only)
+        if directory:
+            os.makedirs(directory, exist_ok=True)
+            ensure_compile_cache()
+            # cache every program, however small/fast: the zero-compile
+            # warm guard covers incidental converts too
+            jax.config.update(
+                'jax_persistent_cache_min_compile_time_secs', 0.0)
+            jax.config.update(
+                'jax_persistent_cache_min_entry_size_bytes', 0)
+        else:
+            # no persistent tier, no reason to write every tiny program
+            # to the compile cache: jax's own threshold (1 s) again
+            jax.config.update(
+                'jax_persistent_cache_min_compile_time_secs', 1.0)
         self._resolve_donation()
         return self
 
@@ -1141,6 +1136,40 @@ class StoredJit:
         return getattr(self._fn, name)
 
 
+def compile_cache_dir() -> str:
+    """Where jax's persistent compilation cache lives for this process:
+    `JAX_COMPILATION_CACHE_DIR` when the environment sets it (the
+    operator, a test harness or an orchestrating parent placed the
+    cache from outside), otherwise `<checkout>/.jax_cache` — a FIXED,
+    git-ignored path: a cache directory that moves from run to run
+    (a mkdtemp store, say) never hits."""
+    env = os.environ.get('JAX_COMPILATION_CACHE_DIR')
+    if env:
+        return env
+    checkout = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    return os.path.join(checkout, '.jax_cache')
+
+
+def ensure_compile_cache() -> str:
+    """THE one place that enables jax's persistent compilation cache;
+    every process that starts on the chip (chip_smoke children,
+    serving/replica_main, `bench.py --phase`) and `ProgramStore
+    .configure` go through it. When `JAX_COMPILATION_CACHE_DIR` is set
+    jax already read it at import and this function changes nothing —
+    no code sets another directory. Returns the directory in use."""
+    path = compile_cache_dir()
+    if jax.config.jax_compilation_cache_dir != path:
+        jax.config.update('jax_compilation_cache_dir', path)
+        # jax memoizes "is the cache used" at the FIRST compile of the
+        # process — enabling it after any compile would silently never
+        # cache. Reset so the next compile re-reads the directory.
+        from jax.experimental.compilation_cache import (
+            compilation_cache as _cc)
+        _cc.reset_cache()
+    return path
+
+
 _store: Optional[ProgramStore] = None
 _store_lock = _concurrency.Lock('store._store_lock')
 
@@ -1152,7 +1181,7 @@ def get_store() -> ProgramStore:
             _store = ProgramStore()
             d = _store.directory
             if d:   # flag/env-configured: engage the full persistent
-                _store.configure(d)   # tier incl. the XLA cache dir
+                _store.configure(d)   # tier incl. the compile cache
         return _store
 
 

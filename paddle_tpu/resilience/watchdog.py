@@ -1,5 +1,6 @@
 """Step watchdog: detect hung steps (deadlocked collective, wedged
-host callback, dead RPC tunnel) that neither raise nor return.
+host callback, a device that stopped answering) that neither raise nor
+return.
 
 A hang is the failure mode retries and NaN checks cannot see — the step
 simply never comes back. The watchdog is a daemon thread holding a

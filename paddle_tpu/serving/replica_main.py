@@ -8,7 +8,8 @@ fleet_proc tier-1 guard measures):
 
 1. `programs.configure(<store dir>)` BEFORE the engine is built, so
    every serving program loads from the ProgramStore persistent tier
-   (StableHLO + the XLA persistent cache) — a new process LOADS, it
+   (StableHLO + jax's persistent compile cache, which
+   `programs.ensure_compile_cache` places) — a new process LOADS, it
    never compiles. Ready-marks of `paddle_jit_compiles_total` /
    `paddle_jit_cache_hits_total` are snapshotted once startup settles
    and shipped in `stats`, so the parent can assert the serving
@@ -337,8 +338,11 @@ def main(argv=None) -> int:
     # program store FIRST: the engine's build-time preload must hit the
     # persistent tier, not the compiler
     try:
+        from .. import programs
+        # one compile cache for every replica process, placed by
+        # JAX_COMPILATION_CACHE_DIR or in-checkout — never per store dir
+        programs.ensure_compile_cache()
         if opts.program_store:
-            from .. import programs
             programs.configure(opts.program_store)
         model = factory(**model_kwargs)
         model.eval()

@@ -141,6 +141,27 @@ class TestPostureResolution:
         assert st['posture'] == 'off'
         assert 'no persistent store' in st['reason']
 
+    def test_auto_on_a_tpu_parent_never_spawns(self, tmp_path, monkeypatch):
+        """A chip has one client: from the process that holds it the
+        probe child could never attach (it used to time out after 180 s
+        and record a false `corrupting`). No spawn, no verdict file,
+        undonated — and loud about it."""
+        monkeypatch.setattr(
+            donation, 'run_probe',
+            lambda *a, **k: pytest.fail('probe spawned from a TPU parent'))
+        pflags.set_flags({'FLAGS_donation': 'auto'})
+        donation.clear_cache()
+        d = str(tmp_path / 'store')
+        fp = dict(programs.backend_fingerprint(), backend='tpu')
+        with pytest.warns(RuntimeWarning, match='no verdict'):
+            posture = donation.resolve_posture(d, fp)
+        assert not posture['enabled'] and posture['verdict'] is None
+        assert posture['source'] == 'no_verdict'
+        assert f'python -m paddle_tpu.programs.donation {d}' \
+            in posture['reason']
+        assert not os.path.exists(d)
+        assert 'donation_no_verdict' in _event_names()
+
     def test_auto_safe_probe_enables_and_records_manifest(self, tmp_path):
         os.environ['PADDLE_DONATION_PROBE_MODE'] = 'ok'
         pflags.set_flags({'FLAGS_donation': 'auto'})

@@ -503,31 +503,132 @@ class TestFitElastic:
 
 
 # ---------------------------------------------------------------------------
-# satellite: bench.py device-probe CPU fallback (regression for BENCH_r05)
+# bench.py measures a device or fails: no CPU stand-in under a green exit
 # ---------------------------------------------------------------------------
 
-def test_bench_probe_timeout_falls_back_to_cpu_phases(tmp_path):
-    """`python bench.py` with a hanging device probe must exit 0 and
-    still produce CPU-phase metrics (BENCH_r05 died with rc=1 and
-    `bench_unavailable`)."""
+def test_bench_probe_timeout_is_a_nonzero_exit():
+    """`python bench.py` with a hanging device probe must exit non-zero
+    and print no result (it used to degrade to CPU phases and exit 0 —
+    a record that reads as a device's and is not)."""
     env_vars = dict(os.environ)
     env_vars.update({
         'BENCH_TEST_PROBE_HANG': '1',   # the probe subprocess wedges
-        'BENCH_PROBE_TIMEOUT': '3',     # bounded: fall back after 3s
-        'BENCH_CPU_PHASES': 'eager',    # one fast phase keeps tier-1 fast
+        'BENCH_PROBE_TIMEOUT': '3',     # bounded: give up after 3s
         'JAX_PLATFORMS': 'cpu',
     })
     bench_path = os.path.join(os.path.dirname(__file__), '..', 'bench.py')
     proc = subprocess.run([sys.executable, bench_path],
-                          capture_output=True, text=True, timeout=300,
+                          capture_output=True, text=True, timeout=120,
                           env=env_vars)
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    out = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert out['device_probe'] == 'failed_cpu_fallback'
-    assert out['probe_error'] == 'timeout'
-    # CPU-phase metrics actually present
-    assert 'eager_dispatch' in out
-    assert out['eager_dispatch']['cached']['steps_per_sec'] > 0
+    assert proc.returncode != 0, proc.stdout[-2000:]
+    assert not proc.stdout.strip(), proc.stdout[-2000:]
+    assert 'device probe failed' in proc.stderr
+    assert 'timeout' in proc.stderr
+
+
+def test_bench_unknown_device_kind_is_an_error(monkeypatch):
+    """An MFU needs the device's own peak: a device_kind missing from
+    the peaks table raises, it is not assumed to be a v5e."""
+    import importlib.util
+    import types
+    spec = importlib.util.spec_from_file_location(
+        'bench', os.path.join(os.path.dirname(__file__), '..', 'bench.py'))
+    bench = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(bench)
+    monkeypatch.delenv('PADDLE_PEAK_FLOPS', raising=False)
+    assert bench._peak_flops(
+        types.SimpleNamespace(device_kind='TPU v5 lite')) == 197e12
+    with pytest.raises(RuntimeError, match='mystery-chip'):
+        bench._peak_flops(types.SimpleNamespace(device_kind='mystery-chip'))
+
+
+# ---------------------------------------------------------------------------
+# chip_smoke.py: fails without a TPU, fails when a phase fails, rehearses
+# ---------------------------------------------------------------------------
+
+_ROOT = os.path.join(os.path.dirname(__file__), '..')
+
+
+def test_chip_smoke_without_a_tpu_fails_and_parent_stays_off_jax():
+    """No flag on a CPU: non-zero exit, the reason named, no result on
+    stdout — and the orchestrating parent has imported neither jax nor
+    paddle_tpu (a parent that touched JAX holds the chip)."""
+    code = ('import sys, chip_smoke\n'
+            'rc = chip_smoke.main([])\n'
+            'held = [m for m in ("jax", "jaxlib", "paddle_tpu", "bench") '
+            'if m in sys.modules]\n'
+            'print("PARENT_IMPORTED", held, file=sys.stderr)\n'
+            'sys.exit(rc)\n')
+    proc = subprocess.run([sys.executable, '-c', code], cwd=_ROOT,
+                          capture_output=True, text=True, timeout=120,
+                          env=dict(os.environ, JAX_PLATFORMS='cpu'))
+    assert proc.returncode != 0
+    assert not proc.stdout.strip(), proc.stdout[-2000:]
+    assert 'no TPU found' in proc.stderr, proc.stderr[-2000:]
+    assert 'PARENT_IMPORTED []' in proc.stderr, proc.stderr[-2000:]
+
+
+def test_chip_smoke_fails_when_any_single_phase_fails(monkeypatch, capsys,
+                                                      tmp_path):
+    """The parent's verdict: every phase ok -> exit 0 and the contract
+    line last; any one phase failing -> non-zero and no result."""
+    sys.path.insert(0, _ROOT)
+    try:
+        import chip_smoke
+    finally:
+        sys.path.remove(_ROOT)
+    probe = {'ok': True, 'platform': 'tpu', 'device_kind': 'TPU v5 lite',
+             'device_count': 1, 'jax': 'x', 'jaxlib': 'x', 'libtpu': 'x',
+             'python': 'x', 'wall_s': 0.1}
+
+    def run(results):
+        monkeypatch.setattr(
+            chip_smoke, '_run_child',
+            lambda phase, rehearse, timeout_s, env: dict(results[phase]))
+        rc = chip_smoke.main([])
+        return rc, capsys.readouterr()
+
+    monkeypatch.setattr(chip_smoke, 'HERE', str(tmp_path))
+    ok = {'ok': True, 'wall_s': 1.0}
+    rc, io = run({'probe': probe, 'train1': ok, 'serve1': ok})
+    assert rc == 0
+    lines = io.out.strip().splitlines()
+    assert json.loads(lines[-1]) == {'ok': True, 'device': {
+        'platform': 'tpu', 'kind': 'TPU v5 lite', 'count': 1}}
+    summary = json.loads(lines[-2])
+    assert summary['phases']['fleet4'] == 'not run (1 device)'
+    for bad in ('train1', 'serve1'):
+        results = {'probe': probe, 'train1': ok, 'serve1': ok,
+                   bad: {'ok': False, 'error': 'boom', 'wall_s': 1.0}}
+        rc, io = run(results)
+        assert rc != 0 and not io.out.strip(), (bad, io.out)
+        assert f'FAILED {bad}: boom' in io.err
+    rc, io = run({'probe': {'ok': False, 'error': 'no backend',
+                            'wall_s': 0.1}})
+    assert rc != 0 and not io.out.strip()
+
+
+def test_chip_smoke_cpu_rehearsal_runs_every_phase():
+    """`--rehearse-cpu`: all three phases at toy size through the same
+    code (kernels interpreted, 8-device CPU mesh for fleet4), and the
+    output says platform: cpu so it cannot be read as a chip record."""
+    proc = subprocess.run(
+        [sys.executable, 'chip_smoke.py', '--rehearse-cpu'], cwd=_ROOT,
+        capture_output=True, text=True, timeout=600, env=dict(os.environ))
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    lines = proc.stdout.strip().splitlines()
+    assert 'platform: cpu' in lines
+    last = json.loads(lines[-1])
+    assert last['ok'] and last['device']['platform'] == 'cpu'
+    summary = json.loads(lines[-2])
+    assert summary['rehearsal'] is True
+    ph = summary['phases']
+    assert all(ph[p]['ok'] for p in ('train1', 'serve1', 'fleet4')), ph
+    assert ph['train1']['losses'][-1] < ph['train1']['losses'][0]
+    assert 'interpreted' in ph['train1']['mosaic_calls']
+    assert ph['serve1']['row']['compiles_after_warmup'] == 0
+    assert ph['serve1']['paged']['compiles_after_warmup'] == 0
+    assert ph['fleet4']['mesh'] == {'pp': 1, 'dp': 4, 'sp': 1, 'mp': 2}
 
 
 # ---------------------------------------------------------------------------

@@ -344,3 +344,55 @@ class TestAdapterMatmul:
         xh = x.astype(jnp.bfloat16)
         out = pk.adapter_matmul(xh, a, b, rows, scale, interpret=True)
         assert out.dtype == jnp.bfloat16
+
+
+class TestNoSilentFallback:
+    """On a TPU a kernel error is an error: with the gate forced on and
+    a kernel made to raise, the caller sees the exception — the XLA path
+    is chosen by the explicit shape conditions only."""
+
+    @pytest.fixture
+    def gate_on(self, monkeypatch):
+        from paddle_tpu.ops import pallas
+        monkeypatch.setattr(pallas, '_pallas_enabled', lambda: True)
+        monkeypatch.setattr(pallas, 'pallas_ce_enabled', lambda: True)
+        return pallas
+
+    def test_flash_kernel_error_reaches_the_caller(self, gate_on,
+                                                   monkeypatch):
+        import paddle_tpu as paddle
+        import paddle_tpu.nn.functional as F
+        from paddle_tpu.ops import pallas_kernels as pk
+
+        def boom(*a, **k):
+            raise RuntimeError('mosaic says no')
+        monkeypatch.setattr(pk, 'flash_attention', boom)
+        q, k, v = (paddle.to_tensor(np.asarray(t)) for t in _qkv(sq=128,
+                                                                 sk=128))
+        with pytest.raises(RuntimeError, match='mosaic says no'):
+            F.scaled_dot_product_attention(q, k, v, is_causal=True)
+        # a shape the kernel does not cover is XLA's by the conditions
+        q2, k2, v2 = (paddle.to_tensor(np.asarray(t))
+                      for t in _qkv(sq=100, sk=100))
+        out = F.scaled_dot_product_attention(q2, k2, v2, is_causal=True)
+        assert np.isfinite(out.numpy()).all()
+
+    def test_fused_ce_kernel_error_reaches_the_caller(self, gate_on,
+                                                      monkeypatch):
+        import paddle_tpu as paddle
+        import paddle_tpu.nn.functional as F
+        from paddle_tpu.ops import pallas_kernels as pk
+
+        def boom(*a, **k):
+            raise RuntimeError('mosaic says no')
+        monkeypatch.setattr(pk, 'softmax_cross_entropy', boom)
+        rng = np.random.default_rng(0)
+        logits = paddle.to_tensor(
+            rng.standard_normal((4, 8192)).astype(np.float32))
+        labels = paddle.to_tensor(rng.integers(0, 8192, (4,)))
+        with pytest.raises(RuntimeError, match='mosaic says no'):
+            F.cross_entropy(logits, labels)
+        small = paddle.to_tensor(
+            rng.standard_normal((4, 512)).astype(np.float32))
+        assert np.isfinite(float(F.cross_entropy(
+            small, paddle.to_tensor(rng.integers(0, 512, (4,)))).numpy()))

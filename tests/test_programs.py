@@ -157,6 +157,31 @@ class TestRoundTrip:
         assert st['misses'] == misses, 'sibling wrapper recompiled'
         assert st['hits_memory'] >= 1
 
+    def test_compile_cache_is_placed_from_outside(self, pstore, tmp_path,
+                                                  monkeypatch):
+        """With JAX_COMPILATION_CACHE_DIR set (conftest sets it) no code
+        path repoints jax's cache — not configure(dir), not
+        configure(None); unset, it resolves to <checkout>/.jax_cache."""
+        env_dir = os.environ['JAX_COMPILATION_CACHE_DIR']
+        assert jax.config.jax_compilation_cache_dir == env_dir
+        pstore.configure(str(tmp_path / 'elsewhere'))
+        assert jax.config.jax_compilation_cache_dir == env_dir
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+        pstore.configure(None)
+        assert jax.config.jax_compilation_cache_dir == env_dir
+        assert programs.ensure_compile_cache() == env_dir
+        monkeypatch.delenv('JAX_COMPILATION_CACHE_DIR')
+        checkout = os.path.dirname(os.path.dirname(
+            os.path.abspath(paddle.__file__)))
+        in_checkout = os.path.join(checkout, '.jax_cache')
+        assert programs.compile_cache_dir() == in_checkout
+        try:
+            assert programs.ensure_compile_cache() == in_checkout
+            assert jax.config.jax_compilation_cache_dir == in_checkout
+        finally:   # back to the session's cache before anything compiles
+            monkeypatch.setenv('JAX_COMPILATION_CACHE_DIR', env_dir)
+            assert programs.ensure_compile_cache() == env_dir
+
     def test_store_without_directory_writes_nothing(self, pstore):
         d = pstore.directory
         pstore.configure(None)
