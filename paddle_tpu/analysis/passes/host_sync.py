@@ -29,6 +29,7 @@ HOT_SCOPES = {
     'paddle_tpu/serving/engine.py': (
         'InferenceEngine.step', 'InferenceEngine.run',
         'InferenceEngine._decode_round', 'InferenceEngine._spec_round',
+        'InferenceEngine._emit_round',
         'InferenceEngine._admit', 'InferenceEngine._begin_request',
         'InferenceEngine._whole_prefill', 'InferenceEngine._advance_prefills',
         'InferenceEngine._prefill_chunk', 'InferenceEngine._activate',

@@ -712,22 +712,24 @@ class DistTrainStep:
                         'bytes of batch data sharded onto the mesh').inc(
                             batch_bytes)
         with _obs.span('fleet.dist_train_step', step=self._n_calls - 1):
-            if self.retry_policy is not None:
-                from ..resilience.retry import call_with_retry
-                loss, new_params, self._opt_state, new_bufs = \
-                    call_with_retry(self._jitted, *args,
-                                    policy=self.retry_policy,
-                                    site='dist_step')
-            else:
-                loss, new_params, self._opt_state, new_bufs = \
-                    self._jitted(*args)
-        pmap = dict(self.layer.named_parameters())
-        for n, v in new_params.items():
-            pmap[n]._data = v
-            pmap[n]._node = None
-        bmap = dict(self.layer.named_buffers())
-        for n, v in new_bufs.items():
-            bmap[n]._data = v
+            with _obs.span('train.dispatch'):
+                if self.retry_policy is not None:
+                    from ..resilience.retry import call_with_retry
+                    loss, new_params, self._opt_state, new_bufs = \
+                        call_with_retry(self._jitted, *args,
+                                        policy=self.retry_policy,
+                                        site='dist_step')
+                else:
+                    loss, new_params, self._opt_state, new_bufs = \
+                        self._jitted(*args)
+        with _obs.span('train.writeback'):
+            pmap = dict(self.layer.named_parameters())
+            for n, v in new_params.items():
+                pmap[n]._data = v
+                pmap[n]._node = None
+            bmap = dict(self.layer.named_buffers())
+            for n, v in new_bufs.items():
+                bmap[n]._data = v
         return Tensor(loss)
 
 

@@ -356,32 +356,37 @@ class TrainStep:
         # call: the trace/compile inside is re-attributed to `compile`
         # by the ledger's nested-interval subtraction)
         with _obs.span('train.step'):
-            params, frozen, buffers = functional_state(self.layer)
-            if self._opt_state is None and not self._offload:
-                self._opt_state = self.optimizer.init_state(params)
-            key = jax.random.fold_in(self._step_key_root, self._n_calls)
-            self._n_calls += 1
-            lr = jnp.asarray(self.optimizer.get_lr(), jnp.float32)
-            batch = self._as_batch(inputs, labels)
-            if self._offload:
-                if self._opt_state is None:
-                    self._opt_state = self._engine.init_state(params)
-                loss, grads, new_bufs = self._jitted_grads(
-                    params, buffers, frozen, key, batch)
-                new_params, self._opt_state = self._engine.apply(
-                    grads, params, self._opt_state, lr)
-            else:
-                loss, new_params, self._opt_state, new_bufs = self._jitted(
-                    params, self._opt_state, buffers, frozen, key, lr,
-                    batch)
-            # write back into the live Layer
-            pmap = dict(self.layer.named_parameters())
-            for n, v in new_params.items():
-                pmap[n]._data = v
-                pmap[n]._node = None
-            bmap = dict(self.layer.named_buffers())
-            for n, v in new_bufs.items():
-                bmap[n]._data = v
+            # train.dispatch: gathering the state and the jitted call,
+            # until it returns (the device runs on); train.writeback:
+            # handing the new arrays to the live Layer
+            with _obs.span('train.dispatch'):
+                params, frozen, buffers = functional_state(self.layer)
+                if self._opt_state is None and not self._offload:
+                    self._opt_state = self.optimizer.init_state(params)
+                key = jax.random.fold_in(self._step_key_root,
+                                         self._n_calls)
+                self._n_calls += 1
+                lr = jnp.asarray(self.optimizer.get_lr(), jnp.float32)
+                batch = self._as_batch(inputs, labels)
+                if self._offload:
+                    if self._opt_state is None:
+                        self._opt_state = self._engine.init_state(params)
+                    loss, grads, new_bufs = self._jitted_grads(
+                        params, buffers, frozen, key, batch)
+                    new_params, self._opt_state = self._engine.apply(
+                        grads, params, self._opt_state, lr)
+                else:
+                    loss, new_params, self._opt_state, new_bufs = \
+                        self._jitted(params, self._opt_state, buffers,
+                                     frozen, key, lr, batch)
+            with _obs.span('train.writeback'):
+                pmap = dict(self.layer.named_parameters())
+                for n, v in new_params.items():
+                    pmap[n]._data = v
+                    pmap[n]._node = None
+                bmap = dict(self.layer.named_buffers())
+                for n, v in new_bufs.items():
+                    bmap[n]._data = v
             return Tensor(loss)
 
 

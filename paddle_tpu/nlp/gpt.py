@@ -104,8 +104,9 @@ class GPTAttention(Layer):
                 q, k, v, attn_mask=attn_mask, is_causal=True,
                 dropout_p=self.dropout_p, training=self.training)
         else:
-            k_cache, v_cache = _update_kv_cache(cache[0], cache[1], k, v,
-                                                slot)
+            with jax.named_scope('kv_write'):
+                k_cache, v_cache = _update_kv_cache(cache[0], cache[1],
+                                                    k, v, slot)
             mask = attn_mask if attn_mask is not None \
                 else _decode_mask(q, k_cache, slot)
             out = F.scaled_dot_product_attention(q, k_cache, v_cache,
@@ -133,16 +134,24 @@ class GPTDecoderLayer(Layer):
 
     def forward(self, hidden, position_offset=None, attn_mask=None,
                 cache=None, cache_offset=None):
+        # the named scopes are the device trace's vocabulary
+        # (programs.scopes.SCOPES): every op's `op_name` carries them
         residual = hidden
-        out = self.attn(self.norm1(hidden), position_offset=position_offset,
-                        attn_mask=attn_mask, cache=cache,
-                        cache_offset=cache_offset)
+        with jax.named_scope('norm'):
+            normed = self.norm1(hidden)
+        with jax.named_scope('attention'):
+            out = self.attn(normed, position_offset=position_offset,
+                            attn_mask=attn_mask, cache=cache,
+                            cache_offset=cache_offset)
         new_cache = None
         if cache is not None:
             out, new_cache = out
         h = residual + self.dropout(out)
-        h = h + self.dropout(self.linear2(self.act(self.linear1(
-            self.norm2(h)))))
+        with jax.named_scope('norm'):
+            normed = self.norm2(h)
+        with jax.named_scope('mlp'):
+            h = h + self.dropout(self.linear2(self.act(self.linear1(
+                normed))))
         if cache is not None:
             return h, new_cache
         return h
@@ -176,15 +185,17 @@ class GPTModel(Layer):
         pos = apply_op(
             lambda iv: jnp.clip(_offset_grid(offset, iv.shape[1]), 0, None),
             ids, _name='positions')
-        h = self.word_embeddings(ids) + self.position_embeddings(pos)
-        h = self.embed_dropout(h)
+        with jax.named_scope('embed'):
+            h = self.word_embeddings(ids) + self.position_embeddings(pos)
+            h = self.embed_dropout(h)
         if blocks_fn is not None:
             # pipeline-parallel path — see LlamaModel.forward
             if attention_mask is not None or cache is not None:
                 raise ValueError('blocks_fn (pipeline) path supports only '
                                  'full-length causal batches')
             h = apply_op(blocks_fn, h, _name='pp_blocks')
-            return self.final_norm(h)
+            with jax.named_scope('norm'):
+                return self.final_norm(h)
         mask = attention_mask
         if mask is not None and not isinstance(mask, Tensor):
             mask = Tensor(to_jax(mask))
@@ -206,7 +217,8 @@ class GPTModel(Layer):
                 new_caches.append(c)
             else:
                 h = out
-        h = self.final_norm(h)
+        with jax.named_scope('norm'):
+            h = self.final_norm(h)
         if use_cache:
             return h, tuple(new_caches)
         return h
@@ -235,10 +247,12 @@ class GPTForCausalLM(Layer, GenerationMixin):
                                   bias_attr=False)
 
     def _logits(self, h):
-        if self.lm_head is not None:
-            return self.lm_head(h)
-        w = self.gpt.word_embeddings.weight
-        return apply_op(lambda hv, wv: hv @ wv.T, h, w, _name='tied_lm_head')
+        with jax.named_scope('lm_head'):
+            if self.lm_head is not None:
+                return self.lm_head(h)
+            w = self.gpt.word_embeddings.weight
+            return apply_op(lambda hv, wv: hv @ wv.T, h, w,
+                            _name='tied_lm_head')
 
     def pp_blocks(self):
         """Pipeline-parallel protocol — see LlamaForCausalLM.pp_blocks."""

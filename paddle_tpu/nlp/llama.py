@@ -185,8 +185,9 @@ class LlamaAttention(Layer):
             out = F.scaled_dot_product_attention(q, k, v, attn_mask=attn_mask,
                                                  is_causal=True)
         else:
-            k_cache, v_cache = _update_kv_cache(cache[0], cache[1], k, v,
-                                                slot)
+            with jax.named_scope('kv_write'):
+                k_cache, v_cache = _update_kv_cache(cache[0], cache[1],
+                                                    k, v, slot)
             # a caller-built mask (padded-prompt decode) wins over the
             # default slot-causal one
             mask = attn_mask if attn_mask is not None \
@@ -226,16 +227,23 @@ class LlamaDecoderLayer(Layer):
 
     def forward(self, hidden, position_offset=None, attn_mask=None,
                 cache=None, cache_offset=None):
+        # the named scopes are the device trace's vocabulary
+        # (programs.scopes.SCOPES): every op's `op_name` carries them
         residual = hidden
-        h = self.input_layernorm(hidden)
-        attn_out = self.self_attn(h, position_offset=position_offset,
-                                  attn_mask=attn_mask, cache=cache,
-                                  cache_offset=cache_offset)
+        with jax.named_scope('norm'):
+            h = self.input_layernorm(hidden)
+        with jax.named_scope('attention'):
+            attn_out = self.self_attn(
+                h, position_offset=position_offset, attn_mask=attn_mask,
+                cache=cache, cache_offset=cache_offset)
         new_cache = None
         if cache is not None:
             attn_out, new_cache = attn_out
         h = residual + attn_out
-        h = h + self.mlp(self.post_attention_layernorm(h))
+        with jax.named_scope('norm'):
+            normed = self.post_attention_layernorm(h)
+        with jax.named_scope('mlp'):
+            h = h + self.mlp(normed)
         if cache is not None:
             return h, new_cache
         return h
@@ -271,7 +279,8 @@ class LlamaModel(LlamaPretrainedModel):
                 cache_offset=None):
         ids = input_ids if isinstance(input_ids, Tensor) \
             else Tensor(to_jax(input_ids))
-        h = self.embed_tokens(ids)
+        with jax.named_scope('embed'):
+            h = self.embed_tokens(ids)
         if blocks_fn is not None:
             # pipeline-parallel path (fleet.DistTrainStep pp): the decoder
             # stack is replaced by a scheduled collective program; embed
@@ -282,7 +291,8 @@ class LlamaModel(LlamaPretrainedModel):
                                  'full-length causal batches from position '
                                  '0 (mask/cache/offset unsupported)')
             h = apply_op(blocks_fn, h, _name='pp_blocks')
-            return self.norm(h)
+            with jax.named_scope('norm'):
+                return self.norm(h)
         sp_pin = None
         if self.config.sequence_parallel:
             # keep activations sequence-sharded over 'sp' between blocks;
@@ -340,7 +350,8 @@ class LlamaModel(LlamaPretrainedModel):
                 h = out
             if sp_pin is not None:
                 h = sp_pin(h)
-        h = self.norm(h)
+        with jax.named_scope('norm'):
+            h = self.norm(h)
         if use_cache:
             return h, tuple(new_caches)
         return h
@@ -367,10 +378,12 @@ class LlamaForCausalLM(LlamaPretrainedModel, GenerationMixin):
                                   bias_attr=False)
 
     def _logits(self, h):
-        if self.lm_head is not None:
-            return self.lm_head(h)
-        w = self.llama.embed_tokens.weight
-        return apply_op(lambda hv, wv: hv @ wv.T, h, w, _name='tied_lm_head')
+        with jax.named_scope('lm_head'):
+            if self.lm_head is not None:
+                return self.lm_head(h)
+            w = self.llama.embed_tokens.weight
+            return apply_op(lambda hv, wv: hv @ wv.T, h, w,
+                            _name='tied_lm_head')
 
     def pp_blocks(self):
         """Pipeline-parallel protocol (consumed by fleet.DistTrainStep):

@@ -1121,7 +1121,8 @@ def cross_entropy(input, label, weight=None, ignore_index=-100,
             return jnp.sum(per) / denom
         return _reduce(per, reduction)
     args = (input, label) if weight is None else (input, label, weight)
-    return defop(f, name='cross_entropy')(*args)
+    with jax.named_scope('loss'):
+        return defop(f, name='cross_entropy')(*args)
 
 
 def softmax_with_cross_entropy(logits, label, soft_label=False,

@@ -32,7 +32,7 @@ from .metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                       count_suppressed, enable, enabled, disable,
                       get_registry, merge_snapshots)
 from .events import (EVENT_SCHEMA, EventLog, Span, declare_event, emit,
-                     get_event_log, span)
+                     get_event_log, record_span, span)
 from .exporters import (chrome_track_metadata, fleet_to_prometheus_text,
                         read_jsonl, to_chrome_trace, to_jsonl,
                         to_prometheus_text)
@@ -70,7 +70,7 @@ __all__ = [
     'QUANTILES', 'SlidingWindow',
     'enable', 'enabled', 'disable', 'get_registry', 'merge_snapshots',
     'EVENT_SCHEMA', 'EventLog', 'Span', 'declare_event', 'emit',
-    'get_event_log', 'span',
+    'get_event_log', 'record_span', 'span',
     'read_jsonl', 'to_chrome_trace', 'to_jsonl', 'to_prometheus_text',
     'chrome_track_metadata', 'fleet_to_prometheus_text',
     'WIRE_VERSION', 'WireError', 'decode_segment', 'encode_segment',

@@ -15,10 +15,13 @@ metrics registry. `emit(name, **attrs)` records an instant event (e.g.
 from __future__ import annotations
 
 import collections
+import itertools
 import json
 import threading
 import time
 from typing import Any, Dict, List, Optional
+
+import jax
 
 from . import metrics as _metrics
 from ..analysis.runtime import concurrency as _concurrency
@@ -169,9 +172,12 @@ def declare_event(name: str, help: str = ''):
 
 
 class EventLog:
-    """Bounded, thread-safe ring of structured events (oldest dropped)."""
+    """Bounded, thread-safe ring of structured events (oldest dropped).
+    The default holds a serving run of a minute with room to spare (some
+    11 router steps a second, 10 spans a step), so that a reader of the
+    newest N steps finds all of them."""
 
-    def __init__(self, capacity: int = 8192):
+    def __init__(self, capacity: int = 32768):
         self._events: collections.deque = collections.deque(maxlen=capacity)
         self._lock = _concurrency.Lock('EventLog._lock')
         self._dropped = 0
@@ -279,17 +285,36 @@ def emit(name: str, **attrs):
 
 class _SpanState(threading.local):
     def __init__(self):
-        self.depth = 0
+        self.stack: List[int] = []   # ids of this thread's open spans
 
 
 _span_state = _SpanState()
+_span_ids = itertools.count(1)       # next() is atomic under the GIL
+
+
+def _record(log, name, ts, dur, depth, span_id, parent, attrs):
+    ev = {'name': name, 'ph': 'X', 'ts': ts, 'dur': dur,
+          'tid': threading.get_ident(), 'depth': depth,
+          'id': span_id, 'parent': parent}
+    if attrs:
+        ev['attrs'] = attrs
+    log.append(ev)
+    _metrics.get_registry().histogram(
+        'paddle_span_seconds', 'span(name) wall time',
+        ('name',)).labels(name=name).observe(dur)
 
 
 class Span:
-    """Timed region recorded into the EventLog + span histogram. Nestable;
-    usable as a context manager or via explicit begin()/end()."""
+    """Timed region recorded into the EventLog + span histogram, and —
+    through a `jax.profiler.TraceAnnotation` of the same name — into the
+    host plane of a running profiler trace, on the device ops' clock
+    (the one place in the package that opens one; with no trace running
+    it costs an atomic check). Each span has an `id` and its `parent`,
+    the innermost span open on the thread when it began (0: none);
+    request-scoped spans carry the request's id as the `request_id`
+    attribute. Nestable; a context manager, or explicit begin()/end()."""
 
-    __slots__ = ('name', 'attrs', '_t0', '_log', '_active')
+    __slots__ = ('name', 'attrs', 'id', 'parent', '_t0', '_log', '_ann')
 
     def __init__(self, name: str, _log: Optional[EventLog] = None, **attrs):
         self.name = name
@@ -298,37 +323,59 @@ class Span:
         # (__len__ == 0) and `or` would silently reroute the span to
         # the default log
         self._log = _default_log if _log is None else _log
+        self.id = self.parent = 0
         self._t0 = 0.0
-        self._active = False
+        self._ann = None             # the open annotation: span is active
 
     def begin(self) -> 'Span':
-        self._active = _metrics.enabled()
-        if self._active:
-            _span_state.depth += 1
+        if _metrics.enabled():
+            stack = _span_state.stack
+            self.parent = stack[-1] if stack else 0
+            self.id = next(_span_ids)
+            stack.append(self.id)
+            self._ann = jax.profiler.TraceAnnotation(self.name)
+            self._ann.__enter__()
             self._t0 = _now()
         return self
 
+    def set(self, **attrs):
+        """Counts known only once the work is done (`admitted=2`);
+        scalars, so that a span costs no list or dict of its own."""
+        if self._ann is not None:
+            self.attrs.update(attrs)
+
     def end(self):
-        if not self._active:
+        if self._ann is None:
             return
-        self._active = False
         dur = _now() - self._t0
-        depth = _span_state.depth
-        _span_state.depth -= 1
-        ev = {'name': self.name, 'ph': 'X', 'ts': self._t0, 'dur': dur,
-              'tid': threading.get_ident(), 'depth': depth}
-        if self.attrs:
-            ev['attrs'] = self.attrs
-        self._log.append(ev)
-        _metrics.get_registry().histogram(
-            'paddle_span_seconds', 'span(name) wall time',
-            ('name',)).labels(name=self.name).observe(dur)
+        self._ann.__exit__(None, None, None)
+        self._ann = None
+        stack = _span_state.stack
+        depth = len(stack)
+        if self.id in stack:
+            # a span left open inside this one goes with it
+            del stack[stack.index(self.id):]
+        _record(self._log, self.name, self._t0, dur, depth, self.id,
+                self.parent, self.attrs)
 
     def __enter__(self) -> 'Span':
         return self.begin()
 
     def __exit__(self, *exc):
         self.end()
+
+
+def record_span(name: str, t_begin: float, **attrs):
+    """A region whose two ends lie in different calls (a request's wait
+    in the queue, submit to admission): recorded when it ends, from the
+    `time.perf_counter()` reading taken when it began. It nests in
+    nothing — no parent, depth 0 — and is not put on the profiler's
+    timeline, where it would lie across the spans of the steps it
+    outlasts."""
+    if _metrics.enabled():
+        ts = t_begin - _EPOCH
+        _record(_default_log, name, ts, _now() - ts, 0, next(_span_ids),
+                0, attrs)
 
 
 def span(name: str, **attrs) -> Span:

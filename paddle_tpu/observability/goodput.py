@@ -201,6 +201,13 @@ class GoodputLedger:
         if event.get('ph') == 'X':
             cat = self._map.get(name)
             if cat is None:
+                if event.get('depth') == 1:
+                    # an uncategorised TOP-LEVEL span closed
+                    # (`serving.router_step` around the decode rounds):
+                    # nothing on this thread can overlap what comes
+                    # next, so its bookkeeping goes, as in _attribute
+                    with self._lock:
+                        self._intervals.pop(event.get('tid', 0), None)
                 return
             self._attribute(event.get('tid', 0), float(event['ts']),
                             float(event.get('dur', 0.0)), cat,

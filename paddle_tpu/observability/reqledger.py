@@ -544,6 +544,13 @@ class RequestLedger:
                      threshold_s=round(thr, 4), driver=driver,
                      failovers=summ['failovers'])
 
+    def window_records(self) -> List[Dict[str, Any]]:
+        """The finalized requests' summaries still in the window (at
+        most WINDOW_MAX, oldest first): per request its phases, its TTFT
+        sub-book and `ts`, the submit instant on the span clock."""
+        with self._lock:
+            return list(self._window)
+
     # -- wire plane -----------------------------------------------------------
     def drain_wire_records(self) -> List[Dict[str, Any]]:
         """Hand the finalized-record backlog to the Shipper (each call

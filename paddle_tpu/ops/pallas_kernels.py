@@ -211,6 +211,7 @@ def flash_attention_fwd(q, k, v, causal=False, block_q=128, block_k=128,
             pltpu.VMEM((block_q, d), jnp.float32),     # output accumulator
         ],
         interpret=interpret,
+        name='flash_fwd',
     )(qt, kt, vt)
     if return_lse:
         out, lse = res
@@ -354,6 +355,7 @@ def flash_attention_bwd(q, k, v, out, lse, g, causal=False, block_q=128,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name='flash_bwd_dq',
     )(q, k, v, g, lse, delta)
 
     # dkv sweep: grid iterates k blocks in dim 2, q blocks in dim 3
@@ -376,6 +378,7 @@ def flash_attention_bwd(q, k, v, out, lse, g, causal=False, block_q=128,
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
         interpret=interpret,
+        name='flash_bwd_dkv',
     )(q, k, v, g, lse, delta)
     return dq, dk, dv
 
@@ -449,6 +452,7 @@ def _rms_pallas(x2d, w, eps, block_rows, interpret):
         out_specs=pl.BlockSpec((block_rows, width), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(x2d.shape, x2d.dtype),
         interpret=interpret,
+        name='rms_norm_fwd',
     )(x2d, w)
 
 
@@ -583,6 +587,7 @@ def softmax_cross_entropy_fwd(logits, labels, block_rows=256,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=('parallel', 'arbitrary')),
         interpret=interpret,
+        name='ce_fwd',
     )(labels.astype(jnp.int32).reshape(np_, 1), logits)
     return loss.reshape(np_)[:n], lse.reshape(np_)[:n]
 
@@ -614,6 +619,7 @@ def softmax_cross_entropy_bwd(logits, labels, lse, g, block_rows=256,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=('parallel', 'parallel')),
         interpret=interpret,
+        name='ce_bwd',
     )(labels.astype(jnp.int32).reshape(np_, 1), g.reshape(np_, 1),
       logits, lse.reshape(np_, 1))
     return dx[:n, :v]
@@ -788,6 +794,7 @@ def _paged_attention_pallas(q, k_pages, v_pages, table, lengths, k_scales,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=('parallel', 'parallel', 'arbitrary')),
         interpret=interpret,
+        name='paged_attention',
     )(*args)
     return out.reshape(n, h, d)
 
@@ -883,6 +890,7 @@ def _adapter_matmul_pallas(x, a_bank, b_bank, rows, scale, interpret):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=('arbitrary',)),
         interpret=interpret,
+        name='adapter_matmul',
     )(rows.astype(jnp.int32), scale.astype(jnp.float32), x, a_bank, b_bank)
 
 

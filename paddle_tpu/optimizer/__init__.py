@@ -113,23 +113,28 @@ class Optimizer:
 
     def apply_gradients(self, grads, params, state, lr_value):
         """Pure: (grads, params, state, lr) -> (new_params, new_state).
-        Safe to call under jit; lr_value may be a traced scalar."""
-        if self._grad_clip is not None:
-            grads = self._grad_clip.apply_pytree(grads)
-        step = state['step'] + 1
-        treedef, names, flat_p, flat_g, flat_s = _flatten_for_update(
-            params, grads, state['slots'])
-        new_p, new_s = [], []
-        for g, p, s, nm in zip(flat_g, flat_p, flat_s, names):
-            if g is None:
-                new_p.append(p)
-                new_s.append(s)
-                continue
-            np_, ns_ = self._leaf_apply(g, p, s, lr_value, step, name=nm)
-            new_p.append(np_)
-            new_s.append(ns_)
-        return (_tree.tree_unflatten(treedef, new_p),
-                {'step': step, 'slots': _tree.tree_unflatten(treedef, new_s)})
+        Safe to call under jit; lr_value may be a traced scalar. Traced
+        under the named scope `optimizer`, which is how a device trace
+        tells the update from the forward and backward passes."""
+        with jax.named_scope('optimizer'):
+            if self._grad_clip is not None:
+                grads = self._grad_clip.apply_pytree(grads)
+            step = state['step'] + 1
+            treedef, names, flat_p, flat_g, flat_s = _flatten_for_update(
+                params, grads, state['slots'])
+            new_p, new_s = [], []
+            for g, p, s, nm in zip(flat_g, flat_p, flat_s, names):
+                if g is None:
+                    new_p.append(p)
+                    new_s.append(s)
+                    continue
+                np_, ns_ = self._leaf_apply(g, p, s, lr_value, step,
+                                            name=nm)
+                new_p.append(np_)
+                new_s.append(ns_)
+            return (_tree.tree_unflatten(treedef, new_p),
+                    {'step': step,
+                     'slots': _tree.tree_unflatten(treedef, new_s)})
 
     # -- eager facade -------------------------------------------------------
     def get_lr(self):

@@ -69,27 +69,21 @@ def _host_reset():
 
 
 class RecordEvent:
-    """Named host region, nestable; shows up in summary() and, when a jax
-    trace is active, as a TraceAnnotation on the device timeline. Each
-    occurrence records its actual begin timestamp and duration (exported
-    verbatim by export_chrome_tracing) and, when observability is
-    enabled, lands in the shared EventLog + span histogram too."""
+    """Named host region, nestable; shows up in summary() and, through
+    the one span primitive (`observability.span`), in the shared EventLog,
+    the span histogram and — when a jax trace is active — on the
+    profiler's timeline beside the device ops. Each occurrence records
+    its actual begin timestamp and duration (exported verbatim by
+    export_chrome_tracing)."""
 
     def __init__(self, name: str):
         self.name = name
-        self._jax_ctx = None
         self._t0 = 0.0
         self._span = None
 
     def begin(self):
-        if _obs.enabled():
-            self._span = _obs.span(self.name).begin()
+        self._span = _obs.span(self.name).begin()
         self._t0 = time.perf_counter()
-        try:
-            self._jax_ctx = jax.profiler.TraceAnnotation(self.name)
-            self._jax_ctx.__enter__()
-        except Exception:  # paddle-lint: disable=swallowed-exception -- jax profiler annotation optional; host timing still recorded
-            self._jax_ctx = None
         return self
 
     def end(self):
@@ -98,9 +92,6 @@ class RecordEvent:
             _host.totals[self.name] += dt
             _host.counts[self.name] += 1
             _host.events.append((self.name, self._t0, dt))
-        if self._jax_ctx is not None:
-            self._jax_ctx.__exit__(None, None, None)
-            self._jax_ctx = None
         if self._span is not None:
             self._span.end()
             self._span = None

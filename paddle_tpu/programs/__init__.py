@@ -15,14 +15,15 @@ matching entries at startup (Model.fit and ReplicaSet do this
 automatically when the store is persistent).
 """
 from . import donation
+from .scopes import SCOPES, scope_path, scope_table
 from .store import (ProgramDeserializeError, ProgramStore, StoredJit,
                     backend_fingerprint, code_token, compile_cache_dir,
                     configure, describe_statics, ensure_compile_cache,
                     get_store, store_key)
 
 __all__ = [
-    'ProgramDeserializeError', 'ProgramStore', 'StoredJit',
+    'ProgramDeserializeError', 'ProgramStore', 'SCOPES', 'StoredJit',
     'backend_fingerprint', 'code_token', 'compile_cache_dir', 'configure',
     'describe_statics', 'donation', 'ensure_compile_cache', 'get_store',
-    'store_key',
+    'scope_path', 'scope_table', 'store_key',
 ]

@@ -106,11 +106,6 @@ class RequestHandle:
         self._t_submit = time.perf_counter()
         self._t_first: Optional[float] = None
         self._t_done: Optional[float] = None
-        # `request_id` doubles as the trace id: the engine threads it
-        # through the serving.queue/prefill/decode_round spans and the
-        # serving_request_failed event, so one request's lifecycle can
-        # be followed in /trace and flight-recorder bundles
-        self._queue_span = None
         # prefix-cache attachment: the node this request was admitted
         # off (pinned until retirement) and how many prompt tokens its
         # copied KV covered

@@ -85,6 +85,7 @@ def _from_device(x):
     return np.asarray(x)
 
 
+@jax.named_scope('sample')
 def sample_rows(logits, temp, topk, topp, greedy, keys, steps):
     """Vectorized per-row sampling over a [N, V] logits slab with PER-ROW
     params (arrays, not static config — one compiled program serves every
@@ -931,10 +932,6 @@ class InferenceEngine:
         self._counts['submitted'] += 1
         if _obs.enabled():
             self._m_requests.labels(status='submitted').inc()
-            # queue span: begins now, ends at admission — the request's
-            # trace id (request_id) threads every span/event it touches
-            h._queue_span = _obs.Span('serving.queue',
-                                      request_id=h.request_id).begin()
         if _reqledger.enabled():
             rec = _reqledger.get_ledger().open_for(h)
             if rec is not None:
@@ -1055,10 +1052,6 @@ class InferenceEngine:
             self._detach_slot(slot, h)
             self.pool.free(slot)
             out.append(h)
-        for h in out:
-            if h._queue_span is not None:   # don't leak open queue spans
-                h._queue_span.end()
-                h._queue_span = None
         if _obs.enabled():
             self._m_active.set(len(self._slot_req))
         return out
@@ -1201,17 +1194,26 @@ class InferenceEngine:
         every ACTIVE slot one decode round (a plain block, or one
         speculation round when a draft model is configured). Returns
         the number of requests that progressed."""
-        self._check_drain()
-        self._admit()
-        self._advance_prefills()
-        n = len(self._slot_req)
-        if not np.any(self._active):
-            return n            # chunk-prefill-only progress this round
-        t_round0 = time.perf_counter()
-        if self.draft_model is not None:
-            toks, counts = self._spec_round()
-        else:
-            toks, counts = self._decode_round()
+        with _obs.span('serving.step'):
+            self._check_drain()
+            with _obs.span('serving.admit') as sp:
+                sp.set(admitted=self._admit())
+            self._advance_prefills()
+            n = len(self._slot_req)
+            if not np.any(self._active):
+                return n        # chunk-prefill-only progress this round
+            t_round0 = time.perf_counter()
+            if self.draft_model is not None:
+                toks, counts = self._spec_round()
+            else:
+                toks, counts = self._decode_round()
+            with _obs.span('serving.emit'):
+                self._emit_round(toks, counts, t_round0)
+            return n
+
+    def _emit_round(self, toks, counts, t_round0: float):
+        """Hand the round's tokens to their requests: ledger booking,
+        emission, retirement and the slots' next-round state."""
         now = time.perf_counter()
         # ledger BEFORE the emission loop, so the round that produced a
         # request's first token still lands in its TTFT sub-book
@@ -1229,7 +1231,6 @@ class InferenceEngine:
         if _obs.enabled():
             self._m_rounds.inc()
             self._m_occupancy.observe(self.pool.occupancy)
-            self._m_tokens.inc(0)   # ensure the family exists even idle
         for slot, h in list(self._slot_req.items()):
             if not self._active[slot]:
                 continue            # mid-chunked-prefill: no tokens yet
@@ -1269,7 +1270,6 @@ class InferenceEngine:
                 self._steps[slot] += (1 if counts is not None else c)
                 # stranded-capacity accounting: rows actually written
                 self.pool.note_written(slot, self._pos[slot] + 1)
-        return n
 
     def _recover_pool(self):
         """A DONATED decode/spec program failed mid-call: its input rows
@@ -1303,38 +1303,48 @@ class InferenceEngine:
 
     def _decode_round(self):
         """The plain compiled decode block (no draft model): every
-        active slot advances `decode_block` tokens."""
+        active slot advances `decode_block` tokens. Its span carries,
+        as scalars, the slots decoding (`active`), the slots there are
+        and the rows that hold a real token by the pool's own book; its
+        children are `serving.decode_dispatch` (staging the host arrays
+        and the page table, and the jitted call until it returns) and
+        `serving.d2h` (the blocking fetch of the round's tokens)."""
         with _obs.span('serving.decode_round',
-                       slots=len(self._slot_req),
-                       requests=[h.request_id
-                                 for h in self._slot_req.values()]):
+                       active=int(np.count_nonzero(self._active)),
+                       slots=self.pool.num_slots,
+                       real_rows=self.pool.written_rows):
             try:
-                if self._paged:
-                    pages, scales = self.pool.device_state()
-                    table = call_with_retry(
-                        _to_device, self.pool.page_table,
-                        policy=self._retry, site='serving.h2d')
-                    toks_dev, new_pages, new_scales = self._decode_jit(
-                        self._params, self._frozen, self._buffers,
-                        pages, scales, table, self._tok, self._pos,
-                        self._steps, self._active, self._temp,
-                        self._topk, self._topp, self._greedy,
-                        self._keys, *self._adapter_args())
-                    self.pool.set_device_state(new_pages, new_scales)
-                else:
-                    toks_dev, new_pool = self._decode_jit(
-                        self._params, self._frozen, self._buffers,
-                        self.pool.cache, self._tok, self._pos,
-                        self._steps, self._active, self._temp,
-                        self._topk, self._topp, self._greedy,
-                        self._keys, *self._adapter_args())
-                    self.pool.cache = new_pool
+                with _obs.span('serving.decode_dispatch'):
+                    if self._paged:
+                        pages, scales = self.pool.device_state()
+                        table = call_with_retry(
+                            _to_device, self.pool.page_table,
+                            policy=self._retry, site='serving.h2d')
+                        toks_dev, new_pages, new_scales = \
+                            self._decode_jit(
+                                self._params, self._frozen, self._buffers,
+                                pages, scales, table, self._tok,
+                                self._pos, self._steps, self._active,
+                                self._temp, self._topk, self._topp,
+                                self._greedy, self._keys,
+                                *self._adapter_args())
+                        self.pool.set_device_state(new_pages, new_scales)
+                    else:
+                        toks_dev, new_pool = self._decode_jit(
+                            self._params, self._frozen, self._buffers,
+                            self.pool.cache, self._tok, self._pos,
+                            self._steps, self._active, self._temp,
+                            self._topk, self._topp, self._greedy,
+                            self._keys, *self._adapter_args())
+                        self.pool.cache = new_pool
             except Exception:
                 if self._donate_pool:
                     self._recover_pool()
                 raise
-            toks = call_with_retry(_from_device, toks_dev,
-                                   policy=self._retry, site='serving.d2h')
+            with _obs.span('serving.d2h'):
+                toks = call_with_retry(_from_device, toks_dev,
+                                       policy=self._retry,
+                                       site='serving.d2h')
         _obs.note_progress('decode')   # /healthz decode liveness beat
         self._counts['decode_steps'] += self.decode_block
         if _obs.enabled():
@@ -1346,47 +1356,50 @@ class InferenceEngine:
         k+1-position target verify; greedy slots advance by their
         accepted count, sampling slots by one."""
         d_params, d_frozen, d_buffers = self._draft_state
-        with _obs.span('serving.spec_round',
-                       slots=len(self._slot_req), k=self.spec_k,
-                       requests=[h.request_id
-                                 for h in self._slot_req.values()]):
+        with _obs.span('serving.spec_round', k=self.spec_k,
+                       active=int(np.count_nonzero(self._active)),
+                       slots=self.pool.num_slots,
+                       real_rows=self.pool.written_rows):
             try:
-                if self._paged:
-                    pages, scales = self.pool.device_state()
-                    table = call_with_retry(
-                        _to_device, self.pool.page_table,
-                        policy=self._retry, site='serving.h2d')
-                    (toks_dev, counts_dev, new_pages, new_scales,
-                     new_d_pool) = self._spec_jit(
-                        self._params, self._frozen, self._buffers,
-                        pages, scales, table, d_params, d_frozen,
-                        d_buffers, self.draft_pool.cache, self._tok,
-                        self._pos, self._steps, self._active,
-                        self._temp, self._topk, self._topp,
-                        self._greedy, self._keys, self._eos_arr,
-                        *self._adapter_args())
-                    self.pool.set_device_state(new_pages, new_scales)
-                else:
-                    toks_dev, counts_dev, new_pool, new_d_pool = \
-                        self._spec_jit(
+                with _obs.span('serving.decode_dispatch'):
+                    if self._paged:
+                        pages, scales = self.pool.device_state()
+                        table = call_with_retry(
+                            _to_device, self.pool.page_table,
+                            policy=self._retry, site='serving.h2d')
+                        (toks_dev, counts_dev, new_pages, new_scales,
+                         new_d_pool) = self._spec_jit(
                             self._params, self._frozen, self._buffers,
-                            self.pool.cache, d_params, d_frozen,
-                            d_buffers, self.draft_pool.cache,
-                            self._tok, self._pos, self._steps,
-                            self._active, self._temp, self._topk,
-                            self._topp, self._greedy, self._keys,
-                            self._eos_arr, *self._adapter_args())
-                    self.pool.cache = new_pool
+                            pages, scales, table, d_params, d_frozen,
+                            d_buffers, self.draft_pool.cache, self._tok,
+                            self._pos, self._steps, self._active,
+                            self._temp, self._topk, self._topp,
+                            self._greedy, self._keys, self._eos_arr,
+                            *self._adapter_args())
+                        self.pool.set_device_state(new_pages, new_scales)
+                    else:
+                        toks_dev, counts_dev, new_pool, new_d_pool = \
+                            self._spec_jit(
+                                self._params, self._frozen, self._buffers,
+                                self.pool.cache, d_params, d_frozen,
+                                d_buffers, self.draft_pool.cache,
+                                self._tok, self._pos, self._steps,
+                                self._active, self._temp, self._topk,
+                                self._topp, self._greedy, self._keys,
+                                self._eos_arr, *self._adapter_args())
+                        self.pool.cache = new_pool
+                    self.draft_pool.cache = new_d_pool
             except Exception:
                 if self._donate_pool:
                     self._recover_pool()
                 raise
-            self.draft_pool.cache = new_d_pool
-            toks = call_with_retry(_from_device, toks_dev,
-                                   policy=self._retry, site='serving.d2h')
-            counts = call_with_retry(_from_device, counts_dev,
-                                     policy=self._retry,
-                                     site='serving.d2h')
+            with _obs.span('serving.d2h'):
+                toks = call_with_retry(_from_device, toks_dev,
+                                       policy=self._retry,
+                                       site='serving.d2h')
+                counts = call_with_retry(_from_device, counts_dev,
+                                         policy=self._retry,
+                                         site='serving.d2h')
         _obs.note_progress('decode')
         self._counts['decode_steps'] += 1   # one target verify pass
         self._counts['spec_rounds'] += 1
@@ -1479,8 +1492,10 @@ class InferenceEngine:
             self.scheduler.requeue(back)
 
     def _admit(self):
+        """Seat what the scheduler admits; -> how many took a slot."""
         admitted = self.scheduler.admissible(self._effective_free(),
                                              self._admission_cost)
+        seated = 0
         for idx, h in enumerate(admitted):
             try:
                 slot = self._alloc_slot()
@@ -1534,8 +1549,11 @@ class InferenceEngine:
                     _obs.emit('serving_request_failed',
                               request_id=h.request_id,
                               error=type(exc).__name__)
+            else:
+                seated += 1
         if _obs.enabled():
             self._m_active.set(len(self._slot_req))
+        return seated
 
     def _seat_paged(self, slot: int, h: RequestHandle, s: int):
         """Page-table admission, BEFORE any handle/engine bookkeeping:
@@ -1657,9 +1675,10 @@ class InferenceEngine:
             if rec is not None:
                 t1 = time.perf_counter()
                 rec.add('prefix_lookup', t1 - t_pfx, now=t1)
-        if h._queue_span is not None:
-            h._queue_span.end()   # admission closes the queue span
-            h._queue_span = None
+        # submit to admission: the request's trace id (request_id)
+        # threads every span and event it touches
+        _obs.record_span('serving.queue', h._t_submit,
+                         request_id=h.request_id)
         self._slot_req[slot] = h
         h.status = RUNNING
         # the no-mixed-version guarantee: stamped ONCE, here — a hot

@@ -213,6 +213,11 @@ class SlotPool:
         self._free.sort(reverse=True)
         self._written[slot] = 0
 
+    @property
+    def written_rows(self) -> int:
+        """Rows holding a real token, over the slots in use."""
+        return sum(self._written)
+
     def note_written(self, slot: int, rows) -> None:
         """Record that `slot` holds live KV through row `rows` (the
         engine calls this at prefill and after each decode round); the
@@ -371,6 +376,7 @@ def scatter_pages(pages, table, contig, start, length: int,
     first = start // page_size                  # [N]
     nwin = (length + page_size - 2) // page_size + 1
 
+    @jax.named_scope('kv_write')
     def upd(leaf, s_leaf, cont):
         for w in range(nwin):
             idx = jnp.clip(first + w, 0, p - 1)             # [N]
@@ -682,6 +688,11 @@ class PagedSlotPool:
         for pid in hold.pages:
             self._decref(pid)
         self._holds_live -= 1
+
+    @property
+    def written_rows(self) -> int:
+        """Rows holding a real token, over the slots in use."""
+        return sum(self._written)
 
     def note_written(self, slot: int, rows) -> None:
         r = min(int(rows), self.max_length)

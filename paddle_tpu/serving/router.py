@@ -756,37 +756,43 @@ class Router:
         just receive no new placements), fail over anything a dying
         replica drops, retire finished requests. Returns the number of
         requests that progressed."""
-        progressed = 0
-        t0 = time.perf_counter()
-        stepped = False
-        for r in list(self.replicas):
-            if not r.engine.has_work:
-                continue
-            try:
-                progressed += r.engine.step()
-                stepped = True
-            except BaseException as exc:
-                self._on_replica_failure(r, exc)
-        if stepped:
-            dt = time.perf_counter() - t0
-            self._ema_round_s = (dt if self._ema_round_s is None
-                                 else 0.8 * self._ema_round_s + 0.2 * dt)
-        self._reap()
-        self._rounds += 1
-        # windowed demand sample: ACCEPTED queued work only, observed
-        # after admission/reaping — never inside the submit path, so a
-        # burst of shed submissions cannot pump the demand signal —
-        # and throttled to a time-uniform cadence (see __init__)
-        now_m = time.monotonic()
-        if now_m - self._last_queue_sample >= self._queue_sample_interval:
-            self._last_queue_sample = now_m
-            self._win_queue.observe(self.queue_depth)
-        # gauges are monitoring, not control flow: refreshing every 8th
-        # round keeps the per-round router cost out of the decode path
-        # (submit/finalize still refresh immediately where it matters)
-        if _obs.enabled() and (self._rounds % 8 == 0 or not self._live):
-            self._refresh_gauges()
-        return progressed
+        with _obs.span('serving.router_step'):
+            progressed = 0
+            t0 = time.perf_counter()
+            stepped = False
+            for r in list(self.replicas):
+                if not r.engine.has_work:
+                    continue
+                try:
+                    progressed += r.engine.step()
+                    stepped = True
+                except BaseException as exc:
+                    self._on_replica_failure(r, exc)
+            if stepped:
+                dt = time.perf_counter() - t0
+                self._ema_round_s = (
+                    dt if self._ema_round_s is None
+                    else 0.8 * self._ema_round_s + 0.2 * dt)
+            self._reap()
+            self._rounds += 1
+            # windowed demand sample: ACCEPTED queued work only,
+            # observed after admission/reaping — never inside the submit
+            # path, so a burst of shed submissions cannot pump the demand
+            # signal — and throttled to a time-uniform cadence (see
+            # __init__)
+            now_m = time.monotonic()
+            if now_m - self._last_queue_sample \
+                    >= self._queue_sample_interval:
+                self._last_queue_sample = now_m
+                self._win_queue.observe(self.queue_depth)
+            # gauges are monitoring, not control flow: refreshing every
+            # 8th round keeps the per-round router cost out of the decode
+            # path (submit/finalize still refresh immediately where it
+            # matters)
+            if _obs.enabled() and (self._rounds % 8 == 0
+                                   or not self._live):
+                self._refresh_gauges()
+            return progressed
 
     def run(self) -> int:
         """Drive until every accepted request is FINISHED or FAILED;
@@ -809,35 +815,36 @@ class Router:
         return rounds
 
     def _reap(self):
-        now = time.perf_counter()
-        still: List[RouterHandle] = []
-        for rh in self._live:
-            if (rh._t_first is None and rh.inner is not None
-                    and rh.inner.tokens):
-                rh._t_first = now
-                self._win_ttft.observe(now - rh._t_submit)
-            with self._lock:
-                replica = self._by_id.get(rh.replica_id)
-            if rh._error is not None:
-                self._finalize(rh, 'failed')
-            elif rh.inner is not None and rh.inner.status == FINISHED:
-                if replica is not None:
-                    replica.breaker.record_success()
-                self._finalize(rh, 'completed')
-                if _obs.enabled() and rh.ttft is not None:
-                    self._m_ttft.labels(priority=rh.priority).observe(
-                        rh.ttft)
-            elif rh.inner is not None and rh.inner.status == FAILED:
-                # request-level failure (engine already classified and
-                # retried transients; this is final) — typed, not lost
-                rh._error = rh.inner.error
-                if (replica is not None
-                        and replica.breaker.state == BREAKER_HALF_OPEN):
-                    replica.breaker.record_failure()   # failed probe
-                self._finalize(rh, 'failed')
-            else:
-                still.append(rh)
-        self._live = still
+        with _obs.span('serving.reap'):
+            now = time.perf_counter()
+            still: List[RouterHandle] = []
+            for rh in self._live:
+                if (rh._t_first is None and rh.inner is not None
+                        and rh.inner.tokens):
+                    rh._t_first = now
+                    self._win_ttft.observe(now - rh._t_submit)
+                with self._lock:
+                    replica = self._by_id.get(rh.replica_id)
+                if rh._error is not None:
+                    self._finalize(rh, 'failed')
+                elif rh.inner is not None and rh.inner.status == FINISHED:
+                    if replica is not None:
+                        replica.breaker.record_success()
+                    self._finalize(rh, 'completed')
+                    if _obs.enabled() and rh.ttft is not None:
+                        self._m_ttft.labels(priority=rh.priority).observe(
+                            rh.ttft)
+                elif rh.inner is not None and rh.inner.status == FAILED:
+                    # request-level failure (engine already classified and
+                    # retried transients; this is final) — typed, not lost
+                    rh._error = rh.inner.error
+                    if (replica is not None
+                            and replica.breaker.state == BREAKER_HALF_OPEN):
+                        replica.breaker.record_failure()   # failed probe
+                    self._finalize(rh, 'failed')
+                else:
+                    still.append(rh)
+            self._live = still
 
     def _finalize(self, rh: RouterHandle, outcome: str):
         if rh._finalized:

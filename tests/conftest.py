@@ -53,19 +53,17 @@ def _seed_everything():
 
 @pytest.fixture(autouse=True)
 def _reset_span_state():
-    """Zero this thread's span nesting depth around every test.
+    """Empty this thread's stack of open spans around every test.
 
-    The PR-11 ordering flake: a test that begin()s a Span and never
-    end()s it (e.g. a serving queue span on a request the test abandons
-    mid-flight) leaks `_span_state.depth` in the main thread, so a
-    later test asserting absolute depths (test_span_nesting_records_
-    depth_and_order) fails when test_serving happens to run first.
-    Span state is per-test scaffolding, not cross-test truth — reset it
-    on both sides."""
+    A test that begin()s a Span and never end()s it leaves it open on
+    the main thread, so a later test asserting absolute depths or
+    parents (test_span_nesting_records_depth_and_order) would fail
+    depending on which test ran first. Span state is per-test
+    scaffolding, not cross-test truth — reset it on both sides."""
     from paddle_tpu.observability import events as _events
-    _events._span_state.depth = 0
+    del _events._span_state.stack[:]
     yield
-    _events._span_state.depth = 0
+    del _events._span_state.stack[:]
 
 
 @pytest.fixture

@@ -417,8 +417,11 @@ class TestSLOEngine:
         objs = slo_mod.default_objectives(slo_ttft_s=2.0)
         assert [o.name for o in objs] \
             == ['ttft_p99', 'availability', 'shed_rate']
-        eng = slo_mod.SLOEngine(objectives=objs, flight=False)
-        rep = eng.poll()   # empty registry: no data, no alerts, no crash
+        # an empty view, not the process registry: a router test that
+        # ran before this one in the same worker leaves its TTFT gauge
+        eng = slo_mod.SLOEngine(objectives=objs, flight=False,
+                                view_fn=lambda: {'metrics': []})
+        rep = eng.poll()   # no data, no alerts, no crash
         assert all(o['alerting'] is False for o in rep['objectives'])
 
 

@@ -1,0 +1,367 @@
+"""The program names its own work (ISSUE 24): one span primitive on the
+host clock AND the profiler's timeline, spans at each boundary inside
+`engine.step` / `Router.step` / `TrainStep`, and named scopes of one
+pinned vocabulary on what is compiled, with the table from an HLO
+instruction back to its scope."""
+import glob
+import os
+import re
+
+import jax
+import numpy as np
+import pytest
+
+import paddle_tpu as paddle
+import paddle_tpu.nn.functional as F
+from paddle_tpu import observability as obs
+from paddle_tpu import profiler, programs
+from paddle_tpu.jit import TrainStep
+from paddle_tpu.nlp.gpt import GPTConfig, GPTForCausalLM
+from paddle_tpu.observability import goodput as goodput_mod
+from paddle_tpu.observability.events import EventLog
+from paddle_tpu.programs import scopes as scopes_mod
+from paddle_tpu.serving import (InferenceEngine, ReplicaSet, Router,
+                                SamplingParams)
+
+PKG = os.path.dirname(os.path.abspath(paddle.__file__))
+
+
+@pytest.fixture(scope='module')
+def gpt():
+    paddle.seed(7)
+    return GPTForCausalLM(GPTConfig.tiny()).eval()
+
+
+@pytest.fixture
+def log():
+    lg = obs.get_event_log()
+    lg.clear()
+    return lg
+
+
+def _spans(log, prefix=''):
+    return [e for e in log.events()
+            if e.get('ph') == 'X' and e['name'].startswith(prefix)]
+
+
+def _serve(gpt, n_requests=3, decode_block=2, new_tokens=4):
+    router = Router(ReplicaSet(gpt, 1, num_slots=2, max_length=64,
+                               decode_block=decode_block))
+    hs = [router.submit([1, 2, 3, 4, 5 + i], SamplingParams(
+        max_new_tokens=new_tokens, eos_token_id=-1))
+        for i in range(n_requests)]
+    router.run()
+    assert all(len(h.tokens) == new_tokens for h in hs)
+    return hs
+
+
+# ---------------------------------------------------------------------------
+# the primitive
+# ---------------------------------------------------------------------------
+
+def test_span_records_id_parent_and_request_id(log):
+    with obs.span('outer') as outer:
+        with obs.span('inner', request_id=41) as inner:
+            pass
+        with obs.span('second') as second:
+            pass
+    ev = {e['name']: e for e in log.events()}
+    assert ev['outer']['parent'] == 0 and ev['outer']['id'] == outer.id > 0
+    assert ev['inner']['parent'] == ev['second']['parent'] == outer.id
+    assert len({outer.id, inner.id, second.id}) == 3
+    assert ev['inner']['attrs'] == {'request_id': 41}
+    assert (ev['outer']['depth'], ev['inner']['depth']) == (1, 2)
+
+
+def test_span_set_adds_counts_and_a_disabled_span_is_a_no_op(log):
+    with obs.span('work', slots=2) as sp:
+        sp.set(admitted=3)
+    assert log.events()[-1]['attrs'] == {'slots': 2, 'admitted': 3}
+    obs.disable()
+    try:
+        n = len(log)
+        with obs.span('off') as sp:
+            sp.set(x=1)
+        assert len(log) == n and sp.id == 0
+    finally:
+        obs.enable()
+
+
+def test_record_span_nests_in_nothing(log):
+    import time
+    t0 = time.perf_counter()
+    with obs.span('step') as step:
+        obs.record_span('serving.queue', t0, request_id=9)
+        with obs.span('child') as child:
+            pass
+    ev = {e['name']: e for e in log.events()}
+    assert ev['serving.queue']['parent'] == 0
+    assert ev['serving.queue']['depth'] == 0
+    assert ev['serving.queue']['dur'] >= 0
+    assert ev['child']['parent'] == step.id     # the queue span is not open
+
+
+def test_one_span_system():
+    """The only call of jax.profiler.TraceAnnotation in the package is
+    the span primitive's; RecordEvent is that span plus the profiler's
+    host table."""
+    hits = []
+    for path in glob.glob(os.path.join(PKG, '**', '*.py'), recursive=True):
+        with open(path) as f:
+            if re.search(r'TraceAnnotation\(', f.read()):
+                hits.append(os.path.relpath(path, PKG))
+    assert hits == [os.path.join('observability', 'events.py')]
+
+
+def test_record_event_is_one_span(log):
+    with profiler.RecordEvent('user.region'):
+        pass
+    assert [e['name'] for e in _spans(log)] == ['user.region']
+    assert not hasattr(profiler.RecordEvent('x'), '_jax_ctx')
+
+
+def test_goodput_bookkeeping_clears_under_an_uncategorised_top_span():
+    lg = EventLog()
+    led = goodput_mod.GoodputLedger(log=lg)
+    led.start(reset=True)
+    try:
+        for _ in range(5):
+            with obs.Span('serving.router_step', _log=lg):
+                with obs.Span('serving.step', _log=lg):
+                    with obs.Span('serving.decode_round', _log=lg):
+                        pass
+        assert all(not v for v in led._intervals.values())
+        assert led.report()['categories']['serving_decode'] > 0
+    finally:
+        led.stop()
+
+
+# ---------------------------------------------------------------------------
+# the serving step
+# ---------------------------------------------------------------------------
+
+def test_serving_spans_nest_and_carry_scalar_counts(gpt, log):
+    _serve(gpt)
+    spans = _spans(log, 'serving.')
+    by_id = {e['id']: e for e in spans}
+    names = {e['name'] for e in spans}
+    assert {'serving.router_step', 'serving.reap', 'serving.step',
+            'serving.admit', 'serving.prefill', 'serving.decode_round',
+            'serving.decode_dispatch', 'serving.d2h', 'serving.emit',
+            'serving.queue'} <= names
+
+    def parent(e):
+        return by_id[e['parent']]['name'] if e['parent'] else None
+    want = {'serving.step': 'serving.router_step',
+            'serving.reap': 'serving.router_step',
+            'serving.admit': 'serving.step',
+            'serving.prefill': 'serving.admit',
+            'serving.decode_round': 'serving.step',
+            'serving.decode_dispatch': 'serving.decode_round',
+            'serving.d2h': 'serving.decode_round',
+            'serving.emit': 'serving.step'}
+    for e in spans:
+        if e['name'] in want:
+            assert parent(e) == want[e['name']], e
+    for e in spans:     # scalars only: no list or dict rides a span
+        assert all(isinstance(v, (int, float, str)) for v in
+                   (e.get('attrs') or {}).values()), e
+    rounds = [e['attrs'] for e in spans
+              if e['name'] == 'serving.decode_round']
+    assert all(set(a) == {'active', 'slots', 'real_rows'} for a in rounds)
+    assert all(a['slots'] == 2 and 1 <= a['active'] <= 2 for a in rounds)
+    # by the pool's own book: a seated request holds its prompt's rows
+    assert all(a['real_rows'] >= 5 * a['active'] for a in rounds)
+    assert sum(e['attrs']['admitted'] for e in spans
+               if e['name'] == 'serving.admit') == 3
+    # a count rides a span only where a metric reads it
+    for name in ('serving.emit', 'serving.reap', 'serving.router_step',
+                 'serving.step'):
+        assert all(not e.get('attrs') for e in spans
+                   if e['name'] == name), name
+    # one request's spans share its identifier
+    rid = [e['attrs']['request_id'] for e in spans
+           if e['name'] == 'serving.queue']
+    assert sorted(rid) == sorted(e['attrs']['request_id'] for e in spans
+                                 if e['name'] == 'serving.prefill')
+
+
+def test_children_of_serving_step_cover_its_wall(gpt, log):
+    kw = dict(n_requests=4, decode_block=8, new_tokens=16)
+    _serve(gpt, **kw)               # warm: the programs are compiled
+    log.clear()
+    _serve(gpt, **kw)
+    spans = _spans(log, 'serving.')
+    steps = [e for e in spans if e['name'] == 'serving.step']
+    covered = sum(e['dur'] for e in spans
+                  if e['parent'] in {s['id'] for s in steps})
+    assert covered >= 0.95 * sum(s['dur'] for s in steps)
+
+
+def test_spec_round_carries_the_same_children(gpt, log):
+    paddle.seed(11)
+    draft = GPTForCausalLM(GPTConfig.tiny(num_hidden_layers=1)).eval()
+    eng = InferenceEngine(gpt, num_slots=2, max_length=64,
+                          draft_model=draft, num_draft_tokens=2)
+    eng.submit([1, 2, 3, 4], SamplingParams(max_new_tokens=4,
+                                            eos_token_id=-1))
+    eng.run()
+    spans = _spans(log, 'serving.')
+    rounds = {e['id']: e for e in spans if e['name'] == 'serving.spec_round'}
+    assert rounds and all(
+        {'active', 'slots', 'real_rows', 'k'} == set(e['attrs'])
+        for e in rounds.values())
+    kids = {e['name'] for e in spans if e['parent'] in rounds}
+    assert kids == {'serving.decode_dispatch', 'serving.d2h'}
+
+
+# ---------------------------------------------------------------------------
+# the train step and the scopes
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope='module')
+def toy_step():
+    paddle.seed(3)
+    cfg = GPTConfig.tiny()
+    model = GPTForCausalLM(cfg)
+    model.train()
+    opt = paddle.optimizer.AdamW(learning_rate=1e-3,
+                                 parameters=model.parameters())
+
+    def loss_fn(logits, labels):
+        return F.cross_entropy(
+            logits[:, :-1].reshape([-1, cfg.vocab_size]),
+            labels[:, 1:].reshape([-1]))
+    step = TrainStep(model, loss_fn, opt)
+    ids = np.random.RandomState(0).randint(0, 128, (2, 16)).astype('int32')
+    step(ids, ids)
+    return step, ids
+
+
+def test_train_step_spans(toy_step, log):
+    step, ids = toy_step
+    step(ids, ids)
+    ev = {e['name']: e for e in _spans(log, 'train.')}
+    assert set(ev) == {'train.step', 'train.dispatch', 'train.writeback'}
+    assert ev['train.dispatch']['parent'] == ev['train.step']['id']
+    assert ev['train.writeback']['parent'] == ev['train.step']['id']
+    assert (ev['train.dispatch']['dur'] + ev['train.writeback']['dur']
+            >= 0.95 * ev['train.step']['dur'])
+
+
+def test_scope_vocabulary_is_pinned(toy_step, gpt):
+    """Every name of the vocabulary is on the toy train step's or the
+    decode program's HLO, and no scope of the models' is outside it."""
+    assert scopes_mod.SCOPES == (
+        'embed', 'attention', 'mlp', 'norm', 'lm_head', 'loss', 'sample',
+        'kv_write', 'optimizer')
+    _serve(gpt, n_requests=1)
+    table = programs.scope_table()
+    assert 'train_step' in table and 'serving.decode_block' in table
+    found = {}
+    for prog in ('train_step', 'serving.decode_block'):
+        found[prog] = {scope for op, *_ in table[prog].values()
+                       for scope in programs.scope_path(op)[-1:]}
+    assert found['train_step'] == {'embed', 'attention', 'mlp', 'norm',
+                                   'lm_head', 'loss', 'optimizer'}
+    assert found['serving.decode_block'] >= {'sample', 'kv_write',
+                                             'attention', 'lm_head'}
+    assert found['train_step'] | found['serving.decode_block'] \
+        == set(scopes_mod.SCOPES)
+    ops = [op for op, *_ in table['train_step'].values()]
+    assert any('transpose(jvp(mlp))' in op for op in ops)      # backward
+    assert any('jvp(mlp)' in op and 'transpose' not in op for op in ops)
+    # every instruction with an op_name joins by its name; shapes ride
+    assert any(shape.startswith('f32_') for _, shape, *_ in
+               table['train_step'].values())
+    # ... and each says how it came by its name
+    assert {how for *_, how in table['train_step'].values()} \
+        <= {'own', 'callee', 'user', 'operand', 'caller', ''}
+
+
+def test_scope_path_lists_the_vocabulary_scopes_outermost_first():
+    assert programs.scope_path('jit(step_fn)/jvp(mlp)/dot_general') \
+        == ('mlp',)
+    assert programs.scope_path(
+        'jit(f)/transpose(jvp(attention))/kv_write/scatter') \
+        == ('attention', 'kv_write')
+    assert programs.scope_path(
+        'jit(f)/jvp(attention)/jit(flash_attention)/pallas_call') \
+        == ('attention',)
+    assert programs.scope_path('jit(f)/mul') == ()
+    assert programs.scope_path('') == ()
+
+
+_HLO = '''HloModule jit_f, entry_computation_layout={(f32[4]{0})->f32[4]{0}}
+
+%fused_computation.1 (param_0.1: f32[4]) -> f32[4] {
+  %param_0.1 = f32[4]{0} parameter(0)
+  %multiply.3 = f32[4]{0} multiply(%param_0.1, %param_0.1), metadata={op_name="jit(f)/jvp(mlp)/mul" source_file="x.py" source_line=3}
+  ROOT %add.4 = f32[4]{0} add(%multiply.3, %param_0.1), metadata={op_name="jit(f)/optimizer/add"}
+}
+
+%body.7 (p: (s32[], f32[4])) -> (s32[], f32[4]) {
+  %p = (s32[], f32[4]{0}) parameter(0)
+  %get-tuple-element.8 = f32[4]{0} get-tuple-element(%p), index=1
+  %fusion.9 = f32[4]{0} fusion(%get-tuple-element.8), kind=kLoop, calls=%fused_computation.nameless
+  ROOT %tuple.10 = (s32[], f32[4]{0}) tuple(%get-tuple-element.8, %fusion.9)
+}
+
+ENTRY %main.5 (Arg_0.1: f32[4]) -> f32[4] {
+  %Arg_0.1 = f32[4]{0} parameter(0), metadata={op_name="x"}
+  %fusion.1 = f32[4]{0} fusion(%Arg_0.1), kind=kLoop, calls=%fused_computation.1
+  %copy-start.6 = (f32[4]{0}, f32[4]{0}, u32[]) copy-start(%fusion.1)
+  %copy-done.6 = f32[4]{0} copy-done(%copy-start.6)
+  %while.11 = (s32[], f32[4]{0}) while(%copy-done.6), condition=%cond.12, body=%body.7, metadata={op_name="jit(f)/attention/kv_write/scatter"}
+  ROOT %fusion.2 = (bf16[8,128]{1,0:T(8,128)(2,1)}, f32[]) fusion(%copy-done.6), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(f)/transpose(jvp(mlp))/dot_general"}
+}
+'''
+
+
+def test_parse_hlo_scopes_says_how_each_instruction_came_by_its_name():
+    t = scopes_mod.parse_hlo_scopes(_HLO)
+    # its own metadata; a fusion without any counts under its root's
+    # ... and holds, inside, instructions of two scopes
+    assert t['fusion.2'] == ('jit(f)/transpose(jvp(mlp))/dot_general',
+                             'bf16_8_128', ('mlp', 'optimizer'), 'own')
+    assert t['fusion.1'] == ('jit(f)/optimizer/add', 'f32_4',
+                             ('mlp', 'optimizer'), 'callee')
+    assert t['multiply.3'][::3] == ('jit(f)/jvp(mlp)/mul', 'own')
+    # borrowed, and marked so — a copy the compiler made: what consumes
+    # its result names it
+    assert t['copy-start.6'][::3] == t['copy-done.6'][::3] \
+        == ('jit(f)/attention/kv_write/scatter', 'user')
+    # the body of the loop a scatter became: the loop's name
+    assert t['fusion.9'] == ('jit(f)/attention/kv_write/scatter', 'f32_4',
+                             (), 'caller')
+    # an argument's name is not an op_name; its user's is
+    assert t['Arg_0.1'] == ('jit(f)/optimizer/add', 'f32_4', (), 'user')
+    # nothing within reach: no name, and no how
+    alone = scopes_mod.parse_hlo_scopes(
+        'ENTRY %m (a: f32[4]) -> f32[4] {\n'
+        '  ROOT %copy.1 = f32[4]{0} copy(%a)\n}\n')
+    assert alone['copy.1'] == ('', 'f32_4', (), '')
+
+
+def test_every_pallas_kernel_states_its_name():
+    with open(os.path.join(PKG, 'ops', 'pallas_kernels.py')) as f:
+        src = f.read()
+    calls = src.split('pl.pallas_call(')[1:]
+    names = [re.search(r"\bname='(\w+)'", c.split(')(', 1)[0]) for c in calls]
+    assert all(names), 'a pallas_call without a stated name'
+    assert [m.group(1) for m in names] == [
+        'flash_fwd', 'flash_bwd_dq', 'flash_bwd_dkv', 'rms_norm_fwd',
+        'ce_fwd', 'ce_bwd', 'paged_attention', 'adapter_matmul']
+
+
+def test_named_kernel_reaches_the_lowered_program():
+    """The stated name is on the lowered custom call (interpret mode on
+    a CPU lowers no custom call, so this reads the jaxpr's params)."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops import pallas_kernels as K
+    x = jnp.ones((16, 256), jnp.float32)
+    lab = jnp.zeros((16,), jnp.int32)
+    jaxpr = jax.make_jaxpr(
+        lambda a, b: K.softmax_cross_entropy_fwd(a, b, interpret=True))(x, lab)
+    eqns = [e for e in jaxpr.jaxpr.eqns if 'pallas' in e.primitive.name]
+    assert eqns and 'ce_fwd' in str(eqns[0].params)
