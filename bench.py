@@ -2651,9 +2651,9 @@ def donation_ab(n_requests=10, max_new=8, train_steps=4, num_slots=4,
 
     Asserted by the tier-1 guard: greedy serving outputs AND train
     losses bit-exact across the arms (donation is value-neutral or it
-    is quarantined), and the pool-copy surface accounting — with
-    per-slot rows every single-slot op moves `row_bytes`, where the old
-    stacked pool moved `pool_bytes`; the reported
+    is quarantined), and the pool-copy surface accounting — a donated
+    single-slot op (seat, copy) writes `row_bytes` in place, where the
+    undonated arm's returns a copy of `pool_bytes`; the reported
     `pool_copy_bytes_saved` is that delta summed over the trace's
     single-slot ops. Tokens/sec for both arms ride along (CPU narrows
     the gap; the number that matters here is parity + bytes)."""

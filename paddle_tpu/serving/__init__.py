@@ -160,7 +160,8 @@ from .hotswap import (CanaryGate, ReplicaUpdater, SwapFailed,
                       WeightLoadError, WeightPublisher, WeightStore,
                       finite_weights_gate)
 from .kv_pool import (PageHold, PagePoolExhausted, PagedSlotPool,
-                      PromptTooLongError, SlotPool, default_buckets)
+                      PoolLostError, PromptTooLongError, SlotPool,
+                      default_buckets)
 from .prefix_cache import PagedPrefixCache, RadixPrefixCache
 from .remote import (FrameChecksumError, IncompleteFrameError,
                      RemoteFatalError, RemoteReplica, RemoteTransientError,
@@ -179,7 +180,7 @@ __all__ = [
     'RequestHandle', 'SamplingParams', 'InferenceEngine', 'sample_rows',
     'SlotPool', 'default_buckets', 'FCFSScheduler', 'RadixPrefixCache',
     'PagedSlotPool', 'PagedPrefixCache', 'PageHold',
-    'PagePoolExhausted', 'PromptTooLongError',
+    'PagePoolExhausted', 'PoolLostError', 'PromptTooLongError',
     'CircuitBreaker', 'Replica', 'ReplicaFailure', 'ReplicaSet',
     'Router', 'RouterHandle',
     'AdmissionRejected', 'Tenant', 'TenantRegistry', 'TokenBucket',

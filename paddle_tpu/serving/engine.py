@@ -25,17 +25,18 @@ and, when the latency stack is enabled (ISSUE 9):
   block when a draft model is configured),
 - one draft prefill program per bucket.
 
-Copy surface (ISSUE 13): the pool lives as PER-SLOT rows
-(kv_pool.SlotPool), so prefill/chunk programs take and return one row —
-the old jitted pool writer/copier and their full-pool round trips are
-gone. The decode block stacks the rows inside the program and splits
-its output back; when the donation gauntlet (programs/donation.py)
-allows it, the pool rows are DONATED so even that round trip aliases
-in place. Donation never changes values, and the engine guards the
-failure mode it introduces: a donated decode program dying mid-call
-invalidates its input rows, so the engine rebuilds zero rows and
-force-clears the prefix cache before re-raising (`_recover_pool`) —
-the error still fails over normally, but the engine stays serviceable.
+Copy surface: the pool is ONE stacked array per layer leaf
+(kv_pool.SlotPool), DONATED into the decode block and the speculation
+round, which carry it through their scan and return it: the pool is
+updated in place and a round copies none of it. Prefill/chunk programs
+take and return one row, undonated, and the pool's own seat program
+writes that row into its slot in place (`SlotPool.set_row`). Donation
+never changes values, and the engine guards the failure mode it
+introduces: a donated program (decode, speculation, seat, copy) dying
+mid-call invalidates the pool it was given, so the engine rebuilds a
+zero pool and force-clears the prefix cache before re-raising
+(`_recover_pool`) — the error still fails over normally, but the engine
+stays serviceable.
 
 Greedy requests take the raw argmax exactly like `generate()`, so their
 outputs are token-for-token identical to a per-request generate() call
@@ -64,9 +65,8 @@ from ..resilience import RetryPolicy, call_with_retry
 from ..tensor import Tensor
 from .adapters.apply import adapter_scope as _adapter_scope
 from .api import GREEDY, RUNNING, RequestHandle, SamplingParams
-from .kv_pool import (PagePoolExhausted, PagedSlotPool, SlotPool,
-                      gather_pages, scatter_pages, split_rows,
-                      stack_rows)
+from .kv_pool import (PagePoolExhausted, PagedSlotPool, PoolLostError,
+                      SlotPool, gather_pages, scatter_pages)
 from .prefix_cache import PagedPrefixCache, RadixPrefixCache
 from .scheduler import FCFSScheduler
 
@@ -162,8 +162,9 @@ class InferenceEngine:
             KV lives in a parallel SlotPool. Sampling requests in the
             same engine simply decode one token per round.
         num_draft_tokens: draft proposals per speculation round (k).
-        donate_pool: donate the KV rows into the decode/spec programs
-            so the pool aliases in place instead of round-tripping
+        donate_pool: donate the KV pool into the decode/spec programs
+            (and the row pool's seat/copy programs) so it is updated in
+            place instead of copied
             (value-neutral; the store-served variant additionally
             requires a donation-gauntlet-safe verdict and runs
             sentinel-guarded). Default True; the bench donation phase
@@ -234,6 +235,10 @@ class InferenceEngine:
             getattr(cfg, 'eos_token_id', -1) if eos_token_id is None
             else eos_token_id)
         self.decode_block = int(decode_block)
+        # pool donation: the decode/spec programs and the row pool's
+        # seat/copy programs DONATE the pool, so it is updated in place
+        self._donate_pool = True if donate_pool is None else bool(
+            donate_pool)
         self._paged = (kv_page_size is not None or kv_pages is not None
                        or kv_quant is not None)
         if self._paged:
@@ -243,7 +248,7 @@ class InferenceEngine:
                 num_pages=kv_pages, quant=kv_quant)
         else:
             self.pool = SlotPool(model, num_slots, max_length, dtype,
-                                 buckets)
+                                 buckets, donate=self._donate_pool)
         self.scheduler = FCFSScheduler(max_prefill_tokens,
                                        max_wait_s=max_wait_s)
         if prefill_chunk_tokens is not None and prefill_chunk_tokens < 1:
@@ -294,7 +299,8 @@ class InferenceEngine:
             # (never alloc/freed itself — slot i of both pools always
             # belongs to the same request)
             self.draft_pool = SlotPool(draft_model, num_slots,
-                                       max_length, dtype, buckets)
+                                       max_length, dtype, buckets,
+                                       donate=self._donate_pool)
         else:
             self._draft_state = None
             self.draft_pool = None
@@ -347,15 +353,11 @@ class InferenceEngine:
         # (or load) each program once.
         from .. import programs as _programs
         store = _programs.get_store()
-        # pool donation (the "kill the copy" half the gauntlet governs):
-        # the decode/spec programs DONATE their row inputs so the pool
-        # aliases in place. Direct in-process compiles donate as
-        # declared (PR-8-safe); the store's export path re-applies the
-        # recorded argnums only on a gauntlet-safe verdict, sentinel-
-        # guarded. donate_pool rides the statics: a donated and an
-        # undonated engine must never share one store key.
-        self._donate_pool = True if donate_pool is None else bool(
-            donate_pool)
+        # donation: direct in-process compiles donate as declared; the
+        # store's export path re-applies the recorded argnums only on a
+        # gauntlet-safe verdict, sentinel-guarded. donate_pool rides the
+        # statics: a donated and an undonated engine must never share
+        # one store key.
         engine_statics = {
             'model': type(model).__qualname__,
             'model_src': _programs.code_token(type(model)),
@@ -535,9 +537,9 @@ class InferenceEngine:
                          adapters=None, adapter_rows=None):
         """One compiled program: `decode_block` single-token steps over
         ALL slots (lax.scan), per-slot positions/masks/sampling. `pool`
-        arrives as the tuple of per-slot rows and is stacked/split
-        inside the program (bit-identical math); with `donate_pool` the
-        row inputs are donated so the round trip aliases in place.
+        is the stacked pool (leaves [num_slots, max_length, H, D]): it
+        is the scan's carry and comes back as the second result, so
+        with `donate_pool` the program updates it in place.
         `adapters`/`adapter_rows` (bank-attached engines only) are the
         packed LoRA banks + per-slot bank rows — traced inputs, so any
         adapter mix replays this same program."""
@@ -545,7 +547,6 @@ class InferenceEngine:
         fwd = cached_forward(self.model, params, frozen, buffers)
         max_len = self.pool.max_length
         k_slot = jnp.arange(max_len, dtype=jnp.int32)
-        pool = stack_rows(pool)
 
         def sub(carry, _):
             tok, pos, steps, pool = carry
@@ -565,14 +566,14 @@ class InferenceEngine:
             (tok, pos, steps, pool), toks = jax.lax.scan(
                 sub, (tok, pos, steps, pool), None,
                 length=self.decode_block)
-        # [num_slots, block] tokens + the pool back as per-slot rows
-        return jnp.transpose(toks), split_rows(pool, self.pool.num_slots)
+        return jnp.transpose(toks), pool    # [num_slots, block] tokens
 
     def _prefill_fn(self, params, frozen, buffers, ids,
                     adapters=None, adapter_rows=None):
         """Prefill ONE request (batch-1, right-padded to its bucket) and
-        return the resulting KV ROW — the host stores it as the slot's
-        row, so the undonated copy surface is one row, never the pool.
+        return the resulting KV ROW — the pool's seat program writes it
+        into the slot, so this program never holds the pool and its
+        failure costs one request.
         KV-only and fully async: no logits leave the device — the
         request's FIRST token falls out of the next decode block, which
         re-forwards the last prompt token at position s-1 (an identical
@@ -642,8 +643,6 @@ class InferenceEngine:
         fwd_t = cached_forward(self.model, params, frozen, buffers)
         fwd_d = cached_forward(self.draft_model, d_params, d_frozen,
                                d_buffers)
-        pool = stack_rows(pool)
-        d_pool = stack_rows(d_pool)
         max_len = self.pool.max_length
         k_slot = jnp.arange(max_len, dtype=jnp.int32)
         n = tok.shape[0]
@@ -690,8 +689,7 @@ class InferenceEngine:
                          jnp.where(j == a[:, None], v_new[:, None], 0))
         toks = jnp.where(active[:, None], toks, 0).astype(jnp.int32)
         counts = jnp.where(active, a + 1, 0).astype(jnp.int32)
-        return (toks, counts, split_rows(pool, n),
-                split_rows(d_pool, self.draft_pool.num_slots))
+        return toks, counts, pool, d_pool
 
     # ------------------------------------------------------------------
     # compiled programs: PAGED layout
@@ -800,9 +798,8 @@ class InferenceEngine:
         `_spec_decode_fn`, with the target KV gathered through the page
         table and the verify's k+1-row span scattered back (reservation
         headroom guarantees the span never clamps past max_length). The
-        DRAFT pool stays a row SlotPool — it is small, never shared,
-        and keeping it row-shaped bounds this PR's blast radius.
-        Donates pages, scales, and the draft rows (argnums 3, 4, 9)."""
+        DRAFT pool stays a row SlotPool — it is small and never shared.
+        Donates pages, scales, and the draft pool (argnums 3, 4, 9)."""
         k = self.spec_k
         self._trace_counts[f'paged_spec_decode_k{k}'] += 1
         fwd_t = cached_forward(self.model, params, frozen, buffers)
@@ -812,7 +809,6 @@ class InferenceEngine:
         table = jnp.where(active[:, None], table, 0)
         pool = gather_pages(pages, table, sc,
                             out_dtype=self.pool.compute_dtype)
-        d_pool = stack_rows(d_pool)
         max_len = self.pool.max_length
         k_slot = jnp.arange(max_len, dtype=jnp.int32)
         n = tok.shape[0]
@@ -853,8 +849,7 @@ class InferenceEngine:
         counts = jnp.where(active, a + 1, 0).astype(jnp.int32)
         pages, sc = scatter_pages(pages, table, pool, pos, k + 1,
                                   self.pool.page_size, sc)
-        return (toks, counts, pages, sc if sc is not None else (),
-                split_rows(d_pool, self.draft_pool.num_slots))
+        return toks, counts, pages, sc if sc is not None else (), d_pool
 
     # ------------------------------------------------------------------
     # submission
@@ -1272,12 +1267,12 @@ class InferenceEngine:
                 self.pool.note_written(slot, self._pos[slot] + 1)
 
     def _recover_pool(self):
-        """A DONATED decode/spec program failed mid-call: its input rows
-        may already be invalidated, so every retained buffer is suspect.
-        Rebuild zero rows and force-clear the prefix cache (its KV
-        floors are gone) BEFORE re-raising — the error still classifies
-        and fails over normally, but the engine itself stays
-        serviceable for the next admission."""
+        """A DONATED program (decode, spec, seat, copy) failed mid-call:
+        the pool it was given may already be invalidated, so every
+        retained buffer is suspect. Rebuild a zero pool and force-clear
+        the prefix cache (its KV floors are gone) BEFORE re-raising —
+        the error still classifies and fails over normally, but the
+        engine itself stays serviceable for the next admission."""
         if self._paged:
             self.pool.reset_pages()
         else:
@@ -1288,6 +1283,36 @@ class InferenceEngine:
             self.prefix_cache.clear(force=True)
         _obs.emit('serving_pool_recovered',
                   slots=self.pool.num_slots)
+
+    def _prefill_row(self, pool: SlotPool, slot: int, program, *args):
+        """Dispatch a row-layout prefill `program` (it returns ONE row
+        and never holds the pool) and seat that row in `pool` in place.
+        First wait until what is queued on the pool has run: the runtime
+        reserves a program's outputs when it is ENQUEUED, so a burst of
+        admissions dispatched back to back holds a row apiece until
+        their seats have run — six rows of 0.75 GiB beside a 4.5 GiB
+        pool (serve-docs, PERF.md PR 25). One at a time, the peak is the
+        pool plus ONE row. After a decode round the pool is ready
+        already, so only the second admission of a step waits, and the
+        device has the first one's prefill to run meanwhile."""
+        jax.block_until_ready(pool.rows)
+        row = program(*args)
+        self._pool_op(pool.set_row, slot, row)
+
+    def _pool_op(self, op, *args):
+        """Run one of the row pool's donated single-slot programs
+        (`set_row`, `copy_slot`). If it dies the pool may be gone with
+        it: recover as after a failed donated decode round and raise
+        `PoolLostError`, which no request-level handler absorbs."""
+        try:
+            op(*args)
+        except Exception as exc:
+            if not self._donate_pool:
+                raise
+            self._recover_pool()
+            raise PoolLostError(
+                f'{op.__name__} died with the pool donated to it: '
+                f'{type(exc).__name__}: {exc}') from exc
 
     def _adapter_args(self, slot: Optional[int] = None) -> tuple:
         """Trailing (bank arrays, per-row bank slots) appended to a
@@ -1522,6 +1547,14 @@ class InferenceEngine:
                           detail=str(exc))
                 self._requeue_blocked(admitted[idx:], 'pool_exhausted')
                 break
+            except PoolLostError:
+                # every seated request lost its KV: the step fails, as
+                # after a failed donated decode round. What was popped
+                # behind this handle goes back to the queue first, so
+                # that evict_all() finds it.
+                for back in reversed(admitted[idx + 1:]):
+                    self.scheduler.requeue(back)
+                raise
             except Exception as exc:
                 from .adapters.bank import AdapterUnavailable
                 if isinstance(exc, AdapterUnavailable) \
@@ -1700,7 +1733,7 @@ class InferenceEngine:
             # retained row; paged mode already shares the pages — then
             # the pending token re-forwards the last prompt position
             if not self._paged:
-                self.pool.copy_slot(src, slot)
+                self._pool_op(self.pool.copy_slot, src, slot)
             self.pool.note_written(slot, s)
             self._activate(slot, h)
             return
@@ -1751,10 +1784,10 @@ class InferenceEngine:
                     *self._adapter_args(slot))
                 self.pool.set_device_state(new_pages, new_scales)
             else:
-                # row in, row out: the undonated copy surface is pool/N
-                self.pool.set_row(slot, self._prefill_jit(
+                self._prefill_row(
+                    self.pool, slot, self._prefill_jit,
                     self._params, self._frozen, self._buffers, ids_dev,
-                    *self._adapter_args(slot)))
+                    *self._adapter_args(slot))
         self.pool.note_written(slot, s)
         self._note_prefill(h, t_pf0)
         self._counts['prefills'] += 1
@@ -1771,6 +1804,8 @@ class InferenceEngine:
             h, cursor, src = self._prefilling[slot]
             try:
                 self._prefill_chunk(slot, h, cursor, src)
+            except PoolLostError:
+                raise               # the step's failure, not this request's
             except Exception as exc:
                 self._detach_slot(slot, h)
                 self.pool.free(slot)
@@ -1817,13 +1852,15 @@ class InferenceEngine:
                     jnp.int32(h._prefix_len), *self._adapter_args(slot))
                 self.pool.set_device_state(new_pages, new_scales)
             else:
-                # forwards against the src ROW (the retained row on a
-                # prefix hit's first chunk, the slot's own row after);
-                # returns the slot's new row — one-row surface either way
-                self.pool.set_row(slot, self._chunk_prefill_jit(
+                # forwards against a copy of the src ROW (the retained
+                # row on a prefix hit's first chunk, the slot's own row
+                # after) and returns the slot's new row — one-row
+                # surface either way
+                self._prefill_row(
+                    self.pool, slot, self._chunk_prefill_jit,
                     self._params, self._frozen, self._buffers,
                     self.pool.row(src), ids_dev, jnp.int32(start),
-                    *self._adapter_args(slot)))
+                    *self._adapter_args(slot))
         new_cursor = min(start + bucket, s)
         self.pool.note_written(slot, new_cursor)
         self._note_prefill(h, t_pf0)
@@ -1879,8 +1916,9 @@ class InferenceEngine:
             ids[0, :s] = h.prompt_tokens
             ids_dev = call_with_retry(_to_device, ids, policy=self._retry,
                                       site='serving.h2d')
-            self.draft_pool.set_row(slot, self._draft_prefill_jit(
-                d_params, d_frozen, d_buffers, ids_dev))
+            self._prefill_row(self.draft_pool, slot,
+                              self._draft_prefill_jit, d_params, d_frozen,
+                              d_buffers, ids_dev)
         self._note_prefill(h, t_pf0)
 
     def _retire(self, slot: int, h: RequestHandle, now: float):
@@ -1912,6 +1950,10 @@ class InferenceEngine:
         """Host-side counters + compile-trace counts (the zero-recompile
         assertions read `traces`: after warmup it must stop growing
         across admissions)."""
+        traces = collections.Counter(self._trace_counts)
+        for pool in (self.pool, self.draft_pool):
+            if isinstance(pool, SlotPool):   # its seat/copy/slice programs
+                traces.update(pool.traces)
         out = {
             'submitted': self._counts['submitted'],
             'completed': self._counts['completed'],
@@ -1928,7 +1970,7 @@ class InferenceEngine:
             'weight_version': self.weight_version,
             'donate_pool': self._donate_pool,
             'kv_layout': 'paged' if self._paged else 'row',
-            'traces': dict(self._trace_counts),
+            'traces': dict(traces),
             'pool': self.pool.stats(),
         }
         if self.prefix_cache is not None:

@@ -1,34 +1,44 @@
-"""Slot-pooled KV cache: N fixed slots x max_length, allocated ONCE —
-held as PER-SLOT sub-buffers so single-slot writes never round-trip the
-whole pool.
+"""Slot-pooled KV cache: N fixed slots x max_length, allocated ONCE as
+one stacked array per layer leaf, and donated through every program that
+returns it.
 
 vLLM's PagedAttention (Kwon et al. SOSP'23) pools KV memory in small
 blocks behind an address-translation step; on TPU the same "requests
 share one preallocated cache" idea wants STATIC shapes, so the pool here
-is the coarser fixed-slot variant: one [1, max_length, H_kv, D] row per
-slot per layer (exactly the model's own `init_cache` layout with the
-batch dim reinterpreted as slots). A slot is the unit of admission:
-alloc on prefill, free on retirement, and the decode step runs over ALL
-slots every iteration with per-slot positions — freed slots are simply
-masked until a new request overwrites them, so admission never
-recompiles anything.
+is the coarser fixed-slot variant: per layer one K and one V leaf of
+[num_slots, max_length, H_kv, D] (exactly the model's own `init_cache`
+layout with the batch dim reinterpreted as slots). A slot is the unit of
+admission: alloc on prefill, free on retirement, and the decode step
+runs over ALL slots every iteration with per-slot positions — freed
+slots are simply masked until a new request overwrites them, so
+admission never recompiles anything.
 
-Representation (the ISSUE-13 copy-surface shrink): the cache is a
-LIST of per-slot row pytrees, not one stacked [N, ...] buffer. Under
-the PR-8 jaxlib constraint store-served programs run undonated, so any
-program that takes the stacked pool and returns it materializes a full
-pool copy — an ~18ms/program floor that bounded chunked prefill's win.
-With per-slot rows:
+Representation: `SlotPool.rows` IS that stacked pytree — 2 x layers
+device buffers, whatever the slot count. The decode and speculation
+programs take it as an argument, carry it through their scan and return
+it, donated, so the pool is updated in place: one copy of it lives on
+the device and a round moves only the rows the attention reads. One
+buffer per leaf is what lets donation alias it into the scan's carry: N
+per-slot arrays cannot alias into one, and a program that stacks them
+copies the whole pool in and out every round (on the v5e 26 ms of an
+89 ms round, and the pool held twice — PERF.md, PR 25).
 
-- prefill / chunk-prefill programs take and return ONE row — the
-  undonated copy surface shrinks from O(pool) to O(row) = pool/N;
-- `write_slot` / `copy_slot` are host-side row replacements (a pointer
-  assignment and a one-row device copy respectively) — the jitted
-  full-pool writer and copier are GONE, along with their compiles;
-- the decode block stacks the rows inside the program
-  (`stack_rows`) and splits its output back (`split_rows`) —
-  bit-identical math, and when the donation gauntlet enables donation
-  the row inputs alias the outputs so even that round trip vanishes.
+Single-slot traffic goes through three small compiled programs, each
+with the slot index TRACED, so each compiles once for all slots:
+
+- `set_row` seats a prefilled row: the pool DONATED, a
+  `dynamic_update_slice` of one row in place (with the dtype cast, so a
+  float32 slab lands in a bf16 pool). The prefill / chunk-prefill
+  programs themselves still take and return ONE row, undonated, so a
+  prefill that dies costs one request, never the pool;
+- `copy_slot` copies row src over row dst in place (the prefix-cache hit
+  path), the pool donated;
+- `row` slices one row out (chunked prefill's source row), undonated.
+
+A donated seat or copy that dies may have consumed the pool: the engine
+treats it as it treats a failed donated decode (`reset_rows`, then the
+error leaves `step()`). Their jitted names hold `prefill`: seating is
+part of what a prefill costs, and the traced device time books it there.
 
 Prefill shapes are length-bucketed: a prompt of length s runs at the
 smallest bucket >= s (right-padded; pad KV lands above the live
@@ -51,7 +61,7 @@ write-into-shared-page case (a full-prompt hit re-forwarding its last
 token) is copy-on-write split via `ensure_exclusive`. The compiled
 programs see (pages, scales, table) and translate addresses with
 `gather_pages` / `scatter_pages` — gather reconstructs the SAME
-[N, max_length, H, D] contiguous view the row pool stacks, so the
+[N, max_length, H, D] contiguous view the row pool holds, so the
 decode math (and greedy output) is bit-identical; scatter writes back
 only the pages overlapping the written span, so settled int8 pages are
 never requantized. Optional int8 storage keeps per-(page, head) absmax
@@ -60,6 +70,7 @@ scales (quantization.kv_page_scales semantics) alongside the pages.
 from __future__ import annotations
 
 import bisect
+import collections
 from typing import Any, List, Optional, Sequence, Tuple
 
 import jax
@@ -83,6 +94,14 @@ class PagePoolExhausted(RuntimeError):
     for retirements (or prefix-cache evictions) to return pages."""
 
 
+class PoolLostError(RuntimeError):
+    """A DONATED single-slot pool program (seat, copy) died mid-call.
+    The pool it was given may be gone, so the engine has rebuilt it and
+    every seated request has lost its KV: unlike a failed prefill this
+    is not one request's failure — it leaves `engine.step()` like a
+    failed donated decode round, with the device error as its cause."""
+
+
 def default_buckets(max_length: int, smallest: int = 8) -> Tuple[int, ...]:
     """Powers of two from `smallest` up to max_length (max_length always
     included so every admissible prompt has a bucket)."""
@@ -93,24 +112,6 @@ def default_buckets(max_length: int, smallest: int = 8) -> Tuple[int, ...]:
         b *= 2
     out.append(max_length)
     return tuple(out)
-
-
-def stack_rows(rows):
-    """Stack a sequence of per-slot row pytrees (leaves [1, ...]) into
-    the decode-facing pool pytree (leaves [N, ...]). Traced inside the
-    decode program — the math downstream is bit-identical to the old
-    stacked representation."""
-    return _tree.tree_map(lambda *ls: jnp.concatenate(ls, axis=0), *rows)
-
-
-def split_rows(stacked, n: int):
-    """Inverse of `stack_rows`: the decode program's output pool back
-    into n per-slot rows (the host-side list representation)."""
-    return tuple(
-        _tree.tree_map(
-            lambda c: jax.lax.dynamic_slice_in_dim(c, i, 1, axis=0),
-            stacked)
-        for i in range(n))
 
 
 def _leaf_bytes(tree) -> int:
@@ -143,32 +144,52 @@ def _bucket_for(buckets: Tuple[int, ...], length: int,
 
 
 class SlotPool:
-    """Owns the per-slot KV rows + the slot free list.
+    """Owns the stacked KV pool + the slot free list.
 
-    Each row is whatever `model.init_cache(1, max_length)` returns
+    `rows` is whatever `model.init_cache(num_slots, max_length)` returns
     (per-layer (K, V) pairs for every causal-LM family here), so the
-    pool works for any model honoring the init_cache contract.
+    pool works for any model honoring the init_cache contract. `donate`
+    is the engine's `donate_pool`: whether the seat and copy programs
+    take the pool donated (in place) or return a copy of it.
     """
 
     def __init__(self, model, num_slots: int, max_length: int,
-                 dtype=None, buckets: Optional[Sequence[int]] = None):
+                 dtype=None, buckets: Optional[Sequence[int]] = None,
+                 donate: bool = True):
         if num_slots < 1:
             raise ValueError('num_slots must be >= 1')
         if max_length < 2:
             raise ValueError('max_length must be >= 2')
         self.num_slots = int(num_slots)
         self.max_length = int(max_length)
-        base = model.init_cache(self.num_slots, self.max_length, dtype)
-        # split ONCE into per-slot rows (a one-time device slice); the
-        # base stacked buffer is dropped
-        self.rows: List[Any] = [
-            _tree.tree_map(lambda c: c[i:i + 1], base)
-            for i in range(self.num_slots)]
+        # THE pool: its leaves are the device buffers (the benchmark
+        # frees them through this attribute once its window has closed)
+        self.rows = model.init_cache(self.num_slots, self.max_length, dtype)
+        self._pool_spec = _tree.tree_map(
+            lambda c: jax.ShapeDtypeStruct(c.shape, c.dtype), self.rows)
         self.row_spec = _tree.tree_map(
             lambda c: jax.ShapeDtypeStruct((1,) + tuple(c.shape[1:]),
-                                           c.dtype), base)
-        self.row_bytes = _leaf_bytes(self.rows[0])
-        self.pool_bytes = self.row_bytes * self.num_slots
+                                           c.dtype), self.rows)
+        self.pool_bytes = _leaf_bytes(self.rows)
+        self.row_bytes = self.pool_bytes // self.num_slots
+        # the single-slot programs, enrolled in the program store like
+        # the engine's own (a warm replica loads them; a quarantine
+        # recompiles them undonated). `donate` rides the statics: a
+        # donated and an undonated pool never share one store key.
+        from .. import programs as _programs
+        store = _programs.get_store()
+        self.traces = collections.Counter()     # python-level traces
+        donated = {'kind': 'serving', 'statics': {'donate': bool(donate)},
+                   'donate_argnums': (0,) if donate else ()}
+        self._seat_jit = store.wrap_jit(
+            self._prefill_seat_row, name='serving.prefill_seat_row',
+            **donated)
+        self._copy_jit = store.wrap_jit(
+            self._prefill_copy_row, name='serving.prefill_copy_row',
+            **donated)
+        self._slice_jit = store.wrap_jit(
+            self._prefill_slice_row, name='serving.prefill_slice_row',
+            kind='serving')
         self.buckets = _normalize_buckets(buckets, self.max_length)
         self._free = sorted(range(self.num_slots), reverse=True)
         # per-slot high-water mark of WRITTEN rows (vs the max_length
@@ -232,65 +253,68 @@ class SlotPool:
         past the largest bucket."""
         return _bucket_for(self.buckets, length, self.max_length)
 
-    # -- the cache pytree (decode-facing view) -----------------------------
+    # -- the compiled single-slot programs --------------------------------
+    def _prefill_seat_row(self, pool, row, slot):
+        """pool[slot] = row, cast to the pool's dtype; `slot` traced."""
+        self.traces['prefill_seat_row'] += 1
+        return _tree.tree_map(
+            lambda p, r: jax.lax.dynamic_update_slice_in_dim(
+                p, r.astype(p.dtype), slot, axis=0), pool, row)
+
+    def _prefill_copy_row(self, pool, src, dst):
+        """pool[dst] = pool[src]; both traced."""
+        self.traces['prefill_copy_row'] += 1
+        return _tree.tree_map(
+            lambda p: jax.lax.dynamic_update_slice_in_dim(
+                p, jax.lax.dynamic_slice_in_dim(p, src, 1, axis=0), dst,
+                axis=0), pool)
+
+    def _prefill_slice_row(self, pool, slot):
+        """pool[slot] as a row pytree (leaves [1, ...]); `slot` traced."""
+        self.traces['prefill_slice_row'] += 1
+        return _tree.tree_map(
+            lambda p: jax.lax.dynamic_slice_in_dim(p, slot, 1, axis=0),
+            pool)
+
+    # -- the cache pytree --------------------------------------------------
     @property
     def cache(self):
-        """The decode program's pool argument: a tuple of per-slot row
-        pytrees (jax flattens it as one input tree)."""
-        return tuple(self.rows)
+        """The decode program's pool argument and result: `rows`."""
+        return self.rows
 
     @cache.setter
-    def cache(self, new_rows):
-        """Accepts the decode program's output (a sequence of N row
-        pytrees) — a host-side pointer swap per slot, no device work."""
-        new_rows = list(new_rows)
-        if len(new_rows) != self.num_slots:
-            raise ValueError(
-                f'pool update has {len(new_rows)} rows, expected '
-                f'{self.num_slots}')
-        self.rows = new_rows
+    def cache(self, new_pool):
+        self.rows = new_pool
 
     def row(self, slot: int):
-        return self.rows[slot]
+        """A copy of one slot's row (leaves [1, max_length, ...])."""
+        return self._slice_jit(self.rows, np.int32(slot))
 
     def set_row(self, slot: int, row):
-        """Replace one slot's row (dtype-cast against the row spec so a
-        float32 slab lands in a bf16 pool without moving any OTHER
-        slot). THE single-slot write surface: O(row), never O(pool)."""
-        self.rows[slot] = _tree.tree_map(
-            lambda spec, leaf: leaf if leaf.dtype == spec.dtype
-            else leaf.astype(spec.dtype),
-            self.row_spec, row)
+        """Seat a batch-1 cache (leaves [1, max_length, ...]) as the
+        pool's row `slot` — the hand-off from prefill to the pooled
+        decode step, dtype-cast so a float32 slab lands in a bf16 pool.
+        THE single-slot write surface: one row written in place."""
+        self.rows = self._seat_jit(self.rows, row, np.int32(slot))
         self._row_writes += 1
 
-    def write_slot(self, slot: int, slab):
-        """Store a batch-1 prefill cache (leaves [1, max_length, ...])
-        as the pool's row `slot` — the hand-off from prefill to the
-        pooled decode step. A host-side row replacement (plus an astype
-        when the dtypes differ): the old jitted full-pool scatter — and
-        its full-pool output copy — is gone."""
-        self.set_row(slot, slab)
-
     def copy_slot(self, src: int, dst: int):
-        """Copy row `src` into row `dst` (the prefix-cache hit path: a
-        retained prefix row becomes the new request's KV floor; stale
-        positions above the prefix are masked until the request's own
-        prefill/decode overwrites them). A ONE-row device copy — a real
-        copy, not an alias, so a donated decode round can never see the
-        same buffer twice."""
-        self.rows[dst] = _tree.tree_map(jnp.array, self.rows[src])
+        """Copy row `src` over row `dst` in place (the prefix-cache hit
+        path: a retained prefix row becomes the new request's KV floor;
+        stale positions above the prefix are masked until the request's
+        own prefill/decode overwrites them)."""
+        self.rows = self._copy_jit(self.rows, np.int32(src),
+                                   np.int32(dst))
         self._row_copies += 1
         self._copied_bytes += self.row_bytes
 
     def reset_rows(self):
-        """Re-zero every row (fresh buffers). The donation-failure
-        recovery path: if a DONATED decode program dies mid-call its
-        input rows may already be invalidated, so the engine rebuilds
-        the pool rather than risk stacking dead buffers."""
-        self.rows = [
-            _tree.tree_map(lambda s: jnp.zeros(s.shape, s.dtype),
-                           self.row_spec)
-            for _ in range(self.num_slots)]
+        """Re-zero the pool (fresh buffers). The donation-failure
+        recovery path: if a DONATED program dies mid-call the pool it
+        was given may already be invalidated, so the engine rebuilds it
+        rather than pass dead buffers on."""
+        self.rows = _tree.tree_map(
+            lambda s: jnp.zeros(s.shape, s.dtype), self._pool_spec)
 
     def _capacity_stats(self) -> dict:
         """Allocated vs written rows over USED slots: the row pool
@@ -334,9 +358,8 @@ def gather_pages(pages, table, scales=None, out_dtype=None):
     """Address-translate the page pool into the decode-facing contiguous
     view: leaves [num_pages, ps, H, D] indexed by `table` [N, P] become
     [N, P*ps, H, D] = [N, max_length, H, D] — the SAME shape (and, for
-    the unquantized path, the same bits) the row pool's `stack_rows`
-    feeds the decode scan, so the attention math downstream is
-    bit-identical. With `scales` (per-(page, head) int8 scales, leaves
+    the unquantized path, the same bits) the row pool feeds the decode
+    scan, so the attention math downstream is bit-identical. With `scales` (per-(page, head) int8 scales, leaves
     [num_pages, H]) the gather dequantizes in the same expression.
     Traced inside every paged program."""
     from ..quantization import kv_dequantize_page

@@ -154,60 +154,234 @@ def test_slot_pool_bucket_for(gpt):
         pool.bucket_for(65)
 
 
-def test_slot_pool_write_slot_touches_one_row(gpt):
-    """ISSUE-13 copy-surface contract: a write replaces ONE per-slot
-    row (host-side, zero compiled programs) and never touches the
-    other slots' buffers."""
-    pool = SlotPool(gpt, num_slots=3, max_length=16)
-    before = [jax.tree_util.tree_leaves(pool.row(i))[0]
-              for i in range(3)]
-    slab = jax.tree_util.tree_map(
-        lambda c: jnp.ones((1,) + c.shape[1:], c.dtype),
-        gpt.init_cache(1, 16))
-    pool.write_slot(1, slab)
-    k1 = np.asarray(jax.tree_util.tree_leaves(pool.row(1))[0])
-    assert (k1 == 1).all()
-    # untouched slots keep their ORIGINAL buffers (pointer-identical:
-    # nothing round-tripped the rest of the pool)
-    assert jax.tree_util.tree_leaves(pool.row(0))[0] is before[0]
-    assert jax.tree_util.tree_leaves(pool.row(2))[0] is before[2]
-    assert pool.stats()['row_writes'] == 1
-    pool.write_slot(2, slab)
-    assert pool.stats()['row_writes'] == 2
+def _leaves(tree):
+    return jax.tree_util.tree_leaves(tree)
 
 
-def test_slot_pool_copy_slot_is_one_row_and_independent(gpt):
+def _ones_row(model, max_length, value=1.0, dtype=None):
+    return jax.tree_util.tree_map(
+        lambda c: jnp.full((1,) + c.shape[1:], value, dtype or c.dtype),
+        model.init_cache(1, max_length))
+
+
+def test_slot_pool_set_row_writes_one_slot(gpt):
+    """Seating a row changes that slot bit-exactly and no other: the
+    pool is one stacked array per leaf and the seat program writes one
+    row of it."""
     pool = SlotPool(gpt, num_slots=3, max_length=16)
-    slab = jax.tree_util.tree_map(
-        lambda c: jnp.ones((1,) + c.shape[1:], c.dtype),
-        gpt.init_cache(1, 16))
-    pool.write_slot(0, slab)
+    rng = np.random.RandomState(0)
+
+    def random_row():
+        return jax.tree_util.tree_map(
+            lambda c: jnp.asarray(rng.randn(1, *c.shape[1:]), c.dtype),
+            gpt.init_cache(1, 16))
+
+    for i in range(3):                      # distinct, non-zero rows
+        pool.set_row(i, random_row())
+    before = [np.asarray(leaf) for leaf in _leaves(pool.cache)]
+    slab = random_row()
+    pool.set_row(1, slab)
+    assert pool.stats()['row_writes'] == 4
+    for was, now, row in zip(before, _leaves(pool.cache), _leaves(slab)):
+        now = np.asarray(now)
+        assert now.shape == was.shape            # still [slots, ...]
+        assert np.array_equal(now[1:2], np.asarray(row))
+        assert np.array_equal(now[0], was[0])
+        assert np.array_equal(now[2], was[2])
+    for got, row in zip(_leaves(pool.row(1)), _leaves(slab)):
+        assert got.shape == row.shape
+        assert np.array_equal(np.asarray(got), np.asarray(row))
+
+
+def test_slot_pool_set_row_casts_to_the_pool_dtype(gpt):
+    """A float32 slab lands in a bfloat16 pool (the cast is part of the
+    seat program) without disturbing the other slots."""
+    pool = SlotPool(gpt, num_slots=2, max_length=16, dtype='bfloat16')
+    slab = _ones_row(gpt, 16, 1.5, jnp.float32)
+    pool.set_row(1, slab)
+    for leaf in _leaves(pool.cache):
+        assert leaf.dtype == jnp.bfloat16
+        got = np.asarray(leaf.astype(jnp.float32))
+        assert (got[1] == 1.5).all() and (got[0] == 0).all()
+
+
+def test_slot_pool_copy_slot_is_an_independent_copy(gpt):
+    pool = SlotPool(gpt, num_slots=3, max_length=16)
+    pool.set_row(0, _ones_row(gpt, 16))
     pool.copy_slot(0, 2)
-    k2 = np.asarray(jax.tree_util.tree_leaves(pool.row(2))[0])
-    assert (k2 == 1).all()
-    # a REAL copy, not an alias: a donated decode round must never see
-    # the same buffer behind two row inputs
-    assert jax.tree_util.tree_leaves(pool.row(2))[0] is not \
-        jax.tree_util.tree_leaves(pool.row(0))[0]
+    k = np.asarray(_leaves(pool.cache)[0])
+    assert (k[2] == 1).all() and (k[0] == 1).all() and (k[1] == 0).all()
+    # a REAL copy: rewriting the source leaves the destination alone
+    pool.set_row(0, _ones_row(gpt, 16, 3.0))
+    k = np.asarray(_leaves(pool.cache)[0])
+    assert (k[0] == 3).all() and (k[2] == 1).all()
     st = pool.stats()
-    assert st['row_copies'] == 1
+    assert st['row_copies'] == 1 and st['row_writes'] == 2
     assert st['copied_bytes'] == st['row_bytes']
     assert st['pool_bytes'] == 3 * st['row_bytes']
 
 
-def test_slot_pool_stack_split_roundtrip(gpt):
-    from paddle_tpu.serving.kv_pool import split_rows, stack_rows
-    pool = SlotPool(gpt, num_slots=3, max_length=16)
-    slab = jax.tree_util.tree_map(
-        lambda c: jnp.full((1,) + c.shape[1:], 2.0, c.dtype),
-        gpt.init_cache(1, 16))
-    pool.write_slot(1, slab)
-    stacked = stack_rows(pool.cache)
-    back = split_rows(stacked, 3)
-    for i in range(3):
-        for a, b in zip(jax.tree_util.tree_leaves(pool.row(i)),
-                        jax.tree_util.tree_leaves(back[i])):
-            assert np.array_equal(np.asarray(a), np.asarray(b))
+def test_slot_pool_programs_compile_once_for_all_slots(gpt):
+    """The slot index is a traced scalar: seat, copy and slice each
+    trace and compile ONCE, whatever slots they are called with."""
+    pool = SlotPool(gpt, num_slots=4, max_length=24)   # shapes of its own
+    slab = _ones_row(gpt, 24)
+    reg = obs.get_registry()
+    pool.set_row(0, slab)
+    pool.copy_slot(0, 1)
+    pool.row(0)
+    assert dict(pool.traces) == {'prefill_seat_row': 1,
+                                 'prefill_copy_row': 1,
+                                 'prefill_slice_row': 1}
+    compiles = reg.value('paddle_jit_compiles_total')
+    for slot in (1, 2, 3, 0):
+        pool.set_row(slot, slab)
+        pool.copy_slot(slot, (slot + 1) % 4)
+        assert (np.asarray(_leaves(pool.row(slot))[0]) == 1).all()
+    assert reg.value('paddle_jit_compiles_total') == compiles
+    assert sum(pool.traces.values()) == 3
+
+
+def test_slot_pool_rows_are_the_device_buffers(gpt):
+    """`pool.rows` is the stacked pytree itself — 2 x layers leaves,
+    however many slots — so deleting its leaves frees the pool (what the
+    benchmark does before its reference pass)."""
+    layers = gpt.config.num_hidden_layers
+    pool = SlotPool(gpt, num_slots=5, max_length=16)
+    pool.set_row(3, _ones_row(gpt, 16))
+    leaves = _leaves(pool.rows)
+    assert len(leaves) == 2 * layers
+    assert sum(leaf.nbytes for leaf in leaves) == pool.pool_bytes
+    assert all(leaf.shape[0] == 5 for leaf in leaves)
+    for a, b in zip(leaves, _leaves(pool.cache)):
+        assert a is b
+    for leaf in leaves:
+        leaf.delete()
+    assert all(leaf.is_deleted() for leaf in _leaves(pool.cache))
+    pool.reset_rows()                       # and it can be rebuilt
+    assert not any(np.asarray(leaf).any() for leaf in _leaves(pool.rows))
+
+
+def test_undonated_slot_pool_leaves_its_input_alive(gpt):
+    """`donate=False` (the engine's donate_pool=False): the seat program
+    returns a new pool and the one it was given stays valid."""
+    pool = SlotPool(gpt, num_slots=2, max_length=16, donate=False)
+    old = _leaves(pool.rows)
+    pool.set_row(1, _ones_row(gpt, 16))
+    assert not any(leaf.is_deleted() for leaf in old)
+    assert not np.asarray(old[0]).any()
+    assert (np.asarray(_leaves(pool.rows)[0])[1] == 1).all()
+
+
+def _decode_args(eng):
+    return (eng._params, eng._frozen, eng._buffers, eng.pool.cache,
+            eng._tok, eng._pos, eng._steps, eng._active, eng._temp,
+            eng._topk, eng._topp, eng._greedy, eng._keys)
+
+
+def test_decode_program_takes_the_stacked_pool_donated(gpt):
+    """The decode call passes 2 x layers pool arrays, all donated, builds
+    no pool leaf by concatenating slot rows, and the compiled program
+    aliases the whole pool from its inputs to its outputs."""
+    layers = gpt.config.num_hidden_layers
+    eng = InferenceEngine(gpt, num_slots=3, max_length=32, decode_block=2)
+    lowered = eng._decode_jit.lower(*_decode_args(eng))
+    pool_args = _leaves(lowered.args_info[0][3])
+    assert len(pool_args) == 2 * layers
+    assert all(a.donated for a in pool_args)
+    leaf = _leaves(eng.pool.cache)[0]
+    shape = 'x'.join(str(d) for d in leaf.shape)
+    for line in lowered.as_text().splitlines():
+        if 'concatenate' in line:
+            assert f'-> tensor<{shape}x' not in line, line
+    mem = lowered.compile().memory_analysis()
+    assert mem.alias_size_in_bytes == eng.pool.pool_bytes
+
+
+def test_failed_donated_seat_recovers_the_pool(gpt):
+    """A seat program dying with the pool donated to it is the STEP's
+    failure (every seated request lost its KV), not one request's: the
+    pool is rebuilt, the error leaves step() typed, the handles are
+    still there for the router to fail over, and the engine serves the
+    next request correctly."""
+    from paddle_tpu.serving import PoolLostError
+    eng = InferenceEngine(gpt, num_slots=2, max_length=64, decode_block=2)
+    prompts = _prompts((6, 9))
+    ref = _ref_generate(gpt, prompts[0], 4)
+    real = eng.pool._seat_jit
+
+    def dying(pool, row, slot):
+        for leaf in _leaves(pool):
+            leaf.delete()                   # what a donated call may do
+        raise RuntimeError('simulated device failure mid-seat')
+
+    eng.pool._seat_jit = dying
+    hs = [eng.submit(p, max_new_tokens=4, eos_token_id=NO_EOS)
+          for p in prompts]
+    with pytest.raises(PoolLostError, match='mid-seat') as err:
+        eng.step()
+    assert isinstance(err.value.__cause__, RuntimeError)
+    assert 'serving_pool_recovered' in [e['name'] for e in
+                                        obs.get_event_log().events()]
+    assert not any(h.done for h in hs)      # nobody was failed in place
+    assert not any(leaf.is_deleted() for leaf in _leaves(eng.pool.rows))
+    assert sorted(h.request_id for h in eng.evict_all()) == \
+        sorted(h.request_id for h in hs)    # seated AND popped-behind
+    assert eng.pool.free_count == 2
+    eng.pool._seat_jit = real
+    h = eng.submit(prompts[0], max_new_tokens=4, eos_token_id=NO_EOS)
+    eng.run()
+    assert h.status == FINISHED and h.tokens == ref
+
+
+def test_one_prefill_row_in_flight(gpt, monkeypatch):
+    """The runtime reserves a program's outputs when it is enqueued, so
+    admissions dispatched back to back would each hold a row until its
+    seat ran. Every prefill dispatch therefore waits for what is queued
+    on the pool — the seat of the admission before it."""
+    eng = InferenceEngine(gpt, num_slots=3, max_length=64, decode_block=2)
+    order = []
+    real_wait, real_prefill = jax.block_until_ready, eng._prefill_jit
+
+    def wait(tree):
+        order.append('wait' if tree is eng.pool.rows else 'wait-other')
+        return real_wait(tree)
+
+    def prefill(*args):
+        order.append('prefill')
+        return real_prefill(*args)
+
+    monkeypatch.setattr(jax, 'block_until_ready', wait)
+    eng._prefill_jit = prefill
+    for p in _prompts((5, 9, 13)):
+        eng.submit(p, max_new_tokens=2, eos_token_id=NO_EOS)
+    eng.step()                              # three admissions, one step
+    assert [o for o in order if o != 'wait-other'] == \
+        ['wait', 'prefill'] * 3
+
+
+def test_failed_undonated_seat_is_request_level(gpt):
+    """With donate_pool=False nothing was donated, so a dying seat costs
+    its own request and nobody else."""
+    eng = InferenceEngine(gpt, num_slots=2, max_length=64, decode_block=2,
+                          donate_pool=False)
+    prompts = _prompts((6, 9))
+    ref = _ref_generate(gpt, prompts[1], 4)
+    real = eng.pool._seat_jit
+    calls = []
+
+    def dying_once(pool, row, slot):
+        calls.append(slot)
+        if len(calls) == 1:
+            raise RuntimeError('simulated seat failure')
+        return real(pool, row, slot)
+
+    eng.pool._seat_jit = dying_once
+    hs = [eng.submit(p, max_new_tokens=4, eos_token_id=NO_EOS)
+          for p in prompts]
+    eng.run()
+    assert hs[0].status == FAILED and hs[1].status == FINISHED
+    assert hs[1].tokens == ref
 
 
 # ---------------------------------------------------------------------------
