@@ -7,6 +7,7 @@ submodule alias mirrors the reference's import path
 """
 from __future__ import annotations
 
+from .afmoe import AfmoeConfig, AfmoeForCausalLM, AfmoeModel
 from .bert import (BertConfig, BertForMaskedLM,
                    BertForSequenceClassification, BertModel)
 from .ernie import (ErnieConfig, ErnieForMaskedLM,
@@ -21,7 +22,7 @@ from .tokenizer import (BPETokenizer, PretrainedTokenizer,
 from . import transformers  # noqa: E402  (API-parity alias module)
 
 __all__ = [
-    'BertConfig', 'BertForMaskedLM', 'BertForSequenceClassification',
+    'AfmoeConfig', 'AfmoeForCausalLM', 'AfmoeModel', 'BertConfig', 'BertForMaskedLM', 'BertForSequenceClassification',
     'BertModel', 'ErnieConfig', 'ErnieForMaskedLM',
     'ErnieForSequenceClassification', 'ErnieModel', 'GenerationMixin',
     'GPTConfig', 'GPTForCausalLM', 'GPTModel', 'LlamaConfig',

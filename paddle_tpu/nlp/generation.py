@@ -11,6 +11,7 @@ early exit when every sequence has emitted EOS.
 """
 from __future__ import annotations
 
+import threading
 import warnings
 from typing import Any, Dict, Optional, Tuple
 
@@ -125,6 +126,55 @@ def padded_decode_mask(keep, cache_len, cache_offset, sq):
     self_ok = k_slot[None, :] == q_slot[:, None]             # [Sq, L]
     m = causal[None] & (keep_full[:, None, :] | self_ok[None])
     return m[:, None]                                        # [B,1,Sq,L]
+
+
+class _RoutingState(threading.local):
+    def __init__(self):
+        self.picks = None
+
+
+_routing = _RoutingState()
+
+
+class routing_scope:
+    """`with routing_scope() as picks: fwd(...)` — what the expert layers
+    traced inside the block routed to, in the idiom of the serving
+    engine's `adapter_scope`: trace-time thread-local state, inert
+    outside a scope. `picks` gets one `(selected [B, S, k] int32 raw
+    array, number of experts)` per expert layer, in layer order; a model
+    without an expert layer leaves it empty, and the program traced
+    around it is the one it was."""
+
+    __slots__ = ('_prev', '_picks')
+
+    def __enter__(self):
+        self._prev = _routing.picks
+        self._picks = _routing.picks = []
+        return self._picks
+
+    def __exit__(self, *exc):
+        _routing.picks = self._prev
+        return False
+
+
+def note_routing(selected, num_experts):
+    """Called by an expert layer with the experts it selected."""
+    if _routing.picks is not None:
+        _routing.picks.append((to_jax(selected), int(num_experts)))
+
+
+def experts_touched(picks, active):
+    """[number of expert layers] int32: per layer, how many distinct
+    experts the rows of `active` ([B] bool) routed to; None without an
+    expert layer."""
+    if not picks:
+        return None
+    out = []
+    for sel, e in picks:
+        hit = (sel[..., None] == jnp.arange(e, dtype=sel.dtype)) \
+            & active[:, None, None, None]
+        out.append(jnp.sum(jnp.any(hit, axis=(0, 1, 2)), dtype=jnp.int32))
+    return jnp.stack(out)
 
 
 def _process_logits(logits, temperature, top_k, top_p):

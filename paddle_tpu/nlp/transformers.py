@@ -1,4 +1,5 @@
 """Import-path parity with the reference's `paddlenlp.transformers`."""
+from .afmoe import AfmoeConfig, AfmoeForCausalLM, AfmoeModel  # noqa: F401
 from .bert import (BertConfig, BertForMaskedLM,  # noqa: F401
                    BertForSequenceClassification, BertModel)
 from .ernie import (ErnieConfig, ErnieForMaskedLM,  # noqa: F401

@@ -80,24 +80,37 @@ def rms_norm(v, epsilon=1e-6, axis=-1):
 
 def _attention_xla(q, k, v, mask=None, causal=False, dropout_p=0.0,
                    dropout_key=None):
-    """Reference attention in [B, S, H, D] layout (paddle SDPA convention)."""
+    """Reference attention in [B, S, H, D] layout (paddle SDPA convention).
+    Grouped KV heads (`H_kv < H`) are contracted in place: the query
+    heads are viewed as [H_kv, rep] groups and each group reads its one
+    K/V head — K and V are never repeated (a `jnp.repeat` of a decode
+    cache is a copy of the whole cache, `rep` times its size, per layer
+    and sub-step). `rep == 1` takes the ungrouped contraction."""
     b, sq, h, d = q.shape
     sk = k.shape[1]
     kv_heads = k.shape[2]
-    if kv_heads != h:  # GQA: broadcast kv heads across query groups
-        rep = h // kv_heads
-        k = jnp.repeat(k, rep, axis=2)
-        v = jnp.repeat(v, rep, axis=2)
+    rep = h // kv_heads
     scale = 1.0 / np.sqrt(d)
-    # [B, H, Sq, Sk]
-    logits = jnp.einsum('bqhd,bkhd->bhqk', q, k,
-                        preferred_element_type=jnp.float32) * scale
+    if rep == 1:
+        # [B, H, Sq, Sk]
+        logits = jnp.einsum('bqhd,bkhd->bhqk', q, k,
+                            preferred_element_type=jnp.float32) * scale
+    else:
+        # [B, H_kv, rep, Sq, Sk]: query head j * rep + r reads KV head j
+        logits = jnp.einsum('bqhrd,bkhd->bhrqk',
+                            q.reshape(b, sq, kv_heads, rep, d), k,
+                            preferred_element_type=jnp.float32) * scale
     if causal:
         idx_q = jnp.arange(sq)[:, None] + (sk - sq)
         idx_k = jnp.arange(sk)[None, :]
         neg = jnp.asarray(jnp.finfo(jnp.float32).min, jnp.float32)
         logits = jnp.where(idx_k <= idx_q, logits, neg)
     if mask is not None:
+        if rep != 1:
+            # [B, 1, Sq, Sk] broadcasts over both head axes; a per-head
+            # [B, H, Sq, Sk] mask is viewed in the same groups
+            mask = mask[:, :, None] if mask.shape[1] == 1 else \
+                mask.reshape(mask.shape[0], kv_heads, rep, *mask.shape[2:])
         if mask.dtype == jnp.bool_:
             logits = jnp.where(mask, logits,
                                jnp.asarray(jnp.finfo(jnp.float32).min))
@@ -107,8 +120,10 @@ def _attention_xla(q, k, v, mask=None, causal=False, dropout_p=0.0,
     if dropout_p and dropout_key is not None:
         keep = jax.random.bernoulli(dropout_key, 1.0 - dropout_p, probs.shape)
         probs = jnp.where(keep, probs / (1.0 - dropout_p), 0.0)
-    out = jnp.einsum('bhqk,bkhd->bqhd', probs.astype(q.dtype), v)
-    return out
+    if rep == 1:
+        return jnp.einsum('bhqk,bkhd->bqhd', probs.astype(q.dtype), v)
+    out = jnp.einsum('bhrqk,bkhd->bqhrd', probs.astype(q.dtype), v)
+    return out.reshape(b, sq, h, d)
 
 
 def flash_attention(q, k, v, mask=None, causal=False, dropout_p=0.0,

@@ -57,8 +57,12 @@ def _fa_block_sizes(sq, sk):
 def flash_attention(q, k, v, causal=False):
     """[B, S, H, D] flash attention via the jax pallas TPU kernel.
 
-    GQA is handled by repeating KV heads (the kernel wants equal heads);
-    the repeat is free at trace level — XLA broadcasts, it does not copy.
+    GQA is handled by repeating KV heads (the kernel wants equal heads).
+    The repeat is NOT free: XLA materialises it, `rep` copies of K and of
+    V (measured on the v5e in the XLA decode path: 0.59 ms per 192 MiB
+    copy, PERF.md section 5; `ops.pallas._attention_xla` therefore
+    contracts over KV groups in place). Here it is a copy of the
+    [B, S, H_kv, D] training activations, once per call.
     PADDLE_TPU_OWN_FLASH=1 switches to this repo's own fwd+bwd kernels
     (flash_attention_own) instead of the jax library's.
     """

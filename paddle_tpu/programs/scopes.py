@@ -1,8 +1,9 @@
 """Which named scope does a compiled instruction belong to.
 
 The layers of what is compiled are traced under `jax.named_scope`s of
-one fixed vocabulary (`SCOPES`: nlp/gpt.py, nlp/llama.py, the loss, the
-optimizer's functional update, the engine's sampling and KV writes), so
+one fixed vocabulary (`SCOPES`: nlp/gpt.py, nlp/llama.py, nlp/afmoe.py's
+expert layer, the loss, the optimizer's functional update, the engine's
+sampling and KV writes), so
 every HLO instruction's `op_name` metadata says where it came from:
 `jit(step_fn)/jvp(mlp)/dot_general` is the forward pass of an MLP,
 `.../transpose(jvp(attention))/...` the backward pass of attention,
@@ -24,7 +25,7 @@ from .store import get_store
 # the vocabulary; tests/test_spans_scopes.py pins it to what the programs
 # carry
 SCOPES = ('embed', 'attention', 'mlp', 'norm', 'lm_head', 'loss', 'sample',
-          'kv_write', 'optimizer')
+          'kv_write', 'optimizer', 'moe/router', 'moe/experts', 'moe/shared')
 
 _INSTR = re.compile(r'^\s*(?:ROOT\s+)?%?([\w.\-]+) = ')
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
@@ -176,11 +177,21 @@ def scope_table() -> Dict[str, Dict[str, Tuple[str, str, tuple, str]]]:
 def scope_path(op_name: str) -> Tuple[str, ...]:
     """The vocabulary scopes an `op_name` lies under, outermost first:
     `jit(f)/transpose(jvp(attention))/kv_write/scatter` ->
-    ('attention', 'kv_write')."""
-    out = []
+    ('attention', 'kv_write'). A name of the vocabulary may span two
+    segments (`moe/router`)."""
+    parts = []
     for part in op_name.split('/'):
         while '(' in part:                  # jvp(x), transpose(jvp(x))
             part = part[part.index('(') + 1:].rstrip(')')
-        if part in SCOPES:
-            out.append(part)
+        parts.append(part)
+    out, i = [], 0
+    while i < len(parts):
+        pair = '/'.join(parts[i:i + 2])
+        if pair in SCOPES:
+            out.append(pair)
+            i += 2
+            continue
+        if parts[i] in SCOPES:
+            out.append(parts[i])
+        i += 1
     return tuple(out)

@@ -60,7 +60,8 @@ import numpy as np
 from .. import observability as _obs
 from ..observability import reqledger as _reqledger
 from ..jit import functional_state
-from ..nlp.generation import _NEG_INF, cached_forward
+from ..nlp.generation import (_NEG_INF, cached_forward, experts_touched,
+                              routing_scope)
 from ..resilience import RetryPolicy, call_with_retry
 from ..tensor import Tensor
 from .adapters.apply import adapter_scope as _adapter_scope
@@ -340,6 +341,19 @@ class InferenceEngine:
         self._adapter_rows = np.zeros(n, np.int32)  # 0 = base adapter
         self._slot_req: dict = {}               # slot -> RequestHandle
 
+        # per layer, the most rows a query can see (a window layer's
+        # window, else the slot): what `needed_rows` is counted from
+        n_layers = len(self.pool.row_spec)
+        windows = getattr(model, 'attention_windows',
+                          lambda: (None,) * n_layers)()
+        self._layer_rows = np.array(
+            [self.pool.max_length if w is None
+             else min(int(w), self.pool.max_length) for w in windows],
+            np.int64)
+        self._read_rows = int(self.pool.num_slots * self.pool.max_length
+                              * n_layers)
+        self._num_experts = int(getattr(cfg, 'num_experts', 0) or 0)
+
         self._trace_counts = collections.Counter()
         self._counts = collections.Counter()
         # enrolled in the program store: per-program FLOPs/bytes/peak
@@ -526,6 +540,10 @@ class InferenceEngine:
         self._m_spec_shared_acc = reg.counter(
             'paddle_spec_accepted_drafts_total',
             'draft tokens accepted by source', ('source',))
+        self._m_experts_touched = reg.counter(
+            'paddle_serving_moe_experts_touched_total',
+            'distinct experts active slots routed to, summed over decode '
+            'sub-steps and expert layers')
         if _obs.enabled():
             self._m_slots.set(self.pool.num_slots)
 
@@ -545,6 +563,20 @@ class InferenceEngine:
         adapter mix replays this same program."""
         self._trace_counts['decode_step'] += 1   # python-level trace count
         fwd = cached_forward(self.model, params, frozen, buffers)
+        return self._decode_scan(fwd, pool, tok, pos, steps, active, temp,
+                                 topk, topp, greedy, keys, adapters,
+                                 adapter_rows)
+
+    def _decode_scan(self, fwd, pool, tok, pos, steps, active, temp, topk,
+                     topp, greedy, keys, adapters, adapter_rows):
+        """The per-token scan both decode programs run over a contiguous
+        [num_slots, max_length, H, D] view. -> (tokens [num_slots,
+        block], the pool) and, where the model has expert layers, a
+        third result: int32 [block, expert layers], the number of
+        distinct experts the ACTIVE slots routed to in each sub-step
+        and layer, gathered as the model is traced (`routing_scope`). A
+        model without experts leaves nothing there, and its program is
+        the one it was."""
         max_len = self.pool.max_length
         k_slot = jnp.arange(max_len, dtype=jnp.int32)
 
@@ -552,21 +584,25 @@ class InferenceEngine:
             tok, pos, steps, pool = carry
             # pending token writes its KV at slot `pos` and attends to
             # every slot <= pos; freed/stale rows above are masked out
+            # (a window layer narrows the mask by itself, from `pos`)
             mask = (k_slot[None, :] <= pos[:, None])[:, None, None, :]
-            logits, pool = fwd(tok[:, None], pool, pos, pos, mask)
+            with routing_scope() as picks:
+                logits, pool = fwd(tok[:, None], pool, pos, pos, mask)
             nxt = sample_rows(logits[:, -1], temp, topk, topp, greedy,
                               keys, steps)
             nxt = jnp.where(active, nxt, 0).astype(jnp.int32)
             pos = jnp.minimum(pos + 1, jnp.int32(max_len - 1))
-            return (nxt, pos, steps + 1, pool), nxt
+            touched = experts_touched(picks, active)
+            return (nxt, pos, steps + 1, pool), \
+                ((nxt,) if touched is None else (nxt, touched))
 
         # the scope is trace-time thread-local state: every tagged
         # Linear the scan body traces adds its gathered per-row delta
         with _adapter_scope(adapters, adapter_rows):
-            (tok, pos, steps, pool), toks = jax.lax.scan(
+            (tok, pos, steps, pool), (toks, *touched) = jax.lax.scan(
                 sub, (tok, pos, steps, pool), None,
                 length=self.decode_block)
-        return jnp.transpose(toks), pool    # [num_slots, block] tokens
+        return (jnp.transpose(toks), pool, *touched)  # [num_slots, block]
 
     def _prefill_fn(self, params, frozen, buffers, ids,
                     adapters=None, adapter_rows=None):
@@ -711,33 +747,17 @@ class InferenceEngine:
         donated (argnums 3, 4) so the pool aliases in place."""
         self._trace_counts['paged_decode_step'] += 1
         fwd = cached_forward(self.model, params, frozen, buffers)
-        max_len = self.pool.max_length
-        k_slot = jnp.arange(max_len, dtype=jnp.int32)
         sc = scales if self.pool.quant else None
         table = jnp.where(active[:, None], table, 0)
         contig = gather_pages(pages, table, sc,
                               out_dtype=self.pool.compute_dtype)
-        pos0 = pos
-
-        def sub(carry, _):
-            tok, pos, steps, pool = carry
-            mask = (k_slot[None, :] <= pos[:, None])[:, None, None, :]
-            logits, pool = fwd(tok[:, None], pool, pos, pos, mask)
-            nxt = sample_rows(logits[:, -1], temp, topk, topp, greedy,
-                              keys, steps)
-            nxt = jnp.where(active, nxt, 0).astype(jnp.int32)
-            pos = jnp.minimum(pos + 1, jnp.int32(max_len - 1))
-            return (nxt, pos, steps + 1, pool), nxt
-
-        with _adapter_scope(adapters, adapter_rows):
-            (tok, pos, steps, contig), toks = jax.lax.scan(
-                sub, (tok, pos, steps, contig), None,
-                length=self.decode_block)
-        pages, sc = scatter_pages(pages, table, contig, pos0,
+        toks, contig, *touched = self._decode_scan(
+            fwd, contig, tok, pos, steps, active, temp, topk, topp, greedy,
+            keys, adapters, adapter_rows)
+        pages, sc = scatter_pages(pages, table, contig, pos,
                                   self.decode_block,
                                   self.pool.page_size, sc)
-        return (jnp.transpose(toks), pages,
-                sc if sc is not None else ())
+        return (toks, pages, sc if sc is not None else (), *touched)
 
     def _paged_prefill_fn(self, params, frozen, buffers, pages, scales,
                           table, ids, adapters=None, adapter_rows=None):
@@ -1326,6 +1346,26 @@ class InferenceEngine:
                 else self._adapter_rows[slot:slot + 1])
         return (self.adapter_bank.device_arrays(), rows)
 
+    def _needed_rows(self) -> int:
+        """Cache rows this round's attention NEEDS, over active slots
+        and layers: the rows a slot has written, and on a window layer
+        at most the window. What the program READS is `_read_rows`:
+        every slot's `max_length` rows on every layer."""
+        written = self._pos[self._active].astype(np.int64) + 1
+        return int(np.minimum(written[:, None],
+                              self._layer_rows[None, :]).sum())
+
+    def _note_routing(self, round_span, touched):
+        """Book a round's routing counts (`[decode_block, expert
+        layers]` distinct experts the active slots routed to) on its
+        span and on `paddle_serving_moe_experts_touched_total`."""
+        n = int(touched.sum())
+        round_span.set(experts_touched=n,
+                       expert_layer_substeps=int(touched.size),
+                       experts=self._num_experts)
+        if _obs.enabled():
+            self._m_experts_touched.inc(n)
+
     def _decode_round(self):
         """The plain compiled decode block (no draft model): every
         active slot advances `decode_block` tokens. Its span carries,
@@ -1337,7 +1377,9 @@ class InferenceEngine:
         with _obs.span('serving.decode_round',
                        active=int(np.count_nonzero(self._active)),
                        slots=self.pool.num_slots,
-                       real_rows=self.pool.written_rows):
+                       real_rows=self.pool.written_rows) as round_span:
+            round_span.set(needed_rows=self._needed_rows(),
+                           read_rows=self._read_rows)
             try:
                 with _obs.span('serving.decode_dispatch'):
                     if self._paged:
@@ -1345,7 +1387,7 @@ class InferenceEngine:
                         table = call_with_retry(
                             _to_device, self.pool.page_table,
                             policy=self._retry, site='serving.h2d')
-                        toks_dev, new_pages, new_scales = \
+                        toks_dev, new_pages, new_scales, *touched = \
                             self._decode_jit(
                                 self._params, self._frozen, self._buffers,
                                 pages, scales, table, self._tok,
@@ -1355,7 +1397,7 @@ class InferenceEngine:
                                 *self._adapter_args())
                         self.pool.set_device_state(new_pages, new_scales)
                     else:
-                        toks_dev, new_pool = self._decode_jit(
+                        toks_dev, new_pool, *touched = self._decode_jit(
                             self._params, self._frozen, self._buffers,
                             self.pool.cache, self._tok, self._pos,
                             self._steps, self._active, self._temp,
@@ -1370,6 +1412,12 @@ class InferenceEngine:
                 toks = call_with_retry(_from_device, toks_dev,
                                        policy=self._retry,
                                        site='serving.d2h')
+                if touched:
+                    # the routing counts left the device with the
+                    # tokens: ready when they are, no further wait
+                    self._note_routing(round_span, call_with_retry(
+                        _from_device, touched[0], policy=self._retry,
+                        site='serving.d2h'))
         _obs.note_progress('decode')   # /healthz decode liveness beat
         self._counts['decode_steps'] += self.decode_block
         if _obs.enabled():
