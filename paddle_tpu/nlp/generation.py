@@ -80,16 +80,29 @@ def update_kv_cache(k_cache, v_cache, k, v, offset):
     per-row slots (the serving engine's slot pool, where every sequence
     decodes at its own position). Returns (k_cache, v_cache) Tensors.
     Shared by every causal-LM family so decode-cache semantics can never
-    diverge between models."""
+    diverge between models.
+
+    Per-row offsets store all B x S rows by ONE scatter whose windows
+    are whole [H_kv, D] rows at explicit (slot, row) indices. The shape
+    matters on the chip: the v5e compiler runs such a scatter natively
+    on the donated leaf, where `vmap(dynamic_update_slice)` — a scatter
+    that keeps the rows as a window dimension — was expanded into a
+    `while` over the slots, twice a layer, in every decode sub-step
+    (0.8 of serve-chat's 14.6 ms: PERF.md, PR 27). A block that would
+    run past the slot's end starts at `L - S`, as `dynamic_update_slice`
+    clamps it."""
     from ..tensor import apply_op as _apply
     off = offset.value if isinstance(offset, Tensor) else offset
 
     def upd(c, new):
         new = new.astype(c.dtype)
         if jnp.ndim(off) >= 1:
-            return jax.vmap(
-                lambda cr, nr, o: jax.lax.dynamic_update_slice(
-                    cr, nr, (o, 0, 0)))(c, new, jnp.asarray(off, jnp.int32))
+            b, s = new.shape[:2]
+            start = jnp.clip(jnp.asarray(off, jnp.int32), 0, c.shape[1] - s)
+            return c.at[jnp.arange(b, dtype=jnp.int32)[:, None],
+                        offset_grid(start, s)].set(
+                new, indices_are_sorted=True, unique_indices=True,
+                mode='promise_in_bounds')
         return jax.lax.dynamic_update_slice(c, new, (0, off, 0, 0))
     return (_apply(upd, k_cache, k, _name='cache_update'),
             _apply(upd, v_cache, v, _name='cache_update'))

@@ -576,7 +576,17 @@ class InferenceEngine:
         distinct experts the ACTIVE slots routed to in each sub-step
         and layer, gathered as the model is traced (`routing_scope`). A
         model without experts leaves nothing there, and its program is
-        the one it was."""
+        the one it was.
+
+        The pool is the scan's carry, read (attention) and written (one
+        row a slot and leaf, `update_kv_cache` under scope `kv_write`)
+        in one body. The write is one scatter a leaf, which the v5e
+        compiler runs natively; its former vmapped form became a `while`
+        over the slots, twice a layer. What the compiler still does to
+        such a body: for one of a layer's two leaves it brings the whole
+        leaf into its fast memory for attention, lets the write land
+        there and copies the leaf back out (PERF.md sections 5 and 7:
+        what turns that off, and why it is not turned off yet)."""
         max_len = self.pool.max_length
         k_slot = jnp.arange(max_len, dtype=jnp.int32)
 

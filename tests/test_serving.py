@@ -385,6 +385,81 @@ def test_failed_undonated_seat_is_request_level(gpt):
 
 
 # ---------------------------------------------------------------------------
+# the KV write: generation.update_kv_cache against a plain numpy loop
+# ---------------------------------------------------------------------------
+
+def _np_kv_write(cache, new, off):
+    """The write as `dynamic_update_slice` defines it, slot by slot: a
+    block that would pass the slot's end starts at L - S instead."""
+    out = np.array(cache)
+    b, s = new.shape[:2]
+    for i in range(b):
+        o = int(off) if np.ndim(off) == 0 else int(off[i])
+        o = min(max(o, 0), cache.shape[1] - s)
+        out[i, o:o + s] = np.asarray(new[i]).astype(cache.dtype)
+    return out
+
+
+_L = 16     # rows of a slot in these cases
+_KV_WRITE_CASES = {
+    # decode: one row a slot, each at its own position
+    's1_heads16': dict(heads=16, s=1, off=[0, 7, 3, 15, 9]),
+    's1_heads8': dict(heads=8, s=1, off=[5, 0, 14, 2, 2]),
+    's1_heads4': dict(heads=4, s=1, off=[1, 1, 8, 13, 6]),
+    # speculation's k+1 rows: 14 and 15 would pass the end of a 16-row
+    # slot and are clamped to 12, as dynamic_update_slice clamps them
+    's4_clamped_at_the_end': dict(heads=4, s=4, off=[0, 14, 15, 12, 5]),
+    # a chunk as long as the slot: every offset clamps to 0
+    's16_whole_slot': dict(heads=4, s=_L, off=[0, 3, 15, 1, 9]),
+    # bf16 rows (the model's activations) into the f32 leaf of the cells
+    'bf16_rows_into_f32': dict(heads=8, s=1, off=[2, 11, 4, 0, 15],
+                               new_dtype='bfloat16'),
+    # inactive slots are parked at the last row: the junk they write
+    # lands there, before anything attends it
+    'parked_at_the_last_row': dict(heads=4, s=1, off=[15, 15, 6, 15, 15]),
+    # scalar offset (generate(), beam search, t5, whole and chunked
+    # prefill): one dynamic_update_slice, as before
+    'scalar_offset': dict(heads=4, s=3, off=6),
+    'scalar_offset_clamped': dict(heads=4, s=3, off=15),
+}
+
+
+@pytest.mark.parametrize('case', list(_KV_WRITE_CASES))
+def test_update_kv_cache_matches_a_plain_loop(case):
+    spec = _KV_WRITE_CASES[case]
+    h, s, d, b = spec['heads'], spec['s'], 8, 5
+    rng = np.random.RandomState(len(case))
+    new_dt = spec.get('new_dtype', 'float32')
+    caches = [rng.randn(b, _L, h, d).astype('float32') for _ in range(2)]
+    news = [jnp.asarray(rng.randn(b, s, h, d), new_dt) for _ in range(2)]
+    off = spec['off']
+    off_dev = jnp.asarray(off, jnp.int32)
+
+    @jax.jit
+    def write(kc, vc, k, v, o):
+        out = generation.update_kv_cache(
+            paddle.Tensor(kc), paddle.Tensor(vc), paddle.Tensor(k),
+            paddle.Tensor(v), o)
+        return tuple(t.value for t in out)
+
+    got = write(jnp.asarray(caches[0]), jnp.asarray(caches[1]), *news,
+                off_dev)
+    for g, c, n in zip(got, caches, news):
+        assert g.dtype == jnp.float32 and g.shape == c.shape
+        np.testing.assert_array_equal(np.asarray(g),
+                                      _np_kv_write(c, np.asarray(n), off))
+    # bit-equal to the one dynamic_update_slice a scalar offset has always
+    # been, and per row to what the vmapped form gave
+    kc, k = jnp.asarray(caches[0]), news[0].astype(jnp.float32)
+    if np.ndim(off) == 0:
+        want = jax.lax.dynamic_update_slice(kc, k, (0, off, 0, 0))
+    else:
+        want = jax.vmap(lambda cr, nr, o: jax.lax.dynamic_update_slice(
+            cr, nr, (o, 0, 0)))(kc, k, off_dev)
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want))
+
+
+# ---------------------------------------------------------------------------
 # scheduler
 # ---------------------------------------------------------------------------
 
