@@ -2291,69 +2291,10 @@ def coldstart_ab(steps=3, timeout_s=420):
     }
 
 
-def coldstart_overhead_ab(steps=30, trials=3):
-    """A/B a jitted TrainStep loop with the program store bypassed
-    (FLAGS_program_store=False — the pre-store AOT path) vs enrolled
-    (memory tier; no directory), with the same min-of-adjacent-pair-
-    ratios estimator as the elastic guard. The store's per-call cost
-    after the first signature resolution is one dict hit either way, so
-    the steady-state ratio must stay under the tier-1 3% bar."""
-    import paddle_tpu as paddle
-    import paddle_tpu.nn.functional as F
-    from paddle_tpu import flags as _pflags
-    from paddle_tpu.jit import TrainStep
-    import paddle_tpu.nn as nn
-
-    rng = np.random.RandomState(0)
-    x = rng.standard_normal((32, 64)).astype('float32')
-    y = rng.randint(0, 10, (32,))
-
-    def run(store_on):
-        import time as _t
-        _pflags.set_flags({'FLAGS_program_store': bool(store_on)})
-        try:
-            paddle.seed(0)
-            model = nn.Sequential(nn.Linear(64, 128), nn.ReLU(),
-                                  nn.Linear(128, 10))
-            opt = paddle.optimizer.SGD(learning_rate=0.01,
-                                       parameters=model.parameters())
-            step = TrainStep(model,
-                             lambda out, lab: F.cross_entropy(out, lab),
-                             opt)
-            xs, ys = paddle.to_tensor(x), paddle.to_tensor(y)
-            float(step(xs, ys).numpy())   # compile outside the window
-            t0 = _t.perf_counter()
-            for _ in range(steps):
-                loss = step(xs, ys)
-            float(loss.numpy())           # sync
-            return steps / (_t.perf_counter() - t0)
-        finally:
-            _pflags.set_flags({'FLAGS_program_store': True})
-
-    best_on = best_off = 0.0
-    ratios = []
-    for _ in range(trials):
-        off = run(store_on=False)
-        on = run(store_on=True)
-        best_off = max(best_off, off)
-        best_on = max(best_on, on)
-        if on:
-            ratios.append(off / on)
-    overhead = min(ratios) - 1 if ratios else float('inf')
-    return {
-        'store_steps_per_sec': round(best_on, 1),
-        'bypass_steps_per_sec': round(best_off, 1),
-        'overhead_ratio': round(best_off / best_on, 4) if best_on else 0.0,
-        'overhead_pct': round(overhead * 100, 2),
-    }
-
-
 def _phase_coldstart():
     """Cold-restart phase: empty-store vs populated-store process
     restart A/B (warm path guarded to zero XLA compiles + bit-exact),
-    then the store-bypassed overhead guard. The restart A/B runs FIRST
-    and entirely in subprocesses — this phase process must not touch
-    the device before its children have."""
+    entirely in subprocesses."""
     out = {}
     try:
         out['coldstart'] = coldstart_ab()
@@ -2361,12 +2302,6 @@ def _phase_coldstart():
         print(f'# coldstart bench failed: {type(e).__name__}: {e}',
               file=sys.stderr)
         out['coldstart'] = {'error': type(e).__name__}
-    try:
-        out['coldstart_overhead'] = coldstart_overhead_ab()
-    except Exception as e:
-        print(f'# coldstart overhead bench failed: '
-              f'{type(e).__name__}: {e}', file=sys.stderr)
-        out['coldstart_overhead'] = {'error': type(e).__name__}
     return out
 
 
@@ -2638,125 +2573,6 @@ def _phase_goodput():
             print(f'# goodput bench {key} failed: {type(e).__name__}: {e}',
                   file=sys.stderr)
             out[key] = {'error': type(e).__name__}
-    return out
-
-
-def donation_ab(n_requests=10, max_new=8, train_steps=4, num_slots=4,
-                max_length=64):
-    """Donation gauntlet A/B (ISSUE 13): the same serving trace and the
-    same train loop with store-served donation FORCED ON vs OFF, both
-    through a persistent program store (the export path the gauntlet
-    governs — the corruption sentinels guard the donated arm's first K
-    invocations).
-
-    Asserted by the tier-1 guard: greedy serving outputs AND train
-    losses bit-exact across the arms (donation is value-neutral or it
-    is quarantined), and the pool-copy surface accounting — a donated
-    single-slot op (seat, copy) writes `row_bytes` in place, where the
-    undonated arm's returns a copy of `pool_bytes`; the reported
-    `pool_copy_bytes_saved` is that delta summed over the trace's
-    single-slot ops. Tokens/sec for both arms ride along (CPU narrows
-    the gap; the number that matters here is parity + bytes)."""
-    import tempfile
-    import paddle_tpu as paddle
-    import paddle_tpu.nn as nn
-    import paddle_tpu.nn.functional as F
-    from paddle_tpu import flags as _pflags, programs
-    from paddle_tpu.jit import TrainStep
-    from paddle_tpu.nlp import GPTConfig, GPTForCausalLM
-    from paddle_tpu.serving import InferenceEngine, SamplingParams
-
-    rng = np.random.RandomState(0)
-    prompts = [rng.randint(1, 128, (s,)).tolist()
-               for s in ([5, 9, 13, 7, 21, 11] * 3)[:n_requests]]
-    x = rng.standard_normal((16, 32)).astype('float32')
-    y = rng.randint(0, 4, (16,))
-
-    def serve_arm(donate):
-        paddle.seed(7)
-        model = GPTForCausalLM(GPTConfig.tiny()).eval()
-        eng = InferenceEngine(model, num_slots=num_slots,
-                              max_length=max_length, donate_pool=donate)
-        t0 = time.perf_counter()
-        handles = eng.generate_many(
-            prompts, SamplingParams(max_new_tokens=max_new,
-                                    eos_token_id=-1))
-        dt = time.perf_counter() - t0
-        toks = [list(h.tokens) for h in handles]
-        n_tok = sum(len(t) for t in toks)
-        return toks, n_tok / dt if dt else 0.0, eng.pool.stats()
-
-    def train_arm():
-        paddle.seed(0)
-        m = nn.Sequential(nn.Linear(32, 64), nn.ReLU(), nn.Linear(64, 4))
-        opt = paddle.optimizer.SGD(learning_rate=0.01,
-                                   parameters=m.parameters())
-        step = TrainStep(m, lambda o, l: F.cross_entropy(o, l), opt)
-        return [float(step(paddle.to_tensor(x),
-                           paddle.to_tensor(y)).numpy())
-                for _ in range(train_steps)]
-
-    prev_flag = _pflags.flag('FLAGS_donation')
-    try:
-        _pflags.set_flags({'FLAGS_donation': 'on'})
-        programs.configure(tempfile.mkdtemp(prefix='bench_donation_on_'))
-        store = programs.get_store()
-        toks_don, tps_don, pool_don = serve_arm(True)
-        losses_don = train_arm()
-        posture = store.donation_state()
-        _pflags.set_flags({'FLAGS_donation': 'off'})
-        programs.configure(tempfile.mkdtemp(prefix='bench_donation_off_'))
-        toks_und, tps_und, pool_und = serve_arm(False)
-        losses_und = train_arm()
-    finally:
-        _pflags.set_flags({'FLAGS_donation': prev_flag})
-        programs.configure(None)
-    single_slot_ops = (pool_und['row_writes'] + pool_und['row_copies'])
-    saved = (pool_und['pool_bytes'] - pool_und['row_bytes']) \
-        * single_slot_ops
-    return {
-        'parity_tokens': toks_don == toks_und,
-        'parity_losses': losses_don == losses_und,
-        'donated_tokens_per_sec': round(tps_don, 1),
-        'undonated_tokens_per_sec': round(tps_und, 1),
-        'speedup': round(tps_don / tps_und, 3) if tps_und else 0.0,
-        'row_bytes': pool_und['row_bytes'],
-        'pool_bytes': pool_und['pool_bytes'],
-        'single_slot_ops': single_slot_ops,
-        'pool_copy_bytes_saved': saved,
-        'donated_posture': posture.get('posture'),
-        'donated_verdict': posture.get('verdict'),
-        # honesty note: a short trace sits inside the donated arm's
-        # sentinel window (snapshot copies + finiteness checks), which
-        # depresses its tokens/sec; steady state begins after
-        # FLAGS_donation_sentinel guarded invocations per program
-        'donated_arm_includes_sentinel_window': True,
-    }
-
-
-def _phase_donation():
-    """Donation phase: probe the installed runtime (recorded as data,
-    not asserted — the verdict is the runtime's, not the bench's), then
-    the forced-on/off A/B whose parity fields the tier-1 guard pins."""
-    out = {}
-    try:
-        from paddle_tpu.programs import donation as _donation
-        probe = _donation.run_probe(runs=4)
-        out['donation_probe'] = {
-            'verdict': probe.get('verdict'),
-            'reason': probe.get('reason', ''),
-            'seconds': probe.get('seconds'),
-        }
-    except Exception as e:
-        print(f'# donation probe failed: {type(e).__name__}: {e}',
-              file=sys.stderr)
-        out['donation_probe'] = {'error': type(e).__name__}
-    try:
-        out['donation_ab'] = donation_ab()
-    except Exception as e:
-        print(f'# donation bench failed: {type(e).__name__}: {e}',
-              file=sys.stderr)
-        out['donation_ab'] = {'error': type(e).__name__}
     return out
 
 
@@ -3102,7 +2918,6 @@ PHASES = {
     'router': _phase_router,
     'coldstart': _phase_coldstart,
     'goodput': _phase_goodput,
-    'donation': _phase_donation,
     'autoscale': _phase_autoscale,
     'fleet_obs': _phase_fleet_obs,
     'fleet_proc': _phase_fleet_proc,
@@ -3145,15 +2960,10 @@ def _cpu_phase_plan():
     return [('headline', 1500), ('eager', 600), ('obs', 600),
             ('resilience', 600), ('serving', 1200), ('adapters', 900),
             ('router', 900), ('coldstart', 900), ('goodput', 600),
-            ('donation', 600), ('autoscale', 600), ('fleet_obs', 600)]
+            ('autoscale', 600), ('fleet_obs', 600)]
 
 
 def main():
-    # phases that configure a program store must not pay (or flake on)
-    # an implicit donation probe: the donation PHASE owns that question
-    # and sets its flags explicitly in-process. An operator exporting
-    # FLAGS_donation still wins.
-    os.environ.setdefault('FLAGS_donation', 'off')
     if len(sys.argv) >= 2 and sys.argv[1] == 'autoscale':
         # `bench.py autoscale [--smoke]`: the tier-1 CI entry point —
         # --smoke is the 5-second deterministic Poisson trace whose
@@ -3231,7 +3041,6 @@ def main():
     out.update(_run_phase_subprocess('serving', 900))
     out.update(_run_phase_subprocess('router', 900))
     out.update(_run_phase_subprocess('coldstart', 900))
-    out.update(_run_phase_subprocess('donation', 600))
     out.update(_run_phase_subprocess('autoscale', 600))
     out.update(_run_phase_subprocess('fleet_obs', 600))
     print(json.dumps(out))

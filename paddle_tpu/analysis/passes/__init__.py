@@ -9,4 +9,3 @@ from . import lock_order      # noqa: F401
 from . import raw_lock        # noqa: F401
 from . import swallowed_exception  # noqa: F401
 from . import obs_schema      # noqa: F401
-from . import donation_path   # noqa: F401

@@ -39,7 +39,7 @@ event (a flight-recorder trigger), `paddle_sanitizer_violations_total
 {kind}` metrics, and — in strict mode — a `ConcurrencySanitizerError`
 raised at the offending acquire/access. Tier-1's chaos gauntlets
 (router failover storm, autoscaler thundering herd, hotswap
-kill-mid-swap, donation sentinel trips) run under strict mode.
+kill-mid-swap, pool recovery) run under strict mode.
 
 The observed acquisition graph exports as a JSON artifact
 (`export_edges`) the static pass consumes (``--runtime-edges`` /
@@ -197,7 +197,7 @@ def _find_path(src: str, dst: str) -> Optional[List[str]]:
 class SanitizedLock:
     """Instrumented `threading.Lock`/`RLock`. Drop-in: acquire/release/
     locked/context manager. `name` keys the lock's CLASS in the
-    acquisition graph ('Router._lock', 'donation._probe_lock')."""
+    acquisition graph ('Router._lock', 'ProgramStore._lock')."""
 
     __slots__ = ('name', 'kind', '_inner')
 

@@ -299,11 +299,7 @@ def _program_store_data() -> dict:
                 'hits_disk': 0, 'misses': 0, 'rejects': 0,
                 'persisted': 0, 'persist_skips': 0, 'invalidated': 0,
                 'preload': None, 'coldstart_seconds': None,
-                'disk_entries': 0,
-                'donation': {'enabled': False, 'posture': 'off',
-                             'verdict': None, 'reason': '',
-                             'donated_entries': 0,
-                             'sentinel_pending': 0}}
+                'disk_entries': 0}
 
 
 def _router_data(reg) -> dict:
@@ -520,19 +516,6 @@ def observability_summary(max_rows: int = 10, as_dict: bool = False):
             f'(preload {pl.get("loaded", 0)} programs in '
             f'{pl.get("seconds", 0.0):.3f}s, '
             f'{pl.get("rejected", 0)} rejected)')
-    dn = ps.get('donation') or {}
-    if dn:
-        extra = ''
-        if dn.get('posture') == 'on':
-            extra = (f'  donated {dn.get("donated_entries", 0)} '
-                     f'resident, sentinel {dn.get("sentinel_pending", 0)}'
-                     f' pending')
-        elif dn.get('reason'):
-            extra = f'  ({dn["reason"]})'
-        lines.append(
-            f'    donation: {dn.get("posture", "off")}'
-            f'{" [" + str(dn.get("verdict")) + "]" if dn.get("verdict") else ""}'
-            f'{extra}')
     lines.append(f'  programs: {len(d["programs"])} tracked '
                  f'(top by host time)')
     for p in d['programs']:

@@ -577,7 +577,7 @@ class DistTrainStep:
                 for n, v in new_params.items()}
             return loss, new_params, new_opt, new_bufs
 
-        self._jitted = jax.jit(step_fn, donate_argnums=(0, 1, 2))  # paddle-lint: disable=donation-path -- direct in-process compile, never store-served: the PR-8 corruption is export-path only
+        self._jitted = jax.jit(step_fn, donate_argnums=(0, 1, 2))
 
     def _pp_forward(self, pv, frozen, buffers, args, key):
         """Forward with the decoder stack routed through the gpipe

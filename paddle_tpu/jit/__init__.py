@@ -295,26 +295,12 @@ class TrainStep:
         # enrolled in the program store: the one AOT compile (or warm
         # disk load) serves the traffic AND yields cost/memory analysis
         # for top_programs(). The store owns the jit AND the donation:
-        # the direct path donates params/opt-state/buffers as before
-        # (in-process compile — the PR-8-safe case), while the
-        # persisted/export path re-applies the donation only on a
-        # gauntlet-safe verdict (donation.py) — that flip is what drops
-        # the transient 2x train-state buffering of the undonated
-        # store posture.
+        # params/opt-state/buffers are donated wherever the step is
+        # compiled (direct, through the export artifact, warm-loaded).
         self._jitted = _programs.get_store().wrap_jit(
             step_fn,
             name='train_step', kind='train', statics=step_statics,
             donate_argnums=(0, 1, 2))
-
-    @property
-    def donation_live(self) -> bool:
-        """True when this step's executable aliases its donated buffers
-        in place — i.e. train state is NOT paying the undonated store
-        path's transient 2x buffering. The direct (non-persistent)
-        path always donates; the store-served path donates only on a
-        donation-gauntlet-safe verdict."""
-        store = _programs.get_store()
-        return (not store.persistent) or store.donation_enabled
 
     @staticmethod
     def _as_batch(inputs, labels):

@@ -48,7 +48,7 @@ from .slo import (Objective, SLOEngine, default_objectives,
 from .telemetry import (StepTelemetry, collective_totals,
                         device_memory_bytes, install,
                         note_jit_cache_entry)
-from .cost import (CatalogedJit, MfuWindow, ProgramCatalog, ProgramRecord,
+from .cost import (MfuWindow, ProgramCatalog, ProgramRecord,
                    aggregate_mfu, device_peaks, record_roofline,
                    roofline_summary, get_catalog as program_catalog)
 from .goodput import (CATEGORIES as GOODPUT_CATEGORIES, GoodputLedger,
@@ -82,7 +82,7 @@ __all__ = [
     'set_slo_engine',
     'StepTelemetry', 'collective_totals', 'device_memory_bytes',
     'install', 'note_jit_cache_entry',
-    'CatalogedJit', 'MfuWindow', 'ProgramCatalog', 'ProgramRecord',
+    'MfuWindow', 'ProgramCatalog', 'ProgramRecord',
     'program_catalog',
     'aggregate_mfu', 'device_peaks', 'record_roofline', 'roofline_summary',
     'GOODPUT_CATEGORIES', 'GoodputLedger', 'get_ledger',

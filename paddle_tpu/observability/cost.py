@@ -12,16 +12,15 @@ cumulative invocation count, and host wall time, so
 actually spending its time and FLOPs in" — train step vs. decode block
 vs. prefill buckets — without a profiler attached.
 
-Zero extra compiles by construction: `wrap_jit` compiles a jitted
-callable ONCE through the AOT path (`lower().compile()`) per input
-signature and then invokes the captured `Compiled` object directly, so
-the cost/memory analyses are read off the very executable that serves
-the traffic — the catalog never compiles anything the program would not
-have compiled anyway (guarded by the serving zero-recompile tests over
-`paddle_jit_compiles_total`). Since the program-store consolidation
-(`paddle_tpu.programs`), compilation itself is owned by the store —
-`wrap_jit` delegates there, THIS catalog remains the bookkeeping, and
-every program is tracked exactly once (tier-1 catalog==store guard).
+Zero extra compiles by construction: the program store
+(the `programs` package) owns compilation — `ProgramStore.wrap_jit`
+compiles a jitted callable ONCE per input signature through the AOT
+path and then invokes the captured `Compiled` object directly, so the
+cost/memory analyses are read off the very executable that serves the
+traffic (guarded by the serving zero-recompile tests over
+`paddle_jit_compiles_total`). THIS catalog is the bookkeeping the store
+writes into: every program is tracked exactly once (tier-1
+catalog==store guard), and nothing here imports the store.
 
 Hot paths never pay: the eager dispatch cache reports only from its
 cold miss path (`note_dispatch_compile`) and its per-op invocation
@@ -32,7 +31,7 @@ from __future__ import annotations
 
 import os
 import time
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 from . import metrics as _metrics
 from ..analysis.runtime import concurrency as _concurrency
@@ -280,100 +279,6 @@ def _read_analysis(compiled, record: ProgramRecord):
         pass
 
 
-class CatalogedJit:
-    """A jax.jit'd callable enrolled in the catalog.
-
-    First call per input signature compiles through the AOT path
-    (`fn.lower(*args).compile()`) — the SAME one backend compile the
-    plain call would have cost — keeps the `Compiled` executable, and
-    reads its cost/memory analyses into the program record. Subsequent
-    calls invoke the captured executable directly and account
-    invocations + host wall time. Any AOT failure (exotic backend,
-    unhashable signature) falls back to the plain jitted call for that
-    signature; the record then carries counts without analysis.
-    """
-
-    def __init__(self, catalog: 'ProgramCatalog', fn, name: Optional[str]
-                 = None, name_fn: Optional[Callable] = None,
-                 kind: str = 'jit'):
-        if name is None and name_fn is None:
-            raise ValueError('CatalogedJit needs name= or name_fn=')
-        self._catalog = catalog
-        self._fn = fn
-        self._name = name
-        self._name_fn = name_fn
-        self._kind = kind
-        self._entries: Dict[Any, Any] = {}   # sig -> (record, callable)
-
-    def _signature(self, args):
-        import jax
-        leaves, treedef = jax.tree_util.tree_flatten(args)
-        sig = []
-        for leaf in leaves:
-            dt = getattr(leaf, 'dtype', None)
-            if dt is not None:
-                sig.append((tuple(getattr(leaf, 'shape', ())), str(dt),
-                            bool(getattr(leaf, 'weak_type', False))))
-            else:
-                sig.append(('py', type(leaf)))
-        key = (treedef, tuple(sig))
-        hash(key)
-        return key
-
-    def _build(self, key, args):
-        if self._name is not None:
-            name = self._name
-        else:
-            try:
-                name = self._name_fn(args)
-            except Exception:  # paddle-lint: disable=swallowed-exception -- naming must never fail a call; kind:unnamed IS the visible trace
-                name = f'{self._kind}:unnamed'   # naming must never fail a call
-        record = self._catalog.record(name, kind=self._kind)
-        call = self._fn
-        if key is not None:
-            t0 = time.perf_counter()
-            try:
-                compiled = self._fn.lower(*args).compile()
-                dt = time.perf_counter() - t0
-                with self._catalog._lock:
-                    record.compile_count += 1
-                    record.compile_seconds += dt
-                _read_analysis(compiled, record)
-                call = compiled
-            except Exception:  # paddle-lint: disable=swallowed-exception -- AOT path unavailable; record.note=aot_unavailable carries the posture into every report
-                # AOT path unavailable here: serve through the plain
-                # jitted call — counts still accumulate, analysis stays
-                # empty and the report marks it
-                record.note = 'aot_unavailable'
-            self._entries[key] = (record, call)
-        return record, call
-
-    def __call__(self, *args):
-        try:
-            key = self._signature(args)
-        except Exception:
-            _metrics.count_suppressed('catalog.signature')
-            key = None
-        entry = self._entries.get(key) if key is not None else None
-        t0 = time.perf_counter()
-        if entry is None:
-            record, call = self._build(key, args)
-        else:
-            record, call = entry
-        out = call(*args)
-        dt = time.perf_counter() - t0
-        with self._catalog._lock:
-            record.invocations += 1
-            record.host_seconds += dt
-        return out
-
-    # the wrapped object still answers AOT introspection (TrainStep's
-    # memory_analysis does `self._jitted.lower(...)`); the lowering
-    # cache makes that free after the wrapper's own compile
-    def __getattr__(self, name):
-        return getattr(self._fn, name)
-
-
 class ProgramCatalog:
     """Registry of every named compiled program in the process."""
 
@@ -388,26 +293,6 @@ class ProgramCatalog:
             if rec is None:
                 rec = self._records[name] = ProgramRecord(name, kind)
             return rec
-
-    def wrap_jit(self, fn, name: Optional[str] = None,
-                 name_fn: Optional[Callable] = None,
-                 kind: str = 'jit', statics: Any = None,
-                 persist: bool = True):
-        """Enroll a jax.jit'd callable; returns the drop-in wrapper.
-
-        Since the program-store consolidation this delegates to
-        `paddle_tpu.programs.ProgramStore.wrap_jit` — the store owns
-        compilation (and the persistent tier); THIS catalog stays the
-        bookkeeping, so every program is tracked exactly once. A
-        catalog that is not the store's own (tests constructing a
-        private one) keeps the legacy in-wrapper AOT path."""
-        from ..programs import get_store
-        store = get_store()
-        if store.catalog is self:
-            return store.wrap_jit(fn, name=name, name_fn=name_fn,
-                                  kind=kind, statics=statics,
-                                  persist=persist)
-        return CatalogedJit(self, fn, name=name, name_fn=name_fn, kind=kind)
 
     def note_invocation(self, name: str, seconds: float = 0.0, n: int = 1,
                         kind: str = 'jit'):

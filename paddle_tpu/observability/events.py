@@ -69,18 +69,6 @@ EVENT_SCHEMA: Dict[str, str] = {
     'program_store_preload': 'bulk preload completed',
     'program_store_invalidate': 'fingerprint refresh dropped entries',
     'program_store_wipe': 'persistent tier deleted on disk',
-    # donation gauntlet (programs/donation.py)
-    'donation_probe_ok': 'subprocess probe classified the runtime '
-                         'donation-safe',
-    'donation_probe_failed': 'probe found corruption/crash; store runs '
-                             'undonated',
-    'donation_no_verdict': 'no verdict recorded and this process holds '
-                           'the chip, so no probe was spawned; store '
-                           'runs undonated',
-    'donation_enabled': 'store-served programs re-apply donate_argnums '
-                        '(sentinel-guarded)',
-    'donation_quarantined': 'corruption sentinel tripped; donation off '
-                            'for this fingerprint',
     'serving_pool_recovered': 'donated decode failed mid-call; pool '
                               'rows rebuilt',
     # serving engine / router / tenancy

@@ -47,7 +47,7 @@ from ..analysis.runtime import concurrency as _concurrency
 TRIGGER_EVENTS = frozenset((
     'hang_suspected', 'loss_spike', 'bad_step', 'skip_budget_exhausted',
     'serving_request_failed', 'checkpoint_corrupt',
-    'router_failover_storm', 'donation_quarantined',
+    'router_failover_storm',
     'sanitizer_violation', 'slo_breach', 'segment_quarantined',
     'replica_crash', 'replica_quarantined', 'request_slow',
 ))

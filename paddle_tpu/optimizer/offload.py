@@ -99,7 +99,7 @@ class OffloadEngine:
             def fn(gv, pv, sv, lr, step):
                 return opt._leaf_apply(gv, pv, sv, lr, step, name=nm)
             # donate g, p, slots: the update is in-place in HBM
-            self._kernels[key] = jax.jit(fn, donate_argnums=(0, 1, 2))  # paddle-lint: disable=donation-path -- per-leaf direct kernels, never store-served: the PR-8 corruption is export-path only
+            self._kernels[key] = jax.jit(fn, donate_argnums=(0, 1, 2))
         return self._kernels[key]
 
     # -- apply --------------------------------------------------------------

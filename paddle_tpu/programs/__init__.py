@@ -1,6 +1,6 @@
 """paddle_tpu.programs — the unified persistent program store.
 
-One `ProgramStore` owns AOT `lower().compile()` for every jitted
+One `ProgramStore` owns the AOT compile of every jitted
 compilation tier (jit.TrainStep / to_static, the serving engine's
 decode + prefill programs; the eager dispatch cache keeps its own
 in-process tier and reports through the same catalog), keyed like the
@@ -14,7 +14,6 @@ Enable persistence with `programs.configure('/path/to/store')`, the
 matching entries at startup (Model.fit and ReplicaSet do this
 automatically when the store is persistent).
 """
-from . import donation
 from .scopes import SCOPES, scope_path, scope_table
 from .store import (ProgramDeserializeError, ProgramStore, StoredJit,
                     backend_fingerprint, code_token, compile_cache_dir,
@@ -24,6 +23,6 @@ from .store import (ProgramDeserializeError, ProgramStore, StoredJit,
 __all__ = [
     'ProgramDeserializeError', 'ProgramStore', 'SCOPES', 'StoredJit',
     'backend_fingerprint', 'code_token', 'compile_cache_dir', 'configure',
-    'describe_statics', 'donation', 'ensure_compile_cache', 'get_store',
+    'describe_statics', 'ensure_compile_cache', 'get_store',
     'scope_path', 'scope_table', 'store_key',
 ]

@@ -1,28 +1,35 @@
 """Unified persistent program store: one owner for every compiled XLA
 program in the process, with a crash-safe on-disk tier.
 
-Before this module, three independent caches each managed compiled
-executables — the eager dispatch cache (`_dispatch.py`), the
-`jit`/`to_static` python-side caches, and the serving engine's
-decode/prefill set — none of which survived a restart, so every
-preemption resume and every cold serving replica paid minutes of XLA
-recompiles before doing useful work. The `ProgramStore` is the single
-compilation owner for the jitted tiers: `wrap_jit` AOT-compiles ONCE
-per (name, fn source, statics, treedef, avals, sharding) key through
-`lower().compile()`, folds the `ProgramCatalog` cost attribution in as
+The `ProgramStore` is the single compilation owner for the jitted tiers
+(`jit.TrainStep` / `to_static`, the serving engine's decode and prefill
+set, the slot pool's seat and copy programs; the eager dispatch cache
+keeps its own in-process tier and reports through the same catalog).
+`wrap_jit` AOT-compiles ONCE per (name, fn source, statics, treedef,
+avals, sharding) key, folds the `ProgramCatalog` cost attribution in as
 its bookkeeping (one `ProgramRecord` per named program — never tracked
 twice), shares executables across wrappers with the same key (N serving
 replicas of one model compile the decode block once), and — when a
-store directory is configured — persists each executable so the next
+store directory is configured — persists each program so the next
 process *loads* instead of compiling.
+
+One compile site, one donation rule. Every route to an executable —
+the direct route (no directory), the cold route through the export
+artifact, the warm route from a persisted payload — ends in
+`_compile_program`, the only function here that lowers and compiles,
+and a program's declared `donate_argnums` is applied wherever it is
+compiled. The manifest records the argnums so the warm process
+re-applies them. A donated program that dies mid-call may have consumed
+its inputs; the owners of donated state recover from that themselves
+(`InferenceEngine._recover_pool`).
 
 Persistence is two complementary layers:
 
   'stablehlo'   `jax.export` bytes (the serialization `jit.save` already
                 uses) — removes Python tracing from the restart path.
-                The cold path compiles THROUGH the exported program
-                (`jax.jit(exported.call)`, donation re-applied), so the
-                cold and warm processes compile the identical module.
+                The cold path compiles THROUGH the exported program, so
+                the cold and warm processes compile the identical
+                module.
   compile cache jax's persistent compilation cache, at the directory
                 `ensure_compile_cache` resolves
                 (`JAX_COMPILATION_CACHE_DIR`, else
@@ -33,25 +40,6 @@ Persistence is two complementary layers:
                 `paddle_jit_compiles_total` tick in the warm window is
                 matched by a `paddle_jit_cache_hits_total` tick (zero
                 real compiles).
-
-(`jax.experimental.serialize_executable` — pickling the PjRt executable
-itself — was evaluated first and rejected: deserialized donated
-executables intermittently corrupted the heap on jaxlib 0.4.36, the
-runtime it was evaluated on. The
-export+cache pair reaches the same zero-compile warm restart through
-two independently hardened upstream paths.)
-
-Donation: whether a store-served program re-applies its recorded
-`donate_argnums` is decided by the donation gauntlet (donation.py) —
-a subprocess-isolated probe of the installed runtime run at store
-init, manifest-recorded per backend fingerprint. On a 'safe' verdict
-donated programs alias their buffers again (no transient 2x train
-state); the first K invocations of each donated executable run under
-a corruption sentinel, and a trip quarantines donation for this
-fingerprint and recompiles undonated — mid-call, without surfacing
-the garbage value. The DIRECT path (in-process `lower().compile()` of
-the caller's own jit, no serialization) donates unconditionally: PR 8
-established that only the export/deserialize path corrupts.
 
 Crash safety (the robustness contract, fault-injection-tested in
 tests/test_programs.py): entries are written payload-first with atomic
@@ -79,11 +67,9 @@ from .. import flags as _flags
 from .. import observability as _obs
 from ..analysis.runtime import concurrency as _concurrency
 from ..observability import cost as _cost
-from . import donation as _donation
 
 _MANIFEST_VERSION = 1
 
-_flags.register_flag('FLAGS_program_store', True)
 _flags.register_flag('FLAGS_program_store_dir', '')
 
 
@@ -275,35 +261,32 @@ def _export_program(jitted, args):
     return _jex.export(jitted, platforms=tuple(sorted(plats)))(*abstract)
 
 
-def _compile_exported(exported, donate_argnums=(), donated=False):
+def _compile_program(fn, args, donate_argnums=()):
+    """THE compile site of the store: every route to an executable ends
+    here, so a program's declared `donate_argnums` is applied wherever
+    that program is compiled. `fn` is a jitted callable — it carries
+    the donation it was declared with — or a plain one (an exported
+    program's `call`), jitted here with `donate_argnums`; `args` are
+    the arguments or their abstract shapes. Returns the `Compiled`."""
+    if not hasattr(fn, 'lower'):
+        fn = jax.jit(fn, donate_argnums=tuple(donate_argnums))
+    return fn.lower(*args).compile()
+
+
+def _compile_exported(exported, donate_argnums=()):
     """AOT-compile an exported program from its own recorded in_avals.
 
     No Python tracing of the original function; the backend compile of
     this module is served by jax's persistent compilation cache on warm
     restarts (same module bytes -> same cache key), so it costs a disk
-    read, not an XLA compile.
-
-    Donation: re-applying `donate_argnums` on the wrapper jit here is
-    the exact operation that intermittently corrupted the heap on
-    jaxlib 0.4.36 (PR 8's fault-injection gauntlet on the runtime of
-    that time: segfaults/garbage losses ~50% of runs; stable 12/12
-    without) — so it happens ONLY when the
-    donation gauntlet classified the installed runtime 'safe'
-    (`donated=True`, probe-verified or operator-forced, and sentinel-
-    guarded by the caller for its first K invocations). Otherwise the
-    program compiles undonated and `donate_argnums` just rides the
-    manifest for a runtime that passes the probe."""
+    read, not an XLA compile."""
     specs = [jax.ShapeDtypeStruct(a.shape, a.dtype)
              for a in exported.in_avals]
-    args, kwargs = jax.tree_util.tree_unflatten(exported.in_tree, specs)
-    donate = tuple(donate_argnums) if donated else ()
-    jitted = jax.jit(exported.call, donate_argnums=donate) if donate \
-        else jax.jit(exported.call)
-    return jitted.lower(*args, **kwargs).compile()
+    args, _ = jax.tree_util.tree_unflatten(exported.in_tree, specs)
+    return _compile_program(exported.call, args, donate_argnums)
 
 
-def _load_stablehlo(payload: bytes, path: str, donate_argnums=(),
-                    donated=False):
+def _load_stablehlo(payload: bytes, path: str, donate_argnums=()):
     """Deserialize exported StableHLO and AOT-compile it — the warm
     half of the restart path."""
     from jax import export as _jex
@@ -313,7 +296,7 @@ def _load_stablehlo(payload: bytes, path: str, donate_argnums=(),
         raise ProgramDeserializeError(
             path, f'{type(exc).__name__}: {exc}') from exc
     try:
-        return _compile_exported(exported, donate_argnums, donated)
+        return _compile_exported(exported, donate_argnums)
     except Exception as exc:
         raise ProgramDeserializeError(
             path, f'aot compile of deserialized program failed: '
@@ -326,10 +309,9 @@ def _load_stablehlo(payload: bytes, path: str, donate_argnums=(),
 
 class _StoreEntry:
     __slots__ = ('key', 'name', 'kind', 'callable', 'source', 'format',
-                 'fingerprint', 'donated', 'donate')
+                 'fingerprint')
 
-    def __init__(self, key, name, kind, call, source, fmt, fingerprint,
-                 donated=False, donate=()):
+    def __init__(self, key, name, kind, call, source, fmt, fingerprint):
         self.key = key
         self.name = name
         self.kind = kind
@@ -337,12 +319,6 @@ class _StoreEntry:
         self.source = source          # 'compile' | 'disk'
         self.format = fmt             # 'stablehlo' | '' (unpersisted)
         self.fingerprint = fingerprint
-        # donate: the RECORDED donate_argnums (what the program wants);
-        # donated: whether this executable was actually compiled with
-        # them re-applied (export path + gauntlet-enabled at the time).
-        # A posture change invalidates entries where the two disagree.
-        self.donated = bool(donated)
-        self.donate = tuple(donate)
 
 
 class ProgramStore:
@@ -368,17 +344,6 @@ class ProgramStore:
         self._invalidated = 0
         self._preload: Optional[Dict[str, Any]] = None
         self._coldstart_s: Optional[float] = None
-        # donation gauntlet state: posture dict from
-        # donation.resolve_posture, a generation counter bumped on
-        # quarantine (wrappers holding donated executables re-resolve),
-        # and per-key sentinel budgets for the guarded first-K window
-        self._donation: Dict[str, Any] = {'enabled': False,
-                                          'posture': 'off',
-                                          'verdict': None, 'reason': '',
-                                          'source': 'init', 'token': ''}
-        self._donation_gen = 0
-        self._sentinel: Dict[str, int] = {}
-        self._resolve_donation()
 
     # -- configuration -------------------------------------------------------
     @property
@@ -416,7 +381,6 @@ class ProgramStore:
             # to the compile cache: jax's own threshold (1 s) again
             jax.config.update(
                 'jax_persistent_cache_min_compile_time_secs', 1.0)
-        self._resolve_donation()
         return self
 
     def refresh_fingerprint(self):
@@ -434,126 +398,6 @@ class ProgramStore:
         if stale:
             _obs.emit('program_store_invalidate', entries=len(stale),
                       reason='fingerprint_change')
-        # a new fingerprint is a new runtime: its donation verdict may
-        # differ (and a quarantine recorded for the OLD runtime no
-        # longer applies)
-        self._resolve_donation()
-        return len(stale)
-
-    # -- donation gauntlet ---------------------------------------------------
-    def _resolve_donation(self) -> Dict[str, Any]:
-        """(Re)run the gauntlet's decision procedure for the current
-        directory + fingerprint (probing in a subprocess when 'auto'
-        finds no recorded verdict — see donation.resolve_posture)."""
-        posture = _donation.resolve_posture(self.directory,
-                                            self._fingerprint)
-        with self._lock:
-            flipped = bool(posture.get('enabled')) \
-                != bool(self._donation.get('enabled'))
-            self._donation = posture
-            if flipped:
-                # entries compiled under the OTHER posture stop being
-                # served: an undonated executable under 'on' silently
-                # loses the aliasing, a donated one under 'off' is the
-                # exact hazard the gauntlet exists to prevent
-                stale = [k for k, e in self._mem.items()
-                         if e.donate
-                         and e.donated != bool(posture.get('enabled'))]
-                for k in stale:
-                    del self._mem[k]
-                    self._sentinel.pop(k, None)
-                self._donation_gen += 1
-        return posture
-
-    @property
-    def donation_enabled(self) -> bool:
-        """True when store-served programs re-apply their recorded
-        donate_argnums (probe-verified safe, or operator-forced)."""
-        return bool(self._donation.get('enabled'))
-
-    @property
-    def donation_gen(self) -> int:
-        """Bumped on quarantine; wrappers caching donated executables
-        compare it to know their callable was invalidated."""
-        return self._donation_gen
-
-    def donation_state(self) -> Dict[str, Any]:
-        with self._lock:
-            out = dict(self._donation)
-            out['donated_entries'] = sum(1 for e in self._mem.values()
-                                         if e.donated)
-            out['sentinel_pending'] = sum(self._sentinel.values())
-        return out
-
-    def _arm_sentinel(self, key: str):
-        n = _donation.sentinel_budget()
-        if n > 0:
-            with self._lock:
-                self._sentinel[key] = n
-
-    def sentinel_remaining(self, key: str) -> int:
-        with self._lock:
-            return self._sentinel.get(key, 0)
-
-    def sentinel_call(self, key: str, name: str, call, args):
-        """One guarded invocation inside the post-enablement window:
-        the donated executable consumes snapshot COPIES of the args (so
-        the originals survive for an undonated re-run), and the outputs
-        pass a finiteness sentinel before anything sees them. Returns
-        ``(out, ok)`` — on ``ok=False`` donation has been QUARANTINED
-        and the caller must recompile undonated and re-run; the corrupt
-        value is never returned."""
-        snap = _donation.snapshot_args(args)
-        detail = ''
-        try:
-            out = call(*snap)
-            ok = _donation.outputs_ok(out)
-            if not ok:
-                detail = 'non-finite output'
-        except Exception as exc:
-            # the donated executable blowing up inside the guard window
-            # is a trip, not a crash: the snapshots absorbed the damage
-            out, ok = None, False
-            detail = f'{type(exc).__name__}: {exc}'
-        if _obs.enabled():
-            _obs.get_registry().counter(
-                'paddle_donation_sentinel_checks_total',
-                'sentinel-guarded invocations of donated programs').inc()
-        if ok:
-            with self._lock:
-                left = self._sentinel.get(key, 0) - 1
-                if left <= 0:
-                    self._sentinel.pop(key, None)
-                else:
-                    self._sentinel[key] = left
-            return out, True
-        self.quarantine_donation(f'sentinel tripped on {name}: {detail}')
-        return None, False
-
-    def quarantine_donation(self, reason: str) -> int:
-        """Donation corrupted on this runtime: flip the posture off,
-        drop every donated executable from the memory tier (the next
-        acquire recompiles undonated from the SAME payload), bump the
-        generation so wrappers re-resolve, and record the quarantine —
-        verdict manifest + `donation_quarantined` event (a flight-
-        recorder trigger). Idempotent once quarantined."""
-        with self._lock:
-            if self._donation.get('posture') == 'quarantined':
-                return 0
-            self._donation = {
-                'enabled': False, 'posture': 'quarantined',
-                'verdict': 'quarantined', 'reason': str(reason),
-                'source': 'sentinel',
-                'token': self._donation.get('token', ''),
-            }
-            self._donation_gen += 1
-            stale = [k for k, e in self._mem.items() if e.donated]
-            for k in stale:
-                del self._mem[k]
-            self._sentinel.clear()
-        # outside the store lock: quarantine() emits the event that
-        # triggers a flight bundle, whose listeners read other locks
-        _donation.quarantine(self.directory, self._fingerprint, reason)
         return len(stale)
 
     # -- metrics/events helpers ---------------------------------------------
@@ -690,11 +534,9 @@ class ProgramStore:
             return None
         fmt = manifest.get('format', '')
         donate = tuple(manifest.get('donate_argnums') or ())
-        donated = bool(donate) and self.donation_enabled
         try:
             if fmt == 'stablehlo':
-                call = _load_stablehlo(payload, bin_path, donate,
-                                       donated=donated)
+                call = _load_stablehlo(payload, bin_path, donate)
             else:
                 self._note_reject(name, bin_path, 'format', fmt)
                 return None
@@ -705,20 +547,16 @@ class ProgramStore:
             self._note_reject(name, bin_path, 'deserialize',
                               type(exc).__name__)
             return None
-        if donated:
-            self._arm_sentinel(key)
         return _StoreEntry(key, name, str(manifest.get('kind', 'jit')),
-                           call, 'disk', fmt, self._fingerprint,
-                           donated=donated, donate=donate)
+                           call, 'disk', fmt, self._fingerprint)
 
     # -- the acquisition path ------------------------------------------------
     def acquire(self, key: str, name: str, kind: str,
-                record: _cost.ProgramRecord,
-                compile_fn: Callable[[], Any],
-                jitted=None, args=None, persist: bool = True,
-                donate_argnums=()):
+                record: _cost.ProgramRecord, jitted, args,
+                persist: bool = True, donate_argnums=()):
         """Resolve one program key to an executable: memory tier, then
-        the integrity-verified disk tier, then a fresh AOT compile.
+        the integrity-verified disk tier, then a fresh AOT compile of
+        `jitted` at `args`.
 
         With a persistent store, the fresh compile goes THROUGH the
         export artifact (trace -> serialize -> compile the exported
@@ -726,7 +564,8 @@ class ProgramStore:
         process will deserialize — the XLA persistent cache then serves
         the warm compile from disk. Export failures fall back to the
         plain direct compile (memory tier only, note='aot_noexport').
-        Returns the resolved `_StoreEntry` (callable + donation flag),
+        Every route compiles in `_compile_program`, with
+        `donate_argnums` applied. Returns the resolved `_StoreEntry`,
         or None when no AOT path works at all — callers fall back to
         their plain jitted call."""
         with self._lock:
@@ -748,28 +587,22 @@ class ProgramStore:
             self._note_hit(name, 'disk', ent.format)
             return ent
         # cold: compile fresh
-        persisting = (persist and self.persistent
-                      and bool(_flags.flag('FLAGS_program_store'))
-                      and jitted is not None and args is not None)
+        persisting = persist and self.persistent
         t0 = time.perf_counter()
         compiled = payload = None
         fmt = ''
-        donated = False
         if persisting:
             try:
                 exported = _export_program(jitted, args)
                 payload = exported.serialize()
-                donated = bool(donate_argnums) and self.donation_enabled
-                compiled = _compile_exported(exported, donate_argnums,
-                                             donated=donated)
+                compiled = _compile_exported(exported, donate_argnums)
                 fmt = 'stablehlo'
             except Exception as exc:
-                donated = False
                 _obs.emit('program_store_persist_skipped', program=name,
                           error=type(exc).__name__)
         if compiled is None:
             try:
-                compiled = compile_fn()
+                compiled = _compile_program(jitted, args, donate_argnums)
             except Exception:  # paddle-lint: disable=swallowed-exception -- no AOT path for this callable; caller serves the plain jitted call which surfaces any real error
                 return None   # no AOT path; caller serves the plain call
             if persisting:
@@ -781,10 +614,7 @@ class ProgramStore:
         _cost._read_analysis(compiled, record)
         self._note_miss(name)
         ent = _StoreEntry(key, name, kind, compiled, 'compile', fmt,
-                          self._fingerprint, donated=donated,
-                          donate=donate_argnums)
-        if donated:
-            self._arm_sentinel(key)
+                          self._fingerprint)
         with self._lock:
             self._mem[key] = ent
         if payload is not None:
@@ -871,9 +701,9 @@ class ProgramStore:
         catalog. `statics` names the compile-time constants baked into
         the program that its input avals cannot see (optimizer
         hyperparams, model config, engine geometry) — part of the
-        persistent key. `donate_argnums` mirrors the wrapped jit's
-        donation so it survives the export round trip (recorded in the
-        manifest for the warm process)."""
+        persistent key. `donate_argnums` is the program's declared
+        donation: applied wherever the program is compiled, and
+        recorded in the manifest for the warm process."""
         return StoredJit(self, fn, name=name, name_fn=name_fn, kind=kind,
                          statics=statics, persist=persist,
                          donate_argnums=donate_argnums)
@@ -886,8 +716,7 @@ class ProgramStore:
     def entries(self) -> List[Dict[str, Any]]:
         with self._lock:
             return [{'key': e.key, 'name': e.name, 'kind': e.kind,
-                     'source': e.source, 'format': e.format,
-                     'donated': e.donated}
+                     'source': e.source, 'format': e.format}
                     for e in self._mem.values()]
 
     def disk_entries(self) -> int:
@@ -942,7 +771,6 @@ class ProgramStore:
                 'coldstart_seconds': self._coldstart_s,
             }
         out['disk_entries'] = self.disk_entries()
-        out['donation'] = self.donation_state()
         return out
 
     def verify_catalog_consistency(self) -> Dict[str, Any]:
@@ -975,15 +803,13 @@ class ProgramStore:
 
 
 class StoredJit:
-    """A jax.jit'd callable enrolled in the program store (the successor
-    of observability.cost.CatalogedJit — same calling contract, same
-    cost attribution, plus the shared memory tier and persistence).
+    """A jax.jit'd callable enrolled in the program store.
 
     First call per input signature resolves through the store: an
     executable already resident (compiled by another wrapper with the
     same key — e.g. a sibling serving replica) or persisted on disk is
-    reused; otherwise the one AOT `lower().compile()` the plain call
-    would have cost runs here, and its analysis lands in the program
+    reused; otherwise the one AOT compile the plain call would have
+    cost runs in the store, and its analysis lands in the program
     record. Any AOT failure falls back to the plain jitted call for
     that signature ('aot_unavailable')."""
 
@@ -999,28 +825,22 @@ class StoredJit:
         self._kind = kind
         self._persist = persist
         self._donate = tuple(donate_argnums)
-        # the store is the donation owner: callers pass the RAW function
-        # plus its donate_argnums and the wrapper jits it here — the
-        # DIRECT path donates as declared (in-process compile, the
-        # PR-8-safe case), while the export path re-applies donation
-        # only on a gauntlet-safe verdict. Already-jitted callables are
-        # still accepted (their donation is whatever they baked in),
-        # and OPAQUE callables (class instances without .lower) are
-        # deliberately NOT auto-jitted — they keep the plain-call
+        # callers pass the RAW function plus its donate_argnums and the
+        # wrapper jits it here. Already-jitted callables are still
+        # accepted (their direct-route donation is whatever they baked
+        # in), and OPAQUE callables (class instances without .lower)
+        # are deliberately NOT auto-jitted — they keep the plain-call
         # 'aot_unavailable' fallback, since tracing an arbitrary
         # callable can change its semantics.
         import types
-        if hasattr(fn, 'lower'):
-            self._fn = fn
-        elif isinstance(fn, (types.FunctionType, types.MethodType)):
-            self._fn = jax.jit(fn, donate_argnums=self._donate) \
-                if self._donate else jax.jit(fn)
+        if isinstance(fn, (types.FunctionType, types.MethodType)) \
+                and not hasattr(fn, 'lower'):
+            self._fn = jax.jit(fn, donate_argnums=self._donate)
         else:
             self._fn = fn
         self._fn_token = code_token(fn)
         self._statics_token = describe_statics(statics)
-        # sig -> (record, callable, store_key, donated, donation_gen)
-        self._entries: Dict[Any, Any] = {}
+        self._entries: Dict[Any, Any] = {}   # sig -> (record, callable)
 
     def _signature(self, args):
         leaves, treedef = jax.tree_util.tree_flatten(args)
@@ -1046,48 +866,27 @@ class StoredJit:
                 name = f'{self._kind}:unnamed'   # naming must never fail
         record = self._store.catalog.record(name, kind=self._kind)
         call = self._fn
-        skey = None
-        donated = False
         if key is not None:
-            try:
-                skey = store_key(name, self._fn_token,
-                                 self._statics_token, args)
-            except Exception:
-                # unkeyable statics: this program silently loses
-                # persistence — make "silently" false
-                _obs.count_suppressed('program_store.key')
-                skey = None
-            got = None
-            if skey is not None and bool(_flags.flag('FLAGS_program_store')):
-                ent = self._store.acquire(
-                    skey, name, self._kind, record,
-                    compile_fn=lambda: self._fn.lower(*args).compile(),
-                    jitted=self._fn, args=args, persist=self._persist,
-                    donate_argnums=self._donate)
-                if ent is not None:
-                    got = ent.callable
-                    donated = ent.donated
-            else:
-                # store bypassed: keep the plain AOT-compile behavior
-                t0 = time.perf_counter()
+            ent = None
+            if hasattr(self._fn, 'lower'):   # an opaque one has no AOT path
                 try:
-                    got = self._fn.lower(*args).compile()
-                    dt = time.perf_counter() - t0
-                    with self._store.catalog._lock:
-                        record.compile_count += 1
-                        record.compile_seconds += dt
-                    _cost._read_analysis(got, record)
-                except Exception:  # paddle-lint: disable=swallowed-exception -- AOT re-analysis failed post-acquire; record.note=aot_unavailable carries the posture
-                    got = None
-            if got is not None:
-                call = got
+                    skey = store_key(name, self._fn_token,
+                                     self._statics_token, args)
+                except Exception:
+                    # unkeyable statics: this program is served by the
+                    # plain jitted call — make "silently" false
+                    _obs.count_suppressed('program_store.key')
+                else:
+                    ent = self._store.acquire(
+                        skey, name, self._kind, record, self._fn, args,
+                        persist=self._persist,
+                        donate_argnums=self._donate)
+            if ent is not None:
+                call = ent.callable
             else:
                 record.note = 'aot_unavailable'
-            entry = (record, call, skey, donated,
-                     self._store.donation_gen)
-            self._entries[key] = entry
-            return entry
-        return (record, call, skey, donated, self._store.donation_gen)
+            self._entries[key] = (record, call)
+        return record, call
 
     def __call__(self, *args):
         try:
@@ -1102,27 +901,8 @@ class StoredJit:
         t0 = time.perf_counter()
         if entry is None:
             entry = self._build(key, args)
-        record, call, skey, donated, gen = entry
-        if self._donate and gen != self._store.donation_gen:
-            # the donation posture moved since this executable was
-            # resolved (quarantine, or a flag/verdict flip at
-            # re-configure): drop it and re-resolve under the current
-            # posture
-            self._entries.pop(key, None)
-            record, call, skey, donated, gen = self._build(key, args)
-        if donated and skey is not None \
-                and self._store.sentinel_remaining(skey) > 0:
-            out, ok = self._store.sentinel_call(skey, record.name, call,
-                                                args)
-            if not ok:
-                # sentinel tripped → donation quarantined; recompile
-                # undonated and serve the SAME call from the original
-                # (never-donated) args — garbage never surfaces
-                self._entries.pop(key, None)
-                record, call, skey, donated, gen = self._build(key, args)
-                out = call(*args)
-        else:
-            out = call(*args)
+        record, call = entry
+        out = call(*args)
         dt = time.perf_counter() - t0
         with self._store.catalog._lock:
             record.invocations += 1

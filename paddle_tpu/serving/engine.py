@@ -163,13 +163,6 @@ class InferenceEngine:
             KV lives in a parallel SlotPool. Sampling requests in the
             same engine simply decode one token per round.
         num_draft_tokens: draft proposals per speculation round (k).
-        donate_pool: donate the KV pool into the decode/spec programs
-            (and the row pool's seat/copy programs) so it is updated in
-            place instead of copied
-            (value-neutral; the store-served variant additionally
-            requires a donation-gauntlet-safe verdict and runs
-            sentinel-guarded). Default True; the bench donation phase
-            A/Bs False against it.
         kv_page_size: setting this (or kv_pages/kv_quant) switches the
             KV cache to the PAGED layout (kv_pool.PagedSlotPool):
             fixed-size pages + a per-slot page table, reservation-based
@@ -212,7 +205,6 @@ class InferenceEngine:
                  prefill_chunk_tokens: Optional[int] = None,
                  draft_model=None, num_draft_tokens: int = 4,
                  weight_version: int = 0,
-                 donate_pool: Optional[bool] = None,
                  kv_page_size: Optional[int] = None,
                  kv_pages: Optional[int] = None,
                  kv_quant: Optional[str] = None,
@@ -236,10 +228,6 @@ class InferenceEngine:
             getattr(cfg, 'eos_token_id', -1) if eos_token_id is None
             else eos_token_id)
         self.decode_block = int(decode_block)
-        # pool donation: the decode/spec programs and the row pool's
-        # seat/copy programs DONATE the pool, so it is updated in place
-        self._donate_pool = True if donate_pool is None else bool(
-            donate_pool)
         self._paged = (kv_page_size is not None or kv_pages is not None
                        or kv_quant is not None)
         if self._paged:
@@ -249,7 +237,7 @@ class InferenceEngine:
                 num_pages=kv_pages, quant=kv_quant)
         else:
             self.pool = SlotPool(model, num_slots, max_length, dtype,
-                                 buckets, donate=self._donate_pool)
+                                 buckets)
         self.scheduler = FCFSScheduler(max_prefill_tokens,
                                        max_wait_s=max_wait_s)
         if prefill_chunk_tokens is not None and prefill_chunk_tokens < 1:
@@ -300,8 +288,7 @@ class InferenceEngine:
             # (never alloc/freed itself — slot i of both pools always
             # belongs to the same request)
             self.draft_pool = SlotPool(draft_model, num_slots,
-                                       max_length, dtype, buckets,
-                                       donate=self._donate_pool)
+                                       max_length, dtype, buckets)
         else:
             self._draft_state = None
             self.draft_pool = None
@@ -367,11 +354,6 @@ class InferenceEngine:
         # (or load) each program once.
         from .. import programs as _programs
         store = _programs.get_store()
-        # donation: direct in-process compiles donate as declared; the
-        # store's export path re-applies the recorded argnums only on a
-        # gauntlet-safe verdict, sentinel-guarded. donate_pool rides the
-        # statics: a donated and an undonated engine must never share
-        # one store key.
         engine_statics = {
             'model': type(model).__qualname__,
             'model_src': _programs.code_token(type(model)),
@@ -379,7 +361,6 @@ class InferenceEngine:
             'num_slots': self.pool.num_slots,
             'max_length': self.pool.max_length,
             'decode_block': self.decode_block,
-            'donate_pool': self._donate_pool,
         }
         if self.adapter_bank is not None:
             # ONLY the packed geometry + target-site set ride the key:
@@ -399,15 +380,15 @@ class InferenceEngine:
                 kv_pages=self.pool.num_pages,
                 kv_quant=self.pool.quant or 'none')
         if self._paged:
-            # page buffers (and scales) donate through the PR-13
-            # gauntlet exactly like the row pool did: decode/spec alias
-            # the pool in place; prefill/chunk stay UNDONATED so a
-            # prefill failure remains request-level (a donated prefill
-            # dying would invalidate the whole pool)
+            # page buffers (and scales) are donated exactly like the
+            # row pool: decode/spec alias the pool in place;
+            # prefill/chunk stay UNDONATED so a prefill failure remains
+            # request-level (a donated prefill dying would invalidate
+            # the whole pool)
             self._decode_jit = store.wrap_jit(
                 self._paged_decode_fn, name='serving.paged_decode_block',
                 kind='serving', statics=engine_statics,
-                donate_argnums=(3, 4) if self._donate_pool else ())
+                donate_argnums=(3, 4))
             self._prefill_jit = store.wrap_jit(   # 1 trace per bucket
                 self._paged_prefill_fn,
                 name_fn=lambda args: f'serving.paged_prefill_'
@@ -422,7 +403,7 @@ class InferenceEngine:
             self._decode_jit = store.wrap_jit(
                 self._decode_block_fn, name='serving.decode_block',
                 kind='serving', statics=engine_statics,
-                donate_argnums=(3,) if self._donate_pool else ())
+                donate_argnums=(3,))
             self._prefill_jit = store.wrap_jit(   # 1 trace per bucket
                 self._prefill_fn,
                 name_fn=lambda args: f'serving.prefill_'
@@ -449,14 +430,13 @@ class InferenceEngine:
                     self._paged_spec_fn,
                     name=f'serving.paged_spec_decode_k{self.spec_k}',
                     kind='serving', statics=spec_statics,
-                    donate_argnums=(3, 4, 9) if self._donate_pool
-                    else ())
+                    donate_argnums=(3, 4, 9))
             else:
                 self._spec_jit = store.wrap_jit(
                     self._spec_decode_fn,
                     name=f'serving.spec_decode_k{self.spec_k}',
                     kind='serving', statics=spec_statics,
-                    donate_argnums=(3, 7) if self._donate_pool else ())
+                    donate_argnums=(3, 7))
             self._draft_prefill_jit = store.wrap_jit(
                 self._draft_prefill_fn,
                 name_fn=lambda args: f'serving.draft_prefill_'
@@ -557,7 +537,7 @@ class InferenceEngine:
         ALL slots (lax.scan), per-slot positions/masks/sampling. `pool`
         is the stacked pool (leaves [num_slots, max_length, H, D]): it
         is the scan's carry and comes back as the second result, so
-        with `donate_pool` the program updates it in place.
+        the program, which takes it donated, updates it in place.
         `adapters`/`adapter_rows` (bank-attached engines only) are the
         packed LoRA banks + per-slot bank rows — traced inputs, so any
         adapter mix replays this same program."""
@@ -1337,8 +1317,6 @@ class InferenceEngine:
         try:
             op(*args)
         except Exception as exc:
-            if not self._donate_pool:
-                raise
             self._recover_pool()
             raise PoolLostError(
                 f'{op.__name__} died with the pool donated to it: '
@@ -1415,8 +1393,7 @@ class InferenceEngine:
                             self._keys, *self._adapter_args())
                         self.pool.cache = new_pool
             except Exception:
-                if self._donate_pool:
-                    self._recover_pool()
+                self._recover_pool()
                 raise
             with _obs.span('serving.d2h'):
                 toks = call_with_retry(_from_device, toks_dev,
@@ -1473,8 +1450,7 @@ class InferenceEngine:
                         self.pool.cache = new_pool
                     self.draft_pool.cache = new_d_pool
             except Exception:
-                if self._donate_pool:
-                    self._recover_pool()
+                self._recover_pool()
                 raise
             with _obs.span('serving.d2h'):
                 toks = call_with_retry(_from_device, toks_dev,
@@ -2026,7 +2002,6 @@ class InferenceEngine:
             'queue_depth': self.scheduler.queue_depth,
             'active_slots': len(self._slot_req),
             'weight_version': self.weight_version,
-            'donate_pool': self._donate_pool,
             'kv_layout': 'paged' if self._paged else 'row',
             'traces': dict(traces),
             'pool': self.pool.stats(),

@@ -148,14 +148,11 @@ class SlotPool:
 
     `rows` is whatever `model.init_cache(num_slots, max_length)` returns
     (per-layer (K, V) pairs for every causal-LM family here), so the
-    pool works for any model honoring the init_cache contract. `donate`
-    is the engine's `donate_pool`: whether the seat and copy programs
-    take the pool donated (in place) or return a copy of it.
+    pool works for any model honoring the init_cache contract.
     """
 
     def __init__(self, model, num_slots: int, max_length: int,
-                 dtype=None, buckets: Optional[Sequence[int]] = None,
-                 donate: bool = True):
+                 dtype=None, buckets: Optional[Sequence[int]] = None):
         if num_slots < 1:
             raise ValueError('num_slots must be >= 1')
         if max_length < 2:
@@ -173,20 +170,17 @@ class SlotPool:
         self.pool_bytes = _leaf_bytes(self.rows)
         self.row_bytes = self.pool_bytes // self.num_slots
         # the single-slot programs, enrolled in the program store like
-        # the engine's own (a warm replica loads them; a quarantine
-        # recompiles them undonated). `donate` rides the statics: a
-        # donated and an undonated pool never share one store key.
+        # the engine's own (a warm replica loads them); seat and copy
+        # take the pool donated
         from .. import programs as _programs
         store = _programs.get_store()
         self.traces = collections.Counter()     # python-level traces
-        donated = {'kind': 'serving', 'statics': {'donate': bool(donate)},
-                   'donate_argnums': (0,) if donate else ()}
         self._seat_jit = store.wrap_jit(
             self._prefill_seat_row, name='serving.prefill_seat_row',
-            **donated)
+            kind='serving', donate_argnums=(0,))
         self._copy_jit = store.wrap_jit(
             self._prefill_copy_row, name='serving.prefill_copy_row',
-            **donated)
+            kind='serving', donate_argnums=(0,))
         self._slice_jit = store.wrap_jit(
             self._prefill_slice_row, name='serving.prefill_slice_row',
             kind='serving')
@@ -199,8 +193,7 @@ class SlotPool:
         # chunked-prefill config rides the pool so stats()/debuggers see
         # the full prefill geometry in one place (the engine sets it)
         self.prefill_chunk_tokens: Optional[int] = None
-        # copy-surface accounting (the bench donation phase reports the
-        # bytes delta vs the old full-pool round trips)
+        # copy-surface accounting
         self._row_writes = 0
         self._row_copies = 0
         self._copied_bytes = 0

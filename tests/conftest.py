@@ -28,15 +28,6 @@ if 'JAX_COMPILATION_CACHE_DIR' not in os.environ:
     atexit.register(shutil.rmtree, os.environ['JAX_COMPILATION_CACHE_DIR'],
                     ignore_errors=True)
 
-# Donation posture is pinned OFF for tier-1 determinism: under 'auto'
-# the first store-configuring test would spawn the subprocess gauntlet,
-# and every donated/undonated code path downstream would then depend on
-# that probe's verdict and timing. The donation tests
-# (tests/test_donation.py) opt back in per-test via set_flags /
-# PADDLE_DONATION_PROBE_MODE. (setdefault: an operator exporting the
-# flag explicitly still wins.)
-os.environ.setdefault('FLAGS_donation', 'off')
-
 import jax  # noqa: E402,F401
 
 import numpy as np  # noqa: E402
@@ -75,7 +66,7 @@ def sanitizer_strict():
     a failover/retry path mid-test, the teardown assertion on the
     violation counter still fails the test. The chaos gauntlets
     (router failover storm, autoscaler thundering herd, hotswap
-    kill-mid-swap, donation sentinel trips) all opt in."""
+    kill-mid-swap, pool recovery) all opt in."""
     from paddle_tpu import observability as obs
     from paddle_tpu.analysis import runtime as _rt
 

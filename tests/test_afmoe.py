@@ -309,8 +309,8 @@ def tiny():
 
 @pytest.mark.parametrize('extra', [
     dict(kv_page_size=8), dict(prefix_cache=True),
-    dict(prefill_chunk_tokens=16), dict(donate_pool=False)],
-    ids=['paged', 'prefix_cache', 'chunked_prefill', 'undonated'])
+    dict(prefill_chunk_tokens=16)],
+    ids=['paged', 'prefix_cache', 'chunked_prefill'])
 def test_engine_modes_serve_the_same_tokens(tiny, extra):
     """Paged pool, prefix cache and chunked prefill hand the model other
     masks, rows and offsets; every window layer narrows them by itself."""

@@ -30,9 +30,8 @@ from paddle_tpu.analysis.passes import obs_schema
 ROOT = pathlib.Path(__file__).resolve().parent.parent
 FIXTURES = ROOT / 'tests' / 'analysis_fixtures'
 
-ALL_PASSES = ('donation-path', 'falsy-guard', 'host-sync', 'lock-order',
-              'obs-schema', 'raw-lock', 'swallowed-exception',
-              'trace-hazard')
+ALL_PASSES = ('falsy-guard', 'host-sync', 'lock-order', 'obs-schema',
+              'raw-lock', 'swallowed-exception', 'trace-hazard')
 
 #: FIXTURE_SPECS entries whose "pass" is a RUNTIME checker: the fixture
 #: modules are EXECUTED under the report-mode sanitizer instead of
@@ -133,8 +132,6 @@ FIXTURE_SPECS = [
     ('swallowed-exception', 'swallowed_exception/bad_swallows.py',
      'swallowed_exception/good_handled.py'),
     ('obs-schema', 'obs_schema/bad_schema.py', 'obs_schema/good_schema.py'),
-    ('donation-path', 'donation_path/bad_donate.py',
-     'donation_path/good_gated.py'),
 ]
 
 

@@ -180,7 +180,7 @@ def _run_store_child(tmp_path, action, fill=None, timeout=240):
             str(tmp_path / 'wstore'), action]
     if fill is not None:
         args.append(str(fill))
-    env = dict(os.environ, JAX_PLATFORMS='cpu', FLAGS_donation='off')
+    env = dict(os.environ, JAX_PLATFORMS='cpu')
     proc = subprocess.run(args, capture_output=True, text=True,
                           timeout=timeout, env=env)
     line = proc.stdout.strip().splitlines()[-1] if proc.stdout.strip() \

@@ -614,7 +614,8 @@ class TestProgramCatalog:
         class NoAot:
             def __call__(self, x):
                 return x + 1
-        wrapped = obs.program_catalog().wrap_jit(
+        from paddle_tpu import programs
+        wrapped = programs.get_store().wrap_jit(
             NoAot(), name='no_aot_prog')
         assert wrapped(np.float32(1.0)) == 2.0
         assert wrapped(np.float32(2.0)) == 3.0

@@ -10,7 +10,7 @@ exception; warm-restart semantics for both a trainer (resume='auto')
 and a serving engine; the ref-counted /healthz `warming` state during
 bulk preload; the catalog==store no-double-attribution guard; the
 dispatch-cache LRU satellite; the typed `ProgramDeserializeError` in
-jit.load; and the bench coldstart tier-1 guards.
+jit.load; and the bench coldstart tier-1 guard.
 """
 import json
 import os
@@ -30,7 +30,9 @@ from paddle_tpu import debug, jit, observability as obs, programs
 from paddle_tpu.flags import set_flags
 from paddle_tpu.nlp import GPTConfig, GPTForCausalLM
 from paddle_tpu.programs import ProgramDeserializeError
+from paddle_tpu.programs import store as store_mod
 from paddle_tpu.serving import InferenceEngine, SamplingParams
+from paddle_tpu.serving.kv_pool import SlotPool
 
 NO_EOS = -1
 
@@ -193,20 +195,6 @@ class TestRoundTrip:
         finally:
             pstore.configure(d)
         assert not [f for f in os.listdir(d) if 'nodisk' in f]
-
-    def test_flag_bypass_keeps_serving(self, pstore):
-        set_flags({'FLAGS_program_store': False})
-        try:
-            before = pstore.stats()['memory_entries']
-            w = _wrap(pstore, 'bypass')
-            x, y = _args()
-            out = np.asarray(w(x, y))
-            assert np.isfinite(out).all()
-            assert pstore.stats()['memory_entries'] == before, \
-                'bypassed call must not touch the store'
-        finally:
-            set_flags({'FLAGS_program_store': True})
-
 
 # ---------------------------------------------------------------------------
 # the corruption gauntlet: every poisoning degrades to recompile
@@ -465,6 +453,335 @@ class TestWarmRestartTrainer:
 
 
 # ---------------------------------------------------------------------------
+# one compile site, one donation rule: a program's declared
+# donate_argnums is applied on every route to an executable
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def open_store(monkeypatch):
+    """`open_store(directory)` replaces the process-wide store with a
+    NEW, empty one pointed at `directory` (None: no persistent tier) —
+    what a fresh process would hold. Teardown puts the old one back."""
+    opened = []
+
+    def _open(directory):
+        store = programs.ProgramStore()
+        monkeypatch.setattr(store_mod, '_store', store)
+        store.configure(directory)
+        opened.append(store)
+        return store
+
+    yield _open
+    if opened:
+        opened[-1].configure(None)
+
+
+def _mlp_step(offload=None):
+    paddle.seed(0)
+    net = nn.Sequential(nn.Linear(8, 16), nn.BatchNorm1D(16), nn.ReLU(),
+                        nn.Linear(16, 4))
+    kind = paddle.optimizer.AdamW if offload else paddle.optimizer.SGD
+    extra = {'offload': offload} if offload else {}
+    opt = kind(learning_rate=0.01, parameters=net.parameters(), **extra)
+    step = jit.TrainStep(net, lambda o, l: F.cross_entropy(o, l), opt)
+    rng = np.random.RandomState(0)
+    batch = step._as_batch(
+        paddle.to_tensor(rng.standard_normal((4, 8)).astype('float32')),
+        paddle.to_tensor(rng.randint(0, 4, (4,))))
+    params, frozen, buffers = jit.functional_state(net)
+    key = jax.random.fold_in(step._step_key_root, 0)
+    return step, opt, params, frozen, buffers, key, batch
+
+
+def _prog_train_step():
+    step, opt, params, frozen, buffers, key, batch = _mlp_step()
+    lr = jnp.asarray(opt.get_lr(), jnp.float32)
+    return step._jitted, (params, opt.init_state(params), buffers, frozen,
+                          key, lr, batch)
+
+
+def _prog_train_step_grads():
+    step, _, params, frozen, buffers, key, batch = _mlp_step('host')
+    return step._jitted_grads, (params, buffers, frozen, key, batch)
+
+
+def _engine(gpt, **kw):
+    return InferenceEngine(gpt, num_slots=2, max_length=32,
+                           decode_block=2, **kw)
+
+
+def _slot_args(eng):
+    return (eng._tok, eng._pos, eng._steps, eng._active, eng._temp,
+            eng._topk, eng._topp, eng._greedy, eng._keys)
+
+
+def _prog_decode(gpt):
+    eng = _engine(gpt)
+    return eng._decode_jit, (eng._params, eng._frozen, eng._buffers,
+                             eng.pool.cache, *_slot_args(eng))
+
+
+def _prog_paged_decode(gpt):
+    eng = _engine(gpt, kv_page_size=8)
+    pages, scales = eng.pool.device_state()
+    return eng._decode_jit, (eng._params, eng._frozen, eng._buffers,
+                             pages, scales, jnp.asarray(eng.pool.page_table),
+                             *_slot_args(eng))
+
+
+def _prog_spec(gpt):
+    eng = _engine(gpt, draft_model=gpt, num_draft_tokens=2)
+    return eng._spec_jit, (eng._params, eng._frozen, eng._buffers,
+                           eng.pool.cache, *eng._draft_state,
+                           eng.draft_pool.cache, *_slot_args(eng),
+                           eng._eos_arr)
+
+
+def _prog_paged_spec(gpt):
+    eng = _engine(gpt, kv_page_size=8, draft_model=gpt,
+                  num_draft_tokens=2)
+    pages, scales = eng.pool.device_state()
+    return eng._spec_jit, (eng._params, eng._frozen, eng._buffers,
+                           pages, scales, jnp.asarray(eng.pool.page_table),
+                           *eng._draft_state, eng.draft_pool.cache,
+                           *_slot_args(eng), eng._eos_arr)
+
+
+def _pool_row(gpt, pool, value):
+    return jax.tree_util.tree_map(
+        lambda c: jnp.full((1,) + c.shape[1:], value, c.dtype), pool.rows)
+
+
+def _prog_set_row(gpt):
+    pool = SlotPool(gpt, num_slots=3, max_length=16)
+    return pool._seat_jit, (pool.rows, _pool_row(gpt, pool, 1.5),
+                            jnp.int32(1))
+
+
+def _prog_copy_slot(gpt):
+    pool = SlotPool(gpt, num_slots=3, max_length=16)
+    rows = jax.tree_util.tree_map(
+        lambda c: jnp.arange(c.size, dtype=c.dtype).reshape(c.shape),
+        pool.rows)
+    return pool._copy_jit, (rows, jnp.int32(0), jnp.int32(2))
+
+
+#: name -> (builder of (StoredJit, args), declared donate_argnums)
+_DONATING_PROGRAMS = {
+    'train_step': (lambda gpt: _prog_train_step(), (0, 1, 2)),
+    'train_step_grads': (lambda gpt: _prog_train_step_grads(), (1,)),
+    'decode_block': (_prog_decode, (3,)),
+    'paged_decode_block': (_prog_paged_decode, (3, 4)),
+    'spec_decode': (_prog_spec, (3, 7)),
+    'paged_spec_decode': (_prog_paged_spec, (3, 4, 9)),
+    'set_row': (_prog_set_row, (0,)),
+    'copy_slot': (_prog_copy_slot, (0,)),
+}
+_REFERENCES = {}
+
+
+def _copy_args(args):
+    return jax.tree_util.tree_map(
+        lambda v: jnp.array(v) if isinstance(v, jax.Array) else v, args)
+
+
+def _unstored_reference(name, wrapper, args, donate):
+    """What a plain `jax.jit` of the same function gives at these
+    arguments, and the bytes it aliases when it donates as declared —
+    no store, no export, computed once per program."""
+    if name not in _REFERENCES:
+        raw = wrapper._fn.__wrapped__
+        out = jax.jit(raw)(*_copy_args(args))
+        mem = jax.jit(raw, donate_argnums=donate).lower(
+            *args).compile().memory_analysis()
+        _REFERENCES[name] = (
+            [np.asarray(v) for v in jax.tree_util.tree_leaves(out)],
+            mem.alias_size_in_bytes)
+    return _REFERENCES[name]
+
+
+class TestDonationRoutes:
+    """Every program that declares a donation, on every route to its
+    executable: the compiled program aliases the declared arguments,
+    the call consumes them, and the results are those of an unstored
+    `jax.jit` of the same function."""
+
+    @pytest.mark.parametrize('route', ['direct', 'cold_export',
+                                       'warm_load'])
+    @pytest.mark.parametrize('name', list(_DONATING_PROGRAMS))
+    def test_declared_donation_is_applied(self, name, route, gpt,
+                                          open_store, tmp_path):
+        build, donate = _DONATING_PROGRAMS[name]
+        directory = None if route == 'direct' else str(tmp_path / 'store')
+        store = open_store(directory)
+        wrapper, args = build(gpt)
+        assert wrapper._donate == donate
+        ref_out, ref_alias = _unstored_reference(name, wrapper, args,
+                                                 donate)
+        reg = obs.get_registry()
+        if route == 'warm_load':
+            wrapper(*args)                   # the cold process persists
+            assert store.stats()['persisted'] == 1
+            store = open_store(directory)    # the warm one starts empty
+            marks = _compile_marks(reg)
+            wrapper, args = build(gpt)
+        donated = [leaf for i in donate
+                   for leaf in jax.tree_util.tree_leaves(args[i])]
+        kept = [leaf for i, a in enumerate(args) if i not in donate
+                for leaf in jax.tree_util.tree_leaves(a)
+                if isinstance(leaf, jax.Array)]
+        out = wrapper(*args)
+        (_, compiled), = wrapper._entries.values()
+        st = store.stats()
+        if route == 'warm_load':
+            assert _real_compiles(reg, marks) == 0
+            assert (st['hits_disk'], st['misses']) == (1, 0)
+        else:
+            assert (st['hits_disk'], st['misses']) == (0, 1)
+            assert st['persisted'] == (route == 'cold_export')
+        assert ref_alias > 0
+        assert compiled.memory_analysis().alias_size_in_bytes == ref_alias
+        assert donated and all(leaf.is_deleted() for leaf in donated)
+        assert not any(leaf.is_deleted() for leaf in kept)
+        got = [np.asarray(v) for v in jax.tree_util.tree_leaves(out)]
+        assert len(got) == len(ref_out)
+        for g, r in zip(got, ref_out):
+            assert g.dtype == r.dtype and g.tobytes() == r.tobytes()
+
+
+@pytest.mark.parametrize('fallback', ['aot_noexport', 'persist_skipped'])
+def test_fallbacks_keep_the_declared_donation(fallback, open_store,
+                                              tmp_path):
+    """A program the persistent tier cannot take — an argument the
+    export refuses, a directory that cannot be written — is still
+    compiled, donated as declared, and served from the memory tier."""
+    directory = tmp_path / 'store'
+    store = open_store(str(directory))
+
+    def f(x, key):
+        return x + 1.0, key
+
+    w = store.wrap_jit(f, name=f'test.{fallback}', donate_argnums=(0,))
+    x = jnp.ones((4, 4))
+    if fallback == 'aot_noexport':
+        key = jax.random.key(0)              # typed keys do not export
+    else:
+        key = jax.random.PRNGKey(0)
+        directory.rmdir()
+        directory.write_text('not a directory')
+    out, _ = w(x, key)
+    assert x.is_deleted() and (np.asarray(out) == 2.0).all()
+    (_, compiled), = w._entries.values()
+    assert compiled.memory_analysis().alias_size_in_bytes == 64
+    st = store.stats()
+    assert (st['misses'], st['persisted']) == (1, 0)
+    skipped = [e for e in _recent_events('program_store_persist_skipped')
+               if e['attrs']['program'] == f'test.{fallback}']
+    assert len(skipped) == 1
+    record = store.catalog.record(f'test.{fallback}')
+    if fallback == 'aot_noexport':
+        assert record.note == 'aot_noexport'
+    else:
+        assert st['persist_skips'] == 1 and record.note == ''
+        directory.unlink()
+
+
+def _train_losses(steps=3):
+    rng = np.random.RandomState(0)
+    x = rng.standard_normal((16, 32)).astype('float32')
+    y = rng.randint(0, 4, (16,))
+    paddle.seed(0)
+    m = nn.Sequential(nn.Linear(32, 64), nn.ReLU(), nn.Linear(64, 4))
+    opt = paddle.optimizer.SGD(learning_rate=0.01,
+                               parameters=m.parameters())
+    step = jit.TrainStep(m, lambda o, l: F.cross_entropy(o, l), opt)
+    return [float(step(paddle.to_tensor(x), paddle.to_tensor(y)).numpy())
+            for _ in range(steps)]
+
+
+def _greedy_tokens(gpt):
+    rng = np.random.RandomState(0)
+    prompts = [rng.randint(1, 128, (n,)).tolist() for n in (5, 9, 13, 7)]
+    eng = InferenceEngine(gpt, num_slots=4, max_length=64)
+    handles = eng.generate_many(
+        prompts, SamplingParams(max_new_tokens=6, eos_token_id=NO_EOS))
+    return [list(h.tokens) for h in handles]
+
+
+def _alias_bytes(store, name):
+    with store._lock:
+        return [e.callable.memory_analysis().alias_size_in_bytes
+                for e in store._mem.values() if e.name == name]
+
+
+def test_store_served_donated_losses_bit_exact(open_store, tmp_path,
+                                               sanitizer_strict):
+    """A train loop served through the export artifact, cold and then
+    warm from disk, donates its state and reproduces the losses of the
+    directory-less run bit for bit."""
+    open_store(None)
+    ref = _train_losses()
+    for source in ('compile', 'disk'):
+        store = open_store(str(tmp_path / 'store'))
+        assert _train_losses() == ref
+        (ent,) = [e for e in store.entries() if e['name'] == 'train_step']
+        assert (ent['source'], ent['format']) == (source, 'stablehlo')
+        assert all(n > 0 for n in _alias_bytes(store, 'train_step'))
+
+
+def test_donated_pool_greedy_parity_store_served(gpt, open_store, tmp_path,
+                                                 sanitizer_strict):
+    """Serving through the export artifact, cold and then warm, aliases
+    the whole pool into the decode block and serves the tokens of the
+    directory-less engine."""
+    open_store(None)
+    ref = _greedy_tokens(gpt)
+    for source in ('compile', 'disk'):
+        store = open_store(str(tmp_path / 'store'))
+        assert _greedy_tokens(gpt) == ref
+        (ent,) = [e for e in store.entries()
+                  if e['name'] == 'serving.decode_block']
+        assert (ent['source'], ent['format']) == (source, 'stablehlo')
+        pool_bytes = SlotPool(gpt, num_slots=4, max_length=64).pool_bytes
+        assert _alias_bytes(store, 'serving.decode_block') == [pool_bytes]
+
+
+@pytest.mark.parametrize('route', ['direct', 'cold_export', 'warm_load'])
+def test_every_route_compiles_through_one_function(route, open_store,
+                                                   monkeypatch, tmp_path):
+    """`store._compile_program` is the store's one compile site: each
+    route to an executable calls it exactly once, with the declared
+    donation."""
+    directory = None if route == 'direct' else str(tmp_path / 'store')
+    store = open_store(directory)
+
+    def build():
+        def f(x, y):
+            return x * 2.0 + y, y
+        return store_mod.get_store().wrap_jit(
+            f, name='test.one_site', statics={'route': route},
+            donate_argnums=(0,))
+
+    if route == 'warm_load':
+        build()(*_args())
+        store = open_store(directory)
+    calls = []
+    real = store_mod._compile_program
+
+    def counting(fn, args, donate_argnums=()):
+        calls.append(tuple(donate_argnums))
+        return real(fn, args, donate_argnums)
+
+    monkeypatch.setattr(store_mod, '_compile_program', counting)
+    x, y = _args()
+    out, _ = build()(x, y)
+    assert calls == [(0,)]
+    assert x.is_deleted() and not y.is_deleted()
+    assert (np.asarray(out) == 2.5).all()
+    assert store.stats()['hits_disk'] == (route == 'warm_load')
+
+
+# ---------------------------------------------------------------------------
 # warm restart: serving replica
 # ---------------------------------------------------------------------------
 
@@ -515,6 +832,7 @@ import paddle_tpu.nn.functional as F
 from paddle_tpu import jit, observability as obs, programs
 from paddle_tpu.nlp import GPTConfig, GPTForCausalLM
 from paddle_tpu.serving import InferenceEngine, SamplingParams
+from paddle_tpu.serving.kv_pool import SlotPool
 
 paddle.seed(0)
 # tier 1: eager dispatch (catalog 'dispatch' records, store-external)
@@ -663,7 +981,7 @@ class TestJitLoadTyped:
 
 
 # ---------------------------------------------------------------------------
-# tier-1 bench guards: coldstart A/B + store-disabled overhead
+# tier-1 bench guard: coldstart A/B
 # ---------------------------------------------------------------------------
 
 def _bench():
@@ -687,16 +1005,3 @@ def test_bench_coldstart_guard():
     assert res['warm_loaded_from_disk'] >= 3
     assert res['warm_rejects'] == 0
     assert res['warm_cold_ratio'] > 1.0, res
-
-
-def test_bench_coldstart_overhead_under_3pct():
-    """Tier-1: the store-disabled fallback path (FLAGS_program_store
-    off) costs < 3% vs the enrolled path on a steady-state jitted train
-    loop (same retry protocol as the other overhead guards)."""
-    bench = _bench()
-    res = None
-    for _ in range(3):
-        res = bench.coldstart_overhead_ab(steps=20, trials=2)
-        if res['overhead_pct'] < 3.0:
-            break
-    assert res['overhead_pct'] < 3.0, res

@@ -262,17 +262,6 @@ def test_slot_pool_rows_are_the_device_buffers(gpt):
     assert not any(np.asarray(leaf).any() for leaf in _leaves(pool.rows))
 
 
-def test_undonated_slot_pool_leaves_its_input_alive(gpt):
-    """`donate=False` (the engine's donate_pool=False): the seat program
-    returns a new pool and the one it was given stays valid."""
-    pool = SlotPool(gpt, num_slots=2, max_length=16, donate=False)
-    old = _leaves(pool.rows)
-    pool.set_row(1, _ones_row(gpt, 16))
-    assert not any(leaf.is_deleted() for leaf in old)
-    assert not np.asarray(old[0]).any()
-    assert (np.asarray(_leaves(pool.rows)[0])[1] == 1).all()
-
-
 def _decode_args(eng):
     return (eng._params, eng._frozen, eng._buffers, eng.pool.cache,
             eng._tok, eng._pos, eng._steps, eng._active, eng._temp,
@@ -334,6 +323,36 @@ def test_failed_donated_seat_recovers_the_pool(gpt):
     assert h.status == FINISHED and h.tokens == ref
 
 
+def test_donated_decode_failure_recovers_the_pool(gpt, sanitizer_strict):
+    """A decode program dying mid-call may have consumed the pool it
+    was given: the engine rebuilds the pool, hands the orphaned request
+    back, and serves the next one correctly."""
+    eng = InferenceEngine(gpt, num_slots=2, max_length=64,
+                          prefix_cache=True)
+    prompt = _prompts((6,))[0]
+    ref = _ref_generate(gpt, prompt, 4)
+    real_jit = eng._decode_jit
+
+    def dying(*args):
+        for leaf in _leaves(args[3]):
+            leaf.delete()                   # what a donated call may do
+        raise RuntimeError('simulated device failure mid-decode')
+
+    eng._decode_jit = dying
+    h = eng.submit(prompt, max_new_tokens=4, eos_token_id=NO_EOS)
+    with pytest.raises(RuntimeError, match='mid-decode'):
+        eng.run()
+    assert 'serving_pool_recovered' in [e['name'] for e in
+                                        obs.get_event_log().events()]
+    assert not any(leaf.is_deleted() for leaf in _leaves(eng.pool.rows))
+    for handle in eng.evict_all():
+        assert handle is h                  # orphan handed back, not lost
+    eng._decode_jit = real_jit
+    h2 = eng.submit(prompt, max_new_tokens=4, eos_token_id=NO_EOS)
+    eng.run()
+    assert h2.tokens == ref
+
+
 def test_one_prefill_row_in_flight(gpt, monkeypatch):
     """The runtime reserves a program's outputs when it is enqueued, so
     admissions dispatched back to back would each hold a row until its
@@ -358,30 +377,6 @@ def test_one_prefill_row_in_flight(gpt, monkeypatch):
     eng.step()                              # three admissions, one step
     assert [o for o in order if o != 'wait-other'] == \
         ['wait', 'prefill'] * 3
-
-
-def test_failed_undonated_seat_is_request_level(gpt):
-    """With donate_pool=False nothing was donated, so a dying seat costs
-    its own request and nobody else."""
-    eng = InferenceEngine(gpt, num_slots=2, max_length=64, decode_block=2,
-                          donate_pool=False)
-    prompts = _prompts((6, 9))
-    ref = _ref_generate(gpt, prompts[1], 4)
-    real = eng.pool._seat_jit
-    calls = []
-
-    def dying_once(pool, row, slot):
-        calls.append(slot)
-        if len(calls) == 1:
-            raise RuntimeError('simulated seat failure')
-        return real(pool, row, slot)
-
-    eng.pool._seat_jit = dying_once
-    hs = [eng.submit(p, max_new_tokens=4, eos_token_id=NO_EOS)
-          for p in prompts]
-    eng.run()
-    assert hs[0].status == FAILED and hs[1].status == FINISHED
-    assert hs[1].tokens == ref
 
 
 # ---------------------------------------------------------------------------
