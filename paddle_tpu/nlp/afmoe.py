@@ -48,6 +48,7 @@ from ..nn.common_layers import Embedding, Linear
 from ..nn.norm import RMSNorm
 from ..tensor import Tensor, apply_op, to_jax
 from .generation import (GenerationMixin, as_offset as _as_offset,
+                         attended_rows as _attended_rows,
                          decode_mask as _decode_mask, note_routing,
                          offset_grid as _offset_grid,
                          update_kv_cache as _update_kv_cache)
@@ -246,12 +247,14 @@ class AfmoeAttention(Layer):
                 else _decode_mask(q, k_cache, slot)
             if window is not None:
                 slot_t = slot if isinstance(slot, Tensor) else Tensor(slot)
+                # against the mask's own length: a caller's shorter mask
+                # is the rows attention reads (`attended_rows`)
                 mask = apply_op(
-                    lambda m, qv, kc, sl: _narrow(m, _window_mask(
-                        sl, qv.shape[1], kc.shape[1], window)),
-                    mask, q, k_cache, slot_t, _name='window_mask')
-            out = F.scaled_dot_product_attention(q, k_cache, v_cache,
-                                                 attn_mask=mask)
+                    lambda m, qv, sl: _narrow(m, _window_mask(
+                        sl, qv.shape[1], m.shape[-1], window)),
+                    mask, q, slot_t, _name='window_mask')
+            out = F.scaled_dot_product_attention(
+                q, *_attended_rows(k_cache, v_cache, mask), attn_mask=mask)
         out = apply_op(
             lambda t: t.reshape(t.shape[0], t.shape[1], nh * hd),
             out, _name='merge_heads')

@@ -123,6 +123,24 @@ def decode_mask(q, k_cache, offset):
     return _apply(fn, q, k_cache, _name='decode_mask')
 
 
+def attended_rows(k_cache, v_cache, mask):
+    """The rows of a static cache that attention contracts over: the
+    first `mask.shape[-1]` of each leaf. A caller that knows no query
+    looks past row `n` hands in a mask of `n` columns (the serving
+    engine's shorter decode program, picked from the batch's positions)
+    and attention then reads `[B, n, H_kv, D]` of each leaf, not the
+    whole of it. The WRITE is not this function's: `update_kv_cache`
+    scatters into the whole leaf, and the whole leaf is what a forward
+    returns. A mask as long as the cache gives the leaves back as they
+    came, so that caller's program is the one it was."""
+    from ..tensor import apply_op as _apply
+    rows = mask.shape[-1]
+    if rows == k_cache.shape[1]:
+        return k_cache, v_cache
+    return tuple(_apply(lambda c: c[:, :rows], c, _name='attended_rows')
+                 for c in (k_cache, v_cache))
+
+
 def padded_decode_mask(keep, cache_len, cache_offset, sq):
     """[B, 1, Sq, L] boolean mask for decode over a static cache holding a
     left/right-PADDED prompt: slot-causal AND key slot not a pad slot.

@@ -18,6 +18,7 @@ from ..nn.layer import Layer
 from ..nn.norm import LayerNorm
 from ..tensor import Tensor, apply_op, to_jax
 from .generation import (GenerationMixin, as_offset as _as_offset,
+                         attended_rows as _attended_rows,
                          decode_mask as _decode_mask,
                          offset_grid as _offset_grid,
                          update_kv_cache as _update_kv_cache)
@@ -109,8 +110,8 @@ class GPTAttention(Layer):
                                                     k, v, slot)
             mask = attn_mask if attn_mask is not None \
                 else _decode_mask(q, k_cache, slot)
-            out = F.scaled_dot_product_attention(q, k_cache, v_cache,
-                                                 attn_mask=mask)
+            out = F.scaled_dot_product_attention(
+                q, *_attended_rows(k_cache, v_cache, mask), attn_mask=mask)
         out = apply_op(lambda t: t.reshape(t.shape[0], t.shape[1], nh * hd),
                        out, _name='merge_heads')
         out = self.out_proj(out)

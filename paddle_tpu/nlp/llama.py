@@ -26,6 +26,7 @@ from ..nn.norm import RMSNorm
 from ..nn.common_layers import Embedding
 from ..tensor import Tensor, apply_op, to_jax
 from .generation import (GenerationMixin, as_offset as _as_offset,
+                         attended_rows as _attended_rows,
                          decode_mask as _decode_mask,
                          offset_grid as _offset_grid,
                          update_kv_cache as _update_kv_cache)
@@ -192,8 +193,8 @@ class LlamaAttention(Layer):
             # default slot-causal one
             mask = attn_mask if attn_mask is not None \
                 else _decode_mask(q, k_cache, slot)
-            out = F.scaled_dot_product_attention(q, k_cache, v_cache,
-                                                 attn_mask=mask)
+            out = F.scaled_dot_product_attention(
+                q, *_attended_rows(k_cache, v_cache, mask), attn_mask=mask)
         out = apply_op(
             lambda t: t.reshape(t.shape[0], t.shape[1], nh * hd),
             out, _name='merge_heads')

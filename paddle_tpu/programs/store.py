@@ -258,7 +258,8 @@ def _export_program(jitted, args):
     abstract = jax.tree_util.tree_map(
         lambda v: jax.ShapeDtypeStruct(v.shape, v.dtype)
         if hasattr(v, 'shape') else v, args)
-    return _jex.export(jitted, platforms=tuple(sorted(plats)))(*abstract)
+    return _with_stack_room(
+        _jex.export(jitted, platforms=tuple(sorted(plats))), *abstract)
 
 
 def _compile_program(fn, args, donate_argnums=()):
@@ -270,7 +271,35 @@ def _compile_program(fn, args, donate_argnums=()):
     the arguments or their abstract shapes. Returns the `Compiled`."""
     if not hasattr(fn, 'lower'):
         fn = jax.jit(fn, donate_argnums=tuple(donate_argnums))
-    return fn.lower(*args).compile()
+    return _with_stack_room(lambda: fn.lower(*args).compile())
+
+
+def _roomy_frame(slots=17000):
+    """-> `with_stack_room(fn, *args)`: calls `fn(*args)` from a frame
+    of `slots` local variables (136 KB), and returns or raises what it
+    does. The store traces, lowers and compiles below it.
+
+    CPython keeps a thread's frames in chunks of 16 KiB, maps a new chunk
+    when a call does not fit in the last one, and unmaps it again when
+    that call returns. Tracing and lowering a large program is a few
+    hundred thousand Python calls, recursing a hundred frames below the
+    caller; where the caller's depth puts a chunk's end inside the inner
+    calls, every one of them costs two system calls. Measured on the
+    v5e's host (PR 29, PERF.md section 6): the decode block lowered in
+    0.45 to 0.6 s from a shallow stack, and in 3.5 to 9.7 s, by the
+    frame, from inside `InferenceEngine.step`; on this CPU 0.24 against
+    0.5 to 0.8 s at some depths. A frame that fits no 16 KiB chunk gets
+    one of its own, the next power of two (256 KiB), and its callees run
+    in what is left of that: 120 KB, with no boundary to cross — so what
+    a program costs to lower no longer depends on who asks for it."""
+    names = ' = '.join(f'_{i}' for i in range(slots))
+    scope = {}
+    exec(f'def with_stack_room(fn, *args):\n    {names} = None\n'
+         f'    return fn(*args)\n', scope)
+    return scope['with_stack_room']
+
+
+_with_stack_room = _roomy_frame()
 
 
 def _compile_exported(exported, donate_argnums=()):
@@ -888,7 +917,12 @@ class StoredJit:
             self._entries[key] = (record, call)
         return record, call
 
-    def __call__(self, *args):
+    def resolve(self, *args):
+        """(record, callable) of the program for these arguments,
+        built through the store (memory -> disk -> compile) if this
+        wrapper has not met their signature — and not called: a caller
+        with several programs over one set of arguments has them all
+        compiled by the time it first runs one."""
         try:
             key = self._signature(args)
         except Exception:
@@ -898,10 +932,11 @@ class StoredJit:
             _obs.count_suppressed('program_store.signature')
             key = None
         entry = self._entries.get(key) if key is not None else None
+        return entry if entry is not None else self._build(key, args)
+
+    def __call__(self, *args):
         t0 = time.perf_counter()
-        if entry is None:
-            entry = self._build(key, args)
-        record, call = entry
+        record, call = self.resolve(*args)
         out = call(*args)
         dt = time.perf_counter() - t0
         with self._store.catalog._lock:

@@ -13,7 +13,10 @@ lengths, token budgets, temperatures, eos ids) share a single XLA
 program and admission/retirement never recompiles anything.
 
 Compiled-program inventory (asserted by the zero-recompile tests):
-- one decode-block step (shapes fixed by num_slots/max_length/block),
+- the decode-block step (shapes fixed by num_slots/max_length/block)
+  at two lengths of attention: over every row of a slot, and over the
+  first half — picked per round from the slots' positions, both built
+  at the first decode dispatch (`_decode_program`),
 - one prefill program per length bucket (right-padded prompts; pad KV
   lands above the live position where the slot-causal mask hides it
   until the slot's own decode overwrites it — the stale-slot argument
@@ -337,8 +340,12 @@ class InferenceEngine:
             [self.pool.max_length if w is None
              else min(int(w), self.pool.max_length) for w in windows],
             np.int64)
-        self._read_rows = int(self.pool.num_slots * self.pool.max_length
-                              * n_layers)
+        # the decode block exists at two lengths of attention: every
+        # row of a slot, and the first half — which `_decode_round`
+        # picks while the batch's positions allow it. 0 where a block
+        # alone would pass the half: then it could never be picked
+        half = self.pool.max_length // 2
+        self._half_rows = half if half > self.decode_block else 0
         self._num_experts = int(getattr(cfg, 'num_experts', 0) or 0)
 
         self._trace_counts = collections.Counter()
@@ -379,6 +386,12 @@ class InferenceEngine:
                 kv_page_size=self.pool.page_size,
                 kv_pages=self.pool.num_pages,
                 kv_quant=self.pool.quant or 'none')
+        # `rows` rides the half program's statics (a slice length,
+        # invisible in any input aval); the whole program keeps the
+        # name and the statics it always had
+        half_statics = dict(engine_statics, rows=self._half_rows)
+        self._decode_half_jit = None
+        self._decode_resolved = False
         if self._paged:
             # page buffers (and scales) are donated exactly like the
             # row pool: decode/spec alias the pool in place;
@@ -389,6 +402,12 @@ class InferenceEngine:
                 self._paged_decode_fn, name='serving.paged_decode_block',
                 kind='serving', statics=engine_statics,
                 donate_argnums=(3, 4))
+            if self._half_rows:
+                self._decode_half_jit = store.wrap_jit(
+                    self._paged_decode_half_fn,
+                    name=f'serving.paged_decode_block_r{self._half_rows}',
+                    kind='serving', statics=half_statics,
+                    donate_argnums=(3, 4))
             self._prefill_jit = store.wrap_jit(   # 1 trace per bucket
                 self._paged_prefill_fn,
                 name_fn=lambda args: f'serving.paged_prefill_'
@@ -404,6 +423,12 @@ class InferenceEngine:
                 self._decode_block_fn, name='serving.decode_block',
                 kind='serving', statics=engine_statics,
                 donate_argnums=(3,))
+            if self._half_rows:
+                self._decode_half_jit = store.wrap_jit(
+                    self._decode_block_half_fn,
+                    name=f'serving.decode_block_r{self._half_rows}',
+                    kind='serving', statics=half_statics,
+                    donate_argnums=(3,))
             self._prefill_jit = store.wrap_jit(   # 1 trace per bucket
                 self._prefill_fn,
                 name_fn=lambda args: f'serving.prefill_'
@@ -520,6 +545,10 @@ class InferenceEngine:
         self._m_spec_shared_acc = reg.counter(
             'paddle_spec_accepted_drafts_total',
             'draft tokens accepted by source', ('source',))
+        self._m_rows_read = reg.counter(
+            'paddle_serving_decode_rows_read_total',
+            'cache rows a layer\'s decode attention read (slots x the '
+            'program\'s rows), summed over decode sub-steps')
         self._m_experts_touched = reg.counter(
             'paddle_serving_moe_experts_touched_total',
             'distinct experts active slots routed to, summed over decode '
@@ -547,16 +576,36 @@ class InferenceEngine:
                                  topk, topp, greedy, keys, adapters,
                                  adapter_rows)
 
+    def _decode_block_half_fn(self, params, frozen, buffers, pool, *state):
+        """`_decode_block_fn` with attention over the first
+        `max_length // 2` rows of every slot: a program of its own,
+        which `_decode_round` runs while no active slot comes near that
+        row. `state` is what follows `pool` there."""
+        self._trace_counts['decode_step_half'] += 1
+        fwd = cached_forward(self.model, params, frozen, buffers)
+        return self._decode_scan(fwd, pool, *state, rows=self._half_rows)
+
     def _decode_scan(self, fwd, pool, tok, pos, steps, active, temp, topk,
-                     topp, greedy, keys, adapters, adapter_rows):
-        """The per-token scan both decode programs run over a contiguous
-        [num_slots, max_length, H, D] view. -> (tokens [num_slots,
-        block], the pool) and, where the model has expert layers, a
-        third result: int32 [block, expert layers], the number of
-        distinct experts the ACTIVE slots routed to in each sub-step
+                     topp, greedy, keys, adapters=None, adapter_rows=None,
+                     rows=None):
+        """The per-token scan every decode program runs over a
+        contiguous [num_slots, max_length, H, D] view. -> (tokens
+        [num_slots, block], the pool) and, where the model has expert
+        layers, a third result: int32 [block, expert layers], the number
+        of distinct experts the ACTIVE slots routed to in each sub-step
         and layer, gathered as the model is traced (`routing_scope`). A
         model without experts leaves nothing there, and its program is
         the one it was.
+
+        `rows` (a Python int; None is `max_length`) is how much of a
+        slot attention READS: the mask has `rows` columns and the models'
+        cache attention contracts over the first `rows` rows of every
+        leaf (`generation.attended_rows`). The caller promises that no
+        active slot reaches row `rows` within the block; an inactive
+        slot's output is discarded whatever it read. The write never
+        narrows: it scatters into the whole leaf, and the whole pool is
+        the carry that comes back. At `max_length` nothing is sliced and
+        the program is the one that ever was.
 
         The pool is the scan's carry, read (attention) and written (one
         row a slot and leaf, `update_kv_cache` under scope `kv_write`)
@@ -568,7 +617,8 @@ class InferenceEngine:
         there and copies the leaf back out (PERF.md sections 5 and 7:
         what turns that off, and why it is not turned off yet)."""
         max_len = self.pool.max_length
-        k_slot = jnp.arange(max_len, dtype=jnp.int32)
+        k_slot = jnp.arange(max_len if rows is None else rows,
+                            dtype=jnp.int32)
 
         def sub(carry, _):
             tok, pos, steps, pool = carry
@@ -723,7 +773,7 @@ class InferenceEngine:
     def _paged_decode_fn(self, params, frozen, buffers, pages, scales,
                          table, tok, pos, steps, active, temp, topk,
                          topp, greedy, keys,
-                         adapters=None, adapter_rows=None):
+                         adapters=None, adapter_rows=None, rows=None):
         """The decode block over the PAGE-TABLE pool: gather every
         slot's pages into the contiguous [N, max_length, H, D] view the
         row-pool scan already consumes (dequantizing int8 pages in the
@@ -734,8 +784,10 @@ class InferenceEngine:
         requantization drift. Inactive slots (parked mid-prefill, free)
         have their table row redirected to the null page so their junk
         token-0 writes can land nowhere real. `pages`/`scales` are
-        donated (argnums 3, 4) so the pool aliases in place."""
-        self._trace_counts['paged_decode_step'] += 1
+        donated (argnums 3, 4) so the pool aliases in place. `rows` is
+        the scan's: how much of the gathered view attention reads."""
+        self._trace_counts['paged_decode_step' if rows is None
+                           else 'paged_decode_step_half'] += 1
         fwd = cached_forward(self.model, params, frozen, buffers)
         sc = scales if self.pool.quant else None
         table = jnp.where(active[:, None], table, 0)
@@ -743,11 +795,17 @@ class InferenceEngine:
                               out_dtype=self.pool.compute_dtype)
         toks, contig, *touched = self._decode_scan(
             fwd, contig, tok, pos, steps, active, temp, topk, topp, greedy,
-            keys, adapters, adapter_rows)
+            keys, adapters, adapter_rows, rows)
         pages, sc = scatter_pages(pages, table, contig, pos,
                                   self.decode_block,
                                   self.pool.page_size, sc)
         return (toks, pages, sc if sc is not None else (), *touched)
+
+    def _paged_decode_half_fn(self, *args):
+        """`_paged_decode_fn` with the scan's attention over the first
+        `max_length // 2` rows of the gathered view: the paged pool's
+        second decode program (`_decode_block_half_fn`)."""
+        return self._paged_decode_fn(*args, rows=self._half_rows)
 
     def _paged_prefill_fn(self, params, frozen, buffers, pages, scales,
                           table, ids, adapters=None, adapter_rows=None):
@@ -1337,8 +1395,8 @@ class InferenceEngine:
     def _needed_rows(self) -> int:
         """Cache rows this round's attention NEEDS, over active slots
         and layers: the rows a slot has written, and on a window layer
-        at most the window. What the program READS is `_read_rows`:
-        every slot's `max_length` rows on every layer."""
+        at most the window. What the program READS is every slot's
+        first `rows` rows on every layer (`_round_rows`)."""
         written = self._pos[self._active].astype(np.int64) + 1
         return int(np.minimum(written[:, None],
                               self._layer_rows[None, :]).sum())
@@ -1354,11 +1412,39 @@ class InferenceEngine:
         if _obs.enabled():
             self._m_experts_touched.inc(n)
 
+    def _round_rows(self) -> int:
+        """How many rows of every slot this round's attention reads:
+        the half program's while the longest ACTIVE position, a block
+        and one row more stay inside it, else `max_length`. A slot that
+        is not decoding (free, or parked at `max_length - 1` while it
+        prefills in chunks) does not count: what it reads is discarded,
+        and its stray write lands in the whole leaf either way."""
+        need = int(self._pos[self._active].max()) + self.decode_block + 1
+        return self._half_rows if need <= self._half_rows \
+            else self.pool.max_length
+
+    def _decode_program(self, rows: int, args):
+        """The decode program that attends over `rows` rows. The first
+        dispatch resolves BOTH through the store (compiled or loaded,
+        not run), so the round that first needs the other one finds it
+        built: a server that has decoded once never compiles a decode
+        program again, whatever its traffic does next."""
+        if not self._decode_resolved:
+            for program in (self._decode_jit, self._decode_half_jit):
+                if program is not None:
+                    program.resolve(*args)
+            self._decode_resolved = True
+        return self._decode_jit if rows == self.pool.max_length \
+            else self._decode_half_jit
+
     def _decode_round(self):
         """The plain compiled decode block (no draft model): every
         active slot advances `decode_block` tokens. Its span carries,
-        as scalars, the slots decoding (`active`), the slots there are
-        and the rows that hold a real token by the pool's own book; its
+        as scalars, the slots decoding (`active`), the slots there are,
+        the rows that hold a real token by the pool's own book, the
+        rows of a slot this round's program attends over (`rows`:
+        `max_length` or half of it, `_round_rows`) and, over slots and
+        layers, the rows it therefore reads and the rows it needed; its
         children are `serving.decode_dispatch` (staging the host arrays
         and the page table, and the jitted call until it returns) and
         `serving.d2h` (the blocking fetch of the round's tokens)."""
@@ -1366,31 +1452,31 @@ class InferenceEngine:
                        active=int(np.count_nonzero(self._active)),
                        slots=self.pool.num_slots,
                        real_rows=self.pool.written_rows) as round_span:
-            round_span.set(needed_rows=self._needed_rows(),
-                           read_rows=self._read_rows)
+            rows = self._round_rows()
+            round_span.set(
+                needed_rows=self._needed_rows(), rows=rows,
+                read_rows=self.pool.num_slots * rows * len(self._layer_rows))
             try:
                 with _obs.span('serving.decode_dispatch'):
+                    state = (self._tok, self._pos, self._steps,
+                             self._active, self._temp, self._topk,
+                             self._topp, self._greedy, self._keys,
+                             *self._adapter_args())
                     if self._paged:
                         pages, scales = self.pool.device_state()
                         table = call_with_retry(
                             _to_device, self.pool.page_table,
                             policy=self._retry, site='serving.h2d')
+                        args = (self._params, self._frozen, self._buffers,
+                                pages, scales, table, *state)
                         toks_dev, new_pages, new_scales, *touched = \
-                            self._decode_jit(
-                                self._params, self._frozen, self._buffers,
-                                pages, scales, table, self._tok,
-                                self._pos, self._steps, self._active,
-                                self._temp, self._topk, self._topp,
-                                self._greedy, self._keys,
-                                *self._adapter_args())
+                            self._decode_program(rows, args)(*args)
                         self.pool.set_device_state(new_pages, new_scales)
                     else:
-                        toks_dev, new_pool, *touched = self._decode_jit(
-                            self._params, self._frozen, self._buffers,
-                            self.pool.cache, self._tok, self._pos,
-                            self._steps, self._active, self._temp,
-                            self._topk, self._topp, self._greedy,
-                            self._keys, *self._adapter_args())
+                        args = (self._params, self._frozen, self._buffers,
+                                self.pool.cache, *state)
+                        toks_dev, new_pool, *touched = \
+                            self._decode_program(rows, args)(*args)
                         self.pool.cache = new_pool
             except Exception:
                 self._recover_pool()
@@ -1409,6 +1495,8 @@ class InferenceEngine:
         self._counts['decode_steps'] += self.decode_block
         if _obs.enabled():
             self._m_decode_steps.inc(self.decode_block)
+            self._m_rows_read.inc(
+                self.pool.num_slots * rows * self.decode_block)
         return toks, None
 
     def _spec_round(self):

@@ -318,6 +318,30 @@ def test_engine_modes_serve_the_same_tokens(tiny, extra):
     assert _serve(model, _requests(), **extra)[0] == base
 
 
+@pytest.mark.parametrize('rows', [16, 24, 32])
+def test_a_shorter_mask_reads_fewer_rows_and_changes_nothing(tiny, rows):
+    """A mask of `rows` columns over a cache of 32 rows: attention
+    contracts over the first `rows` of each leaf, a window layer narrows
+    against THAT length, and the write still lands in the whole leaf.
+    Queries at rows 9 and 13, window 8; 32 is the cache's own length."""
+    _, _, model, _ = tiny
+    fwd = cached_forward(model, *functional_state(model))
+    rs = np.random.RandomState(2)
+    cache = jax.tree_util.tree_map(
+        lambda c: jnp.asarray(rs.randn(*c.shape), c.dtype),
+        model.init_cache(2, 32))
+    pos = jnp.asarray([9, 13], jnp.int32)
+    tok = jnp.asarray([[5], [77]], jnp.int32)
+    mask = (jnp.arange(32)[None] <= pos[:, None])[:, None, None, :]
+    want, wrote = fwd(tok, cache, pos, pos, mask)
+    got, wrote_short = fwd(tok, cache, pos, pos, mask[..., :rows])
+    assert np.abs(np.asarray(got) - np.asarray(want)).max() < TOL
+    for a, b, c in zip(*map(jax.tree_util.tree_leaves,
+                            (wrote, wrote_short, cache))):
+        assert a.shape == c.shape and (np.asarray(a) == np.asarray(b)).all()
+        assert (np.asarray(a)[0, 9] != np.asarray(c)[0, 9]).any()
+
+
 def test_prefix_cache_hit_serves_the_same_tokens(tiny):
     cfg, w, model, _ = tiny
     rs = np.random.RandomState(4)
@@ -393,7 +417,8 @@ def test_decode_round_carries_routing_and_row_counts(tiny):
         hi = min(a['active'] * cfg['num_experts_per_tok'],
                  cfg['num_experts']) * a['expert_layer_substeps']
         assert lo <= a['experts_touched'] <= hi
-        assert a['read_rows'] == 2 * 64 * cfg['num_hidden_layers']
+        assert a['rows'] in (32, 64)
+        assert a['read_rows'] == 2 * a['rows'] * cfg['num_hidden_layers']
         assert 0 < a['needed_rows'] <= a['real_rows'] * 5
     # a lone slot past the window: four layers need 8 rows, one all
     lone = [a for a in rounds if a['active'] == 1 and a['real_rows'] > 8]
@@ -411,7 +436,7 @@ def test_a_model_without_experts_returns_what_it_returned():
     _, eng = _serve(model, _requests())
     a = _rounds(log)[-1]
     assert 'experts_touched' not in a and 'experts' not in a
-    assert a['read_rows'] == 2 * 64 * 2
+    assert a['rows'] == 64 and a['read_rows'] == 2 * 64 * 2
     assert a['needed_rows'] == a['real_rows'] * 2      # no window layer
     out = jax.eval_shape(
         eng._decode_block_fn, eng._params, eng._frozen, eng._buffers,

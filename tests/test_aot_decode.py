@@ -3,9 +3,12 @@ write is one native scatter a leaf (no `while` over the slots under scope
 `kv_write`) and the donated pool is aliased. What PR 27 did NOT reach is
 stated too, so that it cannot get worse unseen: at most one leaf a layer
 is still staged through the fast memory space `S(1)` and copied back out
-(PERF.md section 7 says what turns that off). Compiled for a described
-chip at the cells' widths and two layers; costs no chip time, says
-nothing of speed. Marked slow (about a minute).
+(PERF.md section 7 says what turns that off). The half-length program
+(PR 29: attention over the first `max_length // 2` rows of a slot)
+stages no leaf at all: the slice is fused into both contractions, which
+read the leaf where it lies. Compiled for a described chip at the cells'
+widths and two layers; costs no chip time, says nothing of speed. Marked
+slow (about a minute a case).
 
 The topology is described inside a fixture, never at import: every xdist
 worker imports this file, and only one process may load libtpu — so
@@ -32,9 +35,10 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _compile_decode_block(cell, one_chip):
+def _compile_decode_block(cell, one_chip, program='whole'):
     """-> (optimized HLO text, memory analysis, the pool's leaves) of the
-    decode block of the engine the benchmark builds for `cell`."""
+    decode block (`whole`, or the `half`-length one) of the engine the
+    benchmark builds for `cell`."""
     import jax
     from jax.experimental.compilation_cache import compilation_cache as cc
     from benchmarks.kinds import _serve
@@ -48,7 +52,8 @@ def _compile_decode_block(cell, one_chip):
     try:
         aot.force_kernels_on()      # the engine reads the gate as built
         eng = _serve.Server(_Run).router.replicas[0].engine
-        compiled = eng._decode_jit.lower(*aot.abstract(
+        jit = eng._decode_jit if program == 'whole' else eng._decode_half_jit
+        compiled = jit.lower(*aot.abstract(
             (eng._params, eng._frozen, eng._buffers, eng.pool.cache,
              eng._tok, eng._pos, eng._steps, eng._active, eng._temp,
              eng._topk, eng._topp, eng._greedy, eng._keys),
@@ -99,11 +104,14 @@ def test_staged_pool_rows_reads_the_compilers_spelling():
 
 
 @pytest.mark.slow
+@pytest.mark.parametrize('program', ['whole', 'half'])
 @pytest.mark.parametrize('workload', ['serve-chat', 'serve-moe-docs'])
-def test_decode_block_writes_its_rows_in_place_on_v5e(workload, one_chip):
+def test_decode_block_writes_its_rows_in_place_on_v5e(workload, program,
+                                                      one_chip):
     cell = spec.Spec().cell(workload)
     cell['config']['num_hidden_layers'] = 2
-    text, ma, leaves = _compile_decode_block(cell, one_chip)
+    text, ma, leaves = _compile_decode_block(cell, one_chip, program)
+    assert 'decode' in re.search(r'HloModule (\S+)', text).group(1)
     loops = [ln for ln in text.splitlines()
              if re.search(r' while\(', ln) and 'kv_write' in ln]
     assert not loops, f'the KV write is a loop over the slots again: ' \
@@ -114,7 +122,14 @@ def test_decode_block_writes_its_rows_in_place_on_v5e(workload, one_chip):
     # comes back out (the parent: the same, around the loops); zero is
     # the aim, and passes
     layers = cell['config']['num_hidden_layers']
-    _, out = staged_pool_rows(text, leaves[0])
+    into, out = staged_pool_rows(text, leaves[0])
     assert len(out) <= layers, f'more than a leaf a layer evicted: {out}'
+    if program == 'half':
+        # attention reads half of each leaf where it lies: nothing of a
+        # leaf's size moves, in or out
+        assert not into and not out, (into, out)
+        rows = leaves[0].shape[1] // 2
+        assert re.search(r'f32\[%d,%d,%d,%d\]\S* slice\(' % (
+            leaves[0].shape[0], rows, *leaves[0].shape[2:]), text)
     pool_bytes = sum(v.size * v.dtype.itemsize for v in leaves)
     assert ma.alias_size_in_bytes == pool_bytes

@@ -448,6 +448,7 @@ class TestPagedEngine:
             [SamplingParams(max_new_tokens=4, eos_token_id=NO_EOS)] * 3)
         traces = dict(eng.stats()['traces'])
         assert traces.get('paged_decode_step', 0) <= 1
+        assert traces.get('paged_decode_step_half', 0) <= 1
         compiles_before = obs.get_registry().value(
             'paddle_jit_compiles_total')
         hs = eng.generate_many(

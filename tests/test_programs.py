@@ -159,6 +159,55 @@ class TestRoundTrip:
         assert st['misses'] == misses, 'sibling wrapper recompiled'
         assert st['hits_memory'] >= 1
 
+    def test_resolve_builds_a_program_without_running_it(self, pstore):
+        w = _wrap(pstore, 'resolve')
+        x, y = _args()
+        reg = obs.get_registry()
+        misses = pstore.stats()['misses']
+        record, call = w.resolve(x, y)
+        assert pstore.stats()['misses'] == misses + 1     # compiled here
+        assert record.invocations == 0                    # and not run
+        assert _entry_files(pstore, 'resolve')
+        marks = _compile_marks(reg)
+        assert w.resolve(x, y) == (record, call)
+        out = np.asarray(w(x, y))
+        assert reg.value('paddle_jit_compiles_total') == marks[0]
+        assert pstore.stats()['misses'] == misses + 1
+        assert record.invocations == 1
+        assert (out == np.asarray(jnp.sin(x) @ y + 2.0)).all()
+
+    def test_a_program_is_compiled_with_room_on_the_stack(self, pstore,
+                                                          monkeypatch):
+        """The compile site runs trace, lowering and compile below a
+        frame too large for a 16 KiB chunk of CPython's frame stack — on
+        the caller's thread, so what a trace reads from its thread is
+        the caller's; what they raise comes out unchanged."""
+        room = store_mod._with_stack_room
+        assert room.__code__.co_nlocals * 8 > 8 * 16384
+        seen = []
+
+        def spy(fn, *args):
+            seen.append('in')
+            try:
+                return room(fn, *args)
+            finally:
+                seen.append('out')
+        monkeypatch.setattr(store_mod, '_with_stack_room', spy)
+        local = threading.local()
+        local.scale = 3.0
+
+        def f(x):
+            seen.append(threading.current_thread() is me)
+            return x * getattr(local, 'scale', 0.0)
+        me = threading.current_thread()
+        w = pstore.wrap_jit(f, name='test.stack_room', statics={})
+        assert float(w(jnp.float32(2.0))) == 6.0
+        # through the export artifact: traced there, compiled after
+        assert seen == ['in', True, 'out', 'in', 'out']
+        assert room(lambda a, b: a + b, 3, 4) == 7
+        with pytest.raises(ZeroDivisionError):
+            room(lambda: 1 / 0)
+
     def test_compile_cache_is_placed_from_outside(self, pstore, tmp_path,
                                                   monkeypatch):
         """With JAX_COMPILATION_CACHE_DIR set (conftest sets it) no code

@@ -21,6 +21,7 @@ from paddle_tpu import debug, observability as obs
 from paddle_tpu.nlp import (GPTConfig, GPTForCausalLM, LlamaConfig,
                             LlamaForCausalLM)
 from paddle_tpu.nlp import generation
+from paddle_tpu.nlp.afmoe import AfmoeConfig, AfmoeForCausalLM
 from paddle_tpu.resilience import FatalError, RetryPolicy, TransientError
 from paddle_tpu.serving import (FAILED, FINISHED, FCFSScheduler,
                                 InferenceEngine, RequestHandle,
@@ -331,14 +332,14 @@ def test_donated_decode_failure_recovers_the_pool(gpt, sanitizer_strict):
                           prefix_cache=True)
     prompt = _prompts((6,))[0]
     ref = _ref_generate(gpt, prompt, 4)
-    real_jit = eng._decode_jit
+    real_program = eng._decode_program
 
     def dying(*args):
         for leaf in _leaves(args[3]):
             leaf.delete()                   # what a donated call may do
         raise RuntimeError('simulated device failure mid-decode')
 
-    eng._decode_jit = dying
+    eng._decode_program = lambda rows, args: dying
     h = eng.submit(prompt, max_new_tokens=4, eos_token_id=NO_EOS)
     with pytest.raises(RuntimeError, match='mid-decode'):
         eng.run()
@@ -347,7 +348,7 @@ def test_donated_decode_failure_recovers_the_pool(gpt, sanitizer_strict):
     assert not any(leaf.is_deleted() for leaf in _leaves(eng.pool.rows))
     for handle in eng.evict_all():
         assert handle is h                  # orphan handed back, not lost
-    eng._decode_jit = real_jit
+    eng._decode_program = real_program
     h2 = eng.submit(prompt, max_new_tokens=4, eos_token_id=NO_EOS)
     eng.run()
     assert h2.tokens == ref
@@ -1072,3 +1073,166 @@ class TestGracefulDrain:
             assert h.status == FINISHED
         finally:
             obs.clear_degraded('draining')
+
+
+# ---------------------------------------------------------------------------
+# two decode programs: attention over every row of a slot, or over the
+# first half while no active slot comes near it
+# ---------------------------------------------------------------------------
+
+_FAMILIES = {
+    'gpt': lambda: GPTForCausalLM(GPTConfig.tiny()),
+    'llama_grouped_heads': lambda: LlamaForCausalLM(LlamaConfig.tiny()),
+    'afmoe_window_and_full': lambda: AfmoeForCausalLM(AfmoeConfig.tiny()),
+}
+_MODES = {'row': {}, 'paged': {'kv_page_size': 8},
+          'chunked_prefill': {'prefill_chunk_tokens': 16}}
+
+
+@pytest.fixture(scope='module')
+def families():
+    built = {}
+
+    def get(name):
+        if name not in built:
+            paddle.seed(11)
+            built[name] = _FAMILIES[name]().eval()
+        return built[name]
+    return get
+
+
+def _serve_by_rows(model, whole_only, **kw):
+    """One request set through a 3 x 64 engine: one answer crosses row
+    32, two stay short, one prompt of 40 arrives behind them. -> (tokens,
+    [(rows, slots prefilling in chunks), ...] a decode round)."""
+    eng = InferenceEngine(model, num_slots=3, max_length=64, decode_block=4,
+                          buckets=[16, 32, 48], eos_token_id=NO_EOS, **kw)
+    if whole_only:
+        eng._half_rows = 0          # what an engine of max_length 7 has
+    rounds, pick = [], eng._round_rows
+
+    def noting():
+        rounds.append((pick(), len(eng._prefilling)))
+        return rounds[-1][0]
+    eng._round_rows = noting
+    reqs = zip(_prompts([5, 9, 12, 40], seed=3), (40, 8, 14, 6))
+    hs = [eng.submit(p, SamplingParams(max_new_tokens=n, eos_token_id=NO_EOS))
+          for p, n in reqs]
+    eng.run()
+    assert all(h.status == FINISHED for h in hs)
+    return [list(h.tokens) for h in hs], rounds
+
+
+@pytest.mark.parametrize('mode', list(_MODES))
+@pytest.mark.parametrize('family', list(_FAMILIES))
+def test_half_length_decode_serves_the_whole_programs_tokens(
+        families, family, mode):
+    """The program changes between two rounds of one answer, and the
+    tokens are those of the same set held to the whole program."""
+    model = families(family)
+    toks, rounds = _serve_by_rows(model, False, **_MODES[mode])
+    want, whole = _serve_by_rows(model, True, **_MODES[mode])
+    assert toks == want
+    assert {r for r, _ in whole} == {64}
+    assert {r for r, _ in rounds} == {32, 64}
+    # the first answer starts under the half program and ends past it
+    assert rounds[0][0] == 32 and rounds[-1][0] == 64
+    if mode == 'chunked_prefill':
+        # a slot parked at row 63 while it prefills does not count
+        assert any(r == 32 and n for r, n in rounds)
+
+
+@pytest.mark.parametrize('pos,active,rows', [
+    ((27, 3, 0), (1, 1, 0), 32),        # 27 + 4 + 1 rows: the last fit
+    ((28, 3, 0), (1, 1, 0), 64),
+    ((3, 63, 9), (1, 0, 1), 32),        # parked mid-prefill: not counted
+    ((3, 63, 9), (1, 1, 1), 64),
+    ((0, 0, 0), (0, 0, 1), 32)])
+def test_round_rows_follows_the_longest_active_position(gpt, pos, active,
+                                                        rows):
+    eng = InferenceEngine(gpt, num_slots=3, max_length=64, decode_block=4)
+    eng._pos[:] = pos
+    eng._active[:] = active
+    assert eng._round_rows() == rows
+
+
+def test_no_half_program_where_a_block_cannot_fit_in_it(gpt):
+    eng = InferenceEngine(gpt, num_slots=2, max_length=8, decode_block=4)
+    assert eng._decode_half_jit is None
+    h = eng.submit([1, 2, 3], max_new_tokens=4, eos_token_id=NO_EOS)
+    eng.run()
+    assert h.tokens == _ref_generate(gpt, [1, 2, 3], 4)
+
+
+@pytest.mark.parametrize('mode', ['row', 'paged'])
+def test_first_decode_dispatch_builds_both_programs(gpt, mode):
+    """No compile after the first decode dispatch while the longest
+    position sweeps across `max_length // 2`."""
+    log = obs.get_event_log()
+    eng = InferenceEngine(gpt, num_slots=2, max_length=64, decode_block=2,
+                          **_MODES[mode])
+    h = eng.submit(_prompts([5], seed=9)[0], max_new_tokens=50,
+                   eos_token_id=NO_EOS)
+    eng.step()                  # admission, prefill, one decode round
+    assert len(h.tokens) == 2
+    reg = obs.get_registry()
+    compiles = reg.value('paddle_jit_compiles_total')
+    traces = dict(eng.stats()['traces'])
+    log.clear()
+    eng.run()
+    assert reg.value('paddle_jit_compiles_total') == compiles
+    assert eng.stats()['traces'] == traces
+    rows = [e['attrs']['rows'] for e in log.events()
+            if e['name'] == 'serving.decode_round']
+    assert set(rows) == {32, 64} and rows == sorted(rows)
+    assert h.tokens == _ref_generate(gpt, _prompts([5], seed=9)[0], 50)
+
+
+def test_decode_round_span_and_counter_say_what_was_read(gpt):
+    log = obs.get_event_log()
+    reg = obs.get_registry()
+    eng = InferenceEngine(gpt, num_slots=2, max_length=64, decode_block=2)
+    read = reg.value('paddle_serving_decode_rows_read_total')
+    log.clear()
+    eng.generate_many(_prompts([4, 25]), [SamplingParams(
+        max_new_tokens=12, eos_token_id=NO_EOS)] * 2)
+    rounds = [e['attrs'] for e in log.events()
+              if e['name'] == 'serving.decode_round']
+    layers = gpt.config.num_hidden_layers
+    assert {a['rows'] for a in rounds} == {32, 64}
+    for a in rounds:
+        assert a['read_rows'] == 2 * a['rows'] * layers
+        assert a['needed_rows'] <= a['read_rows']
+    # slots x rows a sub-step, two sub-steps a round
+    assert reg.value('paddle_serving_decode_rows_read_total') - read \
+        == sum(2 * a['rows'] * 2 for a in rounds)
+
+
+def test_whole_decode_program_has_no_slice_and_the_half_one_does(gpt):
+    """`rows=max_length` spelled out lowers to the text of the program
+    the engine has always had; the half program differs from it by a
+    slice of each leaf before attention, and still writes, carries and
+    returns whole leaves."""
+    eng = InferenceEngine(gpt, num_slots=3, max_length=32, decode_block=2)
+    args = _decode_args(eng)
+
+    def _decode_block_fn(params, frozen, buffers, pool, *state):
+        fwd = generation.cached_forward(eng.model, params, frozen, buffers)
+        return eng._decode_scan(fwd, pool, *state, rows=32)
+    spelled = jax.jit(_decode_block_fn, donate_argnums=(3,)).lower(*args)
+    whole = eng._decode_jit.lower(*args)
+    assert whole.as_text() == spelled.as_text()
+    leaf = _leaves(eng.pool.cache)[0]
+    cut = f'-> tensor<{leaf.shape[0]}x16x'
+    assert not [ln for ln in whole.as_text().splitlines()
+                if 'stablehlo.slice' in ln and cut in ln]
+    half = eng._decode_half_jit.lower(*args)
+    assert len([ln for ln in half.as_text().splitlines()
+                if 'stablehlo.slice' in ln and cut in ln]) \
+        == 2 * gpt.config.num_hidden_layers
+    assert half.out_info[1] == whole.out_info[1]        # the pool, whole
+    assert all(a.donated for a in _leaves(half.args_info[0][3]))
+    assert eng._decode_jit._name == 'serving.decode_block'
+    assert eng._decode_half_jit._name == 'serving.decode_block_r16'
+    assert "'rows'" not in eng._decode_jit._statics_token
+    assert "'rows':16" in eng._decode_half_jit._statics_token

@@ -169,7 +169,10 @@ def test_serving_spans_nest_and_carry_scalar_counts(gpt, log):
     rounds = [e['attrs'] for e in spans
               if e['name'] == 'serving.decode_round']
     assert all(set(a) == {'active', 'slots', 'real_rows', 'needed_rows',
-                          'read_rows'} for a in rounds)
+                          'read_rows', 'rows'} for a in rounds)
+    # ten rows of 64 at most: the half-length program, on both layers
+    assert all(a['rows'] == 32 and a['read_rows'] == 2 * 32 * 2
+               for a in rounds)
     assert all(a['slots'] == 2 and 1 <= a['active'] <= 2 for a in rounds)
     # by the pool's own book: a seated request holds its prompt's rows
     assert all(a['real_rows'] >= 5 * a['active'] for a in rounds)
