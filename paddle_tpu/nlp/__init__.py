@@ -14,6 +14,7 @@ from .ernie import (ErnieConfig, ErnieForMaskedLM,
                     ErnieForSequenceClassification, ErnieModel)
 from .generation import GenerationMixin, Seq2SeqGenerationMixin
 from .gpt import GPTConfig, GPTForCausalLM, GPTModel
+from .lfm2 import Lfm2MoeConfig, Lfm2MoeForCausalLM, Lfm2MoeModel
 from .llama import (LlamaConfig, LlamaForCausalLM, LlamaModel)
 from .t5 import T5Config, T5ForConditionalGeneration, T5Model
 from .tokenizer import (BPETokenizer, PretrainedTokenizer,
@@ -25,7 +26,8 @@ __all__ = [
     'AfmoeConfig', 'AfmoeForCausalLM', 'AfmoeModel', 'BertConfig', 'BertForMaskedLM', 'BertForSequenceClassification',
     'BertModel', 'ErnieConfig', 'ErnieForMaskedLM',
     'ErnieForSequenceClassification', 'ErnieModel', 'GenerationMixin',
-    'GPTConfig', 'GPTForCausalLM', 'GPTModel', 'LlamaConfig',
+    'GPTConfig', 'GPTForCausalLM', 'GPTModel', 'Lfm2MoeConfig',
+    'Lfm2MoeForCausalLM', 'Lfm2MoeModel', 'LlamaConfig',
     'LlamaForCausalLM', 'LlamaModel', 'Seq2SeqGenerationMixin',
     'T5Config', 'T5ForConditionalGeneration', 'T5Model', 'BPETokenizer',
     'PretrainedTokenizer', 'WhitespaceTokenizer', 'transformers',

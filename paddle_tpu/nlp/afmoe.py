@@ -178,7 +178,12 @@ def _narrow(mask, win):
 
 
 class AfmoeAttention(Layer):
-    def __init__(self, config: AfmoeConfig, layer_idx: int):
+    """`rotary` / `gated`: what another family's attention layer of
+    this shape has or lacks (`nlp/lfm2.py`: rotary on a full layer, no
+    gate); the defaults are this family's."""
+
+    def __init__(self, config: AfmoeConfig, layer_idx: int,
+                 rotary=None, gated=True):
         super().__init__()
         self.config = config
         h, hd = config.hidden_size, config.head_dim
@@ -188,16 +193,19 @@ class AfmoeAttention(Layer):
         sliding = config.layer_types[layer_idx] == SLIDING
         # what a sliding layer has and a full one has not
         self.window = int(config.sliding_window) if sliding else None
-        self.rotary = sliding
+        self.rotary = sliding if rotary is None else rotary
         self.q_proj = _col_linear(config, h, self.num_heads * hd)
         self.k_proj = _col_linear(config, h, self.num_key_value_heads * hd)
         self.v_proj = _col_linear(config, h, self.num_key_value_heads * hd)
-        self.gate_proj = _col_linear(config, h, self.num_heads * hd)
+        self.gate_proj = _col_linear(config, h, self.num_heads * hd) \
+            if gated else None
         self.o_proj = _row_linear(config, self.num_heads * hd, h)
         self.q_norm = RMSNorm(hd, epsilon=config.rms_norm_eps)
         self.k_norm = RMSNorm(hd, epsilon=config.rms_norm_eps)
 
     def _gated(self, out, hidden):
+        if self.gate_proj is None:
+            return out
         return out * F.sigmoid(self.gate_proj(hidden))
 
     def forward(self, hidden, position_offset=None, attn_mask=None,
@@ -264,14 +272,15 @@ class AfmoeAttention(Layer):
         return out
 
 
-def route(scores, bias, k, route_norm, route_scale):
+def route(scores, bias, k, route_norm, route_scale, eps):
     """The router's choice: `scores` [..., E] float32 (sigmoid), `bias`
-    [E]. The bias SELECTS and is not in the weight. -> (selected
-    [..., k] int32, weights [..., k] float32)."""
+    [E]. The bias SELECTS and is not in the weight; `eps` stands beside
+    the sum the weights are normalised by (1e-20 in AFMoE, 1e-6 in
+    LFM2). -> (selected [..., k] int32, weights [..., k] float32)."""
     _, sel = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
     w = jnp.take_along_axis(scores, sel, axis=-1)
     if route_norm:
-        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + eps)
     return sel.astype(jnp.int32), w * route_scale
 
 
@@ -329,7 +338,10 @@ def grouped_experts(x, sel, w, gate_w, up_w, down_w):
 
 
 class AfmoeSparseMLP(Layer):
-    """Routed experts (stacked leaves [E, h, f]) plus the shared expert."""
+    """Routed experts (stacked leaves [E, h, f]) plus the shared expert
+    (none where the configuration counts no shared expert)."""
+
+    route_norm_eps = 1e-20
 
     def __init__(self, config: AfmoeConfig):
         super().__init__()
@@ -350,12 +362,13 @@ class AfmoeSparseMLP(Layer):
         self.shared_experts = LlamaMLP(types.SimpleNamespace(
             hidden_size=h,
             intermediate_size=f * config.num_shared_experts,
-            tensor_parallel=config.tensor_parallel))
+            tensor_parallel=config.tensor_parallel)) \
+            if config.num_shared_experts else None
 
     def forward(self, x):
         cfg = self.config
-        k, norm, scale = (cfg.num_experts_per_tok, cfg.route_norm,
-                          float(cfg.route_scale))
+        k, norm, scale, eps = (cfg.num_experts_per_tok, cfg.route_norm,
+                               float(cfg.route_scale), self.route_norm_eps)
 
         def router(xv, wr, bias):
             # float32 and exact: which experts a token gets must not
@@ -363,7 +376,7 @@ class AfmoeSparseMLP(Layer):
             scores = jax.nn.sigmoid(jnp.matmul(
                 xv.astype(jnp.float32), wr.astype(jnp.float32),
                 precision=jax.lax.Precision.HIGHEST))
-            return route(scores, bias, k, norm, scale)
+            return route(scores, bias, k, norm, scale, eps)
 
         def experts(xv, sel, w, gw, uw, dw):
             out = grouped_experts(xv.reshape(-1, xv.shape[-1]),
@@ -378,6 +391,8 @@ class AfmoeSparseMLP(Layer):
         with jax.named_scope('moe/experts'):
             routed = apply_op(experts, x, sel, w, self.gate_w, self.up_w,
                               self.down_w, _name='moe_experts')
+        if self.shared_experts is None:
+            return routed
         with jax.named_scope('moe/shared'):
             return self.shared_experts(x) + routed
 

@@ -162,6 +162,7 @@ def padded_decode_mask(keep, cache_len, cache_offset, sq):
 class _RoutingState(threading.local):
     def __init__(self):
         self.picks = None
+        self.folded = None      # `state_scope`
 
 
 _routing = _RoutingState()
@@ -206,6 +207,47 @@ def experts_touched(picks, active):
             & active[:, None, None, None]
         out.append(jnp.sum(jnp.any(hit, axis=(0, 1, 2)), dtype=jnp.int32))
     return jnp.stack(out)
+
+
+def state_layers(cache):
+    """The indices of a cache's entries that are not a (K, V) pair but
+    one leaf of recurrent slot state, `[B, ...]` with no row axis (a
+    short convolution's last inputs). Empty for a model that keeps K
+    and V only."""
+    return tuple(i for i, entry in enumerate(cache)
+                 if not isinstance(entry, (tuple, list)))
+
+
+class state_scope:
+    """`with state_scope(n): fwd(ids, ...)` — a layer with recurrent
+    state folds only the first `n` (a traced scalar) of the call's
+    tokens into the state it returns; its outputs are the whole call's.
+    The serving engine's prefill of a right-padded prompt of `s` tokens
+    runs under `state_scope(s - 1)`: the padding never enters the state,
+    and neither does the last prompt token, which the first decode
+    sub-step forwards again (for K and V an identical overwrite; a
+    recurrent state would take it twice). Trace-time thread-local state
+    in the idiom of `routing_scope`; outside a scope a call folds all
+    of its tokens."""
+
+    __slots__ = ('_n', '_prev')
+
+    def __init__(self, n):
+        self._n = n
+
+    def __enter__(self):
+        self._prev = _routing.folded
+        _routing.folded = self._n
+        return self
+
+    def __exit__(self, *exc):
+        _routing.folded = self._prev
+        return False
+
+
+def folded_tokens(s):
+    """How many of a call's `s` tokens enter the recurrent state."""
+    return s if _routing.folded is None else _routing.folded
 
 
 def _process_logits(logits, temperature, top_k, top_p):

@@ -6,6 +6,8 @@ from .ernie import (ErnieConfig, ErnieForMaskedLM,  # noqa: F401
                     ErnieForSequenceClassification, ErnieModel)
 from .generation import GenerationMixin  # noqa: F401
 from .gpt import GPTConfig, GPTForCausalLM, GPTModel  # noqa: F401
+from .lfm2 import (Lfm2MoeConfig, Lfm2MoeForCausalLM,  # noqa: F401
+                   Lfm2MoeModel)
 from .llama import LlamaConfig, LlamaForCausalLM, LlamaModel  # noqa: F401
 from .t5 import (T5Config, T5ForConditionalGeneration,  # noqa: F401
                  T5Model)

@@ -2,7 +2,8 @@
 
 The layers of what is compiled are traced under `jax.named_scope`s of
 one fixed vocabulary (`SCOPES`: nlp/gpt.py, nlp/llama.py, nlp/afmoe.py's
-expert layer, the loss, the optimizer's functional update, the engine's
+expert layer, nlp/lfm2.py's short convolution and the update of its
+state, the loss, the optimizer's functional update, the engine's
 sampling and KV writes), so
 every HLO instruction's `op_name` metadata says where it came from:
 `jit(step_fn)/jvp(mlp)/dot_general` is the forward pass of an MLP,
@@ -25,7 +26,8 @@ from .store import get_store
 # the vocabulary; tests/test_spans_scopes.py pins it to what the programs
 # carry
 SCOPES = ('embed', 'attention', 'mlp', 'norm', 'lm_head', 'loss', 'sample',
-          'kv_write', 'optimizer', 'moe/router', 'moe/experts', 'moe/shared')
+          'kv_write', 'optimizer', 'moe/router', 'moe/experts', 'moe/shared',
+          'conv', 'state_write')
 
 _INSTR = re.compile(r'^\s*(?:ROOT\s+)?%?([\w.\-]+) = ')
 _OP_NAME = re.compile(r'op_name="([^"]*)"')

@@ -20,7 +20,9 @@ Compiled-program inventory (asserted by the zero-recompile tests):
 - one prefill program per length bucket (right-padded prompts; pad KV
   lands above the live position where the slot-causal mask hides it
   until the slot's own decode overwrites it — the stale-slot argument
-  speculative decoding already relies on),
+  speculative decoding already relies on; a model whose cache also holds
+  recurrent state, which no mask can hide, gets the prompt's real
+  length into the same program: `_state_prefill_fn`),
 and, when the latency stack is enabled (ISSUE 9):
 - one chunk-prefill program per chunk bucket (chunked prefill AND
   prefix-cache suffix prefill — `start`/`slot`/`src` are traced),
@@ -64,7 +66,7 @@ from .. import observability as _obs
 from ..observability import reqledger as _reqledger
 from ..jit import functional_state
 from ..nlp.generation import (_NEG_INF, cached_forward, experts_touched,
-                              routing_scope)
+                              routing_scope, state_layers, state_scope)
 from ..resilience import RetryPolicy, call_with_retry
 from ..tensor import Tensor
 from .adapters.apply import adapter_scope as _adapter_scope
@@ -128,6 +130,45 @@ def sample_rows(logits, temp, topk, topp, greedy, keys, steps):
     sampled = jax.lax.cond(jnp.all(greedy), lambda _: greedy_tok,
                            do_sample, None)
     return jnp.where(greedy, greedy_tok, sampled)
+
+
+# Why each engine mode cannot serve a model that keeps recurrent slot
+# state (a cache entry that is not K and V: `generation.state_layers`).
+# Rows of K and V can be shared up to a position and hidden above one by
+# a mask; a state stands at ONE position and can be neither.
+_STATE_REFUSALS = {
+    'prefix_cache':
+        'a retained row\'s state stands at the END of its donor\'s '
+        'prompt, not at the shared prefix, so a hit would seat the wrong '
+        'state: reuse needs a snapshot of the state at the prefix',
+    'prefill_chunk_tokens':
+        'a chunk would have to carry the state from the chunk before and '
+        'stop before the last prompt token, and a tail chunk shifted '
+        'down to fit the slot forwards tokens twice — harmless for K '
+        'and V, wrong for a state',
+    'draft_model':
+        'speculation rejects proposed tokens by moving the position back, '
+        'and a state cannot be moved back: it needs a snapshot per '
+        'proposed token',
+    'kv_page_size / kv_pages':
+        'the paged pool holds [pages, page, H, D] leaves and a page '
+        'table; a state leaf has no rows to page',
+    'kv_quant':
+        'int8 KV lives in the paged pool (per-page scales), which cannot '
+        'hold a state leaf',
+}
+
+
+def _refuse_state_modes(model, asked):
+    """ValueError naming the first engine mode in `asked` ({mode: was
+    it asked for}) that a model with recurrent slot state cannot run
+    under. Nothing runs and is silently wrong."""
+    for mode, reason in _STATE_REFUSALS.items():
+        if asked.get(mode):
+            raise ValueError(
+                f'{type(model).__name__} keeps recurrent slot state (cache '
+                f'entries that are not K and V), which {mode} cannot '
+                f'serve: {reason}')
 
 
 class InferenceEngine:
@@ -220,6 +261,16 @@ class InferenceEngine:
                 f'max_position_embeddings {max_pos}')
         if decode_block < 1:
             raise ValueError('decode_block must be >= 1')
+        for m in (model, draft_model):
+            if m is not None and state_layers(
+                    jax.eval_shape(lambda: m.init_cache(1, 2))):
+                _refuse_state_modes(m, {
+                    'prefix_cache': prefix_cache,
+                    'prefill_chunk_tokens': prefill_chunk_tokens,
+                    'draft_model': draft_model is not None,
+                    'kv_page_size / kv_pages': kv_page_size is not None
+                    or kv_pages is not None,
+                    'kv_quant': kv_quant is not None})
         model.eval()
         self.model = model
         self._params, self._frozen, self._buffers = functional_state(model)
@@ -333,13 +384,16 @@ class InferenceEngine:
 
         # per layer, the most rows a query can see (a window layer's
         # window, else the slot): what `needed_rows` is counted from
+        # — of the layers that ATTEND: a layer whose cache entry is a
+        # state leaf reads no rows
         n_layers = len(self.pool.row_spec)
         windows = getattr(model, 'attention_windows',
                           lambda: (None,) * n_layers)()
         self._layer_rows = np.array(
             [self.pool.max_length if w is None
-             else min(int(w), self.pool.max_length) for w in windows],
-            np.int64)
+             else min(int(w), self.pool.max_length)
+             for i, w in enumerate(windows)
+             if i not in self.pool.state_layers], np.int64)
         # the decode block exists at two lengths of attention: every
         # row of a slot, and the first half — which `_decode_round`
         # picks while the batch's positions allow it. 0 where a block
@@ -430,7 +484,8 @@ class InferenceEngine:
                     kind='serving', statics=half_statics,
                     donate_argnums=(3,))
             self._prefill_jit = store.wrap_jit(   # 1 trace per bucket
-                self._prefill_fn,
+                self._state_prefill_fn if self.pool.state_layers
+                else self._prefill_fn,
                 name_fn=lambda args: f'serving.prefill_'
                                      f'{args[3].shape[1]}',
                 kind='serving', statics=engine_statics)
@@ -553,6 +608,11 @@ class InferenceEngine:
             'paddle_serving_moe_experts_touched_total',
             'distinct experts active slots routed to, summed over decode '
             'sub-steps and expert layers')
+        self._m_state_bytes = reg.counter(
+            'paddle_serving_slot_state_bytes_total',
+            'bytes of slot state that is not K and V (a conv layer\'s '
+            'last inputs) read and written by decode sub-steps: active '
+            'slots x state layers x leaf bytes x 2')
         if _obs.enabled():
             self._m_slots.set(self.pool.num_slots)
 
@@ -663,6 +723,22 @@ class InferenceEngine:
         with _adapter_scope(adapters, adapter_rows):
             _, slab = fwd(ids, slab, jnp.int32(0), jnp.int32(0), None)
         return slab
+
+    def _state_prefill_fn(self, params, frozen, buffers, ids, length,
+                          adapters=None, adapter_rows=None):
+        """`_prefill_fn` for a model that keeps recurrent slot state: it
+        also takes the prompt's real `length` (traced: still one compile
+        a bucket) and seats the state as it stands BEFORE the last
+        prompt token. Neither of `_prefill_fn`'s two liberties is
+        harmless to a state: the padding up to the bucket would be
+        folded in, and the decode block's re-forward of token `length -
+        1` would fold that token in twice. Folding `length - 1` tokens
+        leaves the re-forward to complete the state; a one-token prompt
+        seats zeros. K and V rows are written for the whole bucket as
+        ever, and masked by position as ever."""
+        with state_scope(length - 1):
+            return self._prefill_fn(params, frozen, buffers, ids, adapters,
+                                    adapter_rows)
 
     def _chunk_prefill_fn(self, params, frozen, buffers, row, ids, start,
                           adapters=None, adapter_rows=None):
@@ -1412,6 +1488,20 @@ class InferenceEngine:
         if _obs.enabled():
             self._m_experts_touched.inc(n)
 
+    def _note_state(self, round_span):
+        """Book what a round does to slot state that is not K and V, on
+        its span and on `paddle_serving_slot_state_bytes_total`: every
+        sub-step reads and writes the state leaf of every state layer
+        for each active slot (an inactive slot's is garbage nobody
+        needs)."""
+        n = (int(np.count_nonzero(self._active)) * self.pool.state_bytes
+             * 2 * self.decode_block)
+        round_span.set(attn_layers=len(self._layer_rows),
+                       state_layers=len(self.pool.state_layers),
+                       state_bytes=n)
+        if _obs.enabled():
+            self._m_state_bytes.inc(n)
+
     def _round_rows(self) -> int:
         """How many rows of every slot this round's attention reads:
         the half program's while the longest ACTIVE position, a block
@@ -1456,6 +1546,8 @@ class InferenceEngine:
             round_span.set(
                 needed_rows=self._needed_rows(), rows=rows,
                 read_rows=self.pool.num_slots * rows * len(self._layer_rows))
+            if self.pool.state_layers:
+                self._note_state(round_span)
             try:
                 with _obs.span('serving.decode_dispatch'):
                     state = (self._tok, self._pos, self._steps,
@@ -1909,6 +2001,7 @@ class InferenceEngine:
                 self._prefill_row(
                     self.pool, slot, self._prefill_jit,
                     self._params, self._frozen, self._buffers, ids_dev,
+                    *((np.int32(s),) if self.pool.state_layers else ()),
                     *self._adapter_args(slot))
         self.pool.note_written(slot, s)
         self._note_prefill(h, t_pf0)

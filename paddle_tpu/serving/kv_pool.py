@@ -77,6 +77,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..nlp.generation import state_layers as _state_layers
+
 _tree = jax.tree_util
 
 
@@ -146,9 +148,16 @@ def _bucket_for(buckets: Tuple[int, ...], length: int,
 class SlotPool:
     """Owns the stacked KV pool + the slot free list.
 
-    `rows` is whatever `model.init_cache(num_slots, max_length)` returns
-    (per-layer (K, V) pairs for every causal-LM family here), so the
-    pool works for any model honoring the init_cache contract.
+    `rows` is whatever `model.init_cache(num_slots, max_length)` returns,
+    one entry a layer: a (K, V) pair of `[num_slots, max_length, H, D]`
+    where the layer attends, or ONE leaf of recurrent slot state,
+    `[num_slots, ...]` with no row axis, where it keeps its past some
+    other way (`nlp/lfm2.py`'s conv layers: `state_layers`). Seating,
+    copying and slicing a slot map over axis 0 of any leaf; what the
+    pool says of ROWS (`written_rows`, buckets, `max_length`) is about
+    the (K, V) entries alone. A state is not a row that a mask can hide
+    part of: it stands at ONE position, so whoever seats it (the engine's
+    prefill) gives it whole, and nothing may share or rewind it.
     """
 
     def __init__(self, model, num_slots: int, max_length: int,
@@ -169,6 +178,11 @@ class SlotPool:
                                            c.dtype), self.rows)
         self.pool_bytes = _leaf_bytes(self.rows)
         self.row_bytes = self.pool_bytes // self.num_slots
+        # the entries that are state and not (K, V), and the bytes of
+        # ONE slot's state over all of them
+        self.state_layers = _state_layers(self.rows)
+        self.state_bytes = _leaf_bytes(
+            [self.rows[i] for i in self.state_layers]) // self.num_slots
         # the single-slot programs, enrolled in the program store like
         # the engine's own (a warm replica loads them); seat and copy
         # take the pool donated
@@ -337,6 +351,8 @@ class SlotPool:
                 'prefill_chunk_tokens': self.prefill_chunk_tokens,
                 'row_bytes': self.row_bytes,
                 'pool_bytes': self.pool_bytes,
+                'state_layers': len(self.state_layers),
+                'state_bytes': self.state_bytes,
                 'row_writes': self._row_writes,
                 'row_copies': self._row_copies,
                 'copied_bytes': self._copied_bytes,
@@ -471,6 +487,11 @@ class PagedSlotPool:
     exhaustion mid-decode — exhaustion surfaces at admission as
     `PagePoolExhausted` and the engine requeues.
     """
+
+    # K and V only: a state leaf has no rows to page (the engine
+    # refuses such a model before it builds this pool)
+    state_layers = ()
+    state_bytes = 0
 
     def __init__(self, model, num_slots: int, max_length: int,
                  dtype=None, buckets: Optional[Sequence[int]] = None,
@@ -770,4 +791,6 @@ class PagedSlotPool:
                 'page_bytes': self.page_bytes,
                 'row_bytes': self.row_bytes,
                 'pool_bytes': self.pool_bytes,
+                'state_layers': len(self.state_layers),
+                'state_bytes': self.state_bytes,
                 **SlotPool._capacity_stats(self)}

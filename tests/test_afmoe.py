@@ -133,11 +133,11 @@ def test_through_the_engine_two_slots_at_different_positions(built):
 # ---------------------------------------------------------------------------
 # each departure from the published mathematics fails the tolerance
 # ---------------------------------------------------------------------------
-def _route_bias_in_weight(scores, bias, k, route_norm, route_scale):
+def _route_bias_in_weight(scores, bias, k, route_norm, route_scale, eps):
     biased = scores + bias.astype(jnp.float32)
     w, sel = jax.lax.top_k(biased, k)
     if route_norm:
-        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + eps)
     return sel.astype(jnp.int32), w * route_scale
 
 

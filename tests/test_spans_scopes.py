@@ -258,10 +258,13 @@ def test_scope_vocabulary_is_pinned(toy_step, gpt):
     decode program's HLO, and no scope of the models' is outside it."""
     assert scopes_mod.SCOPES == (
         'embed', 'attention', 'mlp', 'norm', 'lm_head', 'loss', 'sample',
-        'kv_write', 'optimizer', 'moe/router', 'moe/experts', 'moe/shared')
-    # the expert layer's three are on an expert model's decode program:
-    # tests/test_afmoe.py::test_expert_scopes_are_on_the_decode_program
-    moe = {s for s in scopes_mod.SCOPES if s.startswith('moe/')}
+        'kv_write', 'optimizer', 'moe/router', 'moe/experts', 'moe/shared',
+        'conv', 'state_write')
+    # the expert layer's three are on an expert model's decode program
+    # (tests/test_afmoe.py::test_expert_scopes_are_on_the_decode_program),
+    # the short convolution's two on a hybrid's (below)
+    moe = {s for s in scopes_mod.SCOPES if s.startswith('moe/')} \
+        | {'conv', 'state_write'}
     _serve(gpt, n_requests=1)
     table = programs.scope_table()
     assert 'train_step' in table and 'serving.decode_block' in table
@@ -286,6 +289,30 @@ def test_scope_vocabulary_is_pinned(toy_step, gpt):
     # ... and each says how it came by its name
     assert {how for *_, how in table['train_step'].values()} \
         <= {'own', 'callee', 'user', 'operand', 'caller', ''}
+
+
+def test_conv_scopes_are_placed_on_a_hybrids_decode_program():
+    """`conv` and `state_write`: on the decode program of a model with
+    conv layers, every instruction under them placed by a name of its
+    own or of what it holds; README documents the counter and the three
+    counts that came with them."""
+    from paddle_tpu.nlp.lfm2 import Lfm2MoeConfig, Lfm2MoeForCausalLM
+    assert {'conv', 'state_write'} <= set(scopes_mod.SCOPES)
+    paddle.seed(11)
+    _serve(Lfm2MoeForCausalLM(Lfm2MoeConfig.tiny()).eval(), n_requests=2)
+    rows = programs.scope_table()['serving.decode_block'].values()
+    how = {}
+    for op, _, *more in rows:
+        path = programs.scope_path(op)
+        if 'conv' in path:
+            how.setdefault(path[-1], set()).add((tuple(more) + ((), 'own'))[1])
+    assert {'conv', 'state_write'} <= set(how)
+    assert how['state_write'] <= {'own', 'callee'}
+    with open(os.path.join(os.path.dirname(PKG), 'README.md')) as f:
+        readme = f.read()
+    for word in ('slot_state_bytes_total', '`attn_layers`', '`state_layers`',
+                 '`state_bytes`'):
+        assert word in readme, word
 
 
 def test_scope_path_lists_the_vocabulary_scopes_outermost_first():
