@@ -29,9 +29,12 @@ parameters are stored in (`ACTIVATION_PRECISION`, and why, at
 
 `expert_bias` is a buffer upstream; here it is a frozen parameter (it is
 state a checkpoint fills, and `named_parameters()` is how weights reach
-a model in this repo). The expert layer SERVES: its loop over blocks is
-a `lax.while_loop`, which has no reverse-mode derivative — training an
-expert layer is ROADMAP's.
+a model in this repo). The expert layer SERVES, on one of two schedules
+of the same sum, picked by backend and shape (`ops.pallas.expert_kernel`):
+a call one block wide on a TPU — a decode sub-step — is ONE pallas kernel
+that streams the touched experts' weights; every other call is
+`grouped_experts`, a `lax.while_loop` over blocks. Neither has a
+reverse-mode derivative — training an expert layer is ROADMAP's.
 """
 from __future__ import annotations
 
@@ -46,6 +49,7 @@ from ..nn import functional as F
 from ..nn import initializer as I
 from ..nn.common_layers import Embedding, Linear
 from ..nn.norm import RMSNorm
+from ..ops.pallas import expert_kernel
 from ..tensor import Tensor, apply_op, to_jax
 from .generation import (GenerationMixin, as_offset as _as_offset,
                          attended_rows as _attended_rows,
@@ -291,14 +295,22 @@ def grouped_experts(x, sel, w, gate_w, up_w, down_w):
 
     The T x k picks are sorted by expert and walked in blocks of `bm`
     rows that belong to ONE expert, by a loop that ends after the last
-    real block: an expert nobody picked is never read. One code path,
-    the shapes decide: a prefill of thousands of tokens runs blocks of
-    `BLOCK_ROWS` rows through the MXU; a decode sub-step of a few slots
-    has `bm` = the batch, one block per DISTINCT expert, and moves that
-    expert's weights once — which bytes, the router decided. Nothing of
-    size T x E x anything is built. A block's rows past its expert's
-    last pick belong to the next experts and are overwritten by their
-    own blocks, which come later."""
+    real block: an expert nobody picked is never read. The shapes
+    decide the blocks: a prefill of thousands of tokens runs blocks of
+    `BLOCK_ROWS` rows through the MXU; a batch of fewer rows has `bm` =
+    the batch, one block per DISTINCT expert, and moves that expert's
+    weights once — which bytes, the router decided. Nothing of size
+    T x E x anything is built. A block's rows past its expert's last
+    pick belong to the next experts and are overwritten by their own
+    blocks, which come later.
+
+    This is the path of every backend but a TPU, of a prefill
+    everywhere, and the parity ground truth of the kernel that takes a
+    one-block call on a TPU (`ops.pallas.expert_kernel`): there an
+    iteration starts its three products' weight streams cold, nothing
+    fetches the next expert meanwhile, and `precision='high'` pushes
+    every weight tile through the MXU three times — 29 and 40 us an
+    expert where the bytes are 15 and 23 (PERF.md section 6, PR 31)."""
     t, h = x.shape
     k, e = sel.shape[1], gate_w.shape[0]
     n = t * k
@@ -378,16 +390,22 @@ class AfmoeSparseMLP(Layer):
                 precision=jax.lax.Precision.HIGHEST))
             return route(scores, bias, k, norm, scale, eps)
 
+        # by backend and shape, nothing else: one kernel for a call one
+        # block wide on a TPU, the loop over blocks everywhere else
+        kernel = expert_kernel(math.prod(x.shape[:-1]), BLOCK_ROWS,
+                               to_jax(self.gate_w).dtype)
+        routed_experts = kernel or grouped_experts
+
         def experts(xv, sel, w, gw, uw, dw):
-            out = grouped_experts(xv.reshape(-1, xv.shape[-1]),
-                                  sel.reshape(-1, k), w.reshape(-1, k),
-                                  gw, uw, dw)
+            out = routed_experts(xv.reshape(-1, xv.shape[-1]),
+                                 sel.reshape(-1, k), w.reshape(-1, k),
+                                 gw, uw, dw)
             return out.reshape(xv.shape)
 
         with jax.named_scope('moe/router'):
             sel, w = apply_op(router, x, self.router.weight,
                               self.expert_bias, _name='moe_router')
-        note_routing(sel, cfg.num_experts)
+        note_routing(sel, cfg.num_experts, kernel is not None)
         with jax.named_scope('moe/experts'):
             routed = apply_op(experts, x, sel, w, self.gate_w, self.up_w,
                               self.down_w, _name='moe_experts')
@@ -520,8 +538,13 @@ class AfmoeForCausalLM(AfmoePretrainedModel, GenerationMixin):
         # hangs on the 8th and 9th score, 0.009 apart on average, and in
         # single-pass bf16 one token-layer in thirty picks another
         # expert than the float32 reference does — a tenth of the
-        # hidden state, and more flips in every layer after it. Decode
-        # moves weights, not operands, so the passes cost it nothing.
+        # hidden state, and more flips in every layer after it. The
+        # passes are NOT free where a product is a few rows against a
+        # large weight: XLA pushes every weight tile through the MXU once
+        # a pass, and the expert loop was bound by that, not by its
+        # bytes (PERF.md section 6, PR 31). The expert kernel stacks the
+        # activations' bf16 parts by rows and pushes each tile once;
+        # attention's and the dense products' passes remain.
         with jax.default_matmul_precision(ACTIVATION_PRECISION):
             out = self.model(input_ids, position_offset=position_offset,
                              attention_mask=attention_mask, cache=cache,

@@ -173,9 +173,10 @@ class routing_scope:
     traced inside the block routed to, in the idiom of the serving
     engine's `adapter_scope`: trace-time thread-local state, inert
     outside a scope. `picks` gets one `(selected [B, S, k] int32 raw
-    array, number of experts)` per expert layer, in layer order; a model
-    without an expert layer leaves it empty, and the program traced
-    around it is the one it was."""
+    array, number of experts, whether the layer's routed experts are
+    the kernel)` per expert layer, in layer order; a model without an
+    expert layer leaves it empty, and the program traced around it is
+    the one it was."""
 
     __slots__ = ('_prev', '_picks')
 
@@ -189,24 +190,30 @@ class routing_scope:
         return False
 
 
-def note_routing(selected, num_experts):
-    """Called by an expert layer with the experts it selected."""
+def note_routing(selected, num_experts, kernel=False):
+    """Called by an expert layer with the experts it selected, and
+    whether the call's routed experts run as the pallas kernel
+    (`ops.pallas.expert_kernel`) and not as the loop over blocks."""
     if _routing.picks is not None:
-        _routing.picks.append((to_jax(selected), int(num_experts)))
+        _routing.picks.append((to_jax(selected), int(num_experts),
+                               bool(kernel)))
 
 
 def experts_touched(picks, active):
-    """[number of expert layers] int32: per layer, how many distinct
-    experts the rows of `active` ([B] bool) routed to; None without an
-    expert layer."""
+    """[2, number of expert layers] int32: per layer, how many distinct
+    experts the rows of `active` ([B] bool) routed to, and under it 1
+    where the layer ran the kernel (a constant of the program: it
+    survives a program that is loaded and never traced here); None
+    without an expert layer."""
     if not picks:
         return None
     out = []
-    for sel, e in picks:
+    for sel, e, _ in picks:
         hit = (sel[..., None] == jnp.arange(e, dtype=sel.dtype)) \
             & active[:, None, None, None]
         out.append(jnp.sum(jnp.any(hit, axis=(0, 1, 2)), dtype=jnp.int32))
-    return jnp.stack(out)
+    return jnp.stack([jnp.stack(out),
+                      jnp.asarray([k for *_, k in picks], jnp.int32)])
 
 
 def state_layers(cache):

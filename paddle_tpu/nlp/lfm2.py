@@ -32,8 +32,8 @@ a row past the live position is masked, and a state cannot be masked.
 
 Activations are float32 and products three bf16 passes
 (`afmoe.ACTIVATION_PRECISION`, and why, at `AfmoeForCausalLM.forward`:
-this router is that one). Served, not trained: the expert loop has no
-reverse mode (ROADMAP).
+this router is that one). Served, not trained: neither the expert loop
+nor the decode kernel has a reverse mode (ROADMAP).
 """
 from __future__ import annotations
 

@@ -4,8 +4,10 @@ Upstream analogue: the reference's hand-fused CUDA kernels
 (paddle/phi/kernels/fusion/gpu/*, flash-attn integration). Here the
 default path is plain jax — XLA already fuses normalization chains into
 adjacent matmuls — and the pallas kernels (ops/pallas_kernels.py) take
-over on TPU backends for the attention inner loop, where manual
-VMEM blocking beats the XLA-generated schedule.
+over on TPU backends for two inner loops where a hand-written schedule
+beats the XLA-generated one: attention (manual VMEM blocking), and the
+routed experts of a decode batch (`expert_kernel`: one weight stream
+across the experts, where XLA's `while` fetches each expert cold).
 
 Which path runs is decided by explicit conditions on the backend and
 the shapes, never by a caught exception: on a TPU a kernel that fails
@@ -155,3 +157,24 @@ def flash_attention(q, k, v, mask=None, causal=False, dropout_p=0.0,
             (q, k, v), specs)
     return _attention_xla(q, k, v, mask=mask, causal=causal,
                          dropout_p=dropout_p, dropout_key=dropout_key)
+
+
+def expert_kernel(tokens, block_rows, weight_dtype, interpret=False):
+    """Dispatch for an expert layer's routed experts: the pallas kernel
+    `pallas_kernels.moe_decode_experts` (same arguments as the caller's
+    loop over blocks, `nlp/afmoe.py::grouped_experts`) where it applies,
+    None where the loop runs. The kernel takes a call that is ONE block
+    wide — `tokens <= block_rows`: a decode sub-step, speculation's k+1
+    rows, where every touched expert multiplies every row either way —
+    on a TPU (or anywhere with interpret=True), over bf16 leaves (its
+    three-part product is exact against them alone). A prefill's blocks
+    of `block_rows` rows are compute, not bytes, and keep the loop; so
+    does every other backend, where the loop is the tier-1 path and the
+    parity ground truth. The conditions are the whole selection: a
+    kernel error on a TPU propagates."""
+    if ((interpret or _pallas_enabled()) and tokens <= block_rows
+            and weight_dtype == jnp.bfloat16):
+        from . import pallas_kernels
+        return functools.partial(pallas_kernels.moe_decode_experts,
+                                 interpret=interpret)
+    return None

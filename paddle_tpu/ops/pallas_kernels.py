@@ -15,6 +15,11 @@ Contents:
   interpret mode on CPU against the XLA reference).
 - `rms_norm(x, weight, eps)` — fused RMSNorm pallas kernel with an
   analytic custom VJP.
+- `paged_attention`, `adapter_matmul` — scalar-prefetch kernels of the
+  serving engine (a page table; a packed adapter bank).
+- `moe_decode_experts(x, sel, w, gate_w, up_w, down_w)` — an expert
+  layer's routed experts for a decode batch: one weight stream over the
+  distinct experts the batch picked (dispatch: `ops.pallas.expert_kernel`).
 
 All kernels keep stats/accumulators in fp32 VMEM scratch and feed the
 MXU with `preferred_element_type=float32` per the TPU tiling rules.
@@ -910,3 +915,170 @@ def adapter_matmul(x, a_bank, b_bank, rows, scale, *, interpret=False):
         return _adapter_matmul_pallas(x, a_bank, b_bank, rows, scale,
                                       interpret)
     return adapter_matmul_reference(x, a_bank, b_bank, rows, scale)
+
+
+# ---------------------------------------------------------------------------
+# routed experts of one decode batch (ISSUE 31; upstream analogues: the
+# grouped-GEMM decode kernels of vLLM's fused_moe and MegaBlocks). The
+# batch is one block wide, so every touched expert multiplies every row;
+# the grid walks the DISTINCT experts the batch picked and the weight
+# BlockSpecs read the scalar-prefetched id, so Pallas's double buffering
+# has expert N+1's tiles in flight while expert N multiplies.
+# ---------------------------------------------------------------------------
+
+# rows are padded to a whole packed bf16 tile, so the three parts of the
+# activations stack at tile boundaries
+_EXPERT_ROW_TILE = 16
+
+
+def _expert_f_tile(f):
+    """How much of an expert's `f` a grid step takes: 512 where it
+    divides (trinity-mini's 1024, lfm2's 1536). On the chip 512, 768 and
+    a whole expert stream alike, 733-739 GB/s, and 256 a tenth slower
+    (CHANGES.md, PR 31); the smallest of the equals holds the least
+    VMEM and exposes the shortest first fetch."""
+    return next((n for n in (512, 384, 256, 128) if f % n == 0), f)
+
+
+def _split3(a):
+    """[T, n] float32 -> [3T, n] bf16: the three bf16 parts whose sum is
+    `a` to float32's last bit (hi, mid, lo), stacked by rows so that ONE
+    product against a bf16 weight tile pushes the tile through the MXU
+    once for all three passes."""
+    hi = a.astype(jnp.bfloat16)
+    r = a - hi.astype(jnp.float32)
+    mid = r.astype(jnp.bfloat16)
+    lo = (r - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    return jnp.concatenate([hi, mid, lo], axis=0)
+
+
+def _dot3(parts, w):
+    """`parts` [3T, n] (`_split3`) times a bf16 tile [n, m] -> [T, m]
+    float32: the three passes summed, the small ones first. The
+    precision is spelled out: the models trace this under
+    `default_matmul_precision('high')`, which is what the parts ARE,
+    and which Mosaic refuses on a dot."""
+    t = parts.shape[0] // 3
+    y = jnp.dot(parts, w, preferred_element_type=jnp.float32,
+                precision=jax.lax.Precision.DEFAULT)
+    return (y[2 * t:] + y[t:2 * t]) + y[:t]
+
+
+def _moe_decode_kernel(ids_ref, cnt_ref, x_ref, wd_ref, g_ref, u_ref,
+                       d_ref, o_ref, xs_ref):
+    """Grid (distinct experts, tiles of f), both `arbitrary`: step
+    (i, j) adds `wd[:, ids[i]] * (silu(x G_j) * (x U_j)) D_j` to the
+    resident float32 output. Steps past the count do nothing (and their
+    block indices repeat the last real step's, so nothing is fetched).
+    An expert's column of the dense routing weight `wd` [T, E] is picked
+    with a lane mask: a (T, 1) block over it is a slice Mosaic's tiling
+    refuses (`_adapter_matmul_kernel`)."""
+    i, j = pl.program_id(0), pl.program_id(1)
+
+    @pl.when((i == 0) & (j == 0))
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+        xs_ref[...] = _split3(x_ref[...])
+
+    @pl.when(i < cnt_ref[0])
+    def _():
+        xs = xs_ref[...]
+        g, u = _dot3(xs, g_ref[0]), _dot3(xs, u_ref[0])
+        y = _dot3(_split3(jax.nn.silu(g) * u), d_ref[0])
+        wd = wd_ref[...]
+        lane = jax.lax.broadcasted_iota(jnp.int32, wd.shape, 1)
+        col = jnp.sum(jnp.where(lane == ids_ref[i], wd, 0.0), axis=1,
+                      keepdims=True)
+        o_ref[...] += y * col
+
+
+def moe_decode_experts(x, sel, w, gate_w, up_w, down_w, *, f_tile=None,
+                       interpret=False):
+    """`sum_k w[t, k] * SwiGLU_{sel[t, k]}(x[t])` for a batch one block
+    wide, as ONE kernel that streams the touched experts' weights: x
+    [T, h] float32, sel / w [T, k], bf16 expert leaves [E, h, f],
+    [E, h, f], [E, f, h] -> [T, h] float32.
+
+    Every row is multiplied by every expert the batch touched and
+    weighted by a dense [T, E] routing weight that is zero where the row
+    did not pick it; an expert nobody picked is never read. Products are
+    the activations' three bf16 parts against the bf16 tile, summed in
+    float32 (no less than `precision='high'` gives: that splits in two).
+    `f_tile` (a divisor of f, a multiple of 128 or f itself) is how much
+    of an expert a grid step takes; None is `_expert_f_tile(f)`."""
+    t, h = x.shape
+    k, (e, _, f) = sel.shape[1], gate_w.shape
+    if x.dtype != jnp.float32:
+        raise ValueError(f'moe_decode_experts: float32 activations, not '
+                         f'{x.dtype}')
+    if not (gate_w.dtype == up_w.dtype == down_w.dtype == jnp.bfloat16):
+        raise ValueError(
+            'moe_decode_experts: bf16 expert weights (the three-part '
+            f'product is exact only against them), not {gate_w.dtype}')
+    if up_w.shape != (e, h, f) or down_w.shape != (e, f, h) \
+            or sel.shape != (t, k) or w.shape != (t, k):
+        raise ValueError(
+            f'moe_decode_experts: x {x.shape}, sel {sel.shape}, w '
+            f'{w.shape} against leaves {gate_w.shape}, {up_w.shape}, '
+            f'{down_w.shape}')
+    if f_tile is None:
+        f_tile = _expert_f_tile(f)
+    if f % f_tile or (f_tile != f and f_tile % 128):
+        raise ValueError(f'moe_decode_experts: f_tile {f_tile} must '
+                         f'divide f {f} in multiples of 128')
+    nf = f // f_tile
+    tp = -(-t // _EXPERT_ROW_TILE) * _EXPERT_ROW_TILE
+    bound = min(e, t * k)
+    # tiny, in XLA: the dense routing weight, and the distinct experts in
+    # order, padded with the last real one (a repeated block index is
+    # not fetched again)
+    experts = jnp.arange(e, dtype=jnp.int32)
+    hit = sel[:, :, None] == experts                          # [T, k, E]
+    wd = jnp.sum(jnp.where(hit, w.astype(jnp.float32)[:, :, None], 0.0),
+                 axis=1)
+    touched = jnp.any(hit, axis=(0, 1))
+    cnt = jnp.sum(touched, dtype=jnp.int32)
+    ids = jnp.argsort(~touched, stable=True)[:bound].astype(jnp.int32)
+    ids = jnp.where(jnp.arange(bound) < cnt, ids, ids[cnt - 1])
+
+    def resident(i, j, ids_ref, cnt_ref):
+        return 0, 0
+
+    def tile(i, j, cnt_ref):    # past the count: the last real step's
+        return jnp.where(i < cnt_ref[0], j, nf - 1)
+
+    def columns(i, j, ids_ref, cnt_ref):        # of gate_w, up_w
+        return ids_ref[i], 0, tile(i, j, cnt_ref)
+
+    def rows(i, j, ids_ref, cnt_ref):           # of down_w
+        return ids_ref[i], tile(i, j, cnt_ref), 0
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(bound, nf),
+        in_specs=[
+            pl.BlockSpec((tp, h), resident),
+            pl.BlockSpec((tp, e), resident),
+            pl.BlockSpec((1, h, f_tile), columns),
+            pl.BlockSpec((1, h, f_tile), columns),
+            pl.BlockSpec((1, f_tile, h), rows),
+        ],
+        out_specs=pl.BlockSpec((tp, h), resident),
+        scratch_shapes=[pltpu.VMEM((3 * tp, h), jnp.bfloat16)])
+    # two buffers of each weight tile, the resident rows, and the
+    # products' float32 intermediates; under the chip's 128 MiB
+    tile_bytes = 3 * h * f_tile * 2
+    vmem = 2 * tile_bytes + 3 * tp * (4 * h + 3 * f_tile) * 4 + (8 << 20)
+    pad = ((0, tp - t), (0, 0))
+    out = pl.pallas_call(
+        _moe_decode_kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((tp, h), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=('arbitrary', 'arbitrary'),
+            vmem_limit_bytes=vmem),
+        interpret=interpret,
+        name='moe_decode_experts',
+    )(ids, cnt[None], jnp.pad(x, pad), jnp.pad(wd, pad), gate_w, up_w,
+      down_w)
+    return out[:t]

@@ -608,6 +608,11 @@ class InferenceEngine:
             'paddle_serving_moe_experts_touched_total',
             'distinct experts active slots routed to, summed over decode '
             'sub-steps and expert layers')
+        self._m_expert_kernel = reg.counter(
+            'paddle_serving_moe_expert_kernel_substeps_total',
+            'expert-layer decode sub-steps whose routed experts ran as '
+            'the pallas kernel (every one on a TPU, none where the loop '
+            'over blocks runs)')
         self._m_state_bytes = reg.counter(
             'paddle_serving_slot_state_bytes_total',
             'bytes of slot state that is not K and V (a conv layer\'s '
@@ -651,11 +656,12 @@ class InferenceEngine:
         """The per-token scan every decode program runs over a
         contiguous [num_slots, max_length, H, D] view. -> (tokens
         [num_slots, block], the pool) and, where the model has expert
-        layers, a third result: int32 [block, expert layers], the number
-        of distinct experts the ACTIVE slots routed to in each sub-step
-        and layer, gathered as the model is traced (`routing_scope`). A
-        model without experts leaves nothing there, and its program is
-        the one it was.
+        layers, a third result: int32 [block, 2, expert layers], the
+        number of distinct experts the ACTIVE slots routed to in each
+        sub-step and layer and, under it, 1 where that layer's routed
+        experts ran as the kernel, gathered as the model is traced
+        (`routing_scope`). A model without experts leaves nothing
+        there, and its program is the one it was.
 
         `rows` (a Python int; None is `max_length`) is how much of a
         slot attention READS: the mask has `rows` columns and the models'
@@ -1477,16 +1483,21 @@ class InferenceEngine:
         return int(np.minimum(written[:, None],
                               self._layer_rows[None, :]).sum())
 
-    def _note_routing(self, round_span, touched):
-        """Book a round's routing counts (`[decode_block, expert
-        layers]` distinct experts the active slots routed to) on its
-        span and on `paddle_serving_moe_experts_touched_total`."""
-        n = int(touched.sum())
+    def _note_routing(self, round_span, routing):
+        """Book a round's routing counts (`[decode_block, 2, expert
+        layers]`: the distinct experts the active slots routed to, and
+        whether the layer ran the kernel) on its span, on
+        `paddle_serving_moe_experts_touched_total` and on
+        `paddle_serving_moe_expert_kernel_substeps_total`."""
+        touched, kernel = routing[:, 0], routing[:, 1]
+        n, ran = int(touched.sum()), int(kernel.sum())
         round_span.set(experts_touched=n,
                        expert_layer_substeps=int(touched.size),
+                       expert_kernel_substeps=ran,
                        experts=self._num_experts)
         if _obs.enabled():
             self._m_experts_touched.inc(n)
+            self._m_expert_kernel.inc(ran)
 
     def _note_state(self, round_span):
         """Book what a round does to slot state that is not K and V, on

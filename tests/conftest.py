@@ -58,6 +58,19 @@ def _reset_span_state():
 
 
 @pytest.fixture
+def fresh_programs():
+    """Empty the program store's memory around a test that serves ONE
+    model on two paths a trace picks (the expert loop, then the
+    interpreted expert kernel): the store keys a program by the model's
+    class, configuration and avals, which such a test holds equal."""
+    from paddle_tpu import programs
+    store = programs.get_store()
+    store.clear_memory()
+    yield store
+    store.clear_memory()
+
+
+@pytest.fixture
 def sanitizer_strict():
     """Run the test under the runtime concurrency sanitizer in STRICT
     mode (ISSUE 15): any lock-order cycle, non-reentrant re-entry, or

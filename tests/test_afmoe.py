@@ -8,6 +8,8 @@ for every token; a cache against a full forward; grouped against
 repeated KV heads). Observed at most 1e-5 on logits as large as 7; the
 mildest departure from the published mathematics moves a logit by more
 than 1e-2. 2e-4 lies between with room on both sides."""
+import functools
+
 import numpy as np
 import pytest
 
@@ -23,6 +25,7 @@ from paddle_tpu.nlp import afmoe
 from paddle_tpu.nlp.afmoe import AfmoeConfig, AfmoeForCausalLM
 from paddle_tpu.nlp.generation import cached_forward
 from paddle_tpu.nlp.llama import LlamaConfig, LlamaForCausalLM
+from paddle_tpu.ops import pallas
 from paddle_tpu.ops.pallas import _attention_xla
 from paddle_tpu.serving import InferenceEngine, SamplingParams
 from paddle_tpu.serving.adapters import AdapterBank, make_adapter_factors
@@ -243,28 +246,76 @@ def _experts_by_loop(x, sel, w, gate_w, up_w, down_w):
     return out
 
 
-@pytest.mark.parametrize('tokens,block_rows,one_expert', [
-    (5, 256, False),       # a decode batch: one block of 8 rows an expert
-    (8, 256, True),        # every token on expert 3
-    (70, 16, False),       # a prefill: several blocks an expert
-    (70, 16, True)])
-def test_grouped_experts_against_a_loop_over_picks(
-        tokens, block_rows, one_expert, monkeypatch, fresh_dispatch):
-    monkeypatch.setattr(afmoe, 'BLOCK_ROWS', block_rows)
-    rs = np.random.RandomState(tokens)
-    e, k, h, f = 8, 2, 16, 12
-    x = rs.randn(tokens, h).astype('float32')
-    sel = np.stack([rs.permutation(e)[:k] for _ in range(tokens)])
-    if one_expert:
+def _picks(rs, tokens, e, k, routing):
+    """[tokens, k] distinct picks a row: `random`; `one_expert` (every
+    row's first pick is expert 3); `distinct` (no expert picked twice in
+    the batch); `few` (every row picks among the same k + 1 experts, so
+    most of the layer is left untouched)."""
+    if routing == 'distinct':
+        return rs.permutation(e)[:tokens * k].reshape(tokens, k)
+    pool = e if routing != 'few' else k + 1
+    sel = np.stack([rs.permutation(pool)[:k] for _ in range(tokens)])
+    if routing == 'one_expert':
         sel[:, 0] = 3
         sel[:, 1] = np.where(sel[:, 1] == 3, 4, sel[:, 1])
+    return sel
+
+
+@pytest.mark.parametrize('tokens,block_rows,routing,experts,kernel', [
+    (5, 256, 'random', (8, 2), False),   # a decode batch: one block of 8
+    (8, 256, 'one_expert', (8, 2), False),
+    (70, 16, 'random', (8, 2), False),   # a prefill: several blocks an expert
+    (70, 16, 'one_expert', (8, 2), False),
+    # the kernel, interpreted, at the two cells' decode geometries
+    (8, 256, 'random', (128, 8), True),      # serve-moe-docs: 64 picks of 128
+    (32, 256, 'random', (64, 4), True),      # serve-hybrid-reason: 128 of 64
+    (8, 256, 'one_expert', (128, 8), True),
+    (8, 256, 'distinct', (128, 8), True),    # the grid's bound, all of it real
+    (32, 256, 'few', (64, 4), True),         # 5 of 64 experts touched
+    (5, 256, 'random', (8, 2), True)])       # rows padded to the kernel's 16
+def test_grouped_experts_against_a_loop_over_picks(
+        tokens, block_rows, routing, experts, kernel, monkeypatch,
+        fresh_dispatch):
+    """The loop over blocks and the kernel against a float64 loop over
+    the picks. The kernel takes bf16 leaves under float32 activations,
+    and is held to the tolerance the loop meets — also against the loop
+    itself over the same leaves in float32 at `HIGHEST`."""
+    monkeypatch.setattr(afmoe, 'BLOCK_ROWS', block_rows)
+    rs = np.random.RandomState(tokens)
+    (e, k), h, f = experts, 16, 12
+    x = rs.randn(tokens, h).astype('float32')
+    sel = _picks(rs, tokens, e, k, routing)
     w = rs.rand(tokens, k).astype('float32')
     gw, uw = (0.3 * rs.randn(e, h, f).astype('float32') for _ in range(2))
     dw = 0.3 * rs.randn(e, f, h).astype('float32')
-    got = afmoe.grouped_experts(*map(jnp.asarray, (
-        x, sel.astype('int32'), w, gw, uw, dw)))
+    args = [jnp.asarray(a) for a in (x, sel.astype('int32'), w)]
+    if not kernel:
+        got = afmoe.grouped_experts(*args, *map(jnp.asarray, (gw, uw, dw)))
+    else:
+        leaves = [jnp.asarray(a, jnp.bfloat16) for a in (gw, uw, dw)]
+        fn = pallas.expert_kernel(tokens, block_rows, leaves[0].dtype,
+                                  interpret=True)
+        got = fn(*args, *leaves)
+        gw, uw, dw = (np.asarray(a.astype(jnp.float32)) for a in leaves)
+        with jax.default_matmul_precision('highest'):
+            loop = afmoe.grouped_experts(*args, *map(jnp.asarray,
+                                                     (gw, uw, dw)))
+        assert np.abs(np.asarray(got) - np.asarray(loop)).max() < 1e-5
     want = _experts_by_loop(x, sel, w, gw, uw, dw)
     assert np.abs(np.asarray(got) - want).max() < 1e-5
+
+
+@pytest.mark.parametrize('tokens,dtype,interpret,takes', [
+    (8, 'bfloat16', False, False),      # the CPU, not interpreted: the loop
+    (8, 'bfloat16', True, True),
+    (256, 'bfloat16', True, True),      # one block wide, to the row
+    (257, 'bfloat16', True, False),     # a prefill's blocks keep the loop
+    (8, 'float32', True, False)])       # the three parts want bf16 leaves
+def test_expert_kernel_is_picked_by_backend_and_shape(tokens, dtype,
+                                                      interpret, takes):
+    fn = pallas.expert_kernel(tokens, afmoe.BLOCK_ROWS, jnp.dtype(dtype),
+                              interpret=interpret)
+    assert (fn is not None) == takes
 
 
 def _attention_repeated(q, k, v, mask, causal):
@@ -411,6 +462,7 @@ def test_decode_round_carries_routing_and_row_counts(tiny):
     for a in rounds:
         assert a['experts'] == cfg['num_experts']
         assert a['expert_layer_substeps'] == 4 * layers
+        assert a['expert_kernel_substeps'] == 0     # the CPU runs the loop
         # a token picks k distinct experts; active slots pick at most
         # active * k, and never more than there are
         lo = cfg['num_experts_per_tok'] * a['expert_layer_substeps']
@@ -428,6 +480,35 @@ def test_decode_round_carries_routing_and_row_counts(tiny):
         == sum(a['experts_touched'] for a in rounds)
 
 
+def test_the_kernel_serves_the_loops_tokens_and_says_it_ran(
+        monkeypatch, fresh_programs):
+    """bf16 expert leaves: through the engine the interpreted kernel
+    gives the greedy tokens the loop gives, and every expert-layer
+    sub-step of every round is booked as the kernel's; on the CPU as it
+    is, none."""
+    cfg = _cfg('tiny')
+    w = {name: v.astype(jnp.bfloat16) if 'experts_' in name else v
+         for name, v in _weights(cfg).items()}
+    log, reg = obs.get_event_log(), obs.get_registry()
+    family = 'paddle_serving_moe_expert_kernel_substeps_total'
+    log.clear()
+    before = reg.value(family)
+    base, _ = _serve(_model(cfg, w), _requests())
+    assert all(a['expert_kernel_substeps'] == 0 for a in _rounds(log))
+    assert reg.value(family) == before
+    monkeypatch.setattr(afmoe, 'expert_kernel', functools.partial(
+        pallas.expert_kernel, interpret=True))
+    fresh_programs.clear_memory()
+    log.clear()
+    toks, _ = _serve(_model(cfg, w), _requests())
+    assert toks == base
+    rounds = _rounds(log)
+    assert rounds and all(a['expert_kernel_substeps']
+                          == a['expert_layer_substeps'] for a in rounds)
+    assert reg.value(family) - before \
+        == sum(a['expert_kernel_substeps'] for a in rounds)
+
+
 def test_a_model_without_experts_returns_what_it_returned():
     paddle.seed(5)
     model = LlamaForCausalLM(LlamaConfig.tiny()).eval()
@@ -436,6 +517,7 @@ def test_a_model_without_experts_returns_what_it_returned():
     _, eng = _serve(model, _requests())
     a = _rounds(log)[-1]
     assert 'experts_touched' not in a and 'experts' not in a
+    assert 'expert_kernel_substeps' not in a
     assert a['rows'] == 64 and a['read_rows'] == 2 * 64 * 2
     assert a['needed_rows'] == a['real_rows'] * 2      # no window layer
     out = jax.eval_shape(

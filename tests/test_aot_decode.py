@@ -8,7 +8,9 @@ is still staged through the fast memory space `S(1)` and copied back out
 stages no leaf at all: the slice is fused into both contractions, which
 read the leaf where it lies. Compiled for a described chip at the cells'
 widths and two layers; costs no chip time, says nothing of speed. Marked
-slow (about a minute a case).
+slow (about a minute a case). Since PR 31 also: an expert layer's routed
+experts are ONE Mosaic call, and no `while` is left under `moe/experts`
+(two expert layers; both expert cells, both programs).
 
 The topology is described inside a fixture, never at import: every xdist
 worker imports this file, and only one process may load libtpu — so
@@ -131,5 +133,35 @@ def test_decode_block_writes_its_rows_in_place_on_v5e(workload, program,
         rows = leaves[0].shape[1] // 2
         assert re.search(r'f32\[%d,%d,%d,%d\]\S* slice\(' % (
             leaves[0].shape[0], rows, *leaves[0].shape[2:]), text)
+    pool_bytes = sum(v.size * v.dtype.itemsize for v in leaves)
+    assert ma.alias_size_in_bytes == pool_bytes
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize('program', ['whole', 'half'])
+@pytest.mark.parametrize('workload', ['serve-moe-docs',
+                                      'serve-hybrid-reason'])
+def test_an_expert_layer_is_one_kernel_on_v5e(workload, program, one_chip):
+    """One dense layer and two expert layers of the cell's configuration:
+    the decode block holds one Mosaic custom call a layer under
+    `moe/experts` and no loop there; the pool is still aliased and no
+    more leaves go through `S(1)` than before the kernel."""
+    cell = spec.Spec().cell(workload)
+    cfg = cell['config']
+    cfg['num_hidden_layers'] = 3
+    cfg['layer_types'] = cfg['layer_types'][:3]
+    text, ma, leaves = _compile_decode_block(cell, one_chip, program)
+    assert 'decode' in re.search(r'HloModule (\S+)', text).group(1)
+    experts = [ln for ln in text.splitlines() if 'moe/experts' in ln]
+    kernels = [ln for ln in experts if 'tpu_custom_call' in ln]
+    assert len(kernels) == 2, [ln[:160] for ln in kernels]
+    assert all('moe_decode_experts' in ln for ln in kernels)
+    loops = [ln for ln in experts if re.search(r' while\(', ln)]
+    assert not loops, f'a loop under moe/experts again: {loops[0][:200]}'
+    rows = [v for v in leaves if v.ndim == 4]       # K and V, not state
+    into, out = staged_pool_rows(text, rows[0])
+    assert len(out) <= len(rows) // 2, f'more than a leaf a layer: {out}'
+    if program == 'half':
+        assert not into and not out, (into, out)
     pool_bytes = sum(v.size * v.dtype.itemsize for v in leaves)
     assert ma.alias_size_in_bytes == pool_bytes

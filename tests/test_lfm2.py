@@ -13,6 +13,7 @@ every departure from the published mathematics below moves a logit by
 more than 2, and operands rounded to bfloat16 — what one bf16 pass of
 the MXU would make of the float32 activations — by 1.1, an expert choice
 flipped. 2e-4 lies between with room on both sides."""
+import functools
 
 import numpy as np
 import pytest
@@ -31,6 +32,7 @@ from paddle_tpu.nlp.generation import cached_forward
 from paddle_tpu.nlp.gpt import GPTConfig, GPTForCausalLM
 from paddle_tpu.nlp.lfm2 import Lfm2MoeConfig, Lfm2MoeForCausalLM
 from paddle_tpu.nlp.llama import LlamaConfig, LlamaForCausalLM
+from paddle_tpu.ops import pallas
 from paddle_tpu.serving import (InferenceEngine, ReplicaSet, Router,
                                 SamplingParams)
 
@@ -583,6 +585,30 @@ def test_decode_round_carries_state_and_counts_one_attention_layer(tiny):
         assert a['experts'] == cfg['num_experts']
     assert reg.value('paddle_serving_slot_state_bytes_total') - before \
         == sum(a['state_bytes'] for a in rounds)
+
+
+def test_the_expert_kernel_serves_the_loops_tokens(monkeypatch,
+                                                   fresh_programs):
+    """bf16 expert leaves, four picks of eight experts, a state beside
+    K and V: the interpreted kernel gives the loop's greedy tokens and
+    books every expert-layer sub-step as its own."""
+    cfg = _cfg('tiny')
+    w = {name: v.astype(jnp.bfloat16) if 'experts_' in name else v
+         for name, v in _weights(cfg).items()}
+    prompts, log = _prompts((5, 19, 11)), obs.get_event_log()
+    log.clear()
+    base, _ = _through_the_router(_model(cfg, w), prompts, 14)
+    assert all(a['expert_kernel_substeps'] == 0 for a in _rounds(log))
+    monkeypatch.setattr(afmoe, 'expert_kernel', functools.partial(
+        pallas.expert_kernel, interpret=True))
+    fresh_programs.clear_memory()
+    log.clear()
+    toks, _ = _through_the_router(_model(cfg, w), prompts, 14)
+    assert toks == base
+    rounds = _rounds(log)
+    assert rounds and all(a['expert_kernel_substeps']
+                          == a['expert_layer_substeps'] == BLOCK * 4
+                          for a in rounds)
 
 
 def test_a_model_without_state_carries_none_of_it():

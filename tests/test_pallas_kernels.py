@@ -396,3 +396,75 @@ class TestNoSilentFallback:
             rng.standard_normal((4, 512)).astype(np.float32))
         assert np.isfinite(float(F.cross_entropy(
             small, paddle.to_tensor(rng.integers(0, 512, (4,)))).numpy()))
+
+    def test_expert_kernel_error_reaches_the_caller(self, gate_on,
+                                                    monkeypatch):
+        import paddle_tpu as paddle
+        from paddle_tpu.nlp.afmoe import (BLOCK_ROWS, AfmoeConfig,
+                                          AfmoeForCausalLM)
+        from paddle_tpu.ops import pallas_kernels as pk
+
+        def boom(*a, **k):
+            raise RuntimeError('mosaic says no')
+        monkeypatch.setattr(pk, 'moe_decode_experts', boom)
+        paddle.seed(0)
+        model = AfmoeForCausalLM(AfmoeConfig.tiny()).eval()
+        for layer in model.model.layers[1:]:
+            for p in (layer.mlp.gate_w, layer.mlp.up_w, layer.mlp.down_w):
+                p._data = p._data.astype(jnp.bfloat16)
+        with pytest.raises(RuntimeError, match='mosaic says no'):
+            model(paddle.to_tensor(np.ones((1, 8), 'int32')))
+        # a call wider than one block is the loop's by the conditions
+        out = model(paddle.to_tensor(np.ones((1, BLOCK_ROWS + 1), 'int32')))
+        assert np.isfinite(out.numpy()).all()
+
+
+# ---------------------------------------------------------------------------
+# routed experts of a decode batch (`moe_decode_experts`, PR 31)
+# ---------------------------------------------------------------------------
+def _expert_call(t=4, k=2, e=8, h=16, f=256, seed=0, **changed):
+    rs = np.random.RandomState(seed)
+    call = dict(
+        x=jnp.asarray(rs.randn(t, h), jnp.float32),
+        sel=jnp.asarray(np.stack([rs.permutation(e)[:k] for _ in range(t)]),
+                        jnp.int32),
+        w=jnp.asarray(rs.rand(t, k), jnp.float32),
+        gate_w=jnp.asarray(0.3 * rs.randn(e, h, f), jnp.bfloat16),
+        up_w=jnp.asarray(0.3 * rs.randn(e, h, f), jnp.bfloat16),
+        down_w=jnp.asarray(0.3 * rs.randn(e, f, h), jnp.bfloat16))
+    call.update(changed)
+    return call
+
+
+@pytest.mark.parametrize('f_tile', [None, 128, 256])
+def test_moe_decode_experts_sums_over_tiles_of_f(f_tile):
+    """However an expert is cut into grid steps, the sum is the same:
+    one whole tile, two of 128, and the tile the kernel picks."""
+    from paddle_tpu.ops.pallas_kernels import moe_decode_experts
+    call = _expert_call()
+    got = np.asarray(moe_decode_experts(**call, f_tile=f_tile,
+                                        interpret=True), np.float64)
+    x, w = (np.asarray(call[n], np.float64) for n in ('x', 'w'))
+    gw, uw, dw = (np.asarray(call[n].astype(jnp.float32), np.float64)
+                  for n in ('gate_w', 'up_w', 'down_w'))
+    want = np.zeros_like(x)
+    for t, row in enumerate(np.asarray(call['sel'])):
+        for j, ex in enumerate(row):
+            g, u = x[t] @ gw[ex], x[t] @ uw[ex]
+            want[t] += w[t, j] * ((g / (1 + np.exp(-g)) * u) @ dw[ex])
+    assert np.abs(got - want).max() < 1e-5 * np.abs(want).max()
+
+
+@pytest.mark.parametrize('changed,match', [
+    (dict(x=jnp.zeros((4, 16), jnp.bfloat16)), 'float32 activations'),
+    (dict(gate_w=jnp.zeros((8, 16, 256), jnp.float32)), 'bf16 expert'),
+    (dict(down_w=jnp.zeros((8, 16, 256), jnp.bfloat16)), 'against leaves'),
+    (dict(w=jnp.zeros((4, 3), jnp.float32)), 'against leaves'),
+    (dict(f_tile=96), 'multiples of 128'),
+    (dict(f_tile=192), 'multiples of 128')],
+    ids=['bf16_rows', 'f32_leaves', 'down_not_transposed', 'weights_shape',
+         'tile_off_the_lanes', 'tile_not_a_divisor'])
+def test_moe_decode_experts_refuses(changed, match):
+    from paddle_tpu.ops.pallas_kernels import moe_decode_experts
+    with pytest.raises(ValueError, match=match):
+        moe_decode_experts(**_expert_call(**changed), interpret=True)

@@ -295,7 +295,8 @@ def test_conv_scopes_are_placed_on_a_hybrids_decode_program():
     """`conv` and `state_write`: on the decode program of a model with
     conv layers, every instruction under them placed by a name of its
     own or of what it holds; README documents the counter and the three
-    counts that came with them."""
+    counts that came with them, and the expert layer's counts and
+    counters, the kernel's among them."""
     from paddle_tpu.nlp.lfm2 import Lfm2MoeConfig, Lfm2MoeForCausalLM
     assert {'conv', 'state_write'} <= set(scopes_mod.SCOPES)
     paddle.seed(11)
@@ -311,7 +312,9 @@ def test_conv_scopes_are_placed_on_a_hybrids_decode_program():
     with open(os.path.join(os.path.dirname(PKG), 'README.md')) as f:
         readme = f.read()
     for word in ('slot_state_bytes_total', '`attn_layers`', '`state_layers`',
-                 '`state_bytes`'):
+                 '`state_bytes`', 'moe_expert_kernel_substeps_total',
+                 '`expert_kernel_substeps`', 'moe_experts_touched_total',
+                 '`experts_touched`', '`expert_layer_substeps`'):
         assert word in readme, word
 
 
@@ -387,7 +390,8 @@ def test_every_pallas_kernel_states_its_name():
     assert all(names), 'a pallas_call without a stated name'
     assert [m.group(1) for m in names] == [
         'flash_fwd', 'flash_bwd_dq', 'flash_bwd_dkv', 'rms_norm_fwd',
-        'ce_fwd', 'ce_bwd', 'paged_attention', 'adapter_matmul']
+        'ce_fwd', 'ce_bwd', 'paged_attention', 'adapter_matmul',
+        'moe_decode_experts']
 
 
 def test_named_kernel_reaches_the_lowered_program():
