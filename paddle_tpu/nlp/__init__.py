@@ -16,6 +16,7 @@ from .generation import GenerationMixin, Seq2SeqGenerationMixin
 from .gpt import GPTConfig, GPTForCausalLM, GPTModel
 from .lfm2 import Lfm2MoeConfig, Lfm2MoeForCausalLM, Lfm2MoeModel
 from .llama import (LlamaConfig, LlamaForCausalLM, LlamaModel)
+from .mimo_v2 import MiMoV2Config, MiMoV2ForCausalLM, MiMoV2Model
 from .t5 import T5Config, T5ForConditionalGeneration, T5Model
 from .tokenizer import (BPETokenizer, PretrainedTokenizer,
                         WhitespaceTokenizer)
@@ -28,7 +29,8 @@ __all__ = [
     'ErnieForSequenceClassification', 'ErnieModel', 'GenerationMixin',
     'GPTConfig', 'GPTForCausalLM', 'GPTModel', 'Lfm2MoeConfig',
     'Lfm2MoeForCausalLM', 'Lfm2MoeModel', 'LlamaConfig',
-    'LlamaForCausalLM', 'LlamaModel', 'Seq2SeqGenerationMixin',
+    'LlamaForCausalLM', 'LlamaModel', 'MiMoV2Config', 'MiMoV2ForCausalLM',
+    'MiMoV2Model', 'Seq2SeqGenerationMixin',
     'T5Config', 'T5ForConditionalGeneration', 'T5Model', 'BPETokenizer',
     'PretrainedTokenizer', 'WhitespaceTokenizer', 'transformers',
 ]

@@ -304,6 +304,13 @@ def grouped_experts(x, sel, w, gate_w, up_w, down_w):
     pick belong to the next experts and are overwritten by their own
     blocks, which come later.
 
+    A pick may name NO expert of these leaves: `sel == E`, one past the
+    last (a layer that holds a share of its router's experts gives the
+    picks it does not hold so; never a negative index, which would
+    wrap). Such a pick sorts behind every real one, is counted for no
+    expert and walked by no block; what its row of `ys` holds is some
+    block's overhang, so its weight must be zero, and it adds nothing.
+
     This is the path of every backend but a TPU, of a prefill
     everywhere, and the parity ground truth of the kernel that takes a
     one-block call on a TPU (`ops.pallas.expert_kernel`): there an
@@ -351,7 +358,17 @@ def grouped_experts(x, sel, w, gate_w, up_w, down_w):
 
 class AfmoeSparseMLP(Layer):
     """Routed experts (stacked leaves [E, h, f]) plus the shared expert
-    (none where the configuration counts no shared expert)."""
+    (none where the configuration counts no shared expert).
+
+    The layer may hold a SHARE of the experts its router chooses among —
+    one chip's, of a layer divided over several (expert parallel): the
+    configuration's `num_routed_experts` is then the router's width and
+    `first_expert` the first of the `num_experts` held here. The router
+    scores, selects and normalises over all of them as published; a
+    pick that is not held adds nothing HERE, and the partial sum is the
+    layer's result — what the other chips would add, and the exchange
+    that would bring it, are not stood in for. A configuration without
+    those keys holds every expert, and its program is the one it was."""
 
     route_norm_eps = 1e-20
 
@@ -360,9 +377,16 @@ class AfmoeSparseMLP(Layer):
         self.config = config
         h, f, e = (config.hidden_size, config.moe_intermediate_size,
                    config.num_experts)
-        self.router = Linear(h, e, bias_attr=False)
+        routed = int(getattr(config, 'num_routed_experts', None) or e)
+        self.first_expert = int(getattr(config, 'first_expert', 0))
+        if not 0 <= self.first_expert <= routed - e:
+            raise ValueError(
+                f'experts {self.first_expert}..{self.first_expert + e - 1} '
+                f'are not among the router\'s {routed}')
+        self.holds_share = e < routed
+        self.router = Linear(h, routed, bias_attr=False)
         self.expert_bias = self.create_parameter(
-            (e,), attr=ParamAttr(trainable=False),
+            (routed,), attr=ParamAttr(trainable=False),
             default_initializer=I.Constant(0.0))
         std = I.Normal(0.0, 0.02)
         self.gate_w = self.create_parameter((e, h, f),
@@ -395,6 +419,14 @@ class AfmoeSparseMLP(Layer):
         kernel = expert_kernel(math.prod(x.shape[:-1]), BLOCK_ROWS,
                                to_jax(self.gate_w).dtype)
         routed_experts = kernel or grouped_experts
+        first, held = self.first_expert, cfg.num_experts
+
+        def own(sel, w):
+            # the picks in this layer's own numbering: one that is not
+            # held becomes `held`, one past the last, with no weight
+            local = sel - first
+            mine = (local >= 0) & (local < held)
+            return jnp.where(mine, local, held), jnp.where(mine, w, 0.0)
 
         def experts(xv, sel, w, gw, uw, dw):
             out = routed_experts(xv.reshape(-1, xv.shape[-1]),
@@ -405,7 +437,9 @@ class AfmoeSparseMLP(Layer):
         with jax.named_scope('moe/router'):
             sel, w = apply_op(router, x, self.router.weight,
                               self.expert_bias, _name='moe_router')
-        note_routing(sel, cfg.num_experts, kernel is not None)
+            if self.holds_share:
+                sel, w = apply_op(own, sel, w, _name='moe_own_picks')
+        note_routing(sel, held, kernel is not None, self.holds_share)
         with jax.named_scope('moe/experts'):
             routed = apply_op(experts, x, sel, w, self.gate_w, self.up_w,
                               self.down_w, _name='moe_experts')

@@ -190,30 +190,44 @@ class routing_scope:
         return False
 
 
-def note_routing(selected, num_experts, kernel=False):
-    """Called by an expert layer with the experts it selected, and
-    whether the call's routed experts run as the pallas kernel
-    (`ops.pallas.expert_kernel`) and not as the loop over blocks."""
+def note_routing(selected, num_experts, kernel=False, share=False):
+    """Called by an expert layer with the experts it selected, how many
+    it HOLDS, and whether the call's routed experts run as the pallas
+    kernel (`ops.pallas.expert_kernel`) and not as the loop over
+    blocks. A layer that holds a share of its router's experts
+    (`share`) gives `selected` in its own numbering, `num_experts` for
+    a pick it does not hold."""
     if _routing.picks is not None:
         _routing.picks.append((to_jax(selected), int(num_experts),
-                               bool(kernel)))
+                               bool(kernel), bool(share)))
 
 
 def experts_touched(picks, active):
     """[2, number of expert layers] int32: per layer, how many distinct
-    experts the rows of `active` ([B] bool) routed to, and under it 1
-    where the layer ran the kernel (a constant of the program: it
-    survives a program that is loaded and never traced here); None
-    without an expert layer."""
+    experts of those it holds the rows of `active` ([B] bool) routed
+    to, and under it 1 where the layer ran the kernel (a constant of
+    the program: it survives a program that is loaded and never traced
+    here); None without an expert layer. Where a layer holds a share
+    of its experts, two rows more: the picks of the active rows, and
+    those of them that landed on an expert held here."""
     if not picks:
         return None
-    out = []
-    for sel, e, _ in picks:
+    share = any(p[3] for p in picks)
+    touched, made, held = [], [], []
+    for sel, e, _, _ in picks:
         hit = (sel[..., None] == jnp.arange(e, dtype=sel.dtype)) \
             & active[:, None, None, None]
-        out.append(jnp.sum(jnp.any(hit, axis=(0, 1, 2)), dtype=jnp.int32))
-    return jnp.stack([jnp.stack(out),
-                      jnp.asarray([k for *_, k in picks], jnp.int32)])
+        touched.append(jnp.sum(jnp.any(hit, axis=(0, 1, 2)),
+                               dtype=jnp.int32))
+        if share:
+            made.append(jnp.sum(active, dtype=jnp.int32)
+                        * (sel.size // sel.shape[0]))
+            held.append(jnp.sum(hit, dtype=jnp.int32))
+    rows = [jnp.stack(touched),
+            jnp.asarray([k for _, _, k, _ in picks], jnp.int32)]
+    if share:
+        rows += [jnp.stack(made), jnp.stack(held)]
+    return jnp.stack(rows)
 
 
 def state_layers(cache):
@@ -223,6 +237,90 @@ def state_layers(cache):
     and V only."""
     return tuple(i for i, entry in enumerate(cache)
                  if not isinstance(entry, (tuple, list)))
+
+
+def ring_layers(cache, max_length):
+    """The indices of a cache's (K, V) entries that hold fewer rows
+    than `max_length`: RINGS, in which position p lives in row `p mod
+    rows` (a window layer that keeps its window and no more). A ring
+    shares with a state (`state_layers`) what the serving engine has to
+    know: its rows cannot be hidden by a mask of positions or rewound —
+    a row past the live position has REPLACED one the window still
+    needs — so whoever seats it gives it as it stands at the prompt's
+    real end, and nothing may share or rewind it. Empty for a model
+    whose every layer keeps `max_length` rows."""
+    return tuple(i for i, entry in enumerate(cache)
+                 if isinstance(entry, (tuple, list))
+                 and entry[0].shape[1] < max_length)
+
+
+def _ring_newest(last, rows):
+    """[..., rows] int32: the newest position `p <= last` with `p mod
+    rows == r`, for every row r (negative where the ring has not yet
+    been filled that far)."""
+    r = jnp.arange(rows, dtype=jnp.int32)
+    last = jnp.asarray(last, jnp.int32)[..., None]
+    return last - jnp.mod(last - r, rows)
+
+
+def update_ring_cache(k_cache, v_cache, k, v, offset):
+    """`update_kv_cache` for a ring: the call's tokens stand at
+    positions `offset + i` and position p is written to row `p mod
+    rows`. Of the call's S tokens the first `folded_tokens(S)` enter
+    (`state_scope`: a right-padded prompt's padding must not, for in a
+    ring it would replace rows the window still needs); every row ends
+    up holding the newest position folded so far that maps to it.
+
+    A single token outside a scope — a decode sub-step — is one scatter
+    of a row a slot, as `update_kv_cache`'s; a longer call gathers, for
+    every ring row, the call's newest token that maps to it (or keeps
+    the row), since two of its tokens may map to one row."""
+    from ..tensor import apply_op as _apply
+    off = offset.value if isinstance(offset, Tensor) else offset
+    s = k.shape[1]
+    folded = folded_tokens(s)
+
+    def upd(c, new):
+        new = new.astype(c.dtype)
+        b, rows = new.shape[0], c.shape[1]
+        at = jnp.broadcast_to(jnp.asarray(off, jnp.int32), (b,))
+        if s == 1 and folded == 1:
+            return c.at[jnp.arange(b, dtype=jnp.int32)[:, None],
+                        jnp.mod(at, rows)[:, None]].set(
+                new, indices_are_sorted=True, unique_indices=True,
+                mode='promise_in_bounds')
+        newest = _ring_newest(at + folded - 1, rows)          # [B, rows]
+        mine = newest >= at[:, None]
+        take = jnp.clip(newest - at[:, None], 0, s - 1)
+        fresh = jnp.take_along_axis(new, take[:, :, None, None], axis=1)
+        return jnp.where(mine[:, :, None, None], fresh, c)
+    return (_apply(upd, k_cache, k, _name='ring_update'),
+            _apply(upd, v_cache, v, _name='ring_update'))
+
+
+def ring_mask(offset, batch, sq, rows, window):
+    """[B, 1, Sq, rows (+ Sq)] boolean: what the queries at positions
+    `offset + i` see of a ring of `rows` rows.
+
+    One query (a decode sub-step), AFTER its own write: row r holds the
+    newest position `p <= offset` with `p mod rows == r`, visible iff
+    `p >= 0` — `rows <= window`, so whatever the ring holds is inside
+    the window. Several queries, BEFORE their write (their rows would
+    replace what the first of them still needs): the ring as the call
+    found it, each row visible iff it holds a position and that lies
+    inside the query's window, followed by the call's own Sq tokens,
+    causal and windowed among themselves."""
+    at = jnp.broadcast_to(jnp.asarray(offset, jnp.int32), (batch,))
+    if sq == 1:
+        return (_ring_newest(at, rows) >= 0)[:, None, None, :]
+    held = _ring_newest(at - 1, rows)                         # [B, rows]
+    q_pos = at[:, None] + jnp.arange(sq, dtype=jnp.int32)     # [B, Sq]
+    old = (held[:, None, :] >= 0) \
+        & (q_pos[:, :, None] - held[:, None, :] < window)
+    i = jnp.arange(sq, dtype=jnp.int32)
+    own = (i[None, :] <= i[:, None]) & (i[:, None] - i[None, :] < window)
+    own = jnp.broadcast_to(own[None], (batch, sq, sq))
+    return jnp.concatenate([old, own], axis=-1)[:, None]
 
 
 class state_scope:

@@ -98,9 +98,15 @@ class LlamaConfig:
         return cls(**kw)
 
 
-def _rope(x, positions, theta):
+def _rope(x, positions, theta, rotary_dim=None):
     """Rotary embedding, rotate-half convention. x: [B, S, H, D] raw array,
-    positions: [S] or [B, S] raw int array."""
+    positions: [S] or [B, S] raw int array. `rotary_dim` (None: all of
+    D) is how many LEADING dims of a head rotate, rotate-half within
+    them; the others pass as they are (partial rotary)."""
+    if rotary_dim is not None and rotary_dim < x.shape[-1]:
+        return jnp.concatenate(
+            [_rope(x[..., :rotary_dim], positions, theta),
+             x[..., rotary_dim:]], axis=-1)
     d = x.shape[-1]
     inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
     pos = positions.astype(jnp.float32)

@@ -9,6 +9,8 @@ from .gpt import GPTConfig, GPTForCausalLM, GPTModel  # noqa: F401
 from .lfm2 import (Lfm2MoeConfig, Lfm2MoeForCausalLM,  # noqa: F401
                    Lfm2MoeModel)
 from .llama import LlamaConfig, LlamaForCausalLM, LlamaModel  # noqa: F401
+from .mimo_v2 import (MiMoV2Config, MiMoV2ForCausalLM,  # noqa: F401
+                      MiMoV2Model)
 from .t5 import (T5Config, T5ForConditionalGeneration,  # noqa: F401
                  T5Model)
 from .tokenizer import (BPETokenizer, PretrainedTokenizer,  # noqa: F401

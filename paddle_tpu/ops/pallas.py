@@ -81,13 +81,19 @@ def rms_norm(v, epsilon=1e-6, axis=-1):
 
 
 def _attention_xla(q, k, v, mask=None, causal=False, dropout_p=0.0,
-                   dropout_key=None):
+                   dropout_key=None, sink=None):
     """Reference attention in [B, S, H, D] layout (paddle SDPA convention).
     Grouped KV heads (`H_kv < H`) are contracted in place: the query
     heads are viewed as [H_kv, rep] groups and each group reads its one
     K/V head — K and V are never repeated (a `jnp.repeat` of a decode
     cache is a copy of the whole cache, `rep` times its size, per layer
-    and sub-step). `rep == 1` takes the ungrouped contraction."""
+    and sub-step). `rep == 1` takes the ungrouped contraction. V may be
+    narrower or wider than Q and K: the output has V's head size.
+
+    `sink` ([H] float, one learned logit a query head) is a column of
+    the softmax that takes mass and gives no value: `p_ij = exp(l_ij -
+    m_i) / (exp(s_h - m_i) + sum_j' exp(l_ij' - m_i))`, `m_i = max(s_h,
+    max_j l_ij)`. Without one the traced program is the one it was."""
     b, sq, h, d = q.shape
     sk = k.shape[1]
     kv_heads = k.shape[2]
@@ -118,28 +124,39 @@ def _attention_xla(q, k, v, mask=None, causal=False, dropout_p=0.0,
                                jnp.asarray(jnp.finfo(jnp.float32).min))
         else:
             logits = logits + mask.astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
+    if sink is None:
+        probs = jax.nn.softmax(logits, axis=-1)
+    else:
+        s_h = sink.astype(jnp.float32).reshape(
+            (1, h, 1, 1) if rep == 1 else (1, kv_heads, rep, 1, 1))
+        top = jnp.maximum(jnp.max(logits, axis=-1, keepdims=True), s_h)
+        e = jnp.exp(logits - top)
+        probs = e / (jnp.sum(e, axis=-1, keepdims=True)
+                     + jnp.exp(s_h - top))
     if dropout_p and dropout_key is not None:
         keep = jax.random.bernoulli(dropout_key, 1.0 - dropout_p, probs.shape)
         probs = jnp.where(keep, probs / (1.0 - dropout_p), 0.0)
     if rep == 1:
         return jnp.einsum('bhqk,bkhd->bqhd', probs.astype(q.dtype), v)
     out = jnp.einsum('bhrqk,bkhd->bqhrd', probs.astype(q.dtype), v)
-    return out.reshape(b, sq, h, d)
+    return out.reshape(b, sq, h, v.shape[-1])
 
 
 def flash_attention(q, k, v, mask=None, causal=False, dropout_p=0.0,
-                    dropout_key=None):
+                    dropout_key=None, sink=None):
     """Dispatch: pallas flash kernel on TPU (no mask/dropout path), XLA
     softmax-attention otherwise. The pallas path never materializes the
     [B, H, Sq, Sk] logits — the difference between fitting seq 2048
     training on one chip and OOMing. The conditions below are the whole
-    selection: a kernel error on the pallas side propagates."""
+    selection: a kernel error on the pallas side propagates. The kernel
+    knows neither a `sink` nor a V of another head size than Q's: such
+    a call is XLA's."""
     h, kvh = q.shape[2], k.shape[2]
     # causal requires sq == sk: the pallas kernel's causal mask is
     # top-left aligned while _attention_xla's is bottom-right aligned —
     # they only agree on square attention
     if (_pallas_enabled() and mask is None and dropout_p == 0.0
+            and sink is None and v.shape[-1] == q.shape[-1]
             and q.shape[1] % 128 == 0 and k.shape[1] % 128 == 0
             and (not causal or q.shape[1] == k.shape[1])
             and h % kvh == 0 and q.shape[-1] >= 64):
@@ -156,7 +173,8 @@ def flash_attention(q, k, v, mask=None, causal=False, dropout_p=0.0,
             functools.partial(pallas_kernels.flash_attention, causal=causal),
             (q, k, v), specs)
     return _attention_xla(q, k, v, mask=mask, causal=causal,
-                         dropout_p=dropout_p, dropout_key=dropout_key)
+                         dropout_p=dropout_p, dropout_key=dropout_key,
+                         sink=sink)
 
 
 def expert_kernel(tokens, block_rows, weight_dtype, interpret=False):

@@ -21,8 +21,9 @@ Compiled-program inventory (asserted by the zero-recompile tests):
   lands above the live position where the slot-causal mask hides it
   until the slot's own decode overwrites it — the stale-slot argument
   speculative decoding already relies on; a model whose cache also holds
-  recurrent state, which no mask can hide, gets the prompt's real
-  length into the same program: `_state_prefill_fn`),
+  recurrent state or a ring of a window's rows, which no mask can hide,
+  gets the prompt's real length into the same program:
+  `_state_prefill_fn`),
 and, when the latency stack is enabled (ISSUE 9):
 - one chunk-prefill program per chunk bucket (chunked prefill AND
   prefix-cache suffix prefill — `start`/`slot`/`src` are traced),
@@ -66,7 +67,8 @@ from .. import observability as _obs
 from ..observability import reqledger as _reqledger
 from ..jit import functional_state
 from ..nlp.generation import (_NEG_INF, cached_forward, experts_touched,
-                              routing_scope, state_layers, state_scope)
+                              ring_layers, routing_scope, state_layers,
+                              state_scope)
 from ..resilience import RetryPolicy, call_with_retry
 from ..tensor import Tensor
 from .adapters.apply import adapter_scope as _adapter_scope
@@ -159,16 +161,46 @@ _STATE_REFUSALS = {
 }
 
 
-def _refuse_state_modes(model, asked):
+# Why each engine mode cannot serve a model one of whose layers keeps a
+# RING: a (K, V) entry of fewer rows than the slot, position p in row
+# `p mod rows` (`generation.ring_layers`). A ring's rows are K and V,
+# but it stands at ONE position like a state: a row written past that
+# position has replaced one the window still needs.
+_RING_REFUSALS = {
+    'prefix_cache':
+        'a retained row\'s ring stands at the END of its donor\'s '
+        'prompt: the rows of the shared prefix\'s last window have been '
+        'replaced by what followed, so a hit would seat the wrong ring: '
+        'reuse needs a snapshot of the ring at the prefix',
+    'prefill_chunk_tokens':
+        'a chunk would have to find the ring as the chunk before left it '
+        'and stop at the prompt\'s real end, and a tail chunk shifted '
+        'down to fit the slot forwards tokens twice at rows the ring has '
+        'already given away',
+    'draft_model':
+        'speculation rejects proposed tokens by moving the position back, '
+        'and a ring cannot be moved back: the rejected tokens have '
+        'already replaced the rows a window back',
+    'kv_page_size / kv_pages':
+        'the paged pool has one page geometry and one table of '
+        'max_length rows for every layer; a ring is a leaf of another '
+        'length, with other heads',
+    'kv_quant':
+        'int8 KV lives in the paged pool (per-page scales), which cannot '
+        'hold a ring',
+}
+
+
+def _refuse_modes(model, asked, keeps, refusals):
     """ValueError naming the first engine mode in `asked` ({mode: was
-    it asked for}) that a model with recurrent slot state cannot run
-    under. Nothing runs and is silently wrong."""
-    for mode, reason in _STATE_REFUSALS.items():
+    it asked for}) that a model whose cache `keeps` such an entry cannot
+    run under, with `refusals`' reason. Nothing runs and is silently
+    wrong."""
+    for mode, reason in refusals.items():
         if asked.get(mode):
             raise ValueError(
-                f'{type(model).__name__} keeps recurrent slot state (cache '
-                f'entries that are not K and V), which {mode} cannot '
-                f'serve: {reason}')
+                f'{type(model).__name__} keeps {keeps}, which {mode} '
+                f'cannot serve: {reason}')
 
 
 class InferenceEngine:
@@ -261,16 +293,24 @@ class InferenceEngine:
                 f'max_position_embeddings {max_pos}')
         if decode_block < 1:
             raise ValueError('decode_block must be >= 1')
+        asked = {'prefix_cache': prefix_cache,
+                 'prefill_chunk_tokens': prefill_chunk_tokens,
+                 'draft_model': draft_model is not None,
+                 'kv_page_size / kv_pages': kv_page_size is not None
+                 or kv_pages is not None,
+                 'kv_quant': kv_quant is not None}
         for m in (model, draft_model):
-            if m is not None and state_layers(
-                    jax.eval_shape(lambda: m.init_cache(1, 2))):
-                _refuse_state_modes(m, {
-                    'prefix_cache': prefix_cache,
-                    'prefill_chunk_tokens': prefill_chunk_tokens,
-                    'draft_model': draft_model is not None,
-                    'kv_page_size / kv_pages': kv_page_size is not None
-                    or kv_pages is not None,
-                    'kv_quant': kv_quant is not None})
+            if m is None:
+                continue
+            entries = jax.eval_shape(lambda: m.init_cache(1, max_length))
+            if state_layers(entries):
+                _refuse_modes(m, asked, 'recurrent slot state (cache '
+                              'entries that are not K and V)',
+                              _STATE_REFUSALS)
+            if ring_layers(entries, max_length):
+                _refuse_modes(m, asked, 'a ring of a window\'s rows '
+                              '(cache entries shorter than the slot)',
+                              _RING_REFUSALS)
         model.eval()
         self.model = model
         self._params, self._frozen, self._buffers = functional_state(model)
@@ -389,11 +429,19 @@ class InferenceEngine:
         n_layers = len(self.pool.row_spec)
         windows = getattr(model, 'attention_windows',
                           lambda: (None,) * n_layers)()
+        attending = [i for i in range(n_layers)
+                     if i not in self.pool.state_layers]
         self._layer_rows = np.array(
-            [self.pool.max_length if w is None
-             else min(int(w), self.pool.max_length)
-             for i, w in enumerate(windows)
-             if i not in self.pool.state_layers], np.int64)
+            [self.pool.max_length if windows[i] is None
+             else min(int(windows[i]), self.pool.max_length)
+             for i in attending], np.int64)
+        # which of those keep a ring, and the rows every slot holds there:
+        # a ring is read whole whatever the round's program
+        self._ring = np.array([i in self.pool.ring_layers
+                               for i in attending], bool)
+        self._ring_rows = sum(self.pool.row_spec[i][0].shape[1]
+                              for i in self.pool.ring_layers)
+        self._full_layers = int(np.count_nonzero(~self._ring))
         # the decode block exists at two lengths of attention: every
         # row of a slot, and the first half — which `_decode_round`
         # picks while the batch's positions allow it. 0 where a block
@@ -484,7 +532,7 @@ class InferenceEngine:
                     kind='serving', statics=half_statics,
                     donate_argnums=(3,))
             self._prefill_jit = store.wrap_jit(   # 1 trace per bucket
-                self._state_prefill_fn if self.pool.state_layers
+                self._state_prefill_fn if self.pool.stands_at_one_position
                 else self._prefill_fn,
                 name_fn=lambda args: f'serving.prefill_'
                                      f'{args[3].shape[1]}',
@@ -608,6 +656,12 @@ class InferenceEngine:
             'paddle_serving_moe_experts_touched_total',
             'distinct experts active slots routed to, summed over decode '
             'sub-steps and expert layers')
+        self._m_picks_held = reg.counter(
+            'paddle_serving_moe_picks_held_total',
+            'picks of active slots that landed on an expert held here, '
+            'summed over decode sub-steps and expert layers (a layer that '
+            'holds a share of its router\'s experts; a layer that holds '
+            'them all counts nothing here)')
         self._m_expert_kernel = reg.counter(
             'paddle_serving_moe_expert_kernel_substeps_total',
             'expert-layer decode sub-steps whose routed experts ran as '
@@ -671,7 +725,11 @@ class InferenceEngine:
         slot's output is discarded whatever it read. The write never
         narrows: it scatters into the whole leaf, and the whole pool is
         the carry that comes back. At `max_length` nothing is sliced and
-        the program is the one that ever was.
+        the program is the one that ever was. A layer that keeps a RING
+        (`generation.ring_layers`: fewer rows than the slot, position p
+        in row `p mod rows`) reads neither the mask nor `rows`: it
+        derives what it sees from `pos`, and its leaf is read whole by
+        either program.
 
         The pool is the scan's carry, read (attention) and written (one
         row a slot and leaf, `update_kv_cache` under scope `kv_write`)
@@ -732,16 +790,20 @@ class InferenceEngine:
 
     def _state_prefill_fn(self, params, frozen, buffers, ids, length,
                           adapters=None, adapter_rows=None):
-        """`_prefill_fn` for a model that keeps recurrent slot state: it
-        also takes the prompt's real `length` (traced: still one compile
-        a bucket) and seats the state as it stands BEFORE the last
-        prompt token. Neither of `_prefill_fn`'s two liberties is
-        harmless to a state: the padding up to the bucket would be
-        folded in, and the decode block's re-forward of token `length -
-        1` would fold that token in twice. Folding `length - 1` tokens
-        leaves the re-forward to complete the state; a one-token prompt
-        seats zeros. K and V rows are written for the whole bucket as
-        ever, and masked by position as ever."""
+        """`_prefill_fn` for a model that keeps recurrent slot state or
+        a ring (`SlotPool.stands_at_one_position`): it also takes the
+        prompt's real `length` (traced: still one compile a bucket) and
+        seats the state as it stands BEFORE the last prompt token.
+        Neither of `_prefill_fn`'s two liberties is harmless to a state:
+        the padding up to the bucket would be folded in, and the decode
+        block's re-forward of token `length - 1` would fold that token
+        in twice. Folding `length - 1` tokens leaves the re-forward to
+        complete the state; a one-token prompt seats zeros. A ring takes
+        the first harm and not the second: padding written into it
+        replaces rows the window still needs, while the re-forward
+        writes row `(length - 1) mod rows` again with the same values.
+        K and V rows of a full layer are written for the whole bucket
+        as ever, and masked by position as ever."""
         with state_scope(length - 1):
             return self._prefill_fn(params, frozen, buffers, ids, adapters,
                                     adapter_rows)
@@ -1474,14 +1536,16 @@ class InferenceEngine:
                 else self._adapter_rows[slot:slot + 1])
         return (self.adapter_bank.device_arrays(), rows)
 
-    def _needed_rows(self) -> int:
+    def _needed_rows(self):
         """Cache rows this round's attention NEEDS, over active slots
         and layers: the rows a slot has written, and on a window layer
-        at most the window. What the program READS is every slot's
-        first `rows` rows on every layer (`_round_rows`)."""
+        at most the window -> (all of them, those on ring entries).
+        What the program READS is every slot's first `rows` rows on
+        every layer that keeps the slot's length (`_round_rows`), and
+        the whole of every ring."""
         written = self._pos[self._active].astype(np.int64) + 1
-        return int(np.minimum(written[:, None],
-                              self._layer_rows[None, :]).sum())
+        need = np.minimum(written[:, None], self._layer_rows[None, :])
+        return int(need.sum()), int(need[:, self._ring].sum())
 
     def _note_routing(self, round_span, routing):
         """Book a round's routing counts (`[decode_block, 2, expert
@@ -1498,6 +1562,13 @@ class InferenceEngine:
         if _obs.enabled():
             self._m_experts_touched.inc(n)
             self._m_expert_kernel.inc(ran)
+        if routing.shape[1] > 2:
+            # a layer holds a share of its router's experts: the picks
+            # the active slots made, and those that landed here
+            held = int(routing[:, 3].sum())
+            round_span.set(picks=int(routing[:, 2].sum()), picks_held=held)
+            if _obs.enabled():
+                self._m_picks_held.inc(held)
 
     def _note_state(self, round_span):
         """Book what a round does to slot state that is not K and V, on
@@ -1554,9 +1625,13 @@ class InferenceEngine:
                        slots=self.pool.num_slots,
                        real_rows=self.pool.written_rows) as round_span:
             rows = self._round_rows()
+            needed, needed_ring = self._needed_rows()
             round_span.set(
-                needed_rows=self._needed_rows(), rows=rows,
-                read_rows=self.pool.num_slots * rows * len(self._layer_rows))
+                needed_rows=needed, rows=rows,
+                read_rows=self.pool.num_slots * (
+                    rows * self._full_layers + self._ring_rows))
+            if self.pool.ring_layers:
+                round_span.set(needed_rows_window=needed_ring)
             if self.pool.state_layers:
                 self._note_state(round_span)
             try:
@@ -2012,7 +2087,8 @@ class InferenceEngine:
                 self._prefill_row(
                     self.pool, slot, self._prefill_jit,
                     self._params, self._frozen, self._buffers, ids_dev,
-                    *((np.int32(s),) if self.pool.state_layers else ()),
+                    *((np.int32(s),) if self.pool.stands_at_one_position
+                      else ()),
                     *self._adapter_args(slot))
         self.pool.note_written(slot, s)
         self._note_prefill(h, t_pf0)

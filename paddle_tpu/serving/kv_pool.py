@@ -77,7 +77,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..nlp.generation import state_layers as _state_layers
+from ..nlp.generation import (ring_layers as _ring_layers,
+                              state_layers as _state_layers)
 
 _tree = jax.tree_util
 
@@ -183,6 +184,12 @@ class SlotPool:
         self.state_layers = _state_layers(self.rows)
         self.state_bytes = _leaf_bytes(
             [self.rows[i] for i in self.state_layers]) // self.num_slots
+        # the (K, V) entries that are rings of fewer rows than the slot
+        # (a window layer that keeps its window): like a state, a ring
+        # stands at one position, and the engine seats it whole
+        self.ring_layers = _ring_layers(self.rows, self.max_length)
+        self.stands_at_one_position = bool(self.state_layers
+                                           or self.ring_layers)
         # the single-slot programs, enrolled in the program store like
         # the engine's own (a warm replica loads them); seat and copy
         # take the pool donated
@@ -344,6 +351,20 @@ class SlotPool:
         the paged pool overrides with its page-granular figure)."""
         return self.max_length
 
+    def entry_bytes(self) -> dict:
+        """The pool's bytes by entry geometry: `rows x heads x (K width
+        + V width)` of a (K, V) entry, `state` of a state leaf — one
+        key for a model whose every layer keeps the same."""
+        out = collections.Counter()
+        for i, entry in enumerate(self.rows):
+            if i in self.state_layers:
+                out['state'] += _leaf_bytes(entry)
+            else:
+                k, v = entry
+                out[f'{k.shape[1]}x{k.shape[2]}x({k.shape[3]}+'
+                    f'{v.shape[3]})'] += _leaf_bytes(entry)
+        return dict(out)
+
     def stats(self) -> dict:
         return {'num_slots': self.num_slots, 'max_length': self.max_length,
                 'used': self.used_count, 'free': self.free_count,
@@ -353,6 +374,8 @@ class SlotPool:
                 'pool_bytes': self.pool_bytes,
                 'state_layers': len(self.state_layers),
                 'state_bytes': self.state_bytes,
+                'ring_layers': len(self.ring_layers),
+                'entry_bytes': self.entry_bytes(),
                 'row_writes': self._row_writes,
                 'row_copies': self._row_copies,
                 'copied_bytes': self._copied_bytes,
@@ -488,10 +511,13 @@ class PagedSlotPool:
     `PagePoolExhausted` and the engine requeues.
     """
 
-    # K and V only: a state leaf has no rows to page (the engine
-    # refuses such a model before it builds this pool)
+    # K and V of one geometry only: a state leaf has no rows to page,
+    # a ring is a leaf of another length (the engine refuses such a
+    # model before it builds this pool)
     state_layers = ()
     state_bytes = 0
+    ring_layers = ()
+    stands_at_one_position = False
 
     def __init__(self, model, num_slots: int, max_length: int,
                  dtype=None, buckets: Optional[Sequence[int]] = None,

@@ -468,3 +468,49 @@ def test_moe_decode_experts_refuses(changed, match):
     from paddle_tpu.ops.pallas_kernels import moe_decode_experts
     with pytest.raises(ValueError, match=match):
         moe_decode_experts(**_expert_call(**changed), interpret=True)
+
+
+@pytest.mark.parametrize('tokens,k,routed,held,h,f', [
+    (32, 8, 64, 4, 4096, 2048),     # serve-swa-reason: a share of the experts
+    (8, 8, 32, 4, 2048, 1024),      # serve-moe-docs' expert
+    (32, 4, 16, 4, 2048, 1536)],    # serve-hybrid-reason's
+    ids=['4096x2048', '2048x1024', '2048x1536'])
+def test_moe_decode_experts_and_the_loop_agree_on_picks_not_held(
+        tokens, k, routed, held, h, f):
+    """A layer that holds experts 0..held-1 of a router over `routed`
+    hands both schedules its picks in its own numbering, `held` (one
+    past the last) for a pick it does not hold, with weight zero: such a
+    pick is no `hit` of the kernel and no row of the loop's sorted walk.
+    At the real tiles of the three cells that run this kernel
+    (interpreted), against the loop over the same leaves in float32 at
+    `HIGHEST` and against a float64 sum over the held picks."""
+    from paddle_tpu.nlp.afmoe import grouped_experts
+    from paddle_tpu.ops.pallas_kernels import moe_decode_experts
+    rs = np.random.RandomState(tokens + f)
+    sel = np.stack([rs.permutation(routed)[:k] for _ in range(tokens)])
+    mine = sel < held
+    assert mine.any() and not mine.all() and not mine.all(axis=1).any()
+    w = np.where(mine, rs.rand(tokens, k), 0.0).astype('float32')
+    local = np.where(mine, sel, held).astype('int32')
+    x = rs.randn(tokens, h).astype('float32')
+    gw, uw = (jnp.asarray(0.02 * rs.randn(held, h, f), jnp.bfloat16)
+              for _ in range(2))
+    dw = jnp.asarray(0.02 * rs.randn(held, f, h), jnp.bfloat16)
+    args = (jnp.asarray(x), jnp.asarray(local), jnp.asarray(w))
+    got = np.asarray(moe_decode_experts(*args, gw, uw, dw, interpret=True))
+    g32, u32, d32 = (a.astype(jnp.float32) for a in (gw, uw, dw))
+    with jax.default_matmul_precision('highest'):
+        loop = np.asarray(grouped_experts(*args, g32, u32, d32))
+    want = np.zeros((tokens, h))
+    for t, e_, j in zip(*np.nonzero(mine), local[mine]):
+        g = x[t].astype('float64') @ np.asarray(g32[j], 'float64')
+        u = x[t].astype('float64') @ np.asarray(u32[j], 'float64')
+        want[t] += w[t, e_] * ((g / (1 + np.exp(-g)) * u)
+                               @ np.asarray(d32[j], 'float64'))
+    scale = np.abs(want).max()
+    assert scale > 0
+    assert np.abs(got - want).max() < 2e-5 * scale
+    assert np.abs(loop - want).max() < 2e-5 * scale
+    # a row none of whose picks is held gets nothing from either
+    none = ~mine.any(axis=1)
+    assert (got[none] == 0).all() and (loop[none] == 0).all()
