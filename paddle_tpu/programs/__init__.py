@@ -15,13 +15,14 @@ matching entries at startup (Model.fit and ReplicaSet do this
 automatically when the store is persistent).
 """
 from .scopes import SCOPES, scope_path, scope_table
-from .store import (ProgramDeserializeError, ProgramStore, StoredJit,
+from .store import (PoolIO, ProgramDeserializeError, ProgramStore, StoredJit,
                     backend_fingerprint, code_token, compile_cache_dir,
                     configure, describe_statics, ensure_compile_cache,
                     get_store, store_key)
 
 __all__ = [
-    'ProgramDeserializeError', 'ProgramStore', 'SCOPES', 'StoredJit',
+    'PoolIO', 'ProgramDeserializeError', 'ProgramStore', 'SCOPES',
+    'StoredJit',
     'backend_fingerprint', 'code_token', 'compile_cache_dir', 'configure',
     'describe_statics', 'ensure_compile_cache', 'get_store',
     'scope_path', 'scope_table', 'store_key',

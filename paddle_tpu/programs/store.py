@@ -59,7 +59,8 @@ import json
 import os
 import time
 import uuid
-from typing import Any, Callable, Dict, List, Optional
+from typing import (Any, Callable, Dict, List, NamedTuple, Optional,
+                    Sequence, Tuple)
 
 import jax
 
@@ -222,17 +223,136 @@ def _mesh_token() -> str:
     return repr(tuple(zip(mesh.axis_names, mesh.devices.shape)))
 
 
-def store_key(name: str, fn_token: str, statics_token: str, args) -> str:
+def store_key(name: str, fn_token: str, statics_token: str, args,
+              formats=()) -> str:
     """The persistent cache key: sha256 over (name, fn identity, input
     treedef, tensor avals, static leaves, sharding, mesh) — the dispatch
-    cache's key shape, made process-independent. The backend fingerprint
-    is deliberately NOT part of the key: a skewed entry must be FOUND
-    and rejected (with an event) rather than silently missed."""
+    cache's key shape, made process-independent — and, where the
+    program takes a pool in formats of its own (`pool_formats`), those:
+    a program compiled for another layout is another program. The
+    backend fingerprint is deliberately NOT part of the key: a skewed
+    entry must be FOUND and rejected (with an event) rather than
+    silently missed."""
     leaves, treedef = jax.tree_util.tree_flatten(args)
     sig = tuple(_leaf_sig(leaf) for leaf in leaves)
     blob = repr((_MANIFEST_VERSION, name, fn_token, statics_token,
-                 str(treedef), sig, _mesh_token()))
+                 str(treedef), sig, _mesh_token())
+                + ((_io_manifest(formats),) if formats else ()))
     return hashlib.sha256(blob.encode('utf-8')).hexdigest()[:32]
+
+
+# ---------------------------------------------------------------------------
+# a pool held in formats of its own
+# ---------------------------------------------------------------------------
+
+class PoolIO(NamedTuple):
+    """The one property a program declares beside its donation: which
+    argument is a slot pool, where that pool comes back among the
+    results, and the formats the pool's leaves are held in on the
+    device (`serving/kv_pool.py`: a leaf the device's default layout
+    would have the decode block relay at its edges lives in the layout
+    the block reads it in). The program is compiled to take and return
+    those leaves as they lie."""
+    arg: int
+    # path into the result: () where the result IS the pool, (1,) where
+    # it is the second of a tuple, None where it does not come back
+    result: Optional[Tuple[int, ...]]
+    # -> one entry per leaf of that argument, in tree order: a
+    # `jax.experimental.layout.Format` (its layout concrete, or AUTO:
+    # the compiler's choice, read off the `Compiled` afterwards), or
+    # None for the device's default
+    formats: Callable[[], Sequence]
+
+
+def pool_formats(pool_io: Sequence[PoolIO]) -> tuple:
+    """What `pool_io` asks for NOW, as `((arg, result, formats), ...)`
+    over the pools that hold a leaf in a format of its own — () where
+    none does, and the program is then compiled, keyed and persisted as
+    one that never declared a pool."""
+    asked = ((io.arg, io.result, tuple(io.formats())) for io in pool_io)
+    return tuple(a for a in asked if any(f is not None for f in a[2]))
+
+
+def _layout_manifest(fmt):
+    """A leaf's format as the key and the manifest carry it: None (the
+    default), 'auto', or the layout's own numbers."""
+    from jax.experimental.layout import Layout
+    if fmt is None:
+        return None
+    if not isinstance(fmt.layout, Layout):
+        return 'auto'
+    lay = fmt.layout
+    return {'major_to_minor': list(lay.major_to_minor),
+            'tiling': [list(t) for t in lay.tiling or ()]}
+
+
+def _io_manifest(formats: tuple) -> list:
+    return [{'arg': arg,
+             'result': None if result is None else list(result),
+             'formats': [_layout_manifest(f) for f in fmts]}
+            for arg, result, fmts in formats]
+
+
+def _io_from_manifest(entries) -> tuple:
+    """`_io_manifest`'s inverse, on this process's first device."""
+    from jax.experimental.layout import Format, Layout
+    from jax.sharding import SingleDeviceSharding
+    here = SingleDeviceSharding(jax.devices()[0])
+
+    def fmt(m):
+        if m is None:
+            return None
+        if m == 'auto':
+            return Format(Layout.AUTO, here)
+        return Format(Layout(tuple(m['major_to_minor']),
+                             tuple(tuple(t) for t in m['tiling'])), here)
+
+    return tuple(
+        (int(e['arg']),
+         None if e['result'] is None else tuple(e['result']),
+         tuple(fmt(m) for m in e['formats']))
+        for e in entries or ())
+
+
+def _io_shardings(fn, args, formats: tuple):
+    """-> (`args`, `in_shardings`, `out_shardings`) for `jax.jit(fn)`:
+    the pools' formats where `formats` puts them, None (the device's
+    default, nothing asked) everywhere else. A format goes to the
+    device its argument lies on — a described one, where the arguments
+    are shapes given a sharding — and a pool argument goes as its
+    shapes: the program is compiled for the formats whatever layout the
+    arrays at hand lie in. The results' structure is read from a trace,
+    which the compile that follows finds cached."""
+    from jax.experimental.layout import Format
+    tree = jax.tree_util
+    args, ins = list(args), [None] * len(args)
+    for arg, _, fmts in formats:
+        leaves, treedef = tree.tree_flatten(args[arg])
+        if len(leaves) != len(fmts):
+            raise ValueError(
+                f'argument {arg} has {len(leaves)} leaves and '
+                f'{len(fmts)} formats')
+        there = [getattr(leaf, 'sharding', None) for leaf in leaves]
+        args[arg] = treedef.unflatten(
+            [jax.ShapeDtypeStruct(leaf.shape, leaf.dtype, sharding=sh)
+             for leaf, sh in zip(leaves, there)])
+        ins[arg] = treedef.unflatten(
+            [None if f is None else Format(f.layout, sh or f.sharding)
+             for f, sh in zip(fmts, there)])
+    outs = tree.tree_map(lambda _: None,
+                         jax.jit(fn).trace(*args).out_info)
+    for arg, result, _ in formats:
+        if result is not None:
+            outs = _set_at(outs, result, ins[arg])
+    return args, tuple(ins), outs
+
+
+def _set_at(tree, path, value):
+    if not path:
+        return value
+    items = list(tree)
+    items[path[0]] = _set_at(items[path[0]], path[1:], value)
+    return type(tree)(items)
 
 
 # ---------------------------------------------------------------------------
@@ -262,16 +382,62 @@ def _export_program(jitted, args):
         _jex.export(jitted, platforms=tuple(sorted(plats))), *abstract)
 
 
-def _compile_program(fn, args, donate_argnums=()):
+def _compile_program(fn, args, donate_argnums=(), formats=()):
     """THE compile site of the store: every route to an executable ends
-    here, so a program's declared `donate_argnums` is applied wherever
-    that program is compiled. `fn` is a jitted callable — it carries
-    the donation it was declared with — or a plain one (an exported
-    program's `call`), jitted here with `donate_argnums`; `args` are
-    the arguments or their abstract shapes. Returns the `Compiled`."""
-    if not hasattr(fn, 'lower'):
-        fn = jax.jit(fn, donate_argnums=tuple(donate_argnums))
-    return _with_stack_room(lambda: fn.lower(*args).compile())
+    here, so a program's declared `donate_argnums` — and the `formats`
+    its pools are held in (`pool_formats`) — are applied wherever that
+    program is compiled. `fn` is a jitted callable — it carries the
+    donation it was declared with — or a plain one (an exported
+    program's `call`), jitted here with `donate_argnums`; with
+    `formats` what a jitted one wraps is jitted anew, to take and
+    return those leaves as they lie. `args` are the arguments or their
+    abstract shapes. Returns the `Compiled`."""
+    def compile_():
+        if formats:
+            plain = getattr(fn, '__wrapped__', fn)
+            at, ins, outs = _io_shardings(plain, args, formats)
+            return _LayoutsOnTrust(jax.jit(
+                plain, donate_argnums=tuple(donate_argnums),
+                in_shardings=ins, out_shardings=outs).lower(*at).compile())
+        jitted = fn if hasattr(fn, 'lower') else jax.jit(
+            fn, donate_argnums=tuple(donate_argnums))
+        return jitted.lower(*args).compile()
+    return _with_stack_room(compile_)
+
+
+class _LayoutsOnTrust:
+    """A program compiled to layouts of its own, called without jax's
+    check of the layouts its arguments SAY they lie in.
+
+    Why (jax 0.9.0, XLA:CPU and the TPU alike; PERF.md 7m): an
+    executable that jax's persistent compile cache hands back writes its
+    results in the layouts it was compiled to, but the arrays it returns
+    report the device's DEFAULT layout. jax compares what an argument
+    reports with what the program takes — so in a process that loads its
+    programs, the pool a decode block has just returned would be refused
+    by the next one. Here the program's expectations of its arguments'
+    layouts are taken out of jax's sight (three fields of the loaded
+    executable, before its first call builds on them); the runtime still
+    refuses a buffer that does not lie as the program reads it. What the
+    program takes and returns is read once, from the compiler
+    (`input_formats`, `output_formats`); the rest is the `Compiled`'s."""
+
+    def __init__(self, compiled):
+        self.input_formats = compiled.input_formats
+        self.output_formats = compiled.output_formats
+        exe = compiled._executable
+        own = [lay is not None for lay in exe._dispatch_in_layouts]
+        exe._xla_in_layouts = [None if o else lay for o, lay in
+                               zip(own, exe._xla_in_layouts)]
+        exe._dispatch_in_layouts = [None] * len(own)
+        exe._unloaded_executable.dispatch_in_layouts = [None] * len(own)
+        self._compiled = compiled
+
+    def __call__(self, *args):
+        return self._compiled(*args)
+
+    def __getattr__(self, name):
+        return getattr(self._compiled, name)
 
 
 def _roomy_frame(slots=17000):
@@ -302,7 +468,7 @@ def _roomy_frame(slots=17000):
 _with_stack_room = _roomy_frame()
 
 
-def _compile_exported(exported, donate_argnums=()):
+def _compile_exported(exported, donate_argnums=(), formats=()):
     """AOT-compile an exported program from its own recorded in_avals.
 
     No Python tracing of the original function; the backend compile of
@@ -312,10 +478,11 @@ def _compile_exported(exported, donate_argnums=()):
     specs = [jax.ShapeDtypeStruct(a.shape, a.dtype)
              for a in exported.in_avals]
     args, _ = jax.tree_util.tree_unflatten(exported.in_tree, specs)
-    return _compile_program(exported.call, args, donate_argnums)
+    return _compile_program(exported.call, args, donate_argnums, formats)
 
 
-def _load_stablehlo(payload: bytes, path: str, donate_argnums=()):
+def _load_stablehlo(payload: bytes, path: str, donate_argnums=(),
+                    formats=()):
     """Deserialize exported StableHLO and AOT-compile it — the warm
     half of the restart path."""
     from jax import export as _jex
@@ -325,7 +492,7 @@ def _load_stablehlo(payload: bytes, path: str, donate_argnums=()):
         raise ProgramDeserializeError(
             path, f'{type(exc).__name__}: {exc}') from exc
     try:
-        return _compile_exported(exported, donate_argnums)
+        return _compile_exported(exported, donate_argnums, formats)
     except Exception as exc:
         raise ProgramDeserializeError(
             path, f'aot compile of deserialized program failed: '
@@ -480,7 +647,7 @@ class ProgramStore:
                 os.path.join(d, f'{key}.json'))
 
     def _save_disk(self, key: str, name: str, kind: str, payload: bytes,
-                   donate_argnums=()) -> Optional[str]:
+                   donate_argnums=(), formats=()) -> Optional[str]:
         """Persist one exported program: payload first, manifest second,
         both through atomic renames (a crash between the two leaves a
         manifest-less payload, which the load path treats as absent; a
@@ -507,6 +674,8 @@ class ProgramStore:
                 'sha256': hashlib.sha256(payload).hexdigest(),
                 'size': len(payload),
                 'donate_argnums': list(donate_argnums),
+                **({'pool_formats': _io_manifest(formats)}
+                   if formats else {}),
                 'fingerprint': self._fingerprint,
                 'created': time.time(),
             }
@@ -528,10 +697,13 @@ class ProgramStore:
                       error=type(exc).__name__)
             return None
 
-    def _load_disk(self, key: str):
+    def _load_disk(self, key: str, formats=None):
         """Integrity-verified load of one persisted entry. Returns a
         `_StoreEntry` or None; NEVER raises. Every rejection emits
-        `program_cache_reject` with its reason."""
+        `program_cache_reject` with its reason. An entry is compiled to
+        the pool formats its manifest records; `formats` is what the
+        caller's pools are held in (None: nobody asks, `preload`), and
+        an entry recorded for others is rejected."""
         d = self.directory
         if d is None:
             return None
@@ -563,9 +735,14 @@ class ProgramStore:
             return None
         fmt = manifest.get('format', '')
         donate = tuple(manifest.get('donate_argnums') or ())
+        recorded = manifest.get('pool_formats') or []
+        if formats is not None and recorded != _io_manifest(formats):
+            self._note_reject(name, man_path, 'pool_formats')
+            return None
         try:
             if fmt == 'stablehlo':
-                call = _load_stablehlo(payload, bin_path, donate)
+                call = _load_stablehlo(payload, bin_path, donate,
+                                       _io_from_manifest(recorded))
             else:
                 self._note_reject(name, bin_path, 'format', fmt)
                 return None
@@ -582,7 +759,7 @@ class ProgramStore:
     # -- the acquisition path ------------------------------------------------
     def acquire(self, key: str, name: str, kind: str,
                 record: _cost.ProgramRecord, jitted, args,
-                persist: bool = True, donate_argnums=()):
+                persist: bool = True, donate_argnums=(), formats=()):
         """Resolve one program key to an executable: memory tier, then
         the integrity-verified disk tier, then a fresh AOT compile of
         `jitted` at `args`.
@@ -594,7 +771,9 @@ class ProgramStore:
         the warm compile from disk. Export failures fall back to the
         plain direct compile (memory tier only, note='aot_noexport').
         Every route compiles in `_compile_program`, with
-        `donate_argnums` applied. Returns the resolved `_StoreEntry`,
+        `donate_argnums` and the pools' `formats` applied (a program
+        that cannot be exported and compiled to them goes the
+        'aot_noexport' way too). Returns the resolved `_StoreEntry`,
         or None when no AOT path works at all — callers fall back to
         their plain jitted call."""
         with self._lock:
@@ -604,7 +783,7 @@ class ProgramStore:
             if ent.source == 'disk':
                 record.note = record.note or f'loaded:{ent.format}'
             return ent
-        ent = self._load_disk(key)
+        ent = self._load_disk(key, formats)
         if ent is not None:
             t0 = time.perf_counter()
             _cost._read_analysis(ent.callable, record)
@@ -624,14 +803,16 @@ class ProgramStore:
             try:
                 exported = _export_program(jitted, args)
                 payload = exported.serialize()
-                compiled = _compile_exported(exported, donate_argnums)
+                compiled = _compile_exported(exported, donate_argnums,
+                                             formats)
                 fmt = 'stablehlo'
             except Exception as exc:
                 _obs.emit('program_store_persist_skipped', program=name,
                           error=type(exc).__name__)
         if compiled is None:
             try:
-                compiled = _compile_program(jitted, args, donate_argnums)
+                compiled = _compile_program(jitted, args, donate_argnums,
+                                            formats)
             except Exception:  # paddle-lint: disable=swallowed-exception -- no AOT path for this callable; caller serves the plain jitted call which surfaces any real error
                 return None   # no AOT path; caller serves the plain call
             if persisting:
@@ -648,7 +829,7 @@ class ProgramStore:
             self._mem[key] = ent
         if payload is not None:
             self._save_disk(key, name, kind, payload,
-                            donate_argnums=donate_argnums)
+                            donate_argnums=donate_argnums, formats=formats)
         return ent
 
     # -- warm restart --------------------------------------------------------
@@ -724,7 +905,8 @@ class ProgramStore:
     def wrap_jit(self, fn, name: Optional[str] = None,
                  name_fn: Optional[Callable] = None, kind: str = 'jit',
                  statics: Any = None, persist: bool = True,
-                 donate_argnums=()) -> 'StoredJit':
+                 donate_argnums=(),
+                 pool_io: Sequence[PoolIO] = ()) -> 'StoredJit':
         """Enroll a jax.jit'd callable: AOT compile through the store
         (memory -> disk -> compile), cost attribution folded into the
         catalog. `statics` names the compile-time constants baked into
@@ -732,10 +914,13 @@ class ProgramStore:
         hyperparams, model config, engine geometry) — part of the
         persistent key. `donate_argnums` is the program's declared
         donation: applied wherever the program is compiled, and
-        recorded in the manifest for the warm process."""
+        recorded in the manifest for the warm process. `pool_io`
+        declares which arguments and results are slot pools (`PoolIO`):
+        the program takes and returns their leaves in the formats the
+        pools hold them in, on every route likewise."""
         return StoredJit(self, fn, name=name, name_fn=name_fn, kind=kind,
                          statics=statics, persist=persist,
-                         donate_argnums=donate_argnums)
+                         donate_argnums=donate_argnums, pool_io=pool_io)
 
     # -- bookkeeping / reporting --------------------------------------------
     def program_names(self) -> List[str]:
@@ -845,7 +1030,7 @@ class StoredJit:
     def __init__(self, store: ProgramStore, fn, name: Optional[str] = None,
                  name_fn: Optional[Callable] = None, kind: str = 'jit',
                  statics: Any = None, persist: bool = True,
-                 donate_argnums=()):
+                 donate_argnums=(), pool_io: Sequence[PoolIO] = ()):
         if name is None and name_fn is None:
             raise ValueError('StoredJit needs name= or name_fn=')
         self._store = store
@@ -854,6 +1039,7 @@ class StoredJit:
         self._kind = kind
         self._persist = persist
         self._donate = tuple(donate_argnums)
+        self._pool_io = tuple(pool_io)
         # callers pass the RAW function plus its donate_argnums and the
         # wrapper jits it here. Already-jitted callables are still
         # accepted (their direct-route donation is whatever they baked
@@ -882,6 +1068,10 @@ class StoredJit:
             else:
                 sig.append(('py', type(leaf)))
         key = (treedef, tuple(sig))
+        if self._pool_io:
+            # a pool that has taken formats of its own since is another
+            # program's argument: the key says which this one was built for
+            key += tuple(tuple(io.formats()) for io in self._pool_io)
         hash(key)
         return key
 
@@ -898,9 +1088,10 @@ class StoredJit:
         if key is not None:
             ent = None
             if hasattr(self._fn, 'lower'):   # an opaque one has no AOT path
+                formats = pool_formats(self._pool_io)
                 try:
                     skey = store_key(name, self._fn_token,
-                                     self._statics_token, args)
+                                     self._statics_token, args, formats)
                 except Exception:
                     # unkeyable statics: this program is served by the
                     # plain jitted call — make "silently" false
@@ -909,7 +1100,7 @@ class StoredJit:
                     ent = self._store.acquire(
                         skey, name, self._kind, record, self._fn, args,
                         persist=self._persist,
-                        donate_argnums=self._donate)
+                        donate_argnums=self._donate, formats=formats)
             if ent is not None:
                 call = ent.callable
             else:

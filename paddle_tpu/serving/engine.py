@@ -494,6 +494,10 @@ class InferenceEngine:
         half_statics = dict(engine_statics, rows=self._half_rows)
         self._decode_half_jit = None
         self._decode_resolved = False
+        # nothing to settle where no leaf asks (every backend but a TPU;
+        # a paged pool, whose pages are another geometry)
+        self._pool_layout_settled = self._paged or not any(
+            fmt is not None for fmt in self.pool.own_layout)
         if self._paged:
             # page buffers (and scales) are donated exactly like the
             # row pool: decode/spec alias the pool in place;
@@ -521,16 +525,23 @@ class InferenceEngine:
                                      f'{args[6].shape[1]}',
                 kind='serving', statics=engine_statics)
         else:
+            # the pool is argument 3 and the second result of both. The
+            # whole-length program CHOOSES the layout of the leaves that
+            # ask for one of their own (`SlotPool.own_layout`: compiled
+            # with AUTO there, `_settle_pool_layout`); every other
+            # program is compiled to what the pool then holds
             self._decode_jit = store.wrap_jit(
                 self._decode_block_fn, name='serving.decode_block',
                 kind='serving', statics=engine_statics,
-                donate_argnums=(3,))
+                donate_argnums=(3,),
+                pool_io=self.pool.pool_io(3, (1,), chooses=True))
             if self._half_rows:
                 self._decode_half_jit = store.wrap_jit(
                     self._decode_block_half_fn,
                     name=f'serving.decode_block_r{self._half_rows}',
                     kind='serving', statics=half_statics,
-                    donate_argnums=(3,))
+                    donate_argnums=(3,),
+                    pool_io=self.pool.pool_io(3, (1,)))
             self._prefill_jit = store.wrap_jit(   # 1 trace per bucket
                 self._state_prefill_fn if self.pool.stands_at_one_position
                 else self._prefill_fn,
@@ -564,7 +575,8 @@ class InferenceEngine:
                     self._spec_decode_fn,
                     name=f'serving.spec_decode_k{self.spec_k}',
                     kind='serving', statics=spec_statics,
-                    donate_argnums=(3, 7))
+                    donate_argnums=(3, 7),
+                    pool_io=self.pool.pool_io(3, (2,)))
             self._draft_prefill_jit = store.wrap_jit(
                 self._draft_prefill_fn,
                 name_fn=lambda args: f'serving.draft_prefill_'
@@ -672,8 +684,14 @@ class InferenceEngine:
             'bytes of slot state that is not K and V (a conv layer\'s '
             'last inputs) read and written by decode sub-steps: active '
             'slots x state layers x leaf bytes x 2')
+        self._m_own_layout = reg.gauge(
+            'paddle_serving_pool_own_layout_leaves',
+            'KV pool leaves held in a layout of their own, the one the '
+            'decode block reads them in (a head size that is not whole '
+            'lanes, on a TPU); 0 where every leaf keeps the default')
         if _obs.enabled():
             self._m_slots.set(self.pool.num_slots)
+            self._m_own_layout.set(0)
 
     # ------------------------------------------------------------------
     # compiled programs
@@ -1403,6 +1421,8 @@ class InferenceEngine:
         the number of requests that progressed."""
         with _obs.span('serving.step'):
             self._check_drain()
+            if not self._pool_layout_settled:
+                self._settle_pool_layout()
             with _obs.span('serving.admit') as sp:
                 sp.set(admitted=self._admit())
             self._advance_prefills()
@@ -1477,6 +1497,31 @@ class InferenceEngine:
                 self._steps[slot] += (1 if counts is not None else c)
                 # stranded-capacity accounting: rows actually written
                 self.pool.note_written(slot, self._pos[slot] + 1)
+
+    def _settle_pool_layout(self):
+        """Before the first program touches the pool: compile (or load)
+        the whole-length decode block, which asks the compiler for the
+        layout of every leaf that wants one of its own, and have the
+        pool hold those leaves as that program takes and returns them
+        (`SlotPool.adopt_formats`). The first step reaches this, so a
+        server that has stepped once never relays a leaf at a block's
+        edge nor compiles for it again. A decode block that could not
+        be compiled ahead of time takes the default layout, and the
+        pool keeps it."""
+        _, program = self._decode_jit.resolve(*self._decode_args())
+        if hasattr(program, 'input_formats'):
+            self.pool.adopt_formats(program.input_formats[0][3],
+                                    program.output_formats[1])
+        self._pool_layout_settled = True
+        if _obs.enabled():
+            self._m_own_layout.set(self.pool.own_layout_leaves)
+
+    def _decode_args(self) -> tuple:
+        """The row pool's decode programs' arguments, as they stand."""
+        return (self._params, self._frozen, self._buffers, self.pool.cache,
+                self._tok, self._pos, self._steps, self._active, self._temp,
+                self._topk, self._topp, self._greedy, self._keys,
+                *self._adapter_args())
 
     def _recover_pool(self):
         """A DONATED program (decode, spec, seat, copy) failed mid-call:
@@ -1636,11 +1681,11 @@ class InferenceEngine:
                 self._note_state(round_span)
             try:
                 with _obs.span('serving.decode_dispatch'):
-                    state = (self._tok, self._pos, self._steps,
-                             self._active, self._temp, self._topk,
-                             self._topp, self._greedy, self._keys,
-                             *self._adapter_args())
                     if self._paged:
+                        state = (self._tok, self._pos, self._steps,
+                                 self._active, self._temp, self._topk,
+                                 self._topp, self._greedy, self._keys,
+                                 *self._adapter_args())
                         pages, scales = self.pool.device_state()
                         table = call_with_retry(
                             _to_device, self.pool.page_table,
@@ -1651,8 +1696,7 @@ class InferenceEngine:
                             self._decode_program(rows, args)(*args)
                         self.pool.set_device_state(new_pages, new_scales)
                     else:
-                        args = (self._params, self._frozen, self._buffers,
-                                self.pool.cache, *state)
+                        args = self._decode_args()
                         toks_dev, new_pool, *touched = \
                             self._decode_program(rows, args)(*args)
                         self.pool.cache = new_pool

@@ -40,6 +40,24 @@ treats it as it treats a failed donated decode (`reset_rows`, then the
 error leaves `step()`). Their jitted names hold `prefill`: seating is
 part of what a prefill costs, and the traced device time books it there.
 
+Layout (PR 33): the device's default layout for a leaf whose head size
+is not a whole number of 128 lanes is a compact one with the ROWS minor,
+and the decode block's scan reads heads x head size in the minor tile —
+so the block relaid such a leaf on its way in and again on its way out,
+every round (lfm2's K and V, 64 wide; mimo's K, 192 wide: 12-14% of a
+round on the v5e). Such a leaf asks for a layout of its own
+(`wants_own_layout`: the leaf's shape and the backend, nothing else);
+which one is the compiler's answer: the engine compiles the whole-length
+decode block with `Layout.AUTO` on those leaves, the pool reads the
+chosen formats off the compiled program (`adopt_formats`), holds its
+leaves in them from then on, and every other program that takes or
+returns the pool is compiled to them (`programs.PoolIO`). A prefill
+returns its ROW in the default layout; the seat program relays that one
+slot as it writes it. On any other backend nothing is asked and every
+program is the one it was. What a leaf SAYS of its layout
+(`leaf.format`) is not to be read once programs are loaded from jax's
+compile cache (`programs.store._LayoutsOnTrust`): `formats` is the book.
+
 Prefill shapes are length-bucketed: a prompt of length s runs at the
 smallest bucket >= s (right-padded; pad KV lands above the live
 position, where the slot-causal decode mask hides it until the slot's
@@ -123,6 +141,45 @@ def _leaf_bytes(tree) -> int:
                for leaf in _tree.tree_leaves(tree))
 
 
+_LANES = 128
+
+
+def wants_own_layout(shape, backend: str) -> bool:
+    """THE rule, from the leaf alone: a K or V leaf `[slot, row, H_kv,
+    D]` whose head size is not a whole number of lanes, on a TPU. There
+    the device's default layout has the rows minor and the decode
+    block's scan does not, so the block would relay the whole leaf at
+    both its edges; a leaf of whole lanes arrives as the scan reads it,
+    however few its heads (a 4 x 128 leaf has a tile of 4 sublanes)."""
+    return (backend == 'tpu' and len(shape) == 4
+            and shape[-1] % _LANES != 0)
+
+
+def format_bytes(spec, fmt) -> int:
+    """Bytes of a leaf of `spec` (shape, dtype) on the device in format
+    `fmt`, padding included: the layout's first tile covers the minor-
+    most dimensions, each rounded up to a multiple of it. None is the
+    default layout, booked at the leaf's logical size."""
+    dims = list(spec.shape)
+    tiling = fmt.layout.tiling if fmt is not None else ()
+    if tiling:
+        tile = tiling[0]
+        minor = fmt.layout.major_to_minor[len(dims) - len(tile):]
+        for d, t in zip(minor, tile):
+            dims[d] = -(-dims[d] // t) * t
+    return int(np.prod(dims, dtype=np.int64)) * np.dtype(spec.dtype).itemsize
+
+
+def layout_name(fmt) -> str:
+    """`default`, or the layout's dimensions from major to minor and
+    its tiling: `0,1,2,3:T(8,128)`."""
+    if fmt is None:
+        return 'default'
+    lay = fmt.layout
+    return ','.join(map(str, lay.major_to_minor)) + ':' + ''.join(
+        f'T({",".join(map(str, t))})' for t in lay.tiling or ())
+
+
 def _normalize_buckets(buckets, max_length: int) -> Tuple[int, ...]:
     out = tuple(sorted(set(
         int(b) for b in (buckets or default_buckets(max_length))
@@ -190,21 +247,29 @@ class SlotPool:
         self.ring_layers = _ring_layers(self.rows, self.max_length)
         self.stands_at_one_position = bool(self.state_layers
                                            or self.ring_layers)
+        # one entry per leaf of `rows`, in tree order. `own_layout`:
+        # `Format(Layout.AUTO)` where the rule asks for a layout of the
+        # leaf's own, None elsewhere — all None off a TPU. `formats`:
+        # the format each leaf is HELD in, None = the device's default;
+        # all None until `adopt_formats`
+        self.own_layout = self.asks(jax.default_backend())
+        self.formats = [None] * len(self.own_layout)
         # the single-slot programs, enrolled in the program store like
         # the engine's own (a warm replica loads them); seat and copy
-        # take the pool donated
+        # take the pool donated; all three take it, and the two return
+        # it, in `formats`
         from .. import programs as _programs
         store = _programs.get_store()
         self.traces = collections.Counter()     # python-level traces
         self._seat_jit = store.wrap_jit(
             self._prefill_seat_row, name='serving.prefill_seat_row',
-            kind='serving', donate_argnums=(0,))
+            kind='serving', donate_argnums=(0,), pool_io=self.pool_io(0, ()))
         self._copy_jit = store.wrap_jit(
             self._prefill_copy_row, name='serving.prefill_copy_row',
-            kind='serving', donate_argnums=(0,))
+            kind='serving', donate_argnums=(0,), pool_io=self.pool_io(0, ()))
         self._slice_jit = store.wrap_jit(
             self._prefill_slice_row, name='serving.prefill_slice_row',
-            kind='serving')
+            kind='serving', pool_io=self.pool_io(0, None))
         self.buckets = _normalize_buckets(buckets, self.max_length)
         self._free = sorted(range(self.num_slots), reverse=True)
         # per-slot high-water mark of WRITTEN rows (vs the max_length
@@ -323,12 +388,74 @@ class SlotPool:
         self._copied_bytes += self.row_bytes
 
     def reset_rows(self):
-        """Re-zero the pool (fresh buffers). The donation-failure
-        recovery path: if a DONATED program dies mid-call the pool it
-        was given may already be invalidated, so the engine rebuilds it
-        rather than pass dead buffers on."""
-        self.rows = _tree.tree_map(
-            lambda s: jnp.zeros(s.shape, s.dtype), self._pool_spec)
+        """Re-zero the pool (fresh buffers, in `formats`). The
+        donation-failure recovery path: if a DONATED program dies
+        mid-call the pool it was given may already be invalidated, so
+        the engine rebuilds it rather than pass dead buffers on."""
+        self.rows = self._held(_tree.tree_map(
+            lambda s: jnp.zeros(s.shape, s.dtype), self._pool_spec))
+
+    # -- the layout the leaves are held in ---------------------------------
+    def pool_io(self, arg: int, result, chooses: bool = False):
+        """What a program that takes this pool as argument `arg`, and
+        returns it at `result`, declares to `wrap_jit`: the formats the
+        leaves are held in when it is compiled — or, for THE program
+        whose compile `chooses` them, `own_layout`'s AUTO."""
+        from ..programs import PoolIO
+        return (PoolIO(arg, result, (lambda: self.own_layout) if chooses
+                       else (lambda: self.formats)),)
+
+    def _held(self, rows):
+        """`rows` with every leaf in its format: a leaf of the default
+        format as it is, another put into its own, one leaf at a time."""
+        leaves, treedef = _tree.tree_flatten(rows)
+        return treedef.unflatten(
+            [leaf if fmt is None else jax.device_put(leaf, fmt)
+             for leaf, fmt in zip(leaves, self.formats)])
+
+    def asks(self, backend: str, sharding=None) -> list:
+        """`own_layout` as the rule gives it on `backend`: AUTO on the
+        leaf's own device (or `sharding`: a described one) for every K
+        and V leaf that `wants_own_layout`, None for the others and for
+        a state leaf."""
+        from jax.experimental.layout import Format, Layout
+        return [Format(Layout.AUTO, sharding or leaf.sharding)
+                if i not in self.state_layers
+                and wants_own_layout(leaf.shape, backend) else None
+                for i, entry in enumerate(self.rows)
+                for leaf in _tree.tree_leaves(entry)]
+
+    def adopt_formats(self, taken, returned):
+        """Hold the leaves that ask for a layout of their own
+        (`own_layout`) in the formats a compiled program `taken` them
+        in — the whole-length decode block, compiled with AUTO there:
+        its `input_formats` of the pool argument, and `returned` its
+        `output_formats` of the pool result. What the pool holds is put
+        into them here, once; every program that takes the pool
+        afterwards is compiled to them."""
+        self.book_formats(taken, returned)
+        self.rows = self._held(self.rows)
+
+    def book_formats(self, taken, returned):
+        """`adopt_formats`' bookkeeping: `formats` of the asking leaves
+        become what the program `taken` them in. The pool is one
+        donated buffer a leaf, so that must be what it `returned`."""
+        taken, returned = (
+            _tree.tree_leaves(fmts, is_leaf=lambda f: f is None)
+            for fmts in (taken, returned))
+        for i, asked in enumerate(self.own_layout):
+            if asked is None:
+                continue
+            if taken[i] != returned[i]:
+                raise ValueError(
+                    f'pool leaf {i} is taken as {taken[i]} and returned '
+                    f'as {returned[i]}: one donated buffer cannot be both')
+            self.formats[i] = taken[i]
+
+    @property
+    def own_layout_leaves(self) -> int:
+        """Leaves held in a layout of their own."""
+        return sum(fmt is not None for fmt in self.formats)
 
     def _capacity_stats(self) -> dict:
         """Allocated vs written rows over USED slots: the row pool
@@ -351,19 +478,41 @@ class SlotPool:
         the paged pool overrides with its page-granular figure)."""
         return self.max_length
 
-    def entry_bytes(self) -> dict:
-        """The pool's bytes by entry geometry: `rows x heads x (K width
-        + V width)` of a (K, V) entry, `state` of a state leaf — one
-        key for a model whose every layer keeps the same."""
-        out = collections.Counter()
-        for i, entry in enumerate(self.rows):
+    def _entries(self):
+        """-> (geometry, leaves, their formats) per entry of the pool.
+        The geometry is `rows x heads x (K width + V width)` of a
+        (K, V) entry, `state` of a state leaf: one name for layers that
+        keep the same."""
+        formats = iter(self.formats)
+        for i, entry in enumerate(self._pool_spec):
             if i in self.state_layers:
-                out['state'] += _leaf_bytes(entry)
+                yield 'state', (entry,), (next(formats),)
             else:
                 k, v = entry
-                out[f'{k.shape[1]}x{k.shape[2]}x({k.shape[3]}+'
-                    f'{v.shape[3]})'] += _leaf_bytes(entry)
+                yield (f'{k.shape[1]}x{k.shape[2]}x({k.shape[3]}+'
+                       f'{v.shape[3]})', (k, v),
+                       (next(formats), next(formats)))
+
+    def entry_bytes(self) -> dict:
+        """The pool's bytes ON THE DEVICE by entry geometry: every leaf
+        in the format it is held in, the lanes that format pads
+        included (a K leaf 64 wide held in tiles of 128 lanes is twice
+        its logical size)."""
+        out = collections.Counter()
+        for name, leaves, formats in self._entries():
+            out[name] += sum(map(format_bytes, leaves, formats))
         return dict(out)
+
+    def entry_layouts(self) -> dict:
+        """The layout held, by entry geometry: `default`, or the
+        layout of the entry's leaves (`K ..., V ...` where a layer's
+        two differ)."""
+        out = {}
+        for name, _, formats in self._entries():
+            names = [layout_name(f) for f in formats]
+            out[name] = names[0] if len(set(names)) == 1 else \
+                ', '.join(f'{kv} {n}' for kv, n in zip('KV', names))
+        return out
 
     def stats(self) -> dict:
         return {'num_slots': self.num_slots, 'max_length': self.max_length,
@@ -376,6 +525,7 @@ class SlotPool:
                 'state_bytes': self.state_bytes,
                 'ring_layers': len(self.ring_layers),
                 'entry_bytes': self.entry_bytes(),
+                'entry_layouts': self.entry_layouts(),
                 'row_writes': self._row_writes,
                 'row_copies': self._row_copies,
                 'copied_bytes': self._copied_bytes,

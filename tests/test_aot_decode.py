@@ -10,12 +10,21 @@ read the leaf where it lies. Compiled for a described chip at the cells'
 widths and two layers; costs no chip time, says nothing of speed. Marked
 slow (about a minute a case). Since PR 31 also: an expert layer's routed
 experts are ONE Mosaic call, and no `while` is left under `moe/experts`
-(two expert layers; both expert cells, both programs).
+(two expert layers; both expert cells, both programs). Since PR 33: a
+pool leaf whose head size is not whole lanes is held in the layout the
+whole-length block's compile chose for it (AUTO), and ENTRY of neither
+decode program copies a whole leaf any more (serve-hybrid-reason,
+serve-swa-reason: 4 and 6 such copies with the default layouts, at three
+layers); seat, copy and slice compile to the pool's formats; the other
+cells' leaves keep the default, which is what AUTO would give them. Every
+compile here goes through the store's compile site with the formats the
+pool holds, as the engine's do.
 
 The topology is described inside a fixture, never at import: every xdist
 worker imports this file, and only one process may load libtpu — so
 beside `tests/benchmark_suite/test_aot.py` on another worker one of the
 two skips, unless the run lifts the lock."""
+import contextlib
 import re
 
 import pytest
@@ -37,10 +46,11 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _compile_decode_block(cell, one_chip, program='whole'):
-    """-> (optimized HLO text, memory analysis, the pool's leaves) of the
-    decode block (`whole`, or the `half`-length one) of the engine the
-    benchmark builds for `cell`."""
+@contextlib.contextmanager
+def _engine_for_the_chip(cell):
+    """The engine the benchmark builds for `cell` (on the host CPU), with
+    the kernels' gate forced as on a TPU and jax's compile cache off
+    while the test compiles for the described chip."""
     import jax
     from jax.experimental.compilation_cache import compilation_cache as cc
     from benchmarks.kinds import _serve
@@ -53,20 +63,61 @@ def _compile_decode_block(cell, one_chip, program='whole'):
     gate = pallas._pallas_enabled
     try:
         aot.force_kernels_on()      # the engine reads the gate as built
-        eng = _serve.Server(_Run).router.replicas[0].engine
-        jit = eng._decode_jit if program == 'whole' else eng._decode_half_jit
-        compiled = jit.lower(*aot.abstract(
-            (eng._params, eng._frozen, eng._buffers, eng.pool.cache,
-             eng._tok, eng._pos, eng._steps, eng._active, eng._temp,
-             eng._topk, eng._topp, eng._greedy, eng._keys),
-            one_chip)).compile()
+        yield _serve.Server(_Run).router.replicas[0].engine
     finally:
         pallas._pallas_enabled = gate
         pallas.pallas_ce_enabled.cache_clear()
         jax.config.update('jax_enable_compilation_cache', True)
         cc.reset_cache()
+
+
+def _compile(jit, args, one_chip):
+    """`jit` (a `StoredJit`) through the store's compile site at `args`
+    on the described chip: its donation, and its pools in the formats
+    they hold as this is called."""
+    from paddle_tpu.programs import store
+    return store._compile_program(
+        jit._fn, aot.abstract(args, one_chip), jit._donate,
+        store.pool_formats(jit._pool_io))
+
+
+def _pool_leaves(eng):
+    import jax
+    return jax.tree_util.tree_leaves(eng.pool.cache)
+
+
+def _compile_whole(eng, one_chip):
+    """The whole-length decode block, which chooses the layout of the
+    leaves that ask (`own_layout`, as the rule gives it on a TPU); the
+    pool books what it chose, as `adopt_formats` would on a chip."""
+    eng.pool.own_layout = eng.pool.asks('tpu', one_chip)
+    compiled = _compile(eng._decode_jit, eng._decode_args(), one_chip)
+    eng.pool.book_formats(compiled.input_formats[0][3],
+                          compiled.output_formats[1])
+    return compiled
+
+
+def _compile_decode_block(cell, one_chip, program='whole'):
+    """-> (optimized HLO text, memory analysis, the pool's leaves, its
+    bytes on the device) of the decode block (`whole`, or the `half`-
+    length one) of the engine the benchmark builds for `cell`."""
+    with _engine_for_the_chip(cell) as eng:
+        compiled = _compile_whole(eng, one_chip)
+        if program == 'half':
+            compiled = _compile(eng._decode_half_jit, eng._decode_args(),
+                                one_chip)
     return (compiled.as_text(), compiled.memory_analysis(),
-            jax.tree_util.tree_leaves(eng.pool.cache))
+            _pool_leaves(eng), sum(eng.pool.entry_bytes().values()))
+
+
+def whole_leaf_copies(hlo_text, leaves):
+    """The `copy` instructions of ENTRY whose result is a whole pool
+    leaf, told by dtype and shape: a relayout at the block's edge."""
+    entry = hlo_text[hlo_text.index('\nENTRY '):]
+    shapes = {','.join(map(str, v.shape)) for v in leaves if v.ndim == 4}
+    return [ln.split(' = ')[0].strip() for ln in entry.splitlines()
+            if any(re.search(r' = f32\[%s\]\{[^}]*\} copy\(' % sh, ln)
+                   for sh in shapes)]
 
 
 def staged_pool_rows(hlo_text, leaf):
@@ -105,6 +156,24 @@ def test_staged_pool_rows_reads_the_compilers_spelling():
                                            ['%copy-start.1'])
 
 
+def test_whole_leaf_copies_reads_entry_alone():
+    class leaf:
+        shape, ndim = (32, 4096, 8, 64), 4
+    hlo = '''
+%body (p: f32[32,4096,8,64]) -> f32[32,4096,8,64] {
+  %copy.3 = f32[32,4096,8,64]{3,2,1,0:T(8,128)} copy(%p)
+}
+
+ENTRY %main (a: f32[32,4096,8,64], b: f32[32,3,2048]) -> f32[32,4096,8,64] {
+  %copy.135 = f32[32,4096,8,64]{3,2,1,0:T(8,128)} copy(%a)
+  %copy.9 = f32[32,3,2048]{2,0,1:T(8,128)} copy(%b)
+  %copy.140 = f32[32,4096,8,64]{1,3,2,0:T(8,128)} copy(%while.1)
+  %copy.7 = f32[32,2048,8,64]{3,2,1,0:T(8,128)} copy(%slice.2)
+}
+'''
+    assert whole_leaf_copies(hlo, [leaf]) == ['%copy.135', '%copy.140']
+
+
 @pytest.mark.slow
 @pytest.mark.parametrize('program', ['whole', 'half'])
 @pytest.mark.parametrize('workload', ['serve-chat', 'serve-moe-docs'])
@@ -112,7 +181,8 @@ def test_decode_block_writes_its_rows_in_place_on_v5e(workload, program,
                                                       one_chip):
     cell = spec.Spec().cell(workload)
     cell['config']['num_hidden_layers'] = 2
-    text, ma, leaves = _compile_decode_block(cell, one_chip, program)
+    text, ma, leaves, pool_bytes = _compile_decode_block(cell, one_chip,
+                                                         program)
     assert 'decode' in re.search(r'HloModule (\S+)', text).group(1)
     loops = [ln for ln in text.splitlines()
              if re.search(r' while\(', ln) and 'kv_write' in ln]
@@ -133,7 +203,9 @@ def test_decode_block_writes_its_rows_in_place_on_v5e(workload, program,
         rows = leaves[0].shape[1] // 2
         assert re.search(r'f32\[%d,%d,%d,%d\]\S* slice\(' % (
             leaves[0].shape[0], rows, *leaves[0].shape[2:]), text)
-    pool_bytes = sum(v.size * v.dtype.itemsize for v in leaves)
+    # whole lanes: the default layout pads nothing, so the bytes on the
+    # device are the logical ones
+    assert pool_bytes == sum(v.size * v.dtype.itemsize for v in leaves)
     assert ma.alias_size_in_bytes == pool_bytes
 
 
@@ -150,7 +222,8 @@ def test_an_expert_layer_is_one_kernel_on_v5e(workload, program, one_chip):
     cfg = cell['config']
     cfg['num_hidden_layers'] = 3
     cfg['layer_types'] = cfg['layer_types'][:3]
-    text, ma, leaves = _compile_decode_block(cell, one_chip, program)
+    text, ma, leaves, pool_bytes = _compile_decode_block(cell, one_chip,
+                                                         program)
     assert 'decode' in re.search(r'HloModule (\S+)', text).group(1)
     experts = [ln for ln in text.splitlines() if 'moe/experts' in ln]
     kernels = [ln for ln in experts if 'tpu_custom_call' in ln]
@@ -163,5 +236,114 @@ def test_an_expert_layer_is_one_kernel_on_v5e(workload, program, one_chip):
     assert len(out) <= len(rows) // 2, f'more than a leaf a layer: {out}'
     if program == 'half':
         assert not into and not out, (into, out)
-    pool_bytes = sum(v.size * v.dtype.itemsize for v in leaves)
+    # the pool's bytes ON THE DEVICE: lfm2's K and V, 64 wide, are held
+    # in tiles of 128 lanes, twice their logical size
+    kv, state = (sum(v.size * v.dtype.itemsize for v in leaves
+                     if (v.ndim == 4) is is_kv) for is_kv in (True, False))
+    assert pool_bytes == state + kv * (
+        2 if workload == 'serve-hybrid-reason' else 1)
     assert ma.alias_size_in_bytes == pool_bytes
+
+
+def _cut_to_three_layers(cfg):
+    """A dense layer and two expert layers, every kind of cache entry
+    the configuration has: lfm2 conv, attention, conv; mimo a full
+    layer, a ring, a full layer."""
+    cfg['num_hidden_layers'] = 3
+    if 'layer_types' in cfg:
+        cfg['layer_types'] = cfg['layer_types'][:3]
+    if 'hybrid_layer_pattern' in cfg:
+        cfg['hybrid_layer_pattern'] = [0, 1, 0]
+        cfg['moe_layer_freq'] = [0, 1, 1]
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize('workload, relaid, own', [
+    ('serve-hybrid-reason', 4, 2), ('serve-swa-reason', 6, 3)])
+def test_no_pool_leaf_is_relaid_at_the_blocks_edges_on_v5e(
+        workload, relaid, own, one_chip):
+    """With the device's default layouts ENTRY of the decode block
+    copies every leaf whose head size is not whole lanes on its way in
+    and again on its way out (`relaid` copies at three layers: what the
+    parent of PR 33 ran). Held in the layout the whole-length block's
+    compile chooses, no leaf is copied by either decode program; the
+    pool is aliased at its bytes on the device; seat, copy and slice
+    take and return it in those formats, seat and copy in place."""
+    import jax
+    import numpy as np
+    cell = spec.Spec().cell(workload)
+    _cut_to_three_layers(cell['config'])
+    with _engine_for_the_chip(cell) as eng:
+        pool, args = eng.pool, eng._decode_args()
+        leaves = _pool_leaves(eng)
+        # nothing asked, as on the parent: the copies are there to see
+        assert pool.own_layout == [None] * len(leaves)
+        for jit in (eng._decode_jit, eng._decode_half_jit):
+            text = _compile(jit, args, one_chip).as_text()
+            assert len(whole_leaf_copies(text, leaves)) == relaid
+        whole = _compile_whole(eng, one_chip)
+        assert pool.own_layout_leaves == own
+        asked = [f is not None for f in pool.own_layout]
+        assert asked == [v.ndim == 4 and v.shape[-1] % 128 != 0
+                         for v in leaves]
+        half = _compile(eng._decode_half_jit, args, one_chip)
+        device_bytes = sum(pool.entry_bytes().values())
+        assert device_bytes > pool.pool_bytes       # lanes padded
+        for compiled in (whole, half):
+            assert not whole_leaf_copies(compiled.as_text(), leaves)
+            assert compiled.memory_analysis().alias_size_in_bytes \
+                == device_bytes
+            taken = jax.tree_util.tree_leaves(compiled.input_formats[0][3])
+            back = jax.tree_util.tree_leaves(compiled.output_formats[1])
+            for fmt, t, b in zip(pool.formats, taken, back):
+                assert t == b and (fmt is None or fmt == t)
+        # every leaf is held with the head size in the lanes
+        for fmt in pool.formats:
+            assert fmt is None or fmt.layout.major_to_minor == (0, 1, 2, 3)
+        # a leaf the rule leaves out was taken in the default layout
+        # before and after
+        row = pool.row_spec
+        slot = np.int32(1)
+        for jit, at, in_place in (
+                (pool._seat_jit, (pool.rows, row, slot), True),
+                (pool._copy_jit, (pool.rows, slot, slot), True),
+                (pool._slice_jit, (pool.rows, slot), False)):
+            compiled = _compile(jit, at, one_chip)
+            taken = jax.tree_util.tree_leaves(compiled.input_formats[0][0])
+            assert [t for t, f in zip(taken, pool.formats)
+                    if f is not None] \
+                == [f for f in pool.formats if f is not None]
+            if in_place:
+                assert jax.tree_util.tree_leaves(
+                    compiled.output_formats) == taken
+                assert compiled.memory_analysis().alias_size_in_bytes \
+                    == device_bytes
+                assert not whole_leaf_copies(compiled.as_text(), leaves)
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize('workload', ['serve-chat', 'serve-docs',
+                                      'serve-moe-docs'])
+def test_the_rule_leaves_out_what_the_compiler_would_not_move(workload,
+                                                              one_chip):
+    """A leaf of whole lanes asks for nothing, and AUTO would give it
+    the layout it has: the default, heads x head size in the minor tile
+    (a tile of 4 sublanes for trinity-mini's 4 heads). No whole-leaf
+    copy in ENTRY either way."""
+    import jax
+    from jax.experimental.layout import Format, Layout
+    cell = spec.Spec().cell(workload)
+    cell['config']['num_hidden_layers'] = 2
+    with _engine_for_the_chip(cell) as eng:
+        pool, args = eng.pool, eng._decode_args()
+        leaves = _pool_leaves(eng)
+        assert pool.asks('tpu', one_chip) == [None] * len(leaves)
+        default = _compile(eng._decode_jit, args, one_chip)
+        assert not whole_leaf_copies(default.as_text(), leaves)
+        pool.own_layout = [Format(Layout.AUTO, one_chip)] * len(leaves)
+        auto = _compile(eng._decode_jit, args, one_chip)
+        had, chosen = (jax.tree_util.tree_leaves(c.input_formats[0][3])
+                       for c in (default, auto))
+        assert had == chosen
+        assert all(f.layout.major_to_minor == (0, 1, 2, 3) for f in had)
+        assert not whole_leaf_copies(auto.as_text(), leaves)

@@ -817,9 +817,10 @@ def test_every_route_compiles_through_one_function(route, open_store,
     calls = []
     real = store_mod._compile_program
 
-    def counting(fn, args, donate_argnums=()):
+    def counting(fn, args, donate_argnums=(), formats=()):
+        assert not formats      # no pool declared: nothing asked
         calls.append(tuple(donate_argnums))
-        return real(fn, args, donate_argnums)
+        return real(fn, args, donate_argnums, formats)
 
     monkeypatch.setattr(store_mod, '_compile_program', counting)
     x, y = _args()
