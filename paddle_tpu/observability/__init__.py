@@ -46,7 +46,7 @@ from .slo import (Objective, SLOEngine, default_objectives,
                   get_engine as get_slo_engine,
                   set_engine as set_slo_engine)
 from .telemetry import (StepTelemetry, collective_totals,
-                        device_memory_bytes, install,
+                        device_memory_bytes, gc_pause_seconds, install,
                         note_jit_cache_entry)
 from .cost import (MfuWindow, ProgramCatalog, ProgramRecord,
                    aggregate_mfu, device_peaks, record_roofline,
@@ -81,7 +81,7 @@ __all__ = [
     'Objective', 'SLOEngine', 'default_objectives', 'get_slo_engine',
     'set_slo_engine',
     'StepTelemetry', 'collective_totals', 'device_memory_bytes',
-    'install', 'note_jit_cache_entry',
+    'gc_pause_seconds', 'install', 'note_jit_cache_entry',
     'MfuWindow', 'ProgramCatalog', 'ProgramRecord',
     'program_catalog',
     'aggregate_mfu', 'device_peaks', 'record_roofline', 'roofline_summary',

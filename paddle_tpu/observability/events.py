@@ -75,6 +75,10 @@ EVENT_SCHEMA: Dict[str, str] = {
     'serving_request_failed': 'request failed; engine survives',
     'serving_drain_begin': 'graceful drain started',
     'serving_drain_complete': 'graceful drain finished',
+    'serving_slow_step': 'a router step took over router.SLOW_STEP_S; '
+                         'carries, as scalars, the span name with the '
+                         'largest self time inside it, the thread\'s CPU '
+                         'time, the collector\'s and the programs built',
     'prefix_hit': 'radix prefix-cache hit on admission',
     'prefix_evict': 'retained prefix slot reclaimed',
     # paged KV pool (serving/kv_pool.PagedSlotPool)
@@ -229,6 +233,28 @@ class EventLog:
         with self._lock:
             return list(self._events)
 
+    def spans_under(self, root_id: int) -> List[Dict[str, Any]]:
+        """The recorded spans that descend from span `root_id`, itself
+        included, newest first. One pass back over the ring's tail: a
+        span is appended when it ENDS, so after its children and before
+        its parent; the pass stops at the first span that ended before
+        the root began. For the caller that has just closed a long span
+        and asks what it fell under — never on a hot path."""
+        ids, out, t_root = {root_id}, [], None
+        for e in reversed(self.events()):
+            if e.get('ph') != 'X':
+                continue
+            if e.get('id') == root_id:
+                t_root = e['ts']
+            elif e.get('parent') in ids:
+                ids.add(e['id'])
+            elif t_root is not None and e['ts'] + e['dur'] < t_root:
+                break
+            else:
+                continue
+            out.append(e)
+        return out
+
     def clear(self):
         with self._lock:
             self._events.clear()
@@ -300,9 +326,12 @@ class Span:
     it costs an atomic check). Each span has an `id` and its `parent`,
     the innermost span open on the thread when it began (0: none);
     request-scoped spans carry the request's id as the `request_id`
-    attribute. Nestable; a context manager, or explicit begin()/end()."""
+    attribute. Nestable; a context manager, or explicit begin()/end().
+    Once ended it keeps its duration (`dur`; 0.0 for a span that
+    recorded nothing), for the caller that acts on a long one."""
 
-    __slots__ = ('name', 'attrs', 'id', 'parent', '_t0', '_log', '_ann')
+    __slots__ = ('name', 'attrs', 'id', 'parent', 'dur', '_t0', '_log',
+                 '_ann')
 
     def __init__(self, name: str, _log: Optional[EventLog] = None, **attrs):
         self.name = name
@@ -312,7 +341,7 @@ class Span:
         # the default log
         self._log = _default_log if _log is None else _log
         self.id = self.parent = 0
-        self._t0 = 0.0
+        self.dur = self._t0 = 0.0
         self._ann = None             # the open annotation: span is active
 
     def begin(self) -> 'Span':
@@ -335,7 +364,7 @@ class Span:
     def end(self):
         if self._ann is None:
             return
-        dur = _now() - self._t0
+        self.dur = dur = _now() - self._t0
         self._ann.__exit__(None, None, None)
         self._ann = None
         stack = _span_state.stack

@@ -18,6 +18,7 @@ Wires the passive sources into the registry:
 from __future__ import annotations
 
 import collections
+import gc
 import time
 from typing import Optional
 
@@ -97,13 +98,42 @@ def _dispatch_collector(reg: '_metrics.MetricsRegistry'):
     ev._sole().value = float(s['evictions'])   # mirror, not accumulate
 
 
+_gc_pause = [0.0, 0.0]    # seconds the collector has run; its start
+
+
+def _on_gc(phase: str, info: dict):
+    """`gc.callbacks` hook: runs only when a collection does, on the
+    thread that set it off (which holds the interpreter meanwhile)."""
+    if phase == 'start':
+        _gc_pause[1] = time.perf_counter()
+    else:
+        _gc_pause[0] += time.perf_counter() - _gc_pause[1]
+
+
+def gc_pause_seconds() -> float:
+    """Seconds the cyclic collector has run in this process since
+    `install()`; a caller reads it at both ends of a region (the router
+    step's slow-step record)."""
+    return _gc_pause[0]
+
+
+def _gc_collector(reg: '_metrics.MetricsRegistry'):
+    """Scrape-time mirror of the collector's running time."""
+    fam = reg.counter('paddle_gc_pause_seconds_total',
+                      'seconds the cyclic garbage collector ran')
+    fam._sole().value = _gc_pause[0]   # mirror, not accumulate
+
+
 def install():
-    """Idempotent: register the jax.monitoring listeners and the
-    dispatch collector on the default registry. Runs at package import;
-    safe to call again (e.g. after jax.monitoring.clear_event_listeners
-    in a test)."""
+    """Idempotent: register the jax.monitoring listeners, the `gc`
+    hook, and the dispatch and gc collectors on the default registry.
+    Runs at package import; safe to call again (e.g. after
+    jax.monitoring.clear_event_listeners in a test)."""
     reg = _metrics.get_registry()
     reg.register_collector(_dispatch_collector)
+    reg.register_collector(_gc_collector)
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
     if _installed[0]:
         return
     try:

@@ -965,6 +965,13 @@ class ProgramStore:
         with self._lock:
             self._mem.clear()
 
+    @property
+    def built(self) -> int:
+        """Programs compiled or loaded from the disk tier so far (not
+        those found in memory): a count a caller reads before and after
+        a region to learn whether a program was built inside it."""
+        return self._misses + self._hits_disk
+
     def stats(self) -> Dict[str, Any]:
         with self._lock:
             out = {
@@ -1016,6 +1023,9 @@ class ProgramStore:
             self._preload = None
 
 
+_ON_HOST: Dict[type, bool] = {}   # leaf type -> not a device array
+
+
 class StoredJit:
     """A jax.jit'd callable enrolled in the program store.
 
@@ -1037,6 +1047,8 @@ class StoredJit:
         self._name = name
         self._name_fn = name_fn
         self._kind = kind
+        self._span_resolve = f'{kind}.program_resolve'
+        self._span_call = f'{kind}.program_call'
         self._persist = persist
         self._donate = tuple(donate_argnums)
         self._pool_io = tuple(pool_io)
@@ -1058,9 +1070,18 @@ class StoredJit:
         self._entries: Dict[Any, Any] = {}   # sig -> (record, callable)
 
     def _signature(self, args):
+        """-> (key, leaves flattened, those of them that are not device
+        arrays: each is a transfer the call makes)."""
         leaves, treedef = jax.tree_util.tree_flatten(args)
-        sig = []
+        sig, host = [], 0
         for leaf in leaves:
+            kind = type(leaf)
+            on_host = _ON_HOST.get(kind)
+            if on_host is None:
+                # once a type: `isinstance(x, jax.Array)` runs a Python
+                # `__instancecheck__`, 0.2 us a leaf on every call
+                on_host = _ON_HOST[kind] = not isinstance(leaf, jax.Array)
+            host += on_host
             dt = getattr(leaf, 'dtype', None)
             if dt is not None:
                 sig.append((tuple(getattr(leaf, 'shape', ())), str(dt),
@@ -1073,7 +1094,7 @@ class StoredJit:
             # program's argument: the key says which this one was built for
             key += tuple(tuple(io.formats()) for io in self._pool_io)
         hash(key)
-        return key
+        return key, len(leaves), host
 
     def _build(self, key, args):
         if self._name is not None:
@@ -1108,27 +1129,44 @@ class StoredJit:
             self._entries[key] = (record, call)
         return record, call
 
+    def _resolve(self, args):
+        """-> (record, callable, leaves, host leaves): the program for
+        these arguments, built through the store if this wrapper has
+        not met their signature, and the two counts of `_signature`."""
+        try:
+            key, leaves, host = self._signature(args)
+        except Exception:
+            # an unkeyable signature re-resolves the program EVERY call
+            # — survivable, but it must be visible when it happens per
+            # step instead of once
+            _obs.count_suppressed('program_store.signature')
+            key, leaves, host = None, 0, 0
+        entry = self._entries.get(key) if key is not None else None
+        if entry is None:
+            entry = self._build(key, args)
+        return (*entry, leaves, host)
+
     def resolve(self, *args):
         """(record, callable) of the program for these arguments,
         built through the store (memory -> disk -> compile) if this
         wrapper has not met their signature — and not called: a caller
         with several programs over one set of arguments has them all
         compiled by the time it first runs one."""
-        try:
-            key = self._signature(args)
-        except Exception:
-            # an unkeyable signature re-resolves the program EVERY call
-            # — survivable, but it must be visible when it happens per
-            # step instead of once
-            _obs.count_suppressed('program_store.signature')
-            key = None
-        entry = self._entries.get(key) if key is not None else None
-        return entry if entry is not None else self._build(key, args)
+        return self._resolve(args)[:2]
 
     def __call__(self, *args):
+        """The host's part of a call by cause, as two spans named by the
+        wrapper's kind (`serving.program_resolve`: the signature and
+        the lookup, with the leaves flattened and those that are host
+        arrays; `serving.program_call`: the executable's call until it
+        returns). `host_seconds` covers both, on two clock reads of its
+        own: it is booked with observability off too."""
         t0 = time.perf_counter()
-        record, call = self.resolve(*args)
-        out = call(*args)
+        with _obs.span(self._span_resolve) as sp:
+            record, call, leaves, host = self._resolve(args)
+            sp.set(leaves=leaves, host_leaves=host)
+        with _obs.span(self._span_call):
+            out = call(*args)
         dt = time.perf_counter() - t0
         with self._store.catalog._lock:
             record.invocations += 1

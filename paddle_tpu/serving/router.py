@@ -47,10 +47,12 @@ from __future__ import annotations
 
 import collections
 import itertools
+import logging
 import time
 from typing import Callable, List, Optional, Sequence
 
 from .. import observability as _obs
+from .. import programs as _programs
 from ..analysis.runtime import concurrency as _concurrency
 from ..resilience.retry import is_transient
 from .api import (FAILED, FINISHED, PRIORITY_LOW, QUEUED, RequestHandle,
@@ -60,6 +62,13 @@ from .tenancy import (AdmissionRejected, TenantRegistry,
                       estimate_queue_rounds, parse_tenant_spec)
 
 _router_ids = itertools.count()
+_log = logging.getLogger(__name__)
+
+# a router step longer than this keeps a record of what it fell under
+# (`Router._note_slow_step`): a decode round is tens of milliseconds and
+# a whole prefill a few hundred, so only a stall, a compile or a load
+# is over it
+SLOW_STEP_S = 0.5
 
 # breaker states (gauge encoding: closed=0, half_open=1, open=2)
 BREAKER_CLOSED = 'closed'
@@ -424,6 +433,10 @@ class Router:
         self.storm_window_s = float(storm_window_s)
         self._live: List[RouterHandle] = []
         self._rounds = 0
+        # the store the replicas' programs are enrolled in: its count of
+        # programs built, before and after a step, tells a slow step that
+        # compiled or loaded from a stall
+        self._store = _programs.get_store()
         self._ema_round_s: Optional[float] = None
         self._failover_times: collections.deque = collections.deque(
             maxlen=max(self.storm_threshold, 8))
@@ -506,6 +519,10 @@ class Router:
         self._m_shed_window = reg.gauge(
             'paddle_shed_rate_window',
             'admissions shed per second over the sliding signal window')
+        self._m_slow_steps = reg.counter(
+            'paddle_serving_slow_steps_total',
+            f'router steps over {SLOW_STEP_S} s, by the span name with '
+            'the largest self time inside the step', ('under',))
         if _obs.enabled():
             self._m_replicas.set(len(self.replicas))
             self._refresh_gauges()
@@ -755,8 +772,13 @@ class Router:
         (degraded replicas still DRIVE their in-flight requests — they
         just receive no new placements), fail over anything a dying
         replica drops, retire finished requests. Returns the number of
-        requests that progressed."""
-        with _obs.span('serving.router_step'):
+        requests that progressed. A step over `SLOW_STEP_S` leaves a
+        `serving_slow_step` record; what a fast one pays for that is the
+        thread's CPU clock read at both ends."""
+        cpu0 = time.thread_time()
+        gc0 = _obs.gc_pause_seconds()
+        built0 = self._store.built
+        with _obs.span('serving.router_step') as step_span:
             progressed = 0
             t0 = time.perf_counter()
             stepped = False
@@ -792,7 +814,49 @@ class Router:
             if _obs.enabled() and (self._rounds % 8 == 0
                                    or not self._live):
                 self._refresh_gauges()
-            return progressed
+        cpu_s = time.thread_time() - cpu0
+        if step_span.dur > SLOW_STEP_S:
+            self._note_slow_step(step_span, cpu_s,
+                                 _obs.gc_pause_seconds() - gc0,
+                                 self._store.built - built0)
+        return progressed
+
+    def _note_slow_step(self, step_span, cpu_s: float, gc_s: float,
+                        built: int):
+        """The record a long step keeps of itself: one `serving_slow_step`
+        event of scalars, a sample on `paddle_serving_slow_steps_total`
+        and the same line through the logger at warning level, so that
+        an untraced run's log holds it. `under` / `under_s`: of the
+        spans recorded inside the step (itself among them), the name
+        with the largest self time, summed over its spans — ONE stalled
+        span in a decode-only step, `serving.prefill` in a step that
+        seats a whole backlog. With `cpu_s` and `gc_s` that tells
+        the causes apart: under `serving.d2h` with `cpu_s` near 0 the
+        device or the runtime held the step; under a host span with
+        `cpu_s` near `dur_s` Python did (`gc_s`: the collector's part);
+        under a host span with `cpu_s` near 0 the thread was not
+        running. `built` > 0 is a step that compiled or loaded a
+        program: warm-up's, not a stall."""
+        spans = _obs.get_event_log().spans_under(step_span.id)
+        name = {e['id']: e['name'] for e in spans}
+        self_s = collections.Counter()      # span name -> self time, summed
+        for e in spans:
+            self_s[e['name']] += e['dur']
+            if e['parent'] in name:         # every span's but the step's
+                self_s[name[e['parent']]] -= e['dur']
+        under, under_s = max(self_s.items(), key=lambda kv: kv[1],
+                             default=('', 0.0))
+        record = dict(
+            dur_s=round(step_span.dur, 6), under=under,
+            under_s=round(under_s, 6), cpu_s=round(cpu_s, 6),
+            gc_s=round(gc_s, 6), live=len(self._live),
+            admitted=sum((e.get('attrs') or {}).get('admitted', 0)
+                         for e in spans if e['name'] == 'serving.admit'),
+            built=built)
+        _obs.emit('serving_slow_step', **record)
+        self._m_slow_steps.labels(under=record['under']).inc()
+        _log.warning('serving_slow_step: %s', ' '.join(
+            f'{k}={v}' for k, v in record.items()))
 
     def run(self) -> int:
         """Drive until every accepted request is FINISHED or FAILED;

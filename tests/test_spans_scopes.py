@@ -3,9 +3,11 @@ host clock AND the profiler's timeline, spans at each boundary inside
 `engine.step` / `Router.step` / `TrainStep`, and named scopes of one
 pinned vocabulary on what is compiled, with the table from an HLO
 instruction back to its scope."""
+import gc
 import glob
 import os
 import re
+import time
 
 import jax
 import numpy as np
@@ -88,7 +90,6 @@ def test_span_set_adds_counts_and_a_disabled_span_is_a_no_op(log):
 
 
 def test_record_span_nests_in_nothing(log):
-    import time
     t0 = time.perf_counter()
     with obs.span('step') as step:
         obs.record_span('serving.queue', t0, request_id=9)
@@ -190,8 +191,53 @@ def test_serving_spans_nest_and_carry_scalar_counts(gpt, log):
                                  if e['name'] == 'serving.prefill')
 
 
+def test_a_program_call_is_two_spans_under_dispatch_and_prefill(gpt, log):
+    """ISSUE 35: `StoredJit.__call__` opens `serving.program_resolve`
+    (the signature and the lookup, with the leaves it flattened and
+    those that are host arrays) and `serving.program_call` (the
+    executable's call), under whatever span the engine had open."""
+    _serve(gpt)
+    spans = _spans(log, 'serving.')
+    by_id = {e['id']: e for e in spans}
+    resolves = [e for e in spans if e['name'] == 'serving.program_resolve']
+    calls = [e for e in spans if e['name'] == 'serving.program_call']
+    assert len(resolves) == len(calls) > 0
+    parents = {by_id[e['parent']]['name'] for e in resolves + calls}
+    assert parents == {'serving.decode_dispatch', 'serving.prefill'}
+    assert all(not e.get('attrs') for e in calls)
+    assert all(set(e['attrs']) == {'leaves', 'host_leaves'}
+               for e in resolves)
+    # by hand, on the toy GPT: 28 parameters, K and V of two layers, and
+    # the nine numpy arrays of slot state (tok, pos, steps, active, temp,
+    # topk, topp, greedy, keys); nothing frozen, no buffer, no adapter
+    decode = [e['attrs'] for e in resolves
+              if by_id[e['parent']]['name'] == 'serving.decode_dispatch']
+    assert decode and all(a == {'leaves': 28 + 4 + 9, 'host_leaves': 9}
+                          for a in decode)
+    # a prefill calls two programs: the prefill itself (the parameters
+    # and the ids, on the device) and the seat of its row (the pool's
+    # four leaves, the row's four, and the slot, a Python int)
+    prefill = [e['attrs'] for e in resolves
+               if by_id[e['parent']]['name'] == 'serving.prefill']
+    assert {(a['leaves'], a['host_leaves']) for a in prefill} \
+        == {(28 + 1, 0), (4 + 4 + 1, 1)}
+    # the dispatch's two children leave it a self time, not a hole: they
+    # lie inside it, resolve before call
+    for d in (e for e in spans if e['name'] == 'serving.decode_dispatch'):
+        kids = sorted((e for e in resolves + calls if e['parent'] == d['id']),
+                      key=lambda e: e['ts'])
+        assert [e['name'] for e in kids] == ['serving.program_resolve',
+                                             'serving.program_call']
+        assert d['ts'] <= kids[0]['ts']
+        assert kids[0]['ts'] + kids[0]['dur'] <= kids[1]['ts'] + 1e-9
+        assert kids[1]['ts'] + kids[1]['dur'] <= d['ts'] + d['dur'] + 1e-9
+
+
 def test_children_of_serving_step_cover_its_wall(gpt, log):
-    kw = dict(n_requests=4, decode_block=8, new_tokens=16)
+    # a step long enough for the bound: sixteen sub-steps a block (a
+    # block of eight on the toy model is 2 ms, where the step's own
+    # 0.1 ms of bookkeeping read 0.944-0.955 from run to run)
+    kw = dict(n_requests=4, decode_block=16, new_tokens=32)
     _serve(gpt, **kw)               # warm: the programs are compiled
     log.clear()
     _serve(gpt, **kw)
@@ -200,6 +246,101 @@ def test_children_of_serving_step_cover_its_wall(gpt, log):
     covered = sum(e['dur'] for e in spans
                   if e['parent'] in {s['id'] for s in steps})
     assert covered >= 0.95 * sum(s['dur'] for s in steps)
+
+
+def _spin(seconds):
+    t0 = time.perf_counter()
+    while time.perf_counter() - t0 < seconds:
+        pass
+
+
+def _collect(seconds):
+    junk = []
+    for _ in range(200_000):        # cycles only the collector frees
+        a, b = [], []
+        a.append(b)
+        b.append(a)
+        junk.append(a)
+    del junk, a, b
+    gc.collect()
+    time.sleep(seconds)
+
+
+@pytest.mark.parametrize('stall', [time.sleep, _spin, _collect, None],
+                         ids=['asleep', 'spinning', 'collecting', 'fast'])
+def test_a_slow_router_step_keeps_a_record_of_what_it_fell_under(
+        gpt, log, stall, monkeypatch, caplog):
+    """ISSUE 35: a router step over `SLOW_STEP_S` emits ONE
+    `serving_slow_step` with the span of the largest self time inside
+    it, the thread's CPU time, the collector's time and the programs
+    built; a fast step emits nothing. The stall is patched into the emit
+    path, inside `serving.emit`, on the last step."""
+    from paddle_tpu.serving import router as router_mod
+    _serve(gpt)                     # the programs are compiled
+    log.clear()
+    caplog.clear()
+    family = obs.get_registry().get('paddle_serving_slow_steps_total')
+    before = family.total()
+    emit = InferenceEngine._emit_round
+    left = [3]                      # the rounds of one request of 6 tokens
+
+    def stalled(self, *args):
+        left[0] -= 1
+        if stall is not None and not left[0]:
+            stall(router_mod.SLOW_STEP_S + 0.1)
+        return emit(self, *args)
+    monkeypatch.setattr(InferenceEngine, '_emit_round', stalled)
+    with caplog.at_level('WARNING', logger=router_mod.__name__):
+        _serve(gpt, n_requests=1, new_tokens=6)
+    assert left[0] == 0
+    records = [e for e in log.events() if e['name'] == 'serving_slow_step']
+    lines = [r.getMessage() for r in caplog.records
+             if r.getMessage().startswith('serving_slow_step: ')]
+    if stall is None:
+        assert not records and not lines and family.total() == before
+        return
+    assert len(records) == len(lines) == 1 and family.total() == before + 1
+    rec = records[0]['attrs']
+    assert set(rec) == {'dur_s', 'under', 'under_s', 'cpu_s', 'gc_s', 'live',
+                        'admitted', 'built'}
+    assert all(isinstance(v, (int, float, str)) for v in rec.values())
+    assert rec['under'] == 'serving.emit'
+    assert router_mod.SLOW_STEP_S < rec['under_s'] <= rec['dur_s']
+    assert (rec['live'], rec['admitted'], rec['built']) == (0, 0, 0)
+    assert all(f'{k}={v}' in lines[0] for k, v in rec.items())
+    assert obs.get_registry().value('paddle_serving_slow_steps_total',
+                                    under='serving.emit') >= 1
+    if stall is time.sleep:         # the thread was not running
+        assert rec['cpu_s'] < 0.1 and rec['gc_s'] < 0.1
+    elif stall is _spin:            # Python held it
+        assert abs(rec['cpu_s'] - rec['dur_s']) <= 0.2 * rec['dur_s']
+    else:                           # the collector's part is told apart
+        assert 0 < rec['gc_s'] <= rec['cpu_s'] + 0.1
+        snap = {m['name']: m for m in obs.get_registry().snapshot()['metrics']}
+        assert snap['paddle_gc_pause_seconds_total']['samples'][0]['value'] \
+            >= rec['gc_s']
+
+
+def test_a_slow_step_that_seats_several_requests_falls_under_prefill(
+        gpt, log, monkeypatch):
+    """`under` is the NAME with the largest self time, summed over its
+    spans: a step that seats two requests, each prefill a little over
+    half the constant, is under `serving.prefill` with both."""
+    from paddle_tpu.serving import router as router_mod
+    _serve(gpt)
+    log.clear()
+    seat = InferenceEngine._prefill_row
+
+    def slow_seat(self, *args):
+        time.sleep(0.5 * router_mod.SLOW_STEP_S + 0.05)
+        return seat(self, *args)
+    monkeypatch.setattr(InferenceEngine, '_prefill_row', slow_seat)
+    _serve(gpt, n_requests=2)
+    rec, = [e['attrs'] for e in log.events()
+            if e['name'] == 'serving_slow_step']
+    assert (rec['under'], rec['admitted']) == ('serving.prefill', 2)
+    longest = max(e['dur'] for e in _spans(log, 'serving.prefill'))
+    assert longest < router_mod.SLOW_STEP_S < rec['under_s'] <= rec['dur_s']
 
 
 def test_spec_round_carries_the_same_children(gpt, log):
@@ -246,8 +387,18 @@ def test_train_step_spans(toy_step, log):
     step, ids = toy_step
     step(ids, ids)
     ev = {e['name']: e for e in _spans(log, 'train.')}
-    assert set(ev) == {'train.step', 'train.dispatch', 'train.writeback'}
+    assert set(ev) == {'train.step', 'train.dispatch', 'train.writeback',
+                       'train.program_resolve', 'train.program_call'}
     assert ev['train.dispatch']['parent'] == ev['train.step']['id']
+    # the jitted step's call is the two spans of every stored program,
+    # named by the wrapper's kind; by hand: 28 parameters, AdamW's two
+    # moments of each and its step count, the key, the rate, ids and
+    # labels, every one a device array
+    for name in ('train.program_resolve', 'train.program_call'):
+        assert ev[name]['parent'] == ev['train.dispatch']['id']
+    assert len(jax.tree_util.tree_leaves(step._opt_state)) == 2 * 28 + 1
+    assert ev['train.program_resolve']['attrs'] == {
+        'leaves': 28 + (2 * 28 + 1) + 4, 'host_leaves': 0}
     assert ev['train.writeback']['parent'] == ev['train.step']['id']
     assert (ev['train.dispatch']['dur'] + ev['train.writeback']['dur']
             >= 0.95 * ev['train.step']['dur'])
