@@ -1024,6 +1024,7 @@ class ProgramStore:
 
 
 _ON_HOST: Dict[type, bool] = {}   # leaf type -> not a device array
+_DTYPE_NAME: Dict[Any, str] = {}  # a leaf's dtype -> its name in a key
 
 
 class StoredJit:
@@ -1084,7 +1085,12 @@ class StoredJit:
             host += on_host
             dt = getattr(leaf, 'dtype', None)
             if dt is not None:
-                sig.append((tuple(getattr(leaf, 'shape', ())), str(dt),
+                name = _DTYPE_NAME.get(dt)
+                if name is None:
+                    # once a dtype: `str(np.dtype)` builds the name anew
+                    # on every call, and was nine tenths of a leaf's walk
+                    name = _DTYPE_NAME[dt] = str(dt)
+                sig.append((tuple(getattr(leaf, 'shape', ())), name,
                             bool(getattr(leaf, 'weak_type', False))))
             else:
                 sig.append(('py', type(leaf)))

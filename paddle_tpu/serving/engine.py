@@ -77,6 +77,7 @@ from .kv_pool import (PagePoolExhausted, PagedSlotPool, PoolLostError,
                       SlotPool, gather_pages, scatter_pages)
 from .prefix_cache import PagedPrefixCache, RadixPrefixCache
 from .scheduler import FCFSScheduler
+from .slot_state import SlotState
 
 # occupancy is a ratio; the latency-shaped default buckets are wrong here
 _OCCUPANCY_BUCKETS = (0.125, 0.25, 0.375, 0.5, 0.625, 0.75, 0.875, 1.0)
@@ -407,19 +408,25 @@ class InferenceEngine:
         self.obs_scope: Optional[str] = None
 
         n = self.pool.num_slots
-        # per-slot decode state + sampling params, host-authoritative
-        # (tiny arrays re-staged every step; the KV pool stays on device)
-        self._tok = np.zeros(n, np.int32)       # pending (last emitted)
-        self._pos = np.zeros(n, np.int32)       # its cache slot/position
-        self._steps = np.zeros(n, np.int32)     # per-request sample index
-        self._active = np.zeros(n, bool)
-        self._temp = np.ones(n, np.float32)
-        self._topk = np.zeros(n, np.int32)
-        self._topp = np.ones(n, np.float32)
-        self._greedy = np.ones(n, bool)
-        self._keys = np.zeros((n, 2), np.uint32)
-        self._eos_arr = np.full(n, -1, np.int32)   # spec accept stop
-        self._adapter_rows = np.zeros(n, np.int32)  # 0 = base adapter
+        # per-slot decode state + sampling params, host-authoritative:
+        # ONE buffer (`SlotState`), which every decode program takes as
+        # one argument and one transfer. The names are views into it
+        # with the dtypes they always had; admission, emission and
+        # retirement write through them (never rebind one), so the
+        # buffer IS the state and nothing is packed per round (the KV
+        # pool stays on device)
+        slots = self._slot_state = SlotState(n)
+        self._tok = slots.tok           # pending (last emitted)
+        self._pos = slots.pos           # its cache slot/position
+        self._steps = slots.steps       # per-request sample index
+        self._active = slots.active
+        self._temp = slots.temp
+        self._topk = slots.topk
+        self._topp = slots.topp
+        self._greedy = slots.greedy
+        self._keys = slots.keys
+        self._eos_arr = slots.eos       # spec accept stop
+        self._adapter_rows = slots.adapter_rows   # 0 = base adapter
         self._slot_req: dict = {}               # slot -> RequestHandle
 
         # per layer, the most rows a query can see (a window layer's
@@ -696,31 +703,41 @@ class InferenceEngine:
     # ------------------------------------------------------------------
     # compiled programs
     # ------------------------------------------------------------------
-    def _decode_block_fn(self, params, frozen, buffers, pool, tok, pos,
-                         steps, active, temp, topk, topp, greedy, keys,
-                         adapters=None, adapter_rows=None):
+    def _decode_block_fn(self, params, frozen, buffers, pool, state,
+                         adapters=None):
         """One compiled program: `decode_block` single-token steps over
         ALL slots (lax.scan), per-slot positions/masks/sampling. `pool`
         is the stacked pool (leaves [num_slots, max_length, H, D]): it
         is the scan's carry and comes back as the second result, so
         the program, which takes it donated, updates it in place.
-        `adapters`/`adapter_rows` (bank-attached engines only) are the
-        packed LoRA banks + per-slot bank rows — traced inputs, so any
-        adapter mix replays this same program."""
+        `state` is the slot state's one buffer (`SlotState`), unpacked
+        here into the values the scan takes. `adapters` (bank-attached
+        engines only) is the packed LoRA banks; the per-slot bank rows
+        ride `state` — traced inputs all, so any adapter mix replays
+        this same program."""
         self._trace_counts['decode_step'] += 1   # python-level trace count
         fwd = cached_forward(self.model, params, frozen, buffers)
-        return self._decode_scan(fwd, pool, tok, pos, steps, active, temp,
-                                 topk, topp, greedy, keys, adapters,
-                                 adapter_rows)
+        return self._scan_packed(fwd, pool, state, adapters)
 
-    def _decode_block_half_fn(self, params, frozen, buffers, pool, *state):
+    def _decode_block_half_fn(self, params, frozen, buffers, pool, state,
+                              adapters=None):
         """`_decode_block_fn` with attention over the first
         `max_length // 2` rows of every slot: a program of its own,
         which `_decode_round` runs while no active slot comes near that
-        row. `state` is what follows `pool` there."""
+        row."""
         self._trace_counts['decode_step_half'] += 1
         fwd = cached_forward(self.model, params, frozen, buffers)
-        return self._decode_scan(fwd, pool, *state, rows=self._half_rows)
+        return self._scan_packed(fwd, pool, state, adapters,
+                                 rows=self._half_rows)
+
+    def _scan_packed(self, fwd, pool, state, adapters, rows=None):
+        """`_decode_scan` over the slot state as the programs receive
+        it: one buffer, unpacked on the device (a handful of slices and
+        bitcasts, once a block, outside the scan) into the nine values
+        the scan has always taken, and the adapter rows."""
+        slots = self._slot_state.unpack(state)
+        return self._decode_scan(fwd, pool, *slots[:9], adapters,
+                                 slots.adapter_rows, rows)
 
     def _decode_scan(self, fwd, pool, tok, pos, steps, active, temp, topk,
                      topp, greedy, keys, adapters=None, adapter_rows=None,
@@ -859,10 +876,8 @@ class InferenceEngine:
         return slab
 
     def _spec_decode_fn(self, params, frozen, buffers, pool,
-                        d_params, d_frozen, d_buffers, d_pool,
-                        tok, pos, steps, active, temp, topk, topp,
-                        greedy, keys, eos,
-                        adapters=None, adapter_rows=None):
+                        d_params, d_frozen, d_buffers, d_pool, state,
+                        adapters=None):
         """One compiled SPECULATION round over all slots (replaces the
         plain decode block when a draft model is configured): the draft
         proposes k tokens autoregressively for every slot, the target
@@ -878,6 +893,8 @@ class InferenceEngine:
         Returns (tokens [N, k+1], accepted-counts [N], new pools)."""
         k = self.spec_k
         self._trace_counts[f'spec_decode_k{k}'] += 1
+        (tok, pos, steps, active, temp, topk, topp, greedy, keys, eos,
+         adapter_rows) = self._slot_state.unpack(state)
         fwd_t = cached_forward(self.model, params, frozen, buffers)
         fwd_d = cached_forward(self.draft_model, d_params, d_frozen,
                                d_buffers)
@@ -933,9 +950,7 @@ class InferenceEngine:
     # compiled programs: PAGED layout
     # ------------------------------------------------------------------
     def _paged_decode_fn(self, params, frozen, buffers, pages, scales,
-                         table, tok, pos, steps, active, temp, topk,
-                         topp, greedy, keys,
-                         adapters=None, adapter_rows=None, rows=None):
+                         table, state, adapters=None, rows=None):
         """The decode block over the PAGE-TABLE pool: gather every
         slot's pages into the contiguous [N, max_length, H, D] view the
         row-pool scan already consumes (dequantizing int8 pages in the
@@ -951,23 +966,26 @@ class InferenceEngine:
         self._trace_counts['paged_decode_step' if rows is None
                            else 'paged_decode_step_half'] += 1
         fwd = cached_forward(self.model, params, frozen, buffers)
+        slots = self._slot_state.unpack(state)
         sc = scales if self.pool.quant else None
-        table = jnp.where(active[:, None], table, 0)
+        table = jnp.where(slots.active[:, None], table, 0)
         contig = gather_pages(pages, table, sc,
                               out_dtype=self.pool.compute_dtype)
         toks, contig, *touched = self._decode_scan(
-            fwd, contig, tok, pos, steps, active, temp, topk, topp, greedy,
-            keys, adapters, adapter_rows, rows)
-        pages, sc = scatter_pages(pages, table, contig, pos,
+            fwd, contig, *slots[:9], adapters, slots.adapter_rows, rows)
+        pages, sc = scatter_pages(pages, table, contig, slots.pos,
                                   self.decode_block,
                                   self.pool.page_size, sc)
         return (toks, pages, sc if sc is not None else (), *touched)
 
-    def _paged_decode_half_fn(self, *args):
+    def _paged_decode_half_fn(self, params, frozen, buffers, pages,
+                              scales, table, state, adapters=None):
         """`_paged_decode_fn` with the scan's attention over the first
         `max_length // 2` rows of the gathered view: the paged pool's
         second decode program (`_decode_block_half_fn`)."""
-        return self._paged_decode_fn(*args, rows=self._half_rows)
+        return self._paged_decode_fn(params, frozen, buffers, pages, scales,
+                                     table, state, adapters,
+                                     rows=self._half_rows)
 
     def _paged_prefill_fn(self, params, frozen, buffers, pages, scales,
                           table, ids, adapters=None, adapter_rows=None):
@@ -1019,10 +1037,8 @@ class InferenceEngine:
         return pages, sc if sc is not None else ()
 
     def _paged_spec_fn(self, params, frozen, buffers, pages, scales,
-                       table, d_params, d_frozen, d_buffers, d_pool,
-                       tok, pos, steps, active, temp, topk, topp,
-                       greedy, keys, eos,
-                       adapters=None, adapter_rows=None):
+                       table, d_params, d_frozen, d_buffers, d_pool, state,
+                       adapters=None):
         """The speculation round over the PAGED target pool: identical
         draft-propose / k+1-verify / longest-prefix-accept math as
         `_spec_decode_fn`, with the target KV gathered through the page
@@ -1032,6 +1048,8 @@ class InferenceEngine:
         Donates pages, scales, and the draft pool (argnums 3, 4, 9)."""
         k = self.spec_k
         self._trace_counts[f'paged_spec_decode_k{k}'] += 1
+        (tok, pos, steps, active, temp, topk, topp, greedy, keys, eos,
+         adapter_rows) = self._slot_state.unpack(state)
         fwd_t = cached_forward(self.model, params, frozen, buffers)
         fwd_d = cached_forward(self.draft_model, d_params, d_frozen,
                                d_buffers)
@@ -1519,9 +1537,18 @@ class InferenceEngine:
     def _decode_args(self) -> tuple:
         """The row pool's decode programs' arguments, as they stand."""
         return (self._params, self._frozen, self._buffers, self.pool.cache,
-                self._tok, self._pos, self._steps, self._active, self._temp,
-                self._topk, self._topp, self._greedy, self._keys,
-                *self._adapter_args())
+                *self._state_args())
+
+    def _state_args(self) -> tuple:
+        """What every decode and speculation program takes last: the
+        slot state — the ONE host buffer, as it stands: one argument,
+        one transfer a call, nothing packed or copied here — and, on an
+        engine with a bank, the bank's arrays (the per-slot bank rows
+        ride the buffer). A bank-less engine's signatures and
+        program-store keys carry no trace of adapters."""
+        if self.adapter_bank is None:
+            return (self._slot_state.buffer,)
+        return (self._slot_state.buffer, self.adapter_bank.device_arrays())
 
     def _recover_pool(self):
         """A DONATED program (decode, spec, seat, copy) failed mid-call:
@@ -1569,17 +1596,15 @@ class InferenceEngine:
                 f'{op.__name__} died with the pool donated to it: '
                 f'{type(exc).__name__}: {exc}') from exc
 
-    def _adapter_args(self, slot: Optional[int] = None) -> tuple:
-        """Trailing (bank arrays, per-row bank slots) appended to a
-        program call — () on a bank-less engine, whose signatures and
-        program-store keys stay exactly the pre-adapter ones. `slot`
-        narrows the row vector to one slot's view for the batch-1
-        prefill/chunk programs."""
+    def _adapter_args(self, slot: int) -> tuple:
+        """Trailing (bank arrays, the slot's bank row) appended to a
+        batch-1 prefill/chunk program's call — () on a bank-less
+        engine, whose signatures and program-store keys stay exactly
+        the pre-adapter ones."""
         if self.adapter_bank is None:
             return ()
-        rows = (self._adapter_rows if slot is None
-                else self._adapter_rows[slot:slot + 1])
-        return (self.adapter_bank.device_arrays(), rows)
+        return (self.adapter_bank.device_arrays(),
+                self._adapter_rows[slot:slot + 1])
 
     def _needed_rows(self):
         """Cache rows this round's attention NEEDS, over active slots
@@ -1682,16 +1707,12 @@ class InferenceEngine:
             try:
                 with _obs.span('serving.decode_dispatch'):
                     if self._paged:
-                        state = (self._tok, self._pos, self._steps,
-                                 self._active, self._temp, self._topk,
-                                 self._topp, self._greedy, self._keys,
-                                 *self._adapter_args())
                         pages, scales = self.pool.device_state()
                         table = call_with_retry(
                             _to_device, self.pool.page_table,
                             policy=self._retry, site='serving.h2d')
                         args = (self._params, self._frozen, self._buffers,
-                                pages, scales, table, *state)
+                                pages, scales, table, *self._state_args())
                         toks_dev, new_pages, new_scales, *touched = \
                             self._decode_program(rows, args)(*args)
                         self.pool.set_device_state(new_pages, new_scales)
@@ -1741,11 +1762,8 @@ class InferenceEngine:
                          new_d_pool) = self._spec_jit(
                             self._params, self._frozen, self._buffers,
                             pages, scales, table, d_params, d_frozen,
-                            d_buffers, self.draft_pool.cache, self._tok,
-                            self._pos, self._steps, self._active,
-                            self._temp, self._topk, self._topp,
-                            self._greedy, self._keys, self._eos_arr,
-                            *self._adapter_args())
+                            d_buffers, self.draft_pool.cache,
+                            *self._state_args())
                         self.pool.set_device_state(new_pages, new_scales)
                     else:
                         toks_dev, counts_dev, new_pool, new_d_pool = \
@@ -1753,10 +1771,7 @@ class InferenceEngine:
                                 self._params, self._frozen, self._buffers,
                                 self.pool.cache, d_params, d_frozen,
                                 d_buffers, self.draft_pool.cache,
-                                self._tok, self._pos, self._steps,
-                                self._active, self._temp, self._topk,
-                                self._topp, self._greedy, self._keys,
-                                self._eos_arr, *self._adapter_args())
+                                *self._state_args())
                         self.pool.cache = new_pool
                     self.draft_pool.cache = new_d_pool
             except Exception:

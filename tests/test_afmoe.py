@@ -520,10 +520,7 @@ def test_a_model_without_experts_returns_what_it_returned():
     assert 'expert_kernel_substeps' not in a
     assert a['rows'] == 64 and a['read_rows'] == 2 * 64 * 2
     assert a['needed_rows'] == a['real_rows'] * 2      # no window layer
-    out = jax.eval_shape(
-        eng._decode_block_fn, eng._params, eng._frozen, eng._buffers,
-        eng.pool.cache, eng._tok, eng._pos, eng._steps, eng._active,
-        eng._temp, eng._topk, eng._topp, eng._greedy, eng._keys)
+    out = jax.eval_shape(eng._decode_block_fn, *eng._decode_args())
     assert len(out) == 2                                # tokens, pool
 
 

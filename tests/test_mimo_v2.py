@@ -729,37 +729,39 @@ _FAMILIES = {'gpt': (GPTForCausalLM, GPTConfig),
              'lfm2': (Lfm2MoeForCausalLM, Lfm2MoeConfig)}
 
 # sha256 (first 16 hex digits) of the StableHLO text of each program of
-# a tiny engine (2 slots x 64, block 4, bucket 16), taken on the PARENT
-# of PR 32 (commit 25ee4df) by the very code of `_program_texts` below;
-# jax 0.9.0, which the repository is written for (the verify skill)
+# a tiny engine (2 slots x 64, block 4, bucket 16): the prefills' taken
+# on the PARENT of PR 32 (commit 25ee4df) by the very code of
+# `_program_texts` below; the decode programs' re-taken AT PR 36, which
+# made the slot state one buffer that they unpack (they are the
+# engine's own functions on `_decode_args()`, the pins of
+# `tests/test_pool_layout.py`); jax 0.9.0, which the repository is
+# written for (the verify skill)
 _PARENT_PROGRAMS = {
-    ('afmoe', 'decode'): 'de21aa2d58b2e51f',
-    ('afmoe', 'decode_half'): '6b4cdb8cae057b0c',
+    ('afmoe', 'decode'): '81008fe4d4edb6d9',
+    ('afmoe', 'decode_half'): '8e312056c151a0a2',
     ('afmoe', 'prefill'): '6782a117cd64283e',
-    ('gpt', 'decode'): '1c7fde158e6d725e',
-    ('gpt', 'decode_half'): '704b0c53a42fabf4',
+    ('gpt', 'decode'): '5e706a44cb430fe1',
+    ('gpt', 'decode_half'): '4a4e6ee67293bb7c',
     ('gpt', 'prefill'): '365eec42133d1ab2',
-    ('lfm2', 'decode'): 'ab8c9c607a1a7db3',
-    ('lfm2', 'decode_half'): '53131c84560e1540',
+    ('lfm2', 'decode'): '611c2975c6cfa539',
+    ('lfm2', 'decode_half'): '6df5d3a5564cc3bd',
     ('lfm2', 'prefill'): '1a02dff7d8263eae',
-    ('llama', 'decode'): 'dd096fcedcc19f49',
-    ('llama', 'decode_half'): '8a20ba690ae84204',
+    ('llama', 'decode'): '0b25e1d31f4c9b75',
+    ('llama', 'decode_half'): '8a7f5153ef78c81d',
     ('llama', 'prefill'): '8b4c79aa8dc443ef',
 }
 
 
 def _program_texts(eng):
     state = (eng._params, eng._frozen, eng._buffers)
-    dec = (eng.pool.cache, eng._tok, eng._pos, eng._steps, eng._active,
-           eng._temp, eng._topk, eng._topp, eng._greedy, eng._keys)
+    dec = eng._decode_args()
     ids = jnp.zeros((1, 16), jnp.int32)
     pre = (ids, jnp.int32(5)) if eng.pool.state_layers else (ids,)
     prefill = eng._state_prefill_fn if eng.pool.state_layers \
         else eng._prefill_fn
     return {
-        'decode': jax.jit(eng._decode_block_fn).lower(*state, *dec),
-        'decode_half': jax.jit(eng._decode_block_half_fn).lower(*state,
-                                                                *dec),
+        'decode': jax.jit(eng._decode_block_fn).lower(*dec),
+        'decode_half': jax.jit(eng._decode_block_half_fn).lower(*dec),
         'prefill': jax.jit(prefill).lower(*state, *pre)}
 
 

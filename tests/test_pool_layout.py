@@ -87,20 +87,23 @@ _FAMILIES = {'gpt': (GPTForCausalLM, GPTConfig),
 
 # sha256 (first 16 hex digits) of the StableHLO text of the decode
 # programs of a tiny engine (2 slots x 64, block 4, bucket 16), one per
-# serve cell's family: `tests/test_mimo_v2.py`'s pin of PR 32's parent
-# for four of them, and mimo's taken on the PARENT of PR 33 (commit
-# 410bb69) by the very code below; jax 0.9.0
+# serve cell's family, lowered from the engine's OWN functions on its
+# own arguments. Re-taken AT PR 36, whose programs take the slot state
+# as one buffer and unpack it (the parents' digests, PR 32's and PR
+# 33's, were of programs over nine loose arrays; that the packed
+# program returns the loose one's tokens and pool, bit for bit, is
+# `tests/test_slot_state.py`'s); jax 0.9.0
 _PARENT_DECODE = {
-    ('afmoe', 'decode'): 'de21aa2d58b2e51f',
-    ('afmoe', 'decode_half'): '6b4cdb8cae057b0c',
-    ('gpt', 'decode'): '1c7fde158e6d725e',
-    ('gpt', 'decode_half'): '704b0c53a42fabf4',
-    ('lfm2', 'decode'): 'ab8c9c607a1a7db3',
-    ('lfm2', 'decode_half'): '53131c84560e1540',
-    ('llama', 'decode'): 'dd096fcedcc19f49',
-    ('llama', 'decode_half'): '8a20ba690ae84204',
-    ('mimo', 'decode'): '597da39d7c18c109',
-    ('mimo', 'decode_half'): '47cdce179e840124',
+    ('afmoe', 'decode'): '81008fe4d4edb6d9',
+    ('afmoe', 'decode_half'): '8e312056c151a0a2',
+    ('gpt', 'decode'): '5e706a44cb430fe1',
+    ('gpt', 'decode_half'): '4a4e6ee67293bb7c',
+    ('lfm2', 'decode'): '611c2975c6cfa539',
+    ('lfm2', 'decode_half'): '6df5d3a5564cc3bd',
+    ('llama', 'decode'): '0b25e1d31f4c9b75',
+    ('llama', 'decode_half'): '8a7f5153ef78c81d',
+    ('mimo', 'decode'): '8ea7c6f267237b5a',
+    ('mimo', 'decode_half'): 'cf1b7410b8762beb',
 }
 
 

@@ -560,8 +560,9 @@ def _engine(gpt, **kw):
 
 
 def _slot_args(eng):
-    return (eng._tok, eng._pos, eng._steps, eng._active, eng._temp,
-            eng._topk, eng._topp, eng._greedy, eng._keys)
+    """The slot state as every decode and speculation program takes it:
+    ONE buffer (ISSUE 36), where nine arrays (ten with `eos`) stood."""
+    return (eng._slot_state.buffer,)
 
 
 def _prog_decode(gpt):
@@ -582,8 +583,7 @@ def _prog_spec(gpt):
     eng = _engine(gpt, draft_model=gpt, num_draft_tokens=2)
     return eng._spec_jit, (eng._params, eng._frozen, eng._buffers,
                            eng.pool.cache, *eng._draft_state,
-                           eng.draft_pool.cache, *_slot_args(eng),
-                           eng._eos_arr)
+                           eng.draft_pool.cache, *_slot_args(eng))
 
 
 def _prog_paged_spec(gpt):
@@ -593,7 +593,7 @@ def _prog_paged_spec(gpt):
     return eng._spec_jit, (eng._params, eng._frozen, eng._buffers,
                            pages, scales, jnp.asarray(eng.pool.page_table),
                            *eng._draft_state, eng.draft_pool.cache,
-                           *_slot_args(eng), eng._eos_arr)
+                           *_slot_args(eng))
 
 
 def _pool_row(gpt, pool, value):
@@ -867,6 +867,101 @@ class TestWarmRestartServing:
         InferenceEngine(gpt, num_slots=2, max_length=48, decode_block=2)
         assert pstore.stats()['loaded_from_disk'] >= 1, \
             'engine construction must preload persisted serving programs'
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 36: a signature's key names a dtype by a name looked up once a
+# dtype (`_DTYPE_NAME`), not by `str(dtype)` a leaf a call: the same keys
+# ---------------------------------------------------------------------------
+
+def _weights(dtype=jnp.float32, cols=4):
+    return {'a': jnp.ones((4, cols), dtype), 'b': jnp.ones((cols,), dtype),
+            'c': {'d': jnp.zeros((2, 2), dtype)}}
+
+
+def _keyed(store, tag):
+    def f(w, x, k):
+        return x @ w['a'] + w['b'] + w['c']['d'].sum() + k
+    return store.wrap_jit(f, name=f'test.{tag}', kind='jit',
+                          statics={'tag': tag})
+
+
+class TestSignatureKey:
+    def test_the_key_is_the_one_str_of_the_dtype_gave(self, open_store):
+        store = open_store(None)
+        prog = _keyed(store, 'key')
+        w, x = _weights(jnp.bfloat16), jnp.ones((3, 4))
+        key, leaves, host = prog._signature((w, x, np.float32(1)))
+        treedef, sig = key
+        assert treedef == jax.tree_util.tree_structure((w, x, 0))
+        assert sig == (((4, 4), 'bfloat16', False), ((4,), 'bfloat16', False),
+                       ((2, 2), 'bfloat16', False), ((3, 4), 'float32', False),
+                       ((), 'float32', False))
+        assert (leaves, host) == (5, 1)
+        assert all(name == str(dt)
+                   for dt, name in store_mod._DTYPE_NAME.items())
+        # a Python scalar and a weak-typed value keep keys of their own
+        assert prog._signature((w, x, 1))[0][1][-1] == ('py', int)
+        assert prog._signature((w, x, jnp.float32(1)))[0] == key
+        assert prog._signature((w, x, jnp.asarray(1.0)))[0][1][-1] \
+            == ((), 'float32', True)
+
+    def test_equal_arguments_find_one_program(self, open_store):
+        store = open_store(None)
+        prog = _keyed(store, 'equal')
+        x = jnp.ones((3, 4))
+        log = obs.get_event_log()
+        log.clear()
+        first = prog(_weights(), x, np.float32(1))
+        second = prog(_weights(), x + 1, np.float32(2))
+        attrs = [e['attrs'] for e in log.events()
+                 if e['name'] == 'jit.program_resolve']
+        assert attrs == [{'leaves': 5, 'host_leaves': 1}] * 2
+        assert len(prog._entries) == 1 and store.stats()['misses'] == 1
+        assert np.asarray(second - first).tolist() == [[5.0] * 4] * 3
+
+    @pytest.mark.parametrize('other', ['dtype', 'shape', 'tree'])
+    def test_arguments_of_another_kind_find_another_program(
+            self, open_store, other):
+        store = open_store(None)
+        w, x = _weights(), jnp.ones((3, 4))
+        prog = _keyed(store, f'replace_{other}')
+        out = prog(w, x, np.float32(0))
+        assert out.dtype == jnp.float32
+        if other == 'dtype':
+            w2 = _weights(jnp.bfloat16)
+            prog(w2, x, np.float32(0))
+        elif other == 'shape':
+            w2 = _weights(cols=6)
+            assert prog(w2, x, np.float32(0)).shape == (3, 6)
+        else:
+            w2 = dict(_weights(), e=jnp.ones(()))
+            prog(w2, x, np.float32(0))
+        assert len(prog._entries) == 2 and store.stats()['misses'] == 2
+        assert prog.resolve(w2, x, np.float32(0))[1] \
+            is not prog.resolve(w, x, np.float32(0))[1]
+        # and a mismatch the program cannot take still raises
+        with pytest.raises(TypeError):
+            prog(_weights(cols=6), jnp.ones((3, 5)), np.float32(0))
+
+    def test_swapped_weights_find_the_program_they_had(self, open_store,
+                                                       gpt):
+        """`swap_weights` builds new dicts of the same kind: the next
+        round's signature is the last one's — no compile, no trace,
+        the same tokens."""
+        open_store(None)
+        eng = _engine(gpt)
+        sp = SamplingParams(max_new_tokens=6, eos_token_id=NO_EOS)
+        toks = eng.generate_many([[3, 1, 4, 1, 5]], [sp])[0].tokens
+        compiles = obs.get_registry().value('paddle_jit_compiles_total')
+        traces = dict(eng.stats()['traces'])
+        entries = len(eng._decode_jit._entries)
+        eng.swap_weights(gpt.state_dict(), version=2)
+        assert eng.generate_many([[3, 1, 4, 1, 5]], [sp])[0].tokens == toks
+        assert eng.stats()['traces'] == traces
+        assert len(eng._decode_jit._entries) == entries
+        assert obs.get_registry().value('paddle_jit_compiles_total') \
+            == compiles
 
 
 # ---------------------------------------------------------------------------

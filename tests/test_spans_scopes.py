@@ -208,11 +208,12 @@ def test_a_program_call_is_two_spans_under_dispatch_and_prefill(gpt, log):
     assert all(set(e['attrs']) == {'leaves', 'host_leaves'}
                for e in resolves)
     # by hand, on the toy GPT: 28 parameters, K and V of two layers, and
-    # the nine numpy arrays of slot state (tok, pos, steps, active, temp,
-    # topk, topp, greedy, keys); nothing frozen, no buffer, no adapter
+    # the slot state, ONE numpy buffer since ISSUE 36 (nine arrays
+    # before: tok, pos, steps, active, temp, topk, topp, greedy, keys);
+    # nothing frozen, no buffer, no adapter
     decode = [e['attrs'] for e in resolves
               if by_id[e['parent']]['name'] == 'serving.decode_dispatch']
-    assert decode and all(a == {'leaves': 28 + 4 + 9, 'host_leaves': 9}
+    assert decode and all(a == {'leaves': 28 + 4 + 1, 'host_leaves': 1}
                           for a in decode)
     # a prefill calls two programs: the prefill itself (the parameters
     # and the ids, on the device) and the seat of its row (the pool's
