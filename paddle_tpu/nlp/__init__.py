@@ -10,6 +10,8 @@ from __future__ import annotations
 from .afmoe import AfmoeConfig, AfmoeForCausalLM, AfmoeModel
 from .bert import (BertConfig, BertForMaskedLM,
                    BertForSequenceClassification, BertModel)
+from .deepseek_v3 import (DeepseekV3Config, DeepseekV3ForCausalLM,
+                          DeepseekV3Model)
 from .ernie import (ErnieConfig, ErnieForMaskedLM,
                     ErnieForSequenceClassification, ErnieModel)
 from .generation import GenerationMixin, Seq2SeqGenerationMixin
@@ -25,7 +27,8 @@ from . import transformers  # noqa: E402  (API-parity alias module)
 
 __all__ = [
     'AfmoeConfig', 'AfmoeForCausalLM', 'AfmoeModel', 'BertConfig', 'BertForMaskedLM', 'BertForSequenceClassification',
-    'BertModel', 'ErnieConfig', 'ErnieForMaskedLM',
+    'BertModel', 'DeepseekV3Config', 'DeepseekV3ForCausalLM',
+    'DeepseekV3Model', 'ErnieConfig', 'ErnieForMaskedLM',
     'ErnieForSequenceClassification', 'ErnieModel', 'GenerationMixin',
     'GPTConfig', 'GPTForCausalLM', 'GPTModel', 'Lfm2MoeConfig',
     'Lfm2MoeForCausalLM', 'Lfm2MoeModel', 'LlamaConfig',

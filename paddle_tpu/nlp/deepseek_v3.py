@@ -1,0 +1,496 @@
+"""DeepSeek-V3-style causal LM (`model_type: deepseek_v3`; Kakao
+Kanana-2-30B-A3B, 30B-A3B): multi-head LATENT attention, whose cache is
+a row of `kv_lora_rank + qk_rope_head_dim` numbers a token shared by
+every head, and sparse SwiGLU experts behind a sigmoid router plus a
+shared MLP.
+
+What it is made of, and where that lives:
+
+- `x0 = E[ids]`; a layer is `x += attn(N_in(x))`, `x += f(N_post(x))`
+  (two RMSNorms a layer); `logits = N_final(x) W_head` (untied).
+- attention (`DeepseekV3Attention`, here), `a = N_in(x)`, H heads:
+  `q = a W_q`, a head `[q_nope ; q_rope]`; `[c' ; r'] = a W_kva`, ONE of
+  each a token; `c = RMSNorm_kv(c')`; `[k_nope_h ; v_h] = c W_kvb`;
+  rotary positions on `q_rope` and on `r'` (`llama._rope`, rotate-half;
+  where `rope_interleave`, the dims are stored as interleaved pairs and
+  de-interleaved first, the same permutation on both, so every
+  `q_rope . r` is what a pairwise rotation gives); `k_h = [k_nope_h ;
+  r]`; logits over `sqrt(qk_nope_head_dim + qk_rope_head_dim)`, causal.
+- `f` of the first `first_k_dense_replace` layers is `nlp/llama.py`'s
+  SwiGLU; of the others `nlp/afmoe.py`'s expert layer: `s = sigmoid(m
+  W_r)` in float32, `sel = top_k(s + bias)` (the bias selects only), `w
+  = s[sel] / (sum s[sel] + 1e-20) * routed_scaling_factor`, plus the
+  `n_shared_experts` shared experts as ONE unweighted MLP of their
+  summed width; no token dropped.
+
+**The cache is latent** (`init_cache`, `generation.latent_layers`): an
+entry is `(c [B, L, kv_lora_rank], r [B, L, qk_rope_head_dim])` — rows
+like K and V (position p in row p, hidden above a position by a mask,
+shareable up to one, rewindable), and NO head axis: `c` after its norm,
+`r` after its rotation. Two leaves and not one of their summed width:
+the value product reads `c` alone, and a slice of a wider leaf is a copy
+of the leaf where the compiler does not fuse it.
+
+**Two attention paths, chosen by what the call is** (`forward`):
+(i) a call that STARTS AN EMPTY SLOT — no cache at all, or a cache with
+the literal slot 0 and no mask, which is how a whole prefill arrives
+(`serving.engine._prefill_fn`, `GenerationMixin.generate`): every row a
+query may see is one the call itself brings, so it attends over its OWN
+tokens with `k_h` and `v_h` made from its `c` and `r`, in blocks of
+`PREFILL_QUERY_BLOCK` queries (no more than `[H, block, S]` scores
+live at once), and never reads the slot it writes;
+(ii) any call AGAINST ROWS HELD (a decode sub-step, speculation's k+1
+rows, a prefill chunk): absorbed. With `W_kvb = [W_UK_h ; W_UV_h]` a
+head, `q_nope_h . k_nope_jh = (q_nope_h W_UK_h^T) . c_j`, so `qL_h =
+q_nope_h W_UK_h^T`, logits `(qL_h . c_j + q_rope_h . r_j) /
+sqrt(nope + rope)`, `oL_h = sum_j p_ij c_j`, `o_h = oL_h W_UV_h`: the
+rows are read as they lie and no K or V is ever made from them.
+
+Activations are float32 and products three bf16 passes
+(`afmoe.ACTIVATION_PRECISION`, and why, at `AfmoeForCausalLM.forward`:
+this router is that one, and picks 6 of 128). Served, not trained.
+"""
+from __future__ import annotations
+
+import math
+import types
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..nn.layer import Layer
+from ..nn import functional as F
+from ..nn.common_layers import Embedding, Linear
+from ..nn.norm import RMSNorm
+from ..ops import pallas as _pallas
+from ..tensor import Tensor, apply_op, to_jax
+from .afmoe import ACTIVATION_PRECISION, AfmoeSparseMLP
+from .generation import (GenerationMixin, as_offset as _as_offset,
+                         attended_rows as _attended_rows,
+                         decode_mask as _decode_mask,
+                         offset_grid as _offset_grid,
+                         update_kv_cache as _update_kv_cache)
+from .llama import LlamaMLP, _col_linear, _rope, _row_linear
+
+# queries scored at once by a call that attends over its own tokens
+PREFILL_QUERY_BLOCK = 1024
+
+_NEG = float(jnp.finfo(jnp.float32).min)
+
+
+class DeepseekV3Config:
+    model_type = 'deepseek_v3'
+
+    def __init__(self, vocab_size=128256, hidden_size=2048,
+                 intermediate_size=6144, moe_intermediate_size=768,
+                 num_hidden_layers=48, first_k_dense_replace=1,
+                 moe_layer_freq=1, num_attention_heads=32,
+                 num_key_value_heads=None, q_lora_rank=None,
+                 kv_lora_rank=512, qk_nope_head_dim=128,
+                 qk_rope_head_dim=64, v_head_dim=128, rope_theta=1000000.0,
+                 rope_interleave=True, rope_scaling=None,
+                 n_routed_experts=128, n_shared_experts=2,
+                 num_experts_per_tok=6, norm_topk_prob=True,
+                 routed_scaling_factor=2.448, scoring_func='sigmoid',
+                 topk_method='noaux_tc', n_group=1, topk_group=1,
+                 rms_norm_eps=1e-6, attention_bias=False,
+                 max_position_embeddings=32768, tie_word_embeddings=False,
+                 pad_token_id=0, bos_token_id=1, eos_token_id=2,
+                 tensor_parallel=False, **kwargs):
+        if q_lora_rank is not None:
+            raise ValueError(
+                f'q_lora_rank {q_lora_rank!r}: query compression (q_a_proj, '
+                'q_a_layernorm, q_b_proj) is not implemented; the '
+                'configurations served here give null')
+        if rope_scaling is not None:
+            raise ValueError(f'rope_scaling {rope_scaling!r}: only plain '
+                             'rotary positions (null) are implemented')
+        if scoring_func != 'sigmoid' or topk_method != 'noaux_tc':
+            raise ValueError(
+                f'scoring_func {scoring_func!r} / topk_method '
+                f'{topk_method!r}: only the sigmoid router with a '
+                'selection bias (noaux_tc) is implemented')
+        if n_group != 1 or topk_group != 1:
+            raise ValueError('n_group / topk_group: no group limit is '
+                             'implemented')
+        if moe_layer_freq != 1:
+            raise ValueError('moe_layer_freq: every layer after the dense '
+                             'ones is an expert layer (1), nothing else is '
+                             'implemented')
+        if attention_bias or tie_word_embeddings:
+            raise ValueError('attention_bias / tie_word_embeddings: the '
+                             'family has neither, and neither is '
+                             'implemented')
+        if num_key_value_heads not in (None, num_attention_heads):
+            raise ValueError('num_key_value_heads: latent attention makes '
+                             'a K and V for every query head')
+        if qk_rope_head_dim % 2:
+            raise ValueError('qk_rope_head_dim: rotary positions need an '
+                             'even number of dims')
+        self.vocab_size = vocab_size
+        self.hidden_size = hidden_size
+        self.intermediate_size = intermediate_size
+        self.moe_intermediate_size = moe_intermediate_size
+        self.num_hidden_layers = num_hidden_layers
+        self.first_k_dense_replace = first_k_dense_replace
+        self.num_attention_heads = num_attention_heads
+        self.kv_lora_rank = kv_lora_rank
+        self.qk_nope_head_dim = qk_nope_head_dim
+        self.qk_rope_head_dim = qk_rope_head_dim
+        self.v_head_dim = v_head_dim
+        self.qk_head_dim = qk_nope_head_dim + qk_rope_head_dim
+        self.rope_theta = rope_theta
+        self.rope_interleave = bool(rope_interleave)
+        self.n_routed_experts = n_routed_experts
+        self.n_shared_experts = n_shared_experts
+        self.num_experts_per_tok = num_experts_per_tok
+        self.norm_topk_prob = norm_topk_prob
+        self.routed_scaling_factor = routed_scaling_factor
+        self.rms_norm_eps = rms_norm_eps
+        self.max_position_embeddings = max_position_embeddings
+        self.tie_word_embeddings = False
+        self.pad_token_id = pad_token_id
+        self.bos_token_id = bos_token_id
+        self.eos_token_id = eos_token_id
+        self.tensor_parallel = tensor_parallel
+        # under the names `afmoe.py`'s expert layer reads
+        self.num_experts = n_routed_experts
+        self.route_norm = norm_topk_prob
+        self.route_scale = 1.0 if routed_scaling_factor is None \
+            else routed_scaling_factor
+        self.num_shared_experts = int(n_shared_experts or 0)
+        for k, v in kwargs.items():
+            setattr(self, k, v)
+
+    @classmethod
+    def tiny(cls, **kw):
+        """Test-sized: one dense layer and two expert layers; 4 heads of
+        nope 8 / rope 4 / v 8 over a latent of 16, the rotary dims
+        interleaved; 8 experts top-2 + a shared MLP of two experts'
+        width, the published routing scale."""
+        kw.setdefault('vocab_size', 128)
+        kw.setdefault('hidden_size', 64)
+        kw.setdefault('intermediate_size', 96)
+        kw.setdefault('moe_intermediate_size', 16)
+        kw.setdefault('num_hidden_layers', 3)
+        kw.setdefault('num_attention_heads', 4)
+        kw.setdefault('kv_lora_rank', 16)
+        kw.setdefault('qk_nope_head_dim', 8)
+        kw.setdefault('qk_rope_head_dim', 4)
+        kw.setdefault('v_head_dim', 8)
+        kw.setdefault('n_routed_experts', 8)
+        kw.setdefault('num_experts_per_tok', 2)
+        kw.setdefault('max_position_embeddings', 256)
+        return cls(**kw)
+
+    @classmethod
+    def tiny_wide_v(cls, **kw):
+        """`tiny()` with V WIDER than the un-rotated part of K (12
+        against 8), the rotary dims stored as halves, one shared expert
+        and two dense layers: neither a width, nor the storage order of
+        the rotary dims, nor where the expert layers start may be baked
+        in."""
+        kw.setdefault('v_head_dim', 12)
+        kw.setdefault('rope_interleave', False)
+        kw.setdefault('n_shared_experts', 1)
+        kw.setdefault('first_k_dense_replace', 2)
+        return cls.tiny(**kw)
+
+
+def _rotary(t, positions, theta, interleave):
+    """Rotary positions on `t` `[B, S, heads, rope]`. Where the dims are
+    stored as interleaved pairs (`rope_interleave`) they are brought to
+    halves first, `[x0, x2, .., x1, x3, ..]`, and stay so: the same
+    permutation on a query and on the shared key leaves every product
+    of the two what a rotation of the pairs `(x_2i, x_2i+1)` gives."""
+    if interleave:
+        t = jnp.concatenate([t[..., 0::2], t[..., 1::2]], axis=-1)
+    return _rope(t, positions, theta)
+
+
+def _starts_empty_slot(cache_offset, attn_mask):
+    """Whether a cached call brings every row its queries may see: the
+    LITERAL slot 0 (a Python or NumPy integer — a constant of the
+    caller's program; a traced value may be anything) and no mask. That
+    is how a whole prefill arrives (`generation.cached_forward`'s
+    contract). Anything else finds rows held."""
+    return (attn_mask is None
+            and isinstance(cache_offset, (int, np.integer))
+            and int(cache_offset) == 0)
+
+
+def _own_tokens_attention(q, k, v, mask):
+    """Causal attention of a call over its OWN S tokens: q, k `[B, S, H,
+    qk]`, v `[B, S, H, v]` -> `[B, S, H, v]`; `mask` None or a caller's
+    boolean `[B, 1, 1, S]` of keys that are no padding. More than
+    `PREFILL_QUERY_BLOCK` queries go block after block (`lax.map`), so
+    that one block's `[B, H, block, S]` scores are all that live."""
+    s, blk = q.shape[1], PREFILL_QUERY_BLOCK
+    if s <= blk:
+        return _pallas.flash_attention(q, k, v, mask=mask, causal=True)
+    pad = -s % blk
+    qb = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
+    qb = jnp.moveaxis(qb.reshape(q.shape[0], -1, blk, *q.shape[2:]), 1, 0)
+    j = jnp.arange(s, dtype=jnp.int32)
+
+    def one(args):
+        qi, first = args
+        i = first + jnp.arange(blk, dtype=jnp.int32)
+        seen = (j[None, :] <= i[:, None])[None, None]     # [1, 1, blk, S]
+        if mask is not None:
+            seen = seen & mask
+        return _pallas.flash_attention(qi, k, v, mask=seen)
+    out = jax.lax.map(one, (qb, jnp.arange(qb.shape[0],
+                                           dtype=jnp.int32) * blk))
+    out = jnp.moveaxis(out, 0, 1).reshape(q.shape[0], -1, *out.shape[3:])
+    return out[:, :s]
+
+
+def _latent_attention(q_nope, q_rope, c, r, w_kvb, mask, scale):
+    """Attention over latent rows, absorbed: q_nope `[B, Sq, H, nope]`,
+    q_rope `[B, Sq, H, rope]`, rows c `[B, L, C]` (normed) and r `[B, L,
+    rope]` (rotated), `w_kvb` `[C, H, nope + v]`, `mask` (boolean, or
+    additive) broadcastable to `[B, 1, Sq, L]` -> `[B, Sq, H, v]`. Every head
+    reads the same rows; the softmax is float32."""
+    nope = q_nope.shape[-1]
+    with jax.named_scope('latent_absorb'):
+        q_lat = jnp.einsum('bqhn,chn->bqhc', q_nope,
+                           w_kvb[..., :nope].astype(q_nope.dtype))
+    logits = (jnp.einsum('bqhc,bkc->bhqk', q_lat, c,
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum('bqhr,bkr->bhqk', q_rope, r,
+                           preferred_element_type=jnp.float32)) * scale
+    if mask.dtype == jnp.bool_:
+        logits = jnp.where(mask, logits, _NEG)
+    else:
+        logits = logits + mask.astype(jnp.float32)
+    probs = jax.nn.softmax(logits, axis=-1).astype(q_nope.dtype)
+    o_lat = jnp.einsum('bhqk,bkc->bqhc', probs, c)
+    with jax.named_scope('latent_absorb'):
+        return jnp.einsum('bqhc,chv->bqhv', o_lat,
+                          w_kvb[..., nope:].astype(o_lat.dtype))
+
+
+class DeepseekV3Attention(Layer):
+    def __init__(self, config: DeepseekV3Config):
+        super().__init__()
+        self.config = config
+        h, nh = config.hidden_size, config.num_attention_heads
+        self.q_proj = _col_linear(config, h, nh * config.qk_head_dim)
+        self.kv_a_proj_with_mqa = Linear(
+            h, config.kv_lora_rank + config.qk_rope_head_dim,
+            bias_attr=False)
+        self.kv_a_layernorm = RMSNorm(config.kv_lora_rank,
+                                      epsilon=config.rms_norm_eps)
+        self.kv_b_proj = _col_linear(
+            config, config.kv_lora_rank,
+            nh * (config.qk_nope_head_dim + config.v_head_dim))
+        self.o_proj = _row_linear(config, nh * config.v_head_dim, h)
+
+    def forward(self, hidden, position_offset=None, attn_mask=None,
+                cache=None, cache_offset=None):
+        cfg = self.config
+        offset = _as_offset(position_offset)
+        # cache_offset = SLOT in the static cache, position_offset = the
+        # LOGICAL position (rotary); see LlamaAttention
+        slot = _as_offset(cache_offset) if cache_offset is not None \
+            else offset
+        nh, lat = cfg.num_attention_heads, cfg.kv_lora_rank
+        nope, rd, vd = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                        cfg.v_head_dim)
+        theta, interleave = cfg.rope_theta, cfg.rope_interleave
+        scale = 1.0 / math.sqrt(cfg.qk_head_dim)
+
+        def rope(t, off):
+            return _rotary(t, _offset_grid(off, t.shape[1]), theta,
+                           interleave)
+
+        def split_q(qv, off):
+            qv = qv.reshape(qv.shape[0], qv.shape[1], nh, nope + rd)
+            return qv[..., :nope], rope(qv[..., nope:], off)
+
+        def split_kv(kv, off):
+            return kv[..., :lat], rope(kv[..., None, lat:], off)[:, :, 0]
+        off_t = offset if isinstance(offset, Tensor) else Tensor(offset)
+        q_nope, q_rope = apply_op(split_q, self.q_proj(hidden), off_t,
+                                  _name='mla_split_q')
+        c, r = apply_op(split_kv, self.kv_a_proj_with_mqa(hidden), off_t,
+                        _name='mla_split_kv')
+        c = self.kv_a_layernorm(c)
+
+        fresh = cache is None or _starts_empty_slot(cache_offset, attn_mask)
+        if cache is not None:
+            with jax.named_scope('kv_write'):
+                c_cache, r_cache = _update_kv_cache(cache[0], cache[1],
+                                                    c, r, slot)
+        if fresh:
+            # path (i): over the call's own tokens, K and V made from
+            # its c and r; the slot it writes is never read
+            def own(qn, qr, cv, rv, kv, *m):
+                kv = kv.reshape(kv.shape[0], kv.shape[1], nh, nope + vd)
+                k = jnp.concatenate(
+                    [kv[..., :nope],
+                     jnp.broadcast_to(rv[:, :, None], rv.shape[:2]
+                                      + (nh, rd))], axis=-1)
+                return _own_tokens_attention(
+                    jnp.concatenate([qn, qr], axis=-1), k, kv[..., nope:],
+                    m[0] if m else None)
+            out = apply_op(own, q_nope, q_rope, c, r, self.kv_b_proj(c),
+                           *(() if attn_mask is None else (attn_mask,)),
+                           _name='mla_own_tokens')
+        else:
+            # path (ii): absorbed, over the rows held
+            mask = attn_mask if attn_mask is not None \
+                else _decode_mask(q_nope, c_cache, slot)
+            c_rows, r_rows = _attended_rows(c_cache, r_cache, mask)
+
+            def held(qn, qr, cv, rv, w, m):
+                return _latent_attention(
+                    qn, qr, cv, rv, w.reshape(lat, nh, nope + vd), m, scale)
+            out = apply_op(held, q_nope, q_rope, c_rows, r_rows,
+                           self.kv_b_proj.weight, mask, _name='mla_latent')
+        out = apply_op(
+            lambda t: t.reshape(t.shape[0], t.shape[1], nh * vd),
+            out, _name='merge_heads')
+        out = self.o_proj(out)
+        if cache is not None:
+            return out, (c_cache, r_cache)
+        return out
+
+
+class DeepseekV3DecoderLayer(Layer):
+    def __init__(self, config: DeepseekV3Config, layer_idx: int):
+        super().__init__()
+        eps = config.rms_norm_eps
+        self.self_attn = DeepseekV3Attention(config)
+        self.moe_enabled = layer_idx >= config.first_k_dense_replace
+        self.mlp = AfmoeSparseMLP(config) if self.moe_enabled \
+            else LlamaMLP(types.SimpleNamespace(
+                hidden_size=config.hidden_size,
+                intermediate_size=config.intermediate_size,
+                tensor_parallel=config.tensor_parallel))
+        self.input_layernorm = RMSNorm(config.hidden_size, epsilon=eps)
+        self.post_attention_layernorm = RMSNorm(config.hidden_size,
+                                                epsilon=eps)
+
+    def forward(self, hidden, position_offset=None, attn_mask=None,
+                cache=None, cache_offset=None):
+        with jax.named_scope('norm'):
+            h = self.input_layernorm(hidden)
+        with jax.named_scope('attention'):
+            out = self.self_attn(
+                h, position_offset=position_offset, attn_mask=attn_mask,
+                cache=cache, cache_offset=cache_offset)
+        new_cache = None
+        if cache is not None:
+            out, new_cache = out
+        h = hidden + out
+        with jax.named_scope('norm'):
+            normed = self.post_attention_layernorm(h)
+        if self.moe_enabled:        # its own scopes: moe/router, ...
+            h = h + self.mlp(normed)
+        else:
+            with jax.named_scope('mlp'):
+                h = h + self.mlp(normed)
+        if cache is not None:
+            return h, new_cache
+        return h
+
+
+def _tensor(c):
+    return c if isinstance(c, Tensor) else Tensor(c)
+
+
+class DeepseekV3PretrainedModel(Layer):
+    config_class = DeepseekV3Config
+    base_model_prefix = 'model'
+
+
+class DeepseekV3Model(DeepseekV3PretrainedModel):
+    """embed -> N decoder layers -> the final RMSNorm."""
+
+    def __init__(self, config: DeepseekV3Config):
+        super().__init__()
+        self.config = config
+        self.embed_tokens = Embedding(config.vocab_size, config.hidden_size)
+        self.layers = [DeepseekV3DecoderLayer(config, i)
+                       for i in range(config.num_hidden_layers)]
+        for i, l in enumerate(self.layers):
+            self.add_sublayer(f'layers.{i}', l)
+        self.norm = RMSNorm(config.hidden_size, epsilon=config.rms_norm_eps)
+
+    def forward(self, input_ids, position_offset=None, attention_mask=None,
+                cache=None, use_cache=False, cache_offset=None):
+        ids = input_ids if isinstance(input_ids, Tensor) \
+            else Tensor(to_jax(input_ids))
+        with jax.named_scope('embed'):
+            # float32 from here on, whatever the parameters are stored in
+            h = self.embed_tokens(ids).astype('float32')
+        mask = attention_mask
+        if mask is not None and not isinstance(mask, Tensor):
+            mask = Tensor(to_jax(mask))
+        if mask is not None and len(mask.shape) == 2:
+            # [B, S] padding mask -> [B, 1, 1, S] boolean
+            mask = apply_op(
+                lambda m: (m > 0)[:, None, None, :], mask, _name='pad_mask')
+        new_caches = []
+        for i, layer in enumerate(self.layers):
+            layer_cache = None
+            if cache is not None:
+                layer_cache = tuple(map(_tensor, cache[i]))
+            out = layer(h, position_offset=position_offset, attn_mask=mask,
+                        cache=layer_cache, cache_offset=cache_offset)
+            if layer_cache is not None:
+                h, c = out
+                new_caches.append(c)
+            else:
+                h = out
+        with jax.named_scope('norm'):
+            h = self.norm(h)
+        if use_cache:
+            return h, tuple(new_caches)
+        return h
+
+    def init_cache(self, batch_size, max_length, dtype=None):
+        """One latent entry a layer: `(c [B, max_length, kv_lora_rank],
+        r [B, max_length, qk_rope_head_dim])`, row = position, no head
+        axis (`generation.latent_layers`)."""
+        cfg = self.config
+        dt = dtype or 'float32'
+        lead = (batch_size, int(max_length))
+        return tuple((jnp.zeros(lead + (cfg.kv_lora_rank,), dt),
+                      jnp.zeros(lead + (cfg.qk_rope_head_dim,), dt))
+                     for _ in self.layers)
+
+
+class DeepseekV3ForCausalLM(DeepseekV3PretrainedModel, GenerationMixin):
+    def __init__(self, config: DeepseekV3Config):
+        super().__init__()
+        self.config = config
+        self.model = DeepseekV3Model(config)
+        self.lm_head = Linear(config.hidden_size, config.vocab_size,
+                              bias_attr=False)
+
+    def forward(self, input_ids, position_offset=None, attention_mask=None,
+                cache=None, use_cache=False, labels=None,
+                cache_offset=None):
+        with jax.default_matmul_precision(ACTIVATION_PRECISION):
+            out = self.model(input_ids, position_offset=position_offset,
+                             attention_mask=attention_mask, cache=cache,
+                             use_cache=use_cache, cache_offset=cache_offset)
+            h, new_cache = out if use_cache else (out, None)
+            with jax.named_scope('lm_head'):
+                logits = self.lm_head(h)
+        if labels is not None:
+            loss = F.cross_entropy(
+                logits.reshape([-1, self.config.vocab_size]),
+                (labels if isinstance(labels, Tensor)
+                 else Tensor(to_jax(labels))).reshape([-1]))
+            return (loss, logits, new_cache) if use_cache else (loss, logits)
+        if use_cache:
+            return logits, new_cache
+        return logits
+
+    def init_cache(self, batch_size, max_length, dtype=None):
+        return self.model.init_cache(batch_size, max_length, dtype)

@@ -75,7 +75,9 @@ def offset_grid(offset, s):
 
 def update_kv_cache(k_cache, v_cache, k, v, offset):
     """Write new K/V blocks into the static decode cache at `offset`.
-    All args are Tensors; [B, L, H_kv, D] caches, [B, S, H_kv, D] updates.
+    All args are Tensors; [B, L, H_kv, D] caches, [B, S, H_kv, D] updates
+    — or rows of any trailing rank: a latent entry's leaves are [B, L, C]
+    (`latent_layers`), written the same way.
     `offset` is a scalar slot shared by the whole batch, or a [B] array of
     per-row slots (the serving engine's slot pool, where every sequence
     decodes at its own position). Returns (k_cache, v_cache) Tensors.
@@ -103,7 +105,8 @@ def update_kv_cache(k_cache, v_cache, k, v, offset):
                         offset_grid(start, s)].set(
                 new, indices_are_sorted=True, unique_indices=True,
                 mode='promise_in_bounds')
-        return jax.lax.dynamic_update_slice(c, new, (0, off, 0, 0))
+        return jax.lax.dynamic_update_slice(
+            c, new, (0, off) + (0,) * (c.ndim - 2))
     return (_apply(upd, k_cache, k, _name='cache_update'),
             _apply(upd, v_cache, v, _name='cache_update'))
 
@@ -254,6 +257,19 @@ def ring_layers(cache, max_length):
                  and entry[0].shape[1] < max_length)
 
 
+def latent_layers(cache):
+    """The indices of a cache's entries that are LATENT: a pair of
+    leaves `[B, L, C]` with rows and no head axis (latent attention: a
+    token's compressed K/V and its shared rotary key, read by every
+    head). Its rows are what K and V rows are — position p in row p,
+    hidden above a position by a mask, shareable up to one, rewindable
+    — so whatever serves K and V rows serves these, at their own row
+    bytes. Empty for a model that keeps K and V by head."""
+    return tuple(i for i, entry in enumerate(cache)
+                 if isinstance(entry, (tuple, list))
+                 and len(entry[0].shape) == 3)
+
+
 def _ring_newest(last, rows):
     """[..., rows] int32: the newest position `p <= last` with `p mod
     rows == r`, for every row r (negative where the ring has not yet
@@ -398,7 +414,13 @@ def cached_forward(model, params, frozen, buffers):
     engine's slot-pooled decode step (paddle_tpu.serving.engine), so the
     decode-step semantics (position origin, cache slot, mask override)
     can never diverge between the batch and continuous-batching paths.
-    `pos_offset`/`slot` may be scalars or per-row [B] arrays."""
+    `pos_offset`/`slot` may be scalars or per-row [B] arrays.
+    A `slot` that is the LITERAL 0 (a Python integer, a constant of the
+    caller's program, where a traced value may be anything) with `mask`
+    None says the call brings every row its queries may see: a whole
+    prefill into an empty slot. A model whose attention over its own
+    tokens differs from its attention over rows held (latent attention)
+    chooses by that; every other model reads 0 as it reads an array."""
     def fwd(tok, cache, pos_offset, slot, mask):
         (logits, new_cache), _ = functional_call(
             model, params, frozen, buffers, (tok,),
@@ -488,9 +510,9 @@ class GenerationMixin:
                     return seen
                 return seen.at[jnp.arange(b), tok].set(True)
 
-            # prefill over the whole prompt
-            logits, cache = fwd(ids, cache, offsets, jnp.int32(0),
-                                prefill_mask)
+            # prefill over the whole prompt (the slot is the literal 0:
+            # `cached_forward` says why)
+            logits, cache = fwd(ids, cache, offsets, 0, prefill_mask)
             key, sub = jax.random.split(key)
             nxt, nxt_logp = _next_token(
                 processors(logits[:, -1], seen0, jnp.int32(0)), sub,
@@ -564,7 +586,7 @@ class GenerationMixin:
                 prefill_mask = None
 
             logits, cache = fwd(ids, cache, offsets if padded else
-                                jnp.int32(0), jnp.int32(0), prefill_mask)
+                                jnp.int32(0), 0, prefill_mask)
             logp0 = jax.nn.log_softmax(
                 logits[:, -1].astype(jnp.float32), axis=-1)      # [B, V]
             v = logp0.shape[-1]

@@ -2,6 +2,8 @@
 from .afmoe import AfmoeConfig, AfmoeForCausalLM, AfmoeModel  # noqa: F401
 from .bert import (BertConfig, BertForMaskedLM,  # noqa: F401
                    BertForSequenceClassification, BertModel)
+from .deepseek_v3 import (DeepseekV3Config,  # noqa: F401
+                          DeepseekV3ForCausalLM, DeepseekV3Model)
 from .ernie import (ErnieConfig, ErnieForMaskedLM,  # noqa: F401
                     ErnieForSequenceClassification, ErnieModel)
 from .generation import GenerationMixin  # noqa: F401

@@ -67,8 +67,8 @@ from .. import observability as _obs
 from ..observability import reqledger as _reqledger
 from ..jit import functional_state
 from ..nlp.generation import (_NEG_INF, cached_forward, experts_touched,
-                              ring_layers, routing_scope, state_layers,
-                              state_scope)
+                              latent_layers, ring_layers, routing_scope,
+                              state_layers, state_scope)
 from ..resilience import RetryPolicy, call_with_retry
 from ..tensor import Tensor
 from .adapters.apply import adapter_scope as _adapter_scope
@@ -192,6 +192,36 @@ _RING_REFUSALS = {
 }
 
 
+# Why an engine mode cannot serve a model whose cache entries are LATENT:
+# rows with no head axis (`generation.latent_layers`). Its rows are what
+# K and V rows are, so the prefix cache, chunked prefill and speculation
+# serve it as they serve K and V (`copy_slot` maps over any leaf; a chunk
+# and a verify are calls against rows held); only what reasons BY HEAD
+# does not.
+_LATENT_REFUSALS = {
+    'kv_page_size / kv_pages':
+        'the paged pool holds [pages, page, H_kv, D] leaves, gathers them '
+        'to [N, max_length, H_kv, D] and scatters whole [H_kv, D] rows '
+        'back; a latent leaf is [slot, row, C], with no head axis',
+    'kv_quant':
+        'int8 KV lives in the paged pool with one scale a (page, head); a '
+        'latent row has no heads, and the one norm that made it spans all '
+        'of them',
+}
+
+
+def _whole_prefill(fwd, ids, row_spec):
+    """Forward a whole prompt (batch-1, right-padded to its bucket) over
+    a zero row of `row_spec` -> the row it wrote. The one body of every
+    whole-prefill program (the row pool's, the draft's, the paged
+    pool's): the slot is the literal 0 and there is no mask, which is
+    how `cached_forward` is told that the call brings all its rows."""
+    slab = jax.tree_util.tree_map(
+        lambda s: jnp.zeros(s.shape, s.dtype), row_spec)
+    _, slab = fwd(ids, slab, jnp.int32(0), 0, None)
+    return slab
+
+
 def _refuse_modes(model, asked, keeps, refusals):
     """ValueError naming the first engine mode in `asked` ({mode: was
     it asked for}) that a model whose cache `keeps` such an entry cannot
@@ -312,6 +342,9 @@ class InferenceEngine:
                 _refuse_modes(m, asked, 'a ring of a window\'s rows '
                               '(cache entries shorter than the slot)',
                               _RING_REFUSALS)
+            if latent_layers(entries):
+                _refuse_modes(m, asked, 'latent rows (cache entries with '
+                              'rows and no heads)', _LATENT_REFUSALS)
         model.eval()
         self.model = model
         self._params, self._frozen, self._buffers = functional_state(model)
@@ -696,9 +729,15 @@ class InferenceEngine:
             'KV pool leaves held in a layout of their own, the one the '
             'decode block reads them in (a head size that is not whole '
             'lanes, on a TPU); 0 where every leaf keeps the default')
+        self._m_latent_row_bytes = reg.gauge(
+            'paddle_serving_pool_latent_row_bytes',
+            'logical bytes of one cache row over the layers whose entries '
+            'are latent (rows with no head axis: latent attention); 0 '
+            'where every entry is K and V by head')
         if _obs.enabled():
             self._m_slots.set(self.pool.num_slots)
             self._m_own_layout.set(0)
+            self._m_latent_row_bytes.set(self.pool.latent_row_bytes)
 
     # ------------------------------------------------------------------
     # compiled programs
@@ -817,11 +856,8 @@ class InferenceEngine:
         bucket (ids.shape), everything else traced."""
         self._trace_counts[f'prefill_{ids.shape[1]}'] += 1
         fwd = cached_forward(self.model, params, frozen, buffers)
-        slab = jax.tree_util.tree_map(
-            lambda s: jnp.zeros(s.shape, s.dtype), self.pool.row_spec)
         with _adapter_scope(adapters, adapter_rows):
-            _, slab = fwd(ids, slab, jnp.int32(0), jnp.int32(0), None)
-        return slab
+            return _whole_prefill(fwd, ids, self.pool.row_spec)
 
     def _state_prefill_fn(self, params, frozen, buffers, ids, length,
                           adapters=None, adapter_rows=None):
@@ -869,11 +905,7 @@ class InferenceEngine:
         own prompt KV before it can propose. One compile per bucket."""
         self._trace_counts[f'draft_prefill_{ids.shape[1]}'] += 1
         fwd = cached_forward(self.draft_model, params, frozen, buffers)
-        slab = jax.tree_util.tree_map(
-            lambda s: jnp.zeros(s.shape, s.dtype),
-            self.draft_pool.row_spec)
-        _, slab = fwd(ids, slab, jnp.int32(0), jnp.int32(0), None)
-        return slab
+        return _whole_prefill(fwd, ids, self.draft_pool.row_spec)
 
     def _spec_decode_fn(self, params, frozen, buffers, pool,
                         d_params, d_frozen, d_buffers, d_pool, state,
@@ -997,10 +1029,8 @@ class InferenceEngine:
         b = ids.shape[1]
         self._trace_counts[f'paged_prefill_{b}'] += 1
         fwd = cached_forward(self.model, params, frozen, buffers)
-        slab = jax.tree_util.tree_map(
-            lambda s: jnp.zeros(s.shape, s.dtype), self.pool.row_spec)
         with _adapter_scope(adapters, adapter_rows):
-            _, slab = fwd(ids, slab, jnp.int32(0), jnp.int32(0), None)
+            slab = _whole_prefill(fwd, ids, self.pool.row_spec)
         sc = scales if self.pool.quant else None
         pages, sc = scatter_pages(pages, table, slab,
                                   jnp.zeros(1, jnp.int32), b,
@@ -1704,6 +1734,10 @@ class InferenceEngine:
                 round_span.set(needed_rows_window=needed_ring)
             if self.pool.state_layers:
                 self._note_state(round_span)
+            if self.pool.latent_layers:
+                round_span.set(
+                    latent_layers=len(self.pool.latent_layers),
+                    latent_row_bytes=self.pool.latent_row_bytes)
             try:
                 with _obs.span('serving.decode_dispatch'):
                     if self._paged:

@@ -95,7 +95,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..nlp.generation import (ring_layers as _ring_layers,
+from ..nlp.generation import (latent_layers as _latent_layers,
+                              ring_layers as _ring_layers,
                               state_layers as _state_layers)
 
 _tree = jax.tree_util
@@ -146,12 +147,13 @@ _LANES = 128
 
 def wants_own_layout(shape, backend: str) -> bool:
     """THE rule, from the leaf alone: a K or V leaf `[slot, row, H_kv,
-    D]` whose head size is not a whole number of lanes, on a TPU. There
-    the device's default layout has the rows minor and the decode
-    block's scan does not, so the block would relay the whole leaf at
-    both its edges; a leaf of whole lanes arrives as the scan reads it,
-    however few its heads (a 4 x 128 leaf has a tile of 4 sublanes)."""
-    return (backend == 'tpu' and len(shape) == 4
+    D]` whose head size is not a whole number of lanes, or a latent leaf
+    `[slot, row, C]` whose width is not, on a TPU. There the device's
+    default layout has the rows minor and the decode block's scan does
+    not, so the block would relay the whole leaf at both its edges; a
+    leaf of whole lanes arrives as the scan reads it, however few its
+    heads (a 4 x 128 leaf has a tile of 4 sublanes)."""
+    return (backend == 'tpu' and len(shape) in (3, 4)
             and shape[-1] % _LANES != 0)
 
 
@@ -215,7 +217,10 @@ class SlotPool:
     pool says of ROWS (`written_rows`, buckets, `max_length`) is about
     the (K, V) entries alone. A state is not a row that a mask can hide
     part of: it stands at ONE position, so whoever seats it (the engine's
-    prefill) gives it whole, and nothing may share or rewind it.
+    prefill) gives it whole, and nothing may share or rewind it. A
+    LATENT entry (`latent_layers`: a pair of `[num_slots, max_length,
+    C]` leaves, rows and no heads) is a row entry like K and V, at its
+    own row bytes.
     """
 
     def __init__(self, model, num_slots: int, max_length: int,
@@ -247,6 +252,13 @@ class SlotPool:
         self.ring_layers = _ring_layers(self.rows, self.max_length)
         self.stands_at_one_position = bool(self.state_layers
                                            or self.ring_layers)
+        # the entries whose rows have no heads (latent attention), and
+        # the LOGICAL bytes of one row over all of them, whatever lanes
+        # the device pads (`entry_bytes` books those)
+        self.latent_layers = _latent_layers(self.rows)
+        self.latent_row_bytes = sum(
+            leaf.shape[2] * leaf.dtype.itemsize
+            for i in self.latent_layers for leaf in self.rows[i])
         # one entry per leaf of `rows`, in tree order. `own_layout`:
         # `Format(Layout.AUTO)` where the rule asks for a layout of the
         # leaf's own, None elsewhere — all None off a TPU. `formats`:
@@ -415,9 +427,9 @@ class SlotPool:
 
     def asks(self, backend: str, sharding=None) -> list:
         """`own_layout` as the rule gives it on `backend`: AUTO on the
-        leaf's own device (or `sharding`: a described one) for every K
-        and V leaf that `wants_own_layout`, None for the others and for
-        a state leaf."""
+        leaf's own device (or `sharding`: a described one) for every K,
+        V or latent leaf that `wants_own_layout`, None for the others
+        and for a state leaf."""
         from jax.experimental.layout import Format, Layout
         return [Format(Layout.AUTO, sharding or leaf.sharding)
                 if i not in self.state_layers
@@ -479,19 +491,25 @@ class SlotPool:
         return self.max_length
 
     def _entries(self):
-        """-> (geometry, leaves, their formats) per entry of the pool.
-        The geometry is `rows x heads x (K width + V width)` of a
-        (K, V) entry, `state` of a state leaf: one name for layers that
+        """-> (geometry, its leaves' names, the leaves, their formats)
+        per entry of the pool. The geometry is `rows x heads x (K width
+        + V width)` of a (K, V) entry, `rows x latent(widths)` of a
+        latent one (its leaves `c`, the latent, and `r`, the shared
+        rotary key), `state` of a state leaf: one name for layers that
         keep the same."""
         formats = iter(self.formats)
         for i, entry in enumerate(self._pool_spec):
             if i in self.state_layers:
-                yield 'state', (entry,), (next(formats),)
+                yield 'state', ('state',), (entry,), (next(formats),)
+                continue
+            a, b = entry
+            if i in self.latent_layers:
+                name, leaves = (f'{a.shape[1]}xlatent({a.shape[2]}+'
+                                f'{b.shape[2]})'), 'cr'
             else:
-                k, v = entry
-                yield (f'{k.shape[1]}x{k.shape[2]}x({k.shape[3]}+'
-                       f'{v.shape[3]})', (k, v),
-                       (next(formats), next(formats)))
+                name, leaves = (f'{a.shape[1]}x{a.shape[2]}x({a.shape[3]}+'
+                                f'{b.shape[3]})'), 'KV'
+            yield name, leaves, (a, b), (next(formats), next(formats))
 
     def entry_bytes(self) -> dict:
         """The pool's bytes ON THE DEVICE by entry geometry: every leaf
@@ -499,19 +517,19 @@ class SlotPool:
         included (a K leaf 64 wide held in tiles of 128 lanes is twice
         its logical size)."""
         out = collections.Counter()
-        for name, leaves, formats in self._entries():
+        for name, _, leaves, formats in self._entries():
             out[name] += sum(map(format_bytes, leaves, formats))
         return dict(out)
 
     def entry_layouts(self) -> dict:
         """The layout held, by entry geometry: `default`, or the
         layout of the entry's leaves (`K ..., V ...` where a layer's
-        two differ)."""
+        two differ; `c ..., r ...` of a latent entry)."""
         out = {}
-        for name, _, formats in self._entries():
+        for name, leaves, _, formats in self._entries():
             names = [layout_name(f) for f in formats]
             out[name] = names[0] if len(set(names)) == 1 else \
-                ', '.join(f'{kv} {n}' for kv, n in zip('KV', names))
+                ', '.join(f'{leaf} {n}' for leaf, n in zip(leaves, names))
         return out
 
     def stats(self) -> dict:
@@ -524,6 +542,8 @@ class SlotPool:
                 'state_layers': len(self.state_layers),
                 'state_bytes': self.state_bytes,
                 'ring_layers': len(self.ring_layers),
+                'latent_layers': len(self.latent_layers),
+                'latent_row_bytes': self.latent_row_bytes,
                 'entry_bytes': self.entry_bytes(),
                 'entry_layouts': self.entry_layouts(),
                 'row_writes': self._row_writes,
@@ -665,6 +685,8 @@ class PagedSlotPool:
     # a ring is a leaf of another length (the engine refuses such a
     # model before it builds this pool)
     state_layers = ()
+    latent_layers = ()
+    latent_row_bytes = 0
     state_bytes = 0
     ring_layers = ()
     stands_at_one_position = False

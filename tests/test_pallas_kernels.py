@@ -473,15 +473,16 @@ def test_moe_decode_experts_refuses(changed, match):
 @pytest.mark.parametrize('tokens,k,routed,held,h,f', [
     (32, 8, 64, 4, 4096, 2048),     # serve-swa-reason: a share of the experts
     (8, 8, 32, 4, 2048, 1024),      # serve-moe-docs' expert
-    (32, 4, 16, 4, 2048, 1536)],    # serve-hybrid-reason's
-    ids=['4096x2048', '2048x1024', '2048x1536'])
+    (32, 4, 16, 4, 2048, 1536),     # serve-hybrid-reason's
+    (16, 6, 128, 16, 2048, 768)],   # serve-mla-long's: top-6 of 128, f_tile 384
+    ids=['4096x2048', '2048x1024', '2048x1536', '2048x768'])
 def test_moe_decode_experts_and_the_loop_agree_on_picks_not_held(
         tokens, k, routed, held, h, f):
     """A layer that holds experts 0..held-1 of a router over `routed`
     hands both schedules its picks in its own numbering, `held` (one
     past the last) for a pick it does not hold, with weight zero: such a
     pick is no `hit` of the kernel and no row of the loop's sorted walk.
-    At the real tiles of the three cells that run this kernel
+    At the real tiles of the four cells that run this kernel
     (interpreted), against the loop over the same leaves in float32 at
     `HIGHEST` and against a float64 sum over the held picks."""
     from paddle_tpu.nlp.afmoe import grouped_experts

@@ -234,7 +234,11 @@ def _decode_resolves(log):
 
 
 @pytest.mark.parametrize('mode', list(_MODES))
-def test_a_mixed_batch_serves_the_parents_tokens(gpt, mode):
+def test_a_mixed_batch_serves_the_parents_tokens(gpt, mode, fresh_programs):
+    # `fresh_programs`: the store keys a program by the model's class,
+    # configuration and avals, and `traces` below counts THIS engine's —
+    # an engine of the same shape served earlier in the process (one of
+    # tests/test_serving.py's) would hand its programs over untraced
     log = obs.get_event_log()
     log.clear()
     eng, toks = _serve(gpt, mode)
