@@ -45,6 +45,16 @@ head, `q_nope_h . k_nope_jh = (q_nope_h W_UK_h^T) . c_j`, so `qL_h =
 q_nope_h W_UK_h^T`, logits `(qL_h . c_j + q_rope_h . r_j) /
 sqrt(nope + rope)`, `oL_h = sum_j p_ij c_j`, `o_h = oL_h W_UV_h`: the
 rows are read as they lie and no K or V is ever made from them.
+What runs the middle of that — logits, softmax, `oL` — is picked from
+the call alone (`ops.pallas.latent_decode_kernel`, no flag and no
+name): ONE query a slot with a boolean mask on a TPU, which is a decode
+sub-step of either decode block, goes through the Mosaic kernel
+`pallas_kernels.mla_decode_attention`, which walks each slot's row
+tiles up to the last row the mask shows and reads a tile once for the
+scores and the values (XLA's two einsums stream every row of every slot
+twice); speculation's k+1 rows, a prefill chunk, a prefix attach, an
+additive mask and every other backend are the einsums. The two
+products with `W_kvb` stay XLA's either way (scope `latent_absorb`).
 
 Activations are float32 and products three bf16 passes
 (`afmoe.ACTIVATION_PRECISION`, and why, at `AfmoeForCausalLM.forward`:
@@ -66,7 +76,8 @@ from ..nn.norm import RMSNorm
 from ..ops import pallas as _pallas
 from ..tensor import Tensor, apply_op, to_jax
 from .afmoe import ACTIVATION_PRECISION, AfmoeSparseMLP
-from .generation import (GenerationMixin, as_offset as _as_offset,
+from .generation import (GenerationMixin, active_rows as _active_rows,
+                         as_offset as _as_offset,
                          attended_rows as _attended_rows,
                          decode_mask as _decode_mask,
                          offset_grid as _offset_grid,
@@ -252,21 +263,39 @@ def _latent_attention(q_nope, q_rope, c, r, w_kvb, mask, scale):
     q_rope `[B, Sq, H, rope]`, rows c `[B, L, C]` (normed) and r `[B, L,
     rope]` (rotated), `w_kvb` `[C, H, nope + v]`, `mask` (boolean, or
     additive) broadcastable to `[B, 1, Sq, L]` -> `[B, Sq, H, v]`. Every head
-    reads the same rows; the softmax is float32."""
+    reads the same rows; the softmax is float32.
+
+    Where `ops.pallas.latent_decode_kernel` takes the call (one query a
+    slot, a boolean mask, a TPU: its conditions are the whole choice),
+    the rows go through ONE kernel that walks a slot's row tiles up to
+    the last row the mask shows and reads each once for the scores and
+    the values; a slot that is not decoding (`generation.active_rows`)
+    is bounded at nothing — its output is discarded — and walks one
+    tile. Everything else is XLA's two einsums over every row the mask
+    has a column for."""
     nope = q_nope.shape[-1]
+    kernel = _pallas.latent_decode_kernel(q_nope, c, mask)
     with jax.named_scope('latent_absorb'):
         q_lat = jnp.einsum('bqhn,chn->bqhc', q_nope,
                            w_kvb[..., :nope].astype(q_nope.dtype))
-    logits = (jnp.einsum('bqhc,bkc->bhqk', q_lat, c,
-                         preferred_element_type=jnp.float32)
-              + jnp.einsum('bqhr,bkr->bhqk', q_rope, r,
-                           preferred_element_type=jnp.float32)) * scale
-    if mask.dtype == jnp.bool_:
-        logits = jnp.where(mask, logits, _NEG)
+    if kernel is not None:
+        seen = jnp.broadcast_to(mask[:, 0, 0], (c.shape[0], mask.shape[-1]))
+        active = _active_rows()
+        if active is not None:
+            seen = seen & active[:, None]
+        o_lat = kernel(q_lat[:, 0], q_rope[:, 0], c, r, seen,
+                       scale)[:, None].astype(q_nope.dtype)
     else:
-        logits = logits + mask.astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1).astype(q_nope.dtype)
-    o_lat = jnp.einsum('bhqk,bkc->bqhc', probs, c)
+        logits = (jnp.einsum('bqhc,bkc->bhqk', q_lat, c,
+                             preferred_element_type=jnp.float32)
+                  + jnp.einsum('bqhr,bkr->bhqk', q_rope, r,
+                               preferred_element_type=jnp.float32)) * scale
+        if mask.dtype == jnp.bool_:
+            logits = jnp.where(mask, logits, _NEG)
+        else:
+            logits = logits + mask.astype(jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1).astype(q_nope.dtype)
+        o_lat = jnp.einsum('bhqk,bkc->bqhc', probs, c)
     with jax.named_scope('latent_absorb'):
         return jnp.einsum('bqhc,chv->bqhv', o_lat,
                           w_kvb[..., nope:].astype(o_lat.dtype))
@@ -340,10 +369,15 @@ class DeepseekV3Attention(Layer):
                            *(() if attn_mask is None else (attn_mask,)),
                            _name='mla_own_tokens')
         else:
-            # path (ii): absorbed, over the rows held
+            # path (ii): absorbed, over the rows held. The kernel, where
+            # it takes the call, is handed the leaves whole (it bounds
+            # its own reading, and a slice of a leaf is a copy to it);
+            # XLA's einsums the rows the mask has columns for
             mask = attn_mask if attn_mask is not None \
                 else _decode_mask(q_nope, c_cache, slot)
-            c_rows, r_rows = _attended_rows(c_cache, r_cache, mask)
+            c_rows, r_rows = (c_cache, r_cache) \
+                if _pallas.latent_decode_kernel(q_nope, c_cache, mask) \
+                else _attended_rows(c_cache, r_cache, mask)
 
             def held(qn, qr, cv, rv, w, m):
                 return _latent_attention(

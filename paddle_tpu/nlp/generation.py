@@ -165,6 +165,7 @@ def padded_decode_mask(keep, cache_len, cache_offset, sq):
 class _RoutingState(threading.local):
     def __init__(self):
         self.picks = None
+        self.active = None      # `routing_scope(active)`
         self.folded = None      # `state_scope`
 
 
@@ -179,18 +180,33 @@ class routing_scope:
     array, number of experts, whether the layer's routed experts are
     the kernel)` per expert layer, in layer order; a model without an
     expert layer leaves it empty, and the program traced around it is
-    the one it was."""
+    the one it was. `active` (`[B]` boolean, or None) is which rows of
+    the batch are decoding — a serving slot that is free or prefilling
+    rides every sub-step too, and what it computes is discarded: a
+    layer that can spend less on such a row asks `active_rows()`; one
+    that does not ask traces what it did."""
 
-    __slots__ = ('_prev', '_picks')
+    __slots__ = ('_prev', '_picks', '_active')
+
+    def __init__(self, active=None):
+        self._active = active
 
     def __enter__(self):
-        self._prev = _routing.picks
+        self._prev = _routing.picks, _routing.active
         self._picks = _routing.picks = []
+        _routing.active = self._active
         return self._picks
 
     def __exit__(self, *exc):
-        _routing.picks = self._prev
+        _routing.picks, _routing.active = self._prev
         return False
+
+
+def active_rows():
+    """`[B]` boolean, the rows the enclosing `routing_scope` says are
+    decoding; None outside a scope or where it was given none (every
+    row's output is wanted)."""
+    return _routing.active
 
 
 def note_routing(selected, num_experts, kernel=False, share=False):

@@ -4,10 +4,14 @@ Upstream analogue: the reference's hand-fused CUDA kernels
 (paddle/phi/kernels/fusion/gpu/*, flash-attn integration). Here the
 default path is plain jax — XLA already fuses normalization chains into
 adjacent matmuls — and the pallas kernels (ops/pallas_kernels.py) take
-over on TPU backends for two inner loops where a hand-written schedule
-beats the XLA-generated one: attention (manual VMEM blocking), and the
-routed experts of a decode batch (`expert_kernel`: one weight stream
-across the experts, where XLA's `while` fetches each expert cold).
+over on TPU backends for three inner loops where a hand-written schedule
+beats the XLA-generated one: attention over a call's own tokens
+(`flash_attention`: manual VMEM blocking), the routed experts of a
+decode batch (`expert_kernel`: one weight stream across the experts,
+where XLA's `while` fetches each expert cold), and decode attention over
+latent rows (`latent_decode_kernel`: a slot's row tiles up to that
+slot's length, each read once for the scores and the values, where
+XLA's two einsums stream every row of every slot twice).
 
 Which path runs is decided by explicit conditions on the backend and
 the shapes, never by a caught exception: on a TPU a kernel that fails
@@ -21,6 +25,7 @@ from inside apply_op bodies / jitted train steps).
 from __future__ import annotations
 
 import functools
+import math
 import os
 
 import jax
@@ -195,4 +200,34 @@ def expert_kernel(tokens, block_rows, weight_dtype, interpret=False):
         from . import pallas_kernels
         return functools.partial(pallas_kernels.moe_decode_experts,
                                  interpret=interpret)
+    return None
+
+
+def latent_decode_kernel(q, rows, mask, interpret=False):
+    """Dispatch for absorbed attention over latent rows
+    (`nlp/deepseek_v3.py::_latent_attention`): the pallas kernel
+    `pallas_kernels.mla_decode_attention`, its row tile bound (`.keywords
+    ['tile']`: what the serving engine counts a round's `read_rows`
+    by), where it applies, None where XLA's einsums run. Read from the
+    call alone — `q` `[B, Sq, H, ...]`, the leaf `rows` `[B, L, C]` as
+    it is held, `mask` `[B or 1, 1, Sq, n]` (anything with a `.shape`
+    and a `.dtype`): the kernel takes ONE query a slot (a decode
+    sub-step), hidden rows named by a boolean mask shared by the heads,
+    on a TPU (or anywhere with interpret=True), rows float32 or bf16 of
+    a whole number of lanes, and `n` and `L` a whole number of tiles.
+    Speculation's k+1 rows, a prefill chunk, a prefix attach, an
+    additive mask and every other backend keep the einsums: there they
+    are the tier-1 path and the parity ground truth. The conditions are
+    the whole selection: a kernel error on a TPU propagates."""
+    from . import pallas_kernels
+    n = mask.shape[-1]
+    tile = pallas_kernels._mla_row_tile(math.gcd(n, rows.shape[1]))
+    if ((interpret or _pallas_enabled()) and q.shape[1] == 1
+            and mask.dtype == jnp.bool_ and len(mask.shape) == 4
+            and mask.shape[1] == 1 and mask.shape[2] == 1
+            and mask.shape[0] in (1, q.shape[0])
+            and rows.dtype in (jnp.float32, jnp.bfloat16)
+            and rows.shape[-1] % 128 == 0 and tile is not None):
+        return functools.partial(pallas_kernels.mla_decode_attention,
+                                 tile=tile, interpret=interpret)
     return None

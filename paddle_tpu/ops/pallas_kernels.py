@@ -20,6 +20,10 @@ Contents:
 - `moe_decode_experts(x, sel, w, gate_w, up_w, down_w)` — an expert
   layer's routed experts for a decode batch: one weight stream over the
   distinct experts the batch picked (dispatch: `ops.pallas.expert_kernel`).
+- `mla_decode_attention(q_lat, q_rope, c, r, seen, scale)` — decode
+  attention over latent rows, absorbed: a slot's row tiles up to that
+  slot's length, each read once for the scores and the values
+  (dispatch: `ops.pallas.latent_decode_kernel`).
 
 All kernels keep stats/accumulators in fp32 VMEM scratch and feed the
 MXU with `preferred_element_type=float32` per the TPU tiling rules.
@@ -1082,3 +1086,184 @@ def moe_decode_experts(x, sel, w, gate_w, up_w, down_w, *, f_tile=None,
     )(ids, cnt[None], jnp.pad(x, pad), jnp.pad(wd, pad), gate_w, up_w,
       down_w)
     return out[:t]
+
+
+# ---------------------------------------------------------------------------
+# decode attention over latent rows (ISSUE 38; upstream analogues:
+# FlashMLA's and vLLM's absorbed MLA decode kernels). One query a slot,
+# every head reading the same rows `c` (and `r` for the rotary half of
+# a logit): the grid walks the row tiles the slots HOLD, slot after slot
+# up to each slot's length, and a tile serves the scores AND the values
+# while it sits in VMEM — XLA's two einsums stream `c` twice, over every
+# row of every slot.
+# ---------------------------------------------------------------------------
+
+def _mla_row_tile(rows):
+    """How many rows a grid step takes: 512 where it divides the rows
+    attended (CHANGES.md, PR 38, has the chip's numbers at 256, 512 and
+    1024), else the largest whole number of lanes that does; None where
+    there is none."""
+    return next((n for n in (512, 256, 128) if rows % n == 0), None)
+
+
+def _split2(a):
+    """float32 [n, k] -> [2n, k] bf16, `[hi ; lo]` stacked by rows, hi +
+    lo = a to 16 bits of mantissa: the two parts `precision='high'`
+    splits an operand into. Rows that ARE bf16 have no `lo`: they come
+    back as they are."""
+    if a.dtype == jnp.bfloat16:
+        return a
+    hi = a.astype(jnp.bfloat16)
+    lo = (a - hi.astype(jnp.float32)).astype(jnp.bfloat16)
+    return jnp.concatenate([hi, lo], axis=0)
+
+
+def _dot_high(a2, n, b2, m, dims):
+    """`a . b` as `precision='high'` gives it — hi.lo, lo.hi, hi.hi in
+    float32, the small ones first — from the operands' stacked parts
+    (`_split2`; `n`, `m` the rows of `a`, `b`; a `b2` of `m` rows is an
+    operand that is bf16 and has no `lo`). Both parts of `a` meet a
+    tile of `b_hi` in ONE product, so the tile is pushed through the MXU
+    once for the two."""
+    def dot(a, b):
+        return jax.lax.dot_general(a, b, (dims, ((), ())),
+                                   preferred_element_type=jnp.float32,
+                                   precision=jax.lax.Precision.DEFAULT)
+    y = dot(a2, b2[:m])
+    y = y[n:] + y[:n]
+    return y if b2.shape[0] == m else dot(a2[:n], b2[m:]) + y
+
+
+def _mla_decode_kernel(slot_ref, tile_ref, last_ref, ql_ref, qr_ref, c_ref,
+                       r_ref, seen_ref, o_ref, ql_s, qr_s, m_s, l_s, acc_s,
+                       *, tile, scale):
+    """ONE flat grid over the row tiles the slots hold, slot after slot
+    (`slot_ref`, `tile_ref`: step -> the slot, and which of its tiles):
+    a step folds its tile into the slot's online softmax (running max
+    `m_s`, sum `l_s`, `[H, C]` accumulator `acc_s`, float32); a slot's
+    first tile starts them, its last (`last_ref`) writes the output. No
+    step is spent on a tile past a slot's bound, and the next slot's
+    first tile is in flight while this slot's last multiplies."""
+    i = pl.program_id(0)
+    h = ql_ref.shape[1]
+
+    @pl.when(tile_ref[i] == 0)
+    def _():
+        m_s[...] = jnp.full_like(m_s, _NEG_INF)
+        l_s[...] = jnp.zeros_like(l_s)
+        acc_s[...] = jnp.zeros_like(acc_s)
+        # the scaled query's parts, once a slot
+        ql_s[...] = _split2(ql_ref[0].astype(jnp.float32) * scale)
+        qr_s[...] = _split2(qr_ref[0].astype(jnp.float32) * scale)
+
+    c, r = _split2(c_ref[0]), _split2(r_ref[0])         # once, for both
+    nt = ((1,), (1,))               # contract the minor dim of both
+    s = _dot_high(ql_s[...], h, c, tile, nt) \
+        + _dot_high(qr_s[...], h, r, tile, nt)                 # [H, T]
+    s = jnp.where(seen_ref[0] != 0, s, _NEG_INF)
+    m_prev = m_s[...]                                      # [H, 128]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev[:, :1] - m_new[:, :1])
+    p = jnp.exp(s - m_new[:, :1])
+    l_s[...] = l_s[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc_s[...] = acc_s[...] * alpha + _dot_high(
+        _split2(p), h, c, tile, ((1,), (0,)))
+    m_s[...] = m_new
+
+    @pl.when(last_ref[i] != 0)
+    def _():
+        o_ref[0] = (acc_s[...] / l_s[:, :1]).astype(o_ref.dtype)
+
+
+def mla_decode_attention(q_lat, q_rope, c, r, seen, scale, *, tile=None,
+                         interpret=False):
+    """Absorbed latent attention of ONE query a slot: q_lat `[B, H, C]`
+    and q_rope `[B, H, R]` float32 against the rows c `[B, L, C]` and r
+    `[B, L, R]` (float32 or bf16, as the pool holds them), `seen` `[B,
+    rows]` boolean, `rows <= L`: `softmax_k((q_lat . c_k + q_rope . r_k)
+    * scale) . c` over the rows `seen` shows -> `[B, H, C]` float32.
+
+    A slot's bound is its last seen row + 1: the row tiles under it are
+    walked, each read once for the scores and the values, and a row
+    `seen` hides under the bound stays hidden. A slot that sees no row
+    walks one tile and gives a finite, meaningless average of it (the
+    caller discards an inactive slot's output). The grid is as long as
+    the tiles to walk (a dynamic bound: what a batch costs goes by the
+    rows it holds, not by `B x L`). Products are what `precision='high'`
+    gives XLA (`_dot_high`), the softmax float32. `tile` (dividing
+    `rows` and `L`; on a TPU whole lanes) is the rows a grid step takes;
+    None is `_mla_row_tile` of both."""
+    bsz, h, lat = q_lat.shape
+    length, rope, rows = c.shape[1], r.shape[2], seen.shape[1]
+    if q_rope.shape != (bsz, h, rope) or c.shape != (bsz, length, lat) \
+            or r.shape[:2] != (bsz, length) or seen.shape[0] != bsz \
+            or rows > length or c.dtype != r.dtype:
+        raise ValueError(
+            f'mla_decode_attention: q {q_lat.shape} / {q_rope.shape} '
+            f'against rows {c.shape} {c.dtype} / {r.shape} {r.dtype}, '
+            f'seen {seen.shape}')
+    if seen.dtype != jnp.bool_:
+        raise ValueError(f'mla_decode_attention: a boolean mask, not '
+                         f'{seen.dtype}')
+    if tile is None:
+        tile = _mla_row_tile(math.gcd(rows, length))
+    if not tile or rows % tile or length % tile:
+        raise ValueError(f'mla_decode_attention: tile {tile} must divide '
+                         f'the rows attended {rows} and held {length}')
+    parts = 1 if c.dtype == jnp.bfloat16 else 2
+    # tiny, in XLA: a slot's bound and tiles, the table step -> (slot,
+    # its tile, whether its last) — one entry more than the most steps,
+    # every entry past the walk the last real step's — and the mask as
+    # the kernel reads it
+    k = jnp.arange(1, rows + 1, dtype=jnp.int32)
+    bound = jnp.max(jnp.where(seen, k, 0), axis=1)
+    tiles = (jnp.maximum(bound, 1) + tile - 1) // tile
+    ends = jnp.cumsum(tiles)
+    step = jnp.minimum(jnp.arange(bsz * (rows // tile) + 1, dtype=jnp.int32),
+                       ends[-1] - 1)
+    slot = jnp.sum(ends[None, :] <= step[:, None], axis=1, dtype=jnp.int32)
+    at = step - (ends - tiles)[slot]
+    last = (at == tiles[slot] - 1).astype(jnp.int32)
+
+    def of_slot(i, slot_ref, tile_ref, last_ref):
+        return slot_ref[i], 0, 0
+
+    def of_tile(i, slot_ref, tile_ref, last_ref):
+        return slot_ref[i], tile_ref[i], 0
+
+    def seen_tile(i, slot_ref, tile_ref, last_ref):
+        return slot_ref[i], 0, tile_ref[i]
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(ends[-1],),
+        in_specs=[
+            pl.BlockSpec((1, h, lat), of_slot),
+            pl.BlockSpec((1, h, rope), of_slot),
+            pl.BlockSpec((1, tile, lat), of_tile),
+            pl.BlockSpec((1, tile, rope), of_tile),
+            pl.BlockSpec((1, 1, tile), seen_tile),
+        ],
+        out_specs=pl.BlockSpec((1, h, lat), of_slot),
+        scratch_shapes=[
+            pltpu.VMEM((2 * h, lat), jnp.bfloat16),     # [hi ; lo] q_lat
+            pltpu.VMEM((2 * h, rope), jnp.bfloat16),    # [hi ; lo] q_rope
+            pltpu.VMEM((h, 128), jnp.float32),          # running max
+            pltpu.VMEM((h, 128), jnp.float32),          # running sum
+            pltpu.VMEM((h, lat), jnp.float32),          # accumulator
+        ])
+    # two buffers of each row tile (`r` padded to whole lanes), their
+    # parts, the products' float32 results; far under the chip's 128 MiB
+    lanes = lat + -(-rope // 128) * 128
+    vmem = tile * lanes * (2 * c.dtype.itemsize + 2 * parts) \
+        + 16 * h * (tile + lat) * 4 + (8 << 20)
+    return pl.pallas_call(
+        functools.partial(_mla_decode_kernel, tile=tile, scale=scale),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((bsz, h, lat), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=('arbitrary',), vmem_limit_bytes=vmem),
+        interpret=interpret,
+        name='mla_decode_attention',
+    )(slot, at, last, q_lat, q_rope, c, r,
+      seen.astype(jnp.int32)[:, None, :])

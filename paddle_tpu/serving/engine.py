@@ -69,6 +69,7 @@ from ..jit import functional_state
 from ..nlp.generation import (_NEG_INF, cached_forward, experts_touched,
                               latent_layers, ring_layers, routing_scope,
                               state_layers, state_scope)
+from ..ops import pallas as _pallas
 from ..resilience import RetryPolicy, call_with_retry
 from ..tensor import Tensor
 from .adapters.apply import adapter_scope as _adapter_scope
@@ -489,6 +490,12 @@ class InferenceEngine:
         half = self.pool.max_length // 2
         self._half_rows = half if half > self.decode_block else 0
         self._num_experts = int(getattr(cfg, 'num_experts', 0) or 0)
+        # either program's rows -> the row tile by which a latent
+        # layer's attention is bounded per slot there, None where it
+        # reads every row: what `read_rows` counts such a layer by
+        self._latent_tiles = {
+            rows: self._latent_tile(rows)
+            for rows in (self.pool.max_length, self._half_rows) if rows}
 
         self._trace_counts = collections.Counter()
         self._counts = collections.Counter()
@@ -824,7 +831,7 @@ class InferenceEngine:
             # every slot <= pos; freed/stale rows above are masked out
             # (a window layer narrows the mask by itself, from `pos`)
             mask = (k_slot[None, :] <= pos[:, None])[:, None, None, :]
-            with routing_scope() as picks:
+            with routing_scope(active) as picks:
                 logits, pool = fwd(tok[:, None], pool, pos, pos, mask)
             nxt = sample_rows(logits[:, -1], temp, topk, topp, greedy,
                               keys, steps)
@@ -1647,6 +1654,40 @@ class InferenceEngine:
         need = np.minimum(written[:, None], self._layer_rows[None, :])
         return int(need.sum()), int(need[:, self._ring].sum())
 
+    def _latent_tile(self, rows: int):
+        """The row tile of the kernel that runs a latent layer's decode
+        attention in the program that attends over `rows` rows, None
+        where XLA's einsums run it (no latent entry, another backend):
+        the model's own dispatch, asked with the call the decode scan
+        makes — one query a slot, the leaf as held, a boolean mask of
+        `rows` columns."""
+        if not self.pool.latent_layers:
+            return None
+        spec, slots = jax.ShapeDtypeStruct, self.pool.num_slots
+        kernel = _pallas.latent_decode_kernel(
+            spec((slots, 1), np.float32),
+            self.pool.row_spec[self.pool.latent_layers[0]][0],
+            spec((slots, 1, 1, rows), np.bool_))
+        return kernel and kernel.keywords['tile']
+
+    def _read_rows(self, rows: int) -> int:
+        """Cache rows this round's attention READS, over slots and
+        layers: every slot's first `rows` rows on every layer that keeps
+        the slot's length and the whole of every ring — but on a latent
+        layer whose attention is bounded per slot (`_latent_tile`) what
+        the kernel walks: each decoding slot's length rounded up to the
+        row tile, one tile of a slot that is not decoding."""
+        slots, full = self.pool.num_slots, self._full_layers
+        read = slots * self._ring_rows
+        tile = self._latent_tiles[rows]
+        if tile:
+            written = np.where(self._active,
+                               self._pos.astype(np.int64) + 1, 1)
+            walked = -(-np.minimum(written, rows) // tile) * tile
+            read += int(walked.sum()) * len(self.pool.latent_layers)
+            full -= len(self.pool.latent_layers)
+        return read + slots * rows * full
+
     def _note_routing(self, round_span, routing):
         """Book a round's routing counts (`[decode_block, 2, expert
         layers]`: the distinct experts the active slots routed to, and
@@ -1726,10 +1767,8 @@ class InferenceEngine:
                        real_rows=self.pool.written_rows) as round_span:
             rows = self._round_rows()
             needed, needed_ring = self._needed_rows()
-            round_span.set(
-                needed_rows=needed, rows=rows,
-                read_rows=self.pool.num_slots * (
-                    rows * self._full_layers + self._ring_rows))
+            round_span.set(needed_rows=needed, rows=rows,
+                           read_rows=self._read_rows(rows))
             if self.pool.ring_layers:
                 round_span.set(needed_rows_window=needed_ring)
             if self.pool.state_layers:

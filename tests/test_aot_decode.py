@@ -16,9 +16,12 @@ whole-length block's compile chose for it (AUTO), and ENTRY of neither
 decode program copies a whole leaf any more (serve-hybrid-reason,
 serve-swa-reason: 4 and 6 such copies with the default layouts, at three
 layers); seat, copy and slice compile to the pool's formats; the other
-cells' leaves keep the default, which is what AUTO would give them. Every
-compile here goes through the store's compile site with the formats the
-pool holds, as the engine's do.
+cells' leaves keep the default, which is what AUTO would give them. Since
+PR 38: serve-mla-long's decode programs hold ONE Mosaic attention call a
+latent layer (`mla_decode_attention`, under `attention`), which takes both
+leaves of the layer where the row's write left them: nothing of a leaf's
+size is copied, transposed or staged. Every compile here goes through the
+store's compile site with the formats the pool holds, as the engine's do.
 
 The topology is described inside a fixture, never at import: every xdist
 worker imports this file, and only one process may load libtpu — so
@@ -143,6 +146,48 @@ def staged_pool_rows(hlo_text, leaf):
     return into, out
 
 
+def _rows_of(leaves):
+    """A regex for an array of a pool leaf's dtype and shape, in any
+    layout."""
+    shapes = sorted({','.join(map(str, v.shape)) for v in leaves})
+    return r'f32\[(?:' + '|'.join(shapes) + r')\]\{[^}]*\}'
+
+
+def moved_leaves(hlo_text, leaves):
+    """The instructions ANYWHERE in the program that copy, transpose or
+    stage an array of a pool leaf's size (`copy`, `transpose`,
+    `copy-start`, `slice-start` whose first result is one): what a
+    kernel that constrains its operands' layouts could bring about."""
+    moved = re.compile(r'\s*(%\S+) = \(*' + _rows_of(leaves) + r'[^=]* '
+                       r'(?:copy|copy-start|slice-start|transpose)\(')
+    return [m.group(1) for m in map(moved.match, hlo_text.splitlines())
+            if m]
+
+
+def test_moved_leaves_reads_the_whole_program():
+    class c:
+        shape = (16, 16384, 512)
+
+    class r:
+        shape = (16, 16384, 64)
+    hlo = """
+%body (p: f32[16,16384,64]) -> f32[16,16384,64] {
+  %copy.3 = f32[16,16384,64]{2,1,0:T(8,128)} copy(%p)
+  %transpose.9 = f32[16,16384,512]{1,2,0:T(8,128)} transpose(%q), dimensions={0,2,1}
+  %fusion.4 = f32[16,16384,64]{2,1,0:T(8,128)} fusion(%p, %copy.1), kind=kLoop
+  %copy-start.2 = (f32[16,16384,512]{2,1,0:T(8,128)S(1)}, f32[16,16384,512]{2,1,0:T(8,128)}, u32[]{:S(2)}) copy-start(%x)
+  %copy.8 = f32[16,32,64]{2,1,0:T(8,128)} copy(%small)
+  %k = f32[16,32,512]{2,1,0} custom-call(%a, %fusion.4), custom_call_target="tpu_custom_call", operand_layout_constraints={f32[16,16384,64]{2,1,0}}
+}
+
+ENTRY %main (a: f32[16,16384,64]) -> f32[16,16384,64] {
+  %copy.135 = f32[16,16384,64]{1,2,0:T(8,128)} copy(%a)
+}
+"""
+    assert moved_leaves(hlo, [c, r]) == ['%copy.3', '%transpose.9',
+                                         '%copy-start.2', '%copy.135']
+
+
 def test_staged_pool_rows_reads_the_compilers_spelling():
     class leaf:
         shape = (12, 1024, 16, 128)
@@ -243,6 +288,44 @@ def test_an_expert_layer_is_one_kernel_on_v5e(workload, program, one_chip):
     assert pool_bytes == state + kv * (
         2 if workload == 'serve-hybrid-reason' else 1)
     assert ma.alias_size_in_bytes == pool_bytes
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize('program', ['whole', 'half'])
+def test_latent_attention_is_one_kernel_on_the_leaves_as_held_on_v5e(
+        program, one_chip):
+    """serve-mla-long at 16 x 16,384, a dense layer and two expert
+    layers: a sub-step holds one `mla_decode_attention` a layer under
+    `attention` beside the expert kernels; its operands are the leaves
+    the row's scatter has just written (the current token's row is
+    among those attended), in the layout the pool holds them; no
+    `[16,16384,512]` or `[16,16384,64]` array is copied, transposed or
+    staged anywhere in the program; XLA's temporaries stay under the
+    0.18 GiB the einsums' program had at five layers (PR 37)."""
+    cell = spec.Spec().cell('serve-mla-long')
+    cell['config']['num_hidden_layers'] = 3
+    text, ma, leaves, pool_bytes = _compile_decode_block(cell, one_chip,
+                                                         program)
+    assert 'decode' in re.search(r'HloModule (\S+)', text).group(1)
+    calls = [ln for ln in text.splitlines() if 'tpu_custom_call' in ln]
+    attention = [ln for ln in calls if 'mla_decode_attention' in ln]
+    assert len(attention) == 3, [ln[:160] for ln in calls]
+    assert all('/attention/mla_decode_attention' in ln for ln in attention)
+    assert sum('moe/experts/moe_decode_experts' in ln for ln in calls) == 2
+    assert not moved_leaves(text, leaves)
+    # the kernel reads what `kv_write`'s scatters return, leaf for leaf
+    written = {m.group(1) for ln in text.splitlines() if 'kv_write' in ln
+               for m in [re.match(r'\s*(%\S+) = ' + _rows_of(leaves)
+                                  + r' fusion\(', ln)] if m}
+    for ln in attention:
+        operands = re.search(r'custom-call\(([^)]*)\)', ln).group(1)
+        assert len(written & set(operands.split(', '))) == 2, ln[:300]
+        assert 'f32[16,16384,512]{2,1,0}, f32[16,16384,64]{2,1,0}' in ln
+    # the 64-wide leaf is held 128 lanes wide, as the kernel reads it
+    assert pool_bytes == sum(
+        v.size // v.shape[-1] * max(v.shape[-1], 128) * 4 for v in leaves)
+    assert ma.alias_size_in_bytes == pool_bytes
+    assert ma.temp_size_in_bytes <= 0.18 * 2 ** 30
 
 
 def _cut_to_three_layers(cfg):

@@ -79,16 +79,16 @@ REF_LEN = 64
 _REF = {}
 
 
-def _ref_logits(cfg, w, ids):
+def _ref_logits(cfg, w, ids, ref_len=REF_LEN):
     """The reference's logits, one compile a set of weights: every row
-    goes through alone, right-padded to `REF_LEN` (the reference is
+    goes through alone, right-padded to `ref_len` (the reference is
     causal: what follows a position does not reach it)."""
     if id(w) not in _REF:
         _REF[id(w)] = (w, jax.jit(lambda wt, row: R.logits_of(
             cfg, wt, R.hidden_states(cfg, wt, row))))
     fn = _REF[id(w)][1]
     ids = np.atleast_2d(np.asarray(ids, 'int32'))
-    padded = np.zeros((ids.shape[0], REF_LEN), 'int32')
+    padded = np.zeros((ids.shape[0], ref_len), 'int32')
     padded[:, :ids.shape[1]] = ids
     return np.stack([np.asarray(fn(w, jnp.asarray(row[None])))[0]
                      for row in padded])[:, :ids.shape[1]]
@@ -407,10 +407,11 @@ def test_prefill_program_then_decode_logits_at_every_position(
     assert worst < TOL
 
 
-def _served_gap(cfg, w, prompt, toks):
+def _served_gap(cfg, w, prompt, toks, ref_len=REF_LEN):
     """How far a served token's reference logit lies below the
     reference's best at its position: the benchmark's comparison."""
-    lg = _ref_logits(cfg, w, prompt + toks[:-1])[0, len(prompt) - 1:]
+    lg = _ref_logits(cfg, w, prompt + toks[:-1],
+                     ref_len)[0, len(prompt) - 1:]
     return float((lg.max(-1) - lg[np.arange(len(toks)), toks]).max())
 
 
@@ -858,3 +859,211 @@ def test_config_presets_and_refusals():
                       (dict(qk_rope_head_dim=3), 'even')):
         with pytest.raises(ValueError, match=what):
             DeepseekV3Config.tiny(**bad)
+
+
+# ---------------------------------------------------------------------------
+# (k) decode attention through the kernel (PR 38): which calls take it,
+# and the engine with it interpreted
+# ---------------------------------------------------------------------------
+def _call(queries=1, slots=16, rows=16384, held=16384, width=512,
+          mask='bool', rows_dtype='float32', heads=1):
+    spec = jax.ShapeDtypeStruct
+    return (spec((slots, queries, 32, 128), jnp.float32),
+            spec((slots, held, width), rows_dtype),
+            spec((slots, heads, queries, rows), mask))
+
+
+@pytest.mark.parametrize('what,call,interpret,tile', [
+    ('a decode sub-step', _call(), True, 512),
+    ('... over bf16 rows', _call(rows_dtype='bfloat16'), True, 512),
+    ('... of the half-length program', _call(rows=8192), True, 512),
+    ('... with one mask for every slot', _call(slots=1), True, 512),
+    ('... over 768 rows: the largest tile that divides', _call(
+        rows=768, held=768), True, 256),
+    ('... half of them', _call(rows=384, held=768), True, 128),
+    ('the CPU', _call(), False, None),
+    ("speculation's k+1 rows", _call(queries=5), True, None),
+    ('a prefill chunk', _call(queries=512), True, None),
+    ('an additive mask', _call(mask='float32'), True, None),
+    ('a mask by head', _call(heads=32), True, None),
+    ('rows of 16 numbers', _call(width=16), True, None),
+    ('rows of 576: not whole lanes', _call(width=576), True, None),
+    ('rows in float16', _call(rows_dtype='float16'), True, None),
+    ('64 rows: no tile of whole lanes', _call(rows=64, held=64), True, None),
+    ('192 of 384 rows: no tile', _call(rows=192, held=384), True, None),
+], ids=lambda v: v.replace(' ', '_') if isinstance(v, str) else None)
+def test_the_kernel_takes_a_call_by_what_the_call_is(what, call, interpret,
+                                                     tile):
+    """`ops.pallas.latent_decode_kernel`'s conditions one by one: one
+    query a slot, a boolean mask shared by the heads, a TPU or
+    interpret, float32 or bf16 rows of whole lanes, whole tiles."""
+    from paddle_tpu.ops import pallas, pallas_kernels
+    kernel = pallas.latent_decode_kernel(*call, interpret=interpret)
+    if tile is None:
+        assert kernel is None, what
+    else:
+        assert kernel.func is pallas_kernels.mla_decode_attention
+        assert kernel.keywords == dict(tile=tile, interpret=True), what
+
+
+# sha256 (first 16 hex digits) of the StableHLO text of this family's own
+# programs at the tiny presets (2 slots x 64, block 4, bucket 16), taken
+# on the PARENT of PR 38 (commit 90423b8) by `_own_program_texts` below
+_PARENT_OWN_PROGRAMS = {
+    ('tiny', 'decode'): '7f1fc5826be26a90',
+    ('tiny', 'decode_half'): 'cf4185e6d5cf00af',
+    ('tiny', 'prefill'): 'feba32510e9fd5d1',
+    ('tiny', 'chunk'): '338c89c25e5b4dce',
+    ('tiny_wide_v', 'decode'): '79b7ba536389d5cb',
+    ('tiny_wide_v', 'decode_half'): '8464664a0954bc96',
+    ('tiny_wide_v', 'prefill'): '683ac209cf3b7b56',
+    ('tiny_wide_v', 'chunk'): '0af2fdcdd9919cab',
+}
+
+
+def _own_program_texts(eng):
+    texts = _program_texts(eng)
+    row = jax.tree_util.tree_map(lambda v: jnp.zeros(v.shape, v.dtype),
+                                 eng.pool.row_spec)
+    texts['chunk'] = jax.jit(eng._chunk_prefill_fn).lower(
+        eng._params, eng._frozen, eng._buffers, row,
+        jnp.zeros((1, 16), jnp.int32), jnp.int32(3))
+    return {name: hashlib.sha256(lowered.as_text().encode()).hexdigest()[:16]
+            for name, lowered in texts.items()}
+
+
+def test_on_the_cpu_this_familys_programs_are_the_parents_too(built):
+    """Where the kernel does not take the call — here the CPU — every
+    program is the parent's, byte for byte: both decode blocks, the
+    whole prefill, a chunk against rows held."""
+    cfg, _, model = built
+    preset = 'tiny' if cfg['v_head_dim'] == 8 else 'tiny_wide_v'
+    for name, digest in _own_program_texts(_engine(model)).items():
+        assert digest == _PARENT_OWN_PROGRAMS[preset, name], name
+
+
+@pytest.fixture
+def interpreted(monkeypatch):
+    """The kernel wherever its conditions hold but the backend's:
+    `interpret=True` for the model's dispatch and the engine's count."""
+    import functools
+    from paddle_tpu.ops import pallas
+    monkeypatch.setattr(pallas, 'latent_decode_kernel', functools.partial(
+        pallas.latent_decode_kernel, interpret=True))
+
+
+WIDE_LEN = 768      # whole program: 3 tiles of 256; half: 3 of 128
+
+
+@pytest.fixture(scope='module')
+def wide():
+    """`tiny` with a latent of 128, whole lanes, and positions for
+    `WIDE_LEN` rows: a call the kernel takes."""
+    cfg = _cfg('tiny', kv_lora_rank=128, max_position_embeddings=WIDE_LEN)
+    w = _weights(cfg, seed=9)
+    return cfg, w, _model(cfg, w)
+
+
+def test_with_the_kernel_only_one_query_a_slot_leaves_the_einsums(
+        wide, interpreted):
+    """A latent of whole lanes and the kernel interpreted: the two
+    decode blocks are other programs than the einsums'; a chunk against
+    rows held and the whole prefill are the very programs they are
+    without it."""
+    _, _, model = wide
+    kw = dict(max_length=256, buckets=[16])
+    with_kernel = _own_program_texts(_engine(model, **kw))
+    with pytest.MonkeyPatch.context() as mp:
+        from paddle_tpu.ops import pallas
+        mp.setattr(pallas, 'latent_decode_kernel', lambda *a: None)
+        without = _own_program_texts(_engine(model, **kw))
+    assert {n for n in without if with_kernel[n] != without[n]} \
+        == {'decode', 'decode_half'}
+
+
+def _rounds(log):
+    return [e['attrs'] for e in log.events()
+            if e['name'] == 'serving.decode_round']
+
+
+def test_both_decode_programs_agree_with_the_reference_through_the_kernel(
+        wide, interpreted):
+    """`test_both_decode_programs_agree_with_the_reference` with the
+    kernel interpreted, 2 slots x 768: the half program's rounds walk
+    tiles of 128 rows, the whole program's of 256. One request at a
+    time, so a round's `read_rows` is exact: over the three latent
+    layers, the decoding slot's length rounded up to the tile, and ONE
+    tile of the slot that is not decoding (whatever stale position it
+    holds) — not `slots x rows`."""
+    cfg, w, model = wide
+    log = obs.get_event_log()
+    log.clear()
+    eng = _engine(model, max_length=WIDE_LEN, buckets=[16, 320, 640])
+    assert eng._latent_tile(WIDE_LEN) == 256
+    assert eng._latent_tile(WIDE_LEN // 2) == 128
+    for n_prompt, n_new in ((3, 12), (250, 24), (370, 16), (600, 12)):
+        prompt = _prompts((n_prompt,), seed=n_prompt)[0]
+        h = eng.submit(prompt, SamplingParams(max_new_tokens=n_new,
+                                              eos_token_id=-1))
+        eng.run()
+        assert _served_gap(cfg, w, prompt, list(h.tokens), WIDE_LEN) < TOL
+    rounds = _rounds(log)
+    assert {a['rows'] for a in rounds} == {WIDE_LEN // 2, WIDE_LEN}
+    walked = set()
+    for a in rounds:
+        assert a['active'] == 1 and a['needed_rows'] % 3 == 0
+        tile = 256 if a['rows'] == WIDE_LEN else 128
+        length = a['needed_rows'] // 3
+        tiles = -(-length // tile)
+        walked.add((tile, tiles))
+        assert a['read_rows'] == 3 * (tiles * tile + tile)
+        assert a['needed_rows'] <= a['read_rows'] < 2 * 3 * a['rows']
+    # one, two and three tiles of each size were walked
+    assert walked >= {(128, 1), (128, 2), (128, 3), (256, 2), (256, 3)}
+
+
+def test_through_router_and_engine_every_prompt_length_through_the_kernel(
+        wide, interpreted):
+    """`test_through_router_and_engine_every_prompt_length` with the
+    kernel interpreted: two slots decoding side by side at lengths that
+    differ, each bounded by its own."""
+    cfg, w, model = wide
+    log = obs.get_event_log()
+    log.clear()
+    lengths = (1, 2, BUCKET, 127, 128, 129, 300)
+    prompts = _prompts(lengths)
+    toks, eng = _through_the_router(model, prompts, N_NEW,
+                                    max_length=WIDE_LEN,
+                                    buckets=[BUCKET, 160, 320])
+    for prompt, got in zip(prompts, toks):
+        assert _served_gap(cfg, w, prompt, got, WIDE_LEN) < TOL, len(prompt)
+    assert eng._counts['prefills'] == len(lengths)
+    rounds = _rounds(log)
+    assert any(a['active'] == 2 for a in rounds)
+    for a in rounds:
+        tile = eng._latent_tile(a['rows'])
+        assert a['needed_rows'] <= a['read_rows'] \
+            <= a['needed_rows'] + 3 * 2 * tile
+        assert a['read_rows'] % (3 * tile) == 0
+
+
+def test_decode_round_reads_slots_x_rows_where_the_einsums_run(wide):
+    """The same engine on the CPU, the kernel not interpreted: what a
+    round reads is what it was, every row of every slot."""
+    _, _, model = wide
+    log = obs.get_event_log()
+    log.clear()
+    eng = _engine(model, max_length=WIDE_LEN, buckets=[16])
+    assert eng._latent_tile(WIDE_LEN) is None
+    eng.submit([5, 6, 7], SamplingParams(max_new_tokens=6, eos_token_id=-1))
+    eng.run()
+    rounds = _rounds(log)
+    assert rounds and all(a['read_rows'] == 2 * 3 * a['rows']
+                          for a in rounds)
+
+
+def test_a_model_without_a_latent_entry_is_asked_nothing(interpreted):
+    eng = InferenceEngine(_llama(), num_slots=2, max_length=256,
+                          decode_block=BLOCK, buckets=[BUCKET])
+    assert eng._latent_tile(256) is None
+    assert eng._read_rows(256) == 2 * 256 * len(eng.pool.row_spec)

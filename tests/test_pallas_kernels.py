@@ -515,3 +515,181 @@ def test_moe_decode_experts_and_the_loop_agree_on_picks_not_held(
     # a row none of whose picks is held gets nothing from either
     none = ~mine.any(axis=1)
     assert (got[none] == 0).all() and (loop[none] == 0).all()
+
+
+# ---------------------------------------------------------------------------
+# decode attention over latent rows (`mla_decode_attention`, PR 38)
+# ---------------------------------------------------------------------------
+MLA_ROWS, MLA_TILE = 64, 8
+
+
+def _mla_call(lengths, preset='tiny', dtype='float32', hidden=(), seed=0):
+    """One query a slot at a tiny preset's H x C x rope x nope x v against
+    `MLA_ROWS` rows; slot b sees rows `< lengths[b]`, less `hidden`
+    (pairs (slot, row))."""
+    from paddle_tpu.nlp.deepseek_v3 import DeepseekV3Config
+    cfg = getattr(DeepseekV3Config, preset)()
+    h, lat, rope = (cfg.num_attention_heads, cfg.kv_lora_rank,
+                    cfg.qk_rope_head_dim)
+    nope, vd = cfg.qk_nope_head_dim, cfg.v_head_dim
+    rs = np.random.RandomState(seed)
+    b = len(lengths)
+    seen = np.arange(MLA_ROWS)[None, :] < np.asarray(lengths)[:, None]
+    for slot, row in hidden:
+        seen[slot, row] = False
+    return dict(
+        q_nope=jnp.asarray(rs.randn(b, 1, h, nope), jnp.float32),
+        q_rope=jnp.asarray(rs.randn(b, 1, h, rope), jnp.float32),
+        c=jnp.asarray(rs.randn(b, MLA_ROWS, lat), dtype),
+        r=jnp.asarray(rs.randn(b, MLA_ROWS, rope), dtype),
+        w_kvb=jnp.asarray(0.3 * rs.randn(lat, h, nope + vd), jnp.float32),
+        mask=jnp.asarray(seen)[:, None, None, :],
+        scale=1.0 / np.sqrt(nope + rope))
+
+
+@pytest.fixture
+def mla_interpreted(monkeypatch):
+    """`_latent_attention`'s dispatch answers with the kernel,
+    interpreted, at `MLA_TILE` rows a step — at toy widths too, which
+    the real conditions leave to XLA — and keeps what it was handed."""
+    import functools
+    from paddle_tpu.ops import pallas, pallas_kernels
+    calls = []
+
+    def kernel(*args, **kw):
+        calls.append(args)
+        return pallas_kernels.mla_decode_attention(
+            *args, tile=MLA_TILE, interpret=True, **kw)
+    monkeypatch.setattr(pallas, 'latent_decode_kernel',
+                        lambda q, rows, mask: kernel)
+    return calls
+
+
+def _mla_both(call, calls):
+    from paddle_tpu.nlp import deepseek_v3
+    from paddle_tpu.ops import pallas
+    before = len(calls)
+    got = np.asarray(deepseek_v3._latent_attention(**call), np.float64)
+    assert len(calls) == before + 1
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(pallas, 'latent_decode_kernel', lambda *a: None)
+        want = np.asarray(deepseek_v3._latent_attention(**call), np.float64)
+    assert len(calls) == before + 1     # the einsums, not the kernel again
+    return got, want
+
+
+@pytest.mark.parametrize('dtype', ['float32', 'bfloat16'])
+@pytest.mark.parametrize('preset', ['tiny', 'tiny_wide_v'])
+@pytest.mark.parametrize('lengths,hidden', [
+    ((1,), ()),
+    ((MLA_TILE - 1, MLA_TILE, MLA_TILE + 1), ()),
+    ((MLA_ROWS, 5 * MLA_TILE), ()),
+    ((43, 29, 64), ((0, 3), (0, 41), (1, 0), (2, 63), (2, 17)))],
+    ids=['one_row', 'around_a_tile_edge', 'every_row', 'hidden_under_bound'])
+def test_mla_decode_attention_agrees_with_the_einsums(
+        lengths, hidden, preset, dtype, mla_interpreted):
+    """The kernel, interpreted, against `_latent_attention`'s XLA path
+    on the same call: a slot's row tiles up to its bound, three bf16
+    passes where the einsums are exact float32 on the CPU, an online
+    softmax where they take one over the whole row — float32 rounding
+    of a reordered sum, and the 2^-16 the dropped lo.lo pass is worth."""
+    call = _mla_call(lengths, preset, dtype, hidden)
+    got, want = _mla_both(call, mla_interpreted)
+    assert got.shape == want.shape
+    assert np.abs(got - want).max() < 2e-5 * np.abs(want).max()
+
+
+def test_mla_decode_attention_walks_one_tile_of_a_slot_not_decoding(
+        mla_interpreted):
+    """An inactive slot beside long ones (`routing_scope(active)`, as
+    the decode scan gives it): the kernel is handed NO seen row of it —
+    its bound is nothing, one tile walked, whatever its stale position
+    shows — and the decoding slots read what they read without it."""
+    from paddle_tpu.nlp import deepseek_v3, generation
+    call = _mla_call((57, MLA_ROWS, 40))
+    active = jnp.asarray([True, False, True])
+    with generation.routing_scope(active):
+        got = np.asarray(deepseek_v3._latent_attention(**call))
+    seen = np.asarray(mla_interpreted[0][4])
+    assert seen[0].sum() == 57 and not seen[1].any() and seen[2].sum() == 40
+    assert generation.active_rows() is None         # the scope is closed
+    alone, want = _mla_both(call, mla_interpreted)
+    assert np.isfinite(got).all()
+    assert np.array_equal(got[[0, 2]], alone[[0, 2]].astype(got.dtype))
+    assert np.abs(got[[0, 2]] - want[[0, 2]]).max() \
+        < 2e-5 * np.abs(want).max()
+
+
+@pytest.mark.parametrize('rows', [MLA_ROWS, MLA_ROWS // 2])
+def test_mla_decode_attention_reads_the_leaf_whole_under_a_shorter_mask(
+        rows):
+    """The half-length decode program's mask has half the columns: the
+    kernel takes the leaves whole and reads no row past the mask."""
+    from paddle_tpu.ops.pallas_kernels import mla_decode_attention
+    call = _mla_call((rows, 9))
+    seen = call['mask'][:, 0, 0, :rows]
+    q_lat = jnp.einsum('bqhn,chn->bqhc', call['q_nope'],
+                       call['w_kvb'][..., :call['q_nope'].shape[-1]])[:, 0]
+    args = (q_lat, call['q_rope'][:, 0])
+    poisoned = call['c'].at[:, rows:].set(jnp.nan)
+    got = mla_decode_attention(*args, poisoned, call['r'], seen,
+                               call['scale'], tile=MLA_TILE, interpret=True)
+    cut = mla_decode_attention(*args, call['c'][:, :rows],
+                               call['r'][:, :rows], seen, call['scale'],
+                               tile=MLA_TILE, interpret=True)
+    assert np.array_equal(np.asarray(got), np.asarray(cut))
+
+
+@pytest.mark.parametrize('changed,match', [
+    (dict(seen=jnp.zeros((2, MLA_ROWS), jnp.float32)), 'boolean mask'),
+    (dict(seen=jnp.zeros((3, MLA_ROWS), bool)), 'against rows'),
+    (dict(seen=jnp.zeros((2, 2 * MLA_ROWS), bool)), 'against rows'),
+    (dict(r=jnp.zeros((2, MLA_ROWS, 4), jnp.bfloat16)), 'against rows'),
+    (dict(q_rope=jnp.zeros((2, 4, 8), jnp.float32)), 'against rows'),
+    (dict(tile=24), 'must divide'),
+    (dict(tile=None), 'must divide')],
+    ids=['additive_mask', 'mask_of_other_slots', 'mask_past_the_leaf',
+         'leaves_of_two_dtypes', 'rope_width', 'tile_not_a_divisor',
+         'no_tile_of_whole_lanes'])
+def test_mla_decode_attention_refuses(changed, match):
+    from paddle_tpu.ops.pallas_kernels import mla_decode_attention
+    call = dict(q_lat=jnp.zeros((2, 4, 16), jnp.float32),
+                q_rope=jnp.zeros((2, 4, 4), jnp.float32),
+                c=jnp.zeros((2, MLA_ROWS, 16), jnp.float32),
+                r=jnp.zeros((2, MLA_ROWS, 4), jnp.float32),
+                seen=jnp.zeros((2, MLA_ROWS), bool), scale=0.25,
+                tile=MLA_TILE)
+    call.update(changed)
+    with pytest.raises(ValueError, match=match):
+        mla_decode_attention(**call, interpret=True)
+
+
+def test_latent_decode_kernel_error_reaches_the_caller(monkeypatch):
+    """The gate forced on and the kernel made to raise: a decode step
+    (one query a slot against rows held, whole lanes, whole tiles) sees
+    the exception, never a silent XLA stand-in; speculation's two rows
+    are the einsums' by the conditions, and run."""
+    import paddle_tpu as paddle
+    from paddle_tpu.nlp.deepseek_v3 import (DeepseekV3Config,
+                                            DeepseekV3ForCausalLM)
+    from paddle_tpu.ops import pallas, pallas_kernels as pk
+
+    def boom(*a, **k):
+        raise RuntimeError('mosaic says no')
+    monkeypatch.setattr(pallas, '_pallas_enabled', lambda: True)
+    monkeypatch.setattr(pk, 'mla_decode_attention', boom)
+    paddle.seed(0)
+    model = DeepseekV3ForCausalLM(DeepseekV3Config.tiny(
+        kv_lora_rank=128)).eval()
+    pos = jnp.asarray([5, 9], jnp.int32)
+
+    def step(queries):
+        mask = (jnp.arange(128)[None, None, :]
+                <= (pos[:, None] + jnp.arange(queries))[:, :, None])[:, None]
+        return model(paddle.to_tensor(np.ones((2, queries), 'int32')),
+                     cache=model.init_cache(2, 128), use_cache=True,
+                     position_offset=pos, cache_offset=pos,
+                     attention_mask=mask)[0]
+    with pytest.raises(RuntimeError, match='mosaic says no'):
+        step(1)
+    assert np.isfinite(step(2).numpy()).all()
