@@ -20,6 +20,7 @@ from .lfm2 import Lfm2MoeConfig, Lfm2MoeForCausalLM, Lfm2MoeModel
 from .llama import (LlamaConfig, LlamaForCausalLM, LlamaModel)
 from .mimo_v2 import MiMoV2Config, MiMoV2ForCausalLM, MiMoV2Model
 from .t5 import T5Config, T5ForConditionalGeneration, T5Model
+from .xing4 import Xing4Config, Xing4ForCausalLM, Xing4Model
 from .tokenizer import (BPETokenizer, PretrainedTokenizer,
                         WhitespaceTokenizer)
 
@@ -35,5 +36,6 @@ __all__ = [
     'LlamaForCausalLM', 'LlamaModel', 'MiMoV2Config', 'MiMoV2ForCausalLM',
     'MiMoV2Model', 'Seq2SeqGenerationMixin',
     'T5Config', 'T5ForConditionalGeneration', 'T5Model', 'BPETokenizer',
-    'PretrainedTokenizer', 'WhitespaceTokenizer', 'transformers',
+    'PretrainedTokenizer', 'WhitespaceTokenizer', 'Xing4Config',
+    'Xing4ForCausalLM', 'Xing4Model', 'transformers',
 ]

@@ -1,21 +1,33 @@
 """DeepSeek-V3-style causal LM (`model_type: deepseek_v3`; Kakao
-Kanana-2-30B-A3B, 30B-A3B): multi-head LATENT attention, whose cache is
-a row of `kv_lora_rank + qk_rope_head_dim` numbers a token shared by
-every head, and sparse SwiGLU experts behind a sigmoid router plus a
-shared MLP.
+Kanana-2-30B-A3B, 30B-A3B; `nlp/xing4.py` builds its layer from this
+file's attention): multi-head LATENT attention, whose cache is a row of
+`kv_lora_rank + qk_rope_head_dim` numbers a token shared by every head,
+and sparse SwiGLU experts behind a sigmoid router plus a shared MLP.
 
 What it is made of, and where that lives:
 
 - `x0 = E[ids]`; a layer is `x += attn(N_in(x))`, `x += f(N_post(x))`
   (two RMSNorms a layer); `logits = N_final(x) W_head` (untied).
 - attention (`DeepseekV3Attention`, here), `a = N_in(x)`, H heads:
-  `q = a W_q`, a head `[q_nope ; q_rope]`; `[c' ; r'] = a W_kva`, ONE of
-  each a token; `c = RMSNorm_kv(c')`; `[k_nope_h ; v_h] = c W_kvb`;
-  rotary positions on `q_rope` and on `r'` (`llama._rope`, rotate-half;
-  where `rope_interleave`, the dims are stored as interleaved pairs and
-  de-interleaved first, the same permutation on both, so every
-  `q_rope . r` is what a pairwise rotation gives); `k_h = [k_nope_h ;
-  r]`; logits over `sqrt(qk_nope_head_dim + qk_rope_head_dim)`, causal.
+  `q = a W_q` or, where the configuration gives a `q_lora_rank`, the
+  COMPRESSED query `q = RMSNorm_q(a W_qa) W_qb`; a head `[q_nope ;
+  q_rope]`; `[c' ; r'] = a W_kva`, ONE of each a token; `c =
+  RMSNorm_kv(c')`; `[k_nope_h ; v_h] = c W_kvb`; rotary positions on
+  `q_rope` and on `r'` (`llama._rope`, rotate-half; where
+  `rope_interleave`, the dims are stored as interleaved pairs and
+  de-interleaved first, the same permutation on both, so every `q_rope
+  . r` is what a pairwise rotation gives); `k_h = [k_nope_h ; r]`;
+  logits over `sqrt(qk_nope_head_dim + qk_rope_head_dim)`, causal.
+- positions are plain (`rope_scaling` null) or YaRN's (`rope_scaling.
+  type: yarn`, the public DeepSeek-V3 rule; `yarn_inv_freq`): the
+  pairs that turn more than `beta_fast` times over the original length
+  keep `theta^(-2i/d)`, those that turn less than `beta_slow` times take
+  it over `factor`, a linear ramp between; the rotated dims times
+  `mscale(factor, mscale) / mscale(factor, mscale_all_dim)` and the
+  LOGITS times `mscale(factor, mscale_all_dim)^2`, `mscale(s, a) = 0.1
+  a ln s + 1` (`DeepseekV3Config.softmax_scale`: the einsums and the
+  kernel alike take the configuration's scale). Any other
+  `rope_scaling.type` is refused by name.
 - `f` of the first `first_k_dense_replace` layers is `nlp/llama.py`'s
   SwiGLU; of the others `nlp/afmoe.py`'s expert layer: `s = sigmoid(m
   W_r)` in float32, `sel = top_k(s + bias)` (the bias selects only), `w
@@ -90,6 +102,13 @@ PREFILL_QUERY_BLOCK = 1024
 _NEG = float(jnp.finfo(jnp.float32).min)
 
 
+_TINY_YARN = dict(
+    q_lora_rank=12, rope_theta=100.0,
+    rope_scaling={'type': 'yarn', 'factor': 8, 'beta_fast': 4,
+                  'beta_slow': 1, 'mscale': 1.0, 'mscale_all_dim': 0.5,
+                  'original_max_position_embeddings': 16})
+
+
 class DeepseekV3Config:
     model_type = 'deepseek_v3'
 
@@ -109,14 +128,16 @@ class DeepseekV3Config:
                  max_position_embeddings=32768, tie_word_embeddings=False,
                  pad_token_id=0, bos_token_id=1, eos_token_id=2,
                  tensor_parallel=False, **kwargs):
-        if q_lora_rank is not None:
+        if q_lora_rank is not None and int(q_lora_rank) < 1:
             raise ValueError(
-                f'q_lora_rank {q_lora_rank!r}: query compression (q_a_proj, '
-                'q_a_layernorm, q_b_proj) is not implemented; the '
-                'configurations served here give null')
-        if rope_scaling is not None:
-            raise ValueError(f'rope_scaling {rope_scaling!r}: only plain '
-                             'rotary positions (null) are implemented')
+                f'q_lora_rank {q_lora_rank!r}: null (no query compression) '
+                'or the width of the compressed query')
+        kind = None if rope_scaling is None else rope_scaling.get(
+            'type', rope_scaling.get('rope_type'))
+        if rope_scaling is not None and kind != 'yarn':
+            raise ValueError(
+                f'rope_scaling type {kind!r}: plain rotary positions '
+                '(null) and YaRN (yarn) are implemented, nothing else')
         if scoring_func != 'sigmoid' or topk_method != 'noaux_tc':
             raise ValueError(
                 f'scoring_func {scoring_func!r} / topk_method '
@@ -146,6 +167,7 @@ class DeepseekV3Config:
         self.num_hidden_layers = num_hidden_layers
         self.first_k_dense_replace = first_k_dense_replace
         self.num_attention_heads = num_attention_heads
+        self.q_lora_rank = None if q_lora_rank is None else int(q_lora_rank)
         self.kv_lora_rank = kv_lora_rank
         self.qk_nope_head_dim = qk_nope_head_dim
         self.qk_rope_head_dim = qk_rope_head_dim
@@ -153,6 +175,13 @@ class DeepseekV3Config:
         self.qk_head_dim = qk_nope_head_dim + qk_rope_head_dim
         self.rope_theta = rope_theta
         self.rope_interleave = bool(rope_interleave)
+        self.rope_scaling = None if rope_scaling is None \
+            else dict(rope_scaling)
+        # the logits' scale: 1/sqrt(qk) and, under YaRN, mscale^2 on it
+        self.softmax_gain = 1.0 if rope_scaling is None else _yarn_mscale(
+            rope_scaling['factor'],
+            rope_scaling.get('mscale_all_dim', 0)) ** 2
+        self.softmax_scale = self.softmax_gain / math.sqrt(self.qk_head_dim)
         self.n_routed_experts = n_routed_experts
         self.n_shared_experts = n_shared_experts
         self.num_experts_per_tok = num_experts_per_tok
@@ -196,6 +225,15 @@ class DeepseekV3Config:
         return cls(**kw)
 
     @classmethod
+    def tiny_yarn(cls, **kw):
+        """`tiny()` with a COMPRESSED query (rank 12) and YaRN positions
+        stretched 8 times over 16 original positions: at theta 100 the
+        two rotary pairs straddle the ramp, so a test at 64 positions
+        reads every part of the rule; mscale and mscale_all_dim differ,
+        so both factors show."""
+        return cls.tiny(**{**_TINY_YARN, **kw})
+
+    @classmethod
     def tiny_wide_v(cls, **kw):
         """`tiny()` with V WIDER than the un-rotated part of K (12
         against 8), the rotary dims stored as halves, one shared expert
@@ -209,15 +247,51 @@ class DeepseekV3Config:
         return cls.tiny(**kw)
 
 
-def _rotary(t, positions, theta, interleave):
+def _yarn_mscale(factor, mscale):
+    """YaRN's magnitude correction for a stretch of `factor`."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_inv_freq(dim, theta, factor, original_max_position_embeddings,
+                  beta_fast=32, beta_slow=1, **_):
+    """`[dim/2]` float32: the angle a position turns each rotary pair by
+    under YaRN (the public DeepSeek-V3 rule). With `f = theta^(-2i/dim)`
+    as `llama._rope` makes it, pair i keeps `f` below `low`, takes `f /
+    factor` above `high`, and in between `f/factor * ramp + f * (1 -
+    ramp)`, `ramp = (i - low) / (high - low)`; `low` / `high` are the
+    pairs that turn `beta_fast` / `beta_slow` times over the original
+    length, rounded outwards. Written as `f * (1 - ramp * (1 - 1/factor))`
+    — the same number — so that `factor` 1 gives `f` bit for bit."""
+    def turns(n):       # the (fractional) pair that turns n times
+        return dim * math.log(original_max_position_embeddings
+                              / (n * 2 * math.pi)) / (2 * math.log(theta))
+    low = max(math.floor(turns(beta_fast)), 0)
+    high = min(math.ceil(turns(beta_slow)), dim - 1)
+    span = (high - low) or 0.001
+    f = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low) / span,
+                    0.0, 1.0)
+    return f * (1.0 - ramp * (1.0 - 1.0 / factor))
+
+
+def _rotary(t, positions, theta, interleave, scaling=None):
     """Rotary positions on `t` `[B, S, heads, rope]`. Where the dims are
     stored as interleaved pairs (`rope_interleave`) they are brought to
     halves first, `[x0, x2, .., x1, x3, ..]`, and stay so: the same
     permutation on a query and on the shared key leaves every product
-    of the two what a rotation of the pairs `(x_2i, x_2i+1)` gives."""
+    of the two what a rotation of the pairs `(x_2i, x_2i+1)` gives.
+    `scaling` (a YaRN `rope_scaling`, or None) gives the angles and the
+    factor on the rotated dims."""
     if interleave:
         t = jnp.concatenate([t[..., 0::2], t[..., 1::2]], axis=-1)
-    return _rope(t, positions, theta)
+    if scaling is None:
+        return _rope(t, positions, theta)
+    out = _rope(t, positions, theta,
+                inv_freq=yarn_inv_freq(t.shape[-1], theta, **scaling))
+    factor = scaling['factor']
+    gain = _yarn_mscale(factor, scaling.get('mscale', 1)) \
+        / _yarn_mscale(factor, scaling.get('mscale_all_dim', 0))
+    return out if gain == 1.0 else out * jnp.asarray(gain, out.dtype)
 
 
 def _starts_empty_slot(cache_offset, attn_mask):
@@ -231,12 +305,17 @@ def _starts_empty_slot(cache_offset, attn_mask):
             and int(cache_offset) == 0)
 
 
-def _own_tokens_attention(q, k, v, mask):
+def _own_tokens_attention(q, k, v, mask, gain=1.0):
     """Causal attention of a call over its OWN S tokens: q, k `[B, S, H,
     qk]`, v `[B, S, H, v]` -> `[B, S, H, v]`; `mask` None or a caller's
-    boolean `[B, 1, 1, S]` of keys that are no padding. More than
+    boolean `[B, 1, 1, S]` of keys that are no padding; logits over
+    `sqrt(qk)` times `gain` — `ops.pallas.flash_attention` scales by
+    `1/sqrt(qk)` and takes no other, so what a configuration has beside
+    that (`softmax_gain`) is put on the queries. More than
     `PREFILL_QUERY_BLOCK` queries go block after block (`lax.map`), so
     that one block's `[B, H, block, S]` scores are all that live."""
+    if gain != 1.0:
+        q = q * jnp.asarray(gain, q.dtype)
     s, blk = q.shape[1], PREFILL_QUERY_BLOCK
     if s <= blk:
         return _pallas.flash_attention(q, k, v, mask=mask, causal=True)
@@ -306,7 +385,14 @@ class DeepseekV3Attention(Layer):
         super().__init__()
         self.config = config
         h, nh = config.hidden_size, config.num_attention_heads
-        self.q_proj = _col_linear(config, h, nh * config.qk_head_dim)
+        if config.q_lora_rank is None:
+            self.q_proj = _col_linear(config, h, nh * config.qk_head_dim)
+        else:       # the compressed query: down, norm, up by head
+            self.q_a_proj = Linear(h, config.q_lora_rank, bias_attr=False)
+            self.q_a_layernorm = RMSNorm(config.q_lora_rank,
+                                         epsilon=config.rms_norm_eps)
+            self.q_b_proj = _col_linear(config, config.q_lora_rank,
+                                        nh * config.qk_head_dim)
         self.kv_a_proj_with_mqa = Linear(
             h, config.kv_lora_rank + config.qk_rope_head_dim,
             bias_attr=False)
@@ -329,11 +415,11 @@ class DeepseekV3Attention(Layer):
         nope, rd, vd = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                         cfg.v_head_dim)
         theta, interleave = cfg.rope_theta, cfg.rope_interleave
-        scale = 1.0 / math.sqrt(cfg.qk_head_dim)
+        scaling, scale = cfg.rope_scaling, cfg.softmax_scale
 
         def rope(t, off):
             return _rotary(t, _offset_grid(off, t.shape[1]), theta,
-                           interleave)
+                           interleave, scaling)
 
         def split_q(qv, off):
             qv = qv.reshape(qv.shape[0], qv.shape[1], nh, nope + rd)
@@ -342,8 +428,9 @@ class DeepseekV3Attention(Layer):
         def split_kv(kv, off):
             return kv[..., :lat], rope(kv[..., None, lat:], off)[:, :, 0]
         off_t = offset if isinstance(offset, Tensor) else Tensor(offset)
-        q_nope, q_rope = apply_op(split_q, self.q_proj(hidden), off_t,
-                                  _name='mla_split_q')
+        q = self.q_proj(hidden) if cfg.q_lora_rank is None \
+            else self.q_b_proj(self.q_a_layernorm(self.q_a_proj(hidden)))
+        q_nope, q_rope = apply_op(split_q, q, off_t, _name='mla_split_q')
         c, r = apply_op(split_kv, self.kv_a_proj_with_mqa(hidden), off_t,
                         _name='mla_split_kv')
         c = self.kv_a_layernorm(c)
@@ -364,7 +451,7 @@ class DeepseekV3Attention(Layer):
                                       + (nh, rd))], axis=-1)
                 return _own_tokens_attention(
                     jnp.concatenate([qn, qr], axis=-1), k, kv[..., nope:],
-                    m[0] if m else None)
+                    m[0] if m else None, cfg.softmax_gain)
             out = apply_op(own, q_nope, q_rope, c, r, self.kv_b_proj(c),
                            *(() if attn_mask is None else (attn_mask,)),
                            _name='mla_own_tokens')
@@ -408,25 +495,35 @@ class DeepseekV3DecoderLayer(Layer):
         self.post_attention_layernorm = RMSNorm(config.hidden_size,
                                                 epsilon=eps)
 
-    def forward(self, hidden, position_offset=None, attn_mask=None,
-                cache=None, cache_offset=None):
+    def attention_block(self, hidden, position_offset=None, attn_mask=None,
+                        cache=None, cache_offset=None):
+        """`attn(N_in(hidden))` -> (what it adds to the residual path,
+        the layer's new cache entry or None). The two blocks are what a
+        layer with another residual path (`nlp/xing4.py`) shares."""
         with jax.named_scope('norm'):
             h = self.input_layernorm(hidden)
         with jax.named_scope('attention'):
             out = self.self_attn(
                 h, position_offset=position_offset, attn_mask=attn_mask,
                 cache=cache, cache_offset=cache_offset)
-        new_cache = None
-        if cache is not None:
-            out, new_cache = out
-        h = hidden + out
+        return out if cache is not None else (out, None)
+
+    def mlp_block(self, hidden):
+        """`f(N_post(hidden))`: the dense MLP or the expert layer."""
         with jax.named_scope('norm'):
-            normed = self.post_attention_layernorm(h)
+            normed = self.post_attention_layernorm(hidden)
         if self.moe_enabled:        # its own scopes: moe/router, ...
-            h = h + self.mlp(normed)
-        else:
-            with jax.named_scope('mlp'):
-                h = h + self.mlp(normed)
+            return self.mlp(normed)
+        with jax.named_scope('mlp'):
+            return self.mlp(normed)
+
+    def forward(self, hidden, position_offset=None, attn_mask=None,
+                cache=None, cache_offset=None):
+        out, new_cache = self.attention_block(
+            hidden, position_offset=position_offset, attn_mask=attn_mask,
+            cache=cache, cache_offset=cache_offset)
+        h = hidden + out
+        h = h + self.mlp_block(h)
         if cache is not None:
             return h, new_cache
         return h
@@ -442,13 +539,18 @@ class DeepseekV3PretrainedModel(Layer):
 
 
 class DeepseekV3Model(DeepseekV3PretrainedModel):
-    """embed -> N decoder layers -> the final RMSNorm."""
+    """embed -> N decoder layers -> the final RMSNorm. What the layers
+    hand from one to the next is the hidden state `[B, S, h]`; a family
+    whose residual path is wider (`nlp/xing4.py`: `[B, S, n, h]`) gives
+    its own `layer_class` and the two ends of the path."""
+
+    layer_class = DeepseekV3DecoderLayer
 
     def __init__(self, config: DeepseekV3Config):
         super().__init__()
         self.config = config
         self.embed_tokens = Embedding(config.vocab_size, config.hidden_size)
-        self.layers = [DeepseekV3DecoderLayer(config, i)
+        self.layers = [self.layer_class(config, i)
                        for i in range(config.num_hidden_layers)]
         for i, l in enumerate(self.layers):
             self.add_sublayer(f'layers.{i}', l)
@@ -461,6 +563,7 @@ class DeepseekV3Model(DeepseekV3PretrainedModel):
         with jax.named_scope('embed'):
             # float32 from here on, whatever the parameters are stored in
             h = self.embed_tokens(ids).astype('float32')
+        h = self.residual_in(h)
         mask = attention_mask
         if mask is not None and not isinstance(mask, Tensor):
             mask = Tensor(to_jax(mask))
@@ -480,11 +583,20 @@ class DeepseekV3Model(DeepseekV3PretrainedModel):
                 new_caches.append(c)
             else:
                 h = out
+        h = self.residual_out(h)
         with jax.named_scope('norm'):
             h = self.norm(h)
         if use_cache:
             return h, tuple(new_caches)
         return h
+
+    def residual_in(self, embedded):
+        """The embedding as the first layer takes it."""
+        return embedded
+
+    def residual_out(self, hidden):
+        """What the last layer hands on, as the final norm takes it."""
+        return hidden
 
     def init_cache(self, batch_size, max_length, dtype=None):
         """One latent entry a layer: `(c [B, max_length, kv_lora_rank],
@@ -499,10 +611,12 @@ class DeepseekV3Model(DeepseekV3PretrainedModel):
 
 
 class DeepseekV3ForCausalLM(DeepseekV3PretrainedModel, GenerationMixin):
+    model_class = DeepseekV3Model
+
     def __init__(self, config: DeepseekV3Config):
         super().__init__()
         self.config = config
-        self.model = DeepseekV3Model(config)
+        self.model = self.model_class(config)
         self.lm_head = Linear(config.hidden_size, config.vocab_size,
                               bias_attr=False)
 

@@ -98,17 +98,21 @@ class LlamaConfig:
         return cls(**kw)
 
 
-def _rope(x, positions, theta, rotary_dim=None):
+def _rope(x, positions, theta, rotary_dim=None, inv_freq=None):
     """Rotary embedding, rotate-half convention. x: [B, S, H, D] raw array,
     positions: [S] or [B, S] raw int array. `rotary_dim` (None: all of
     D) is how many LEADING dims of a head rotate, rotate-half within
-    them; the others pass as they are (partial rotary)."""
+    them; the others pass as they are (partial rotary). `inv_freq`
+    (`[D/2]` float32; None: `theta ** (-2i/D)`, the plain table) is the
+    angle a position turns each pair by, for a caller whose positions
+    are scaled (`deepseek_v3.yarn_inv_freq`); `theta` is then unread."""
     if rotary_dim is not None and rotary_dim < x.shape[-1]:
         return jnp.concatenate(
             [_rope(x[..., :rotary_dim], positions, theta),
              x[..., rotary_dim:]], axis=-1)
     d = x.shape[-1]
-    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)) \
+        if inv_freq is None else inv_freq
     pos = positions.astype(jnp.float32)
     freqs = pos[..., None] * inv                      # [..., S, D/2]
     while freqs.ndim < 3:

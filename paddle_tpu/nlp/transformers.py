@@ -17,3 +17,5 @@ from .t5 import (T5Config, T5ForConditionalGeneration,  # noqa: F401
                  T5Model)
 from .tokenizer import (BPETokenizer, PretrainedTokenizer,  # noqa: F401
                         WhitespaceTokenizer)
+from .xing4 import (Xing4Config, Xing4ForCausalLM,  # noqa: F401
+                    Xing4Model)

@@ -27,7 +27,7 @@ from .store import get_store
 # carry
 SCOPES = ('embed', 'attention', 'mlp', 'norm', 'lm_head', 'loss', 'sample',
           'kv_write', 'optimizer', 'moe/router', 'moe/experts', 'moe/shared',
-          'conv', 'state_write', 'latent_absorb')
+          'conv', 'state_write', 'latent_absorb', 'mhc')
 
 _INSTR = re.compile(r'^\s*(?:ROOT\s+)?%?([\w.\-]+) = ')
 _OP_NAME = re.compile(r'op_name="([^"]*)"')

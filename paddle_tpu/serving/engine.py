@@ -490,6 +490,11 @@ class InferenceEngine:
         half = self.pool.max_length // 2
         self._half_rows = half if half > self.decode_block else 0
         self._num_experts = int(getattr(cfg, 'num_experts', 0) or 0)
+        # how many streams of the hidden size a layer hands the next,
+        # where the model's residual path is wider than one (0: one,
+        # and a decode round's span says nothing)
+        self._residual_streams = int(
+            getattr(model, 'residual_streams', 0) or 0)
         # either program's rows -> the row tile by which a latent
         # layer's attention is bounded per slot there, None where it
         # reads every row: what `read_rows` counts such a layer by
@@ -1777,6 +1782,8 @@ class InferenceEngine:
                 round_span.set(
                     latent_layers=len(self.pool.latent_layers),
                     latent_row_bytes=self.pool.latent_row_bytes)
+            if self._residual_streams:
+                round_span.set(residual_streams=self._residual_streams)
             try:
                 with _obs.span('serving.decode_dispatch'):
                     if self._paged:

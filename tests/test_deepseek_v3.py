@@ -848,8 +848,20 @@ def test_config_presets_and_refusals():
     assert tiny.v_head_dim == tiny.qk_nope_head_dim == 8
     assert wide.v_head_dim > wide.qk_nope_head_dim
     assert not wide.rope_interleave and wide.first_k_dense_replace == 2
-    for bad, what in ((dict(q_lora_rank=1536), 'q_lora_rank'),
-                      (dict(rope_scaling={'type': 'yarn'}), 'rope_scaling'),
+    # accepted since PR 39: a compressed query and YaRN positions
+    # (tests/test_xing4.py holds both to the reference)
+    yarn = DeepseekV3Config.tiny_yarn()
+    assert yarn.q_lora_rank == 12 and yarn.rope_scaling['factor'] == 8
+    assert tiny.q_lora_rank is None and tiny.rope_scaling is None
+    assert tiny.softmax_gain == 1.0 \
+        and tiny.softmax_scale == 1.0 / math.sqrt(12)
+    assert yarn.softmax_scale == pytest.approx(
+        (0.1 * 0.5 * math.log(8) + 1) ** 2 / math.sqrt(12))
+    for bad, what in ((dict(q_lora_rank=0), 'q_lora_rank'),
+                      (dict(rope_scaling={'type': 'linear', 'factor': 2}),
+                       "rope_scaling type 'linear'"),
+                      (dict(rope_scaling={'rope_type': 'llama3'}),
+                       "rope_scaling type 'llama3'"),
                       (dict(scoring_func='softmax'), 'scoring_func'),
                       (dict(topk_method='greedy'), 'topk_method'),
                       (dict(n_group=8), 'n_group'),
