@@ -27,6 +27,18 @@ Activations are float32 and products three bf16 passes whatever the
 parameters are stored in (`ACTIVATION_PRECISION`, and why, at
 `AfmoeForCausalLM.forward`).
 
+Attention against a cache SERVES on one of two schedules of the same
+sum, picked from the call alone (`ops.pallas.kv_decode_kernel` through
+`generation.bounded_decode_attention`; no flag, no model's name): one
+float32 query a slot under a boolean mask on a TPU — a decode sub-step
+— is ONE pallas kernel a layer over the leaves whole, which walks each
+slot's row tiles from the first row the mask shows (a window layer's
+window starts there) to the last and reads each once for the scores and
+the values, in the arithmetic `ACTIVATION_PRECISION` gives XLA; every
+other call (a prefill, a chunk, speculation's k+1 rows, every other
+backend) is `_attention_xla` over the rows the mask has columns for.
+`nlp/lfm2.py`'s attention layers are this class.
+
 `expert_bias` is a buffer upstream; here it is a frozen parameter (it is
 state a checkpoint fills, and `named_parameters()` is how weights reach
 a model in this repo). The expert layer SERVES, on one of two schedules
@@ -53,6 +65,7 @@ from ..ops.pallas import expert_kernel
 from ..tensor import Tensor, apply_op, to_jax
 from .generation import (GenerationMixin, as_offset as _as_offset,
                          attended_rows as _attended_rows,
+                         bounded_decode_attention, bounded_decode_tile,
                          decode_mask as _decode_mask, note_routing,
                          offset_grid as _offset_grid,
                          update_kv_cache as _update_kv_cache)
@@ -265,8 +278,15 @@ class AfmoeAttention(Layer):
                     lambda m, qv, sl: _narrow(m, _window_mask(
                         sl, qv.shape[1], m.shape[-1], window)),
                     mask, q, slot_t, _name='window_mask')
-            out = F.scaled_dot_product_attention(
-                q, *_attended_rows(k_cache, v_cache, mask), attn_mask=mask)
+            # a decode sub-step on a TPU is ONE kernel over the leaves
+            # whole, bounded per slot by the mask (the window's first
+            # row too); every other call XLA's chain over the rows the
+            # mask has columns for
+            out = bounded_decode_attention(q, k_cache, v_cache, mask)
+            if out is None:
+                out = F.scaled_dot_product_attention(
+                    q, *_attended_rows(k_cache, v_cache, mask),
+                    attn_mask=mask)
         out = apply_op(
             lambda t: t.reshape(t.shape[0], t.shape[1], nh * hd),
             out, _name='merge_heads')
@@ -604,3 +624,13 @@ class AfmoeForCausalLM(AfmoePretrainedModel, GenerationMixin):
         sliding layer, None for a full one. The serving engine counts
         the cache rows a round NEEDS from it."""
         return tuple(l.self_attn.window for l in self.model.layers)
+
+    def decode_tiles(self, cache, slots, rows):
+        """Per layer, the row tile by which a decode sub-step's
+        attention over `cache`'s entry is bounded per slot under a mask
+        of `rows` columns, None where it reads every row
+        (`generation.bounded_decode_tile`: float32 queries, no sink).
+        The serving engine counts the cache rows a round READS by it."""
+        return tuple(bounded_decode_tile(l.self_attn.num_heads, entry,
+                                         slots, rows)
+                     for l, entry in zip(self.model.layers, cache))

@@ -144,6 +144,53 @@ def attended_rows(k_cache, v_cache, mask):
                  for c in (k_cache, v_cache))
 
 
+def bounded_decode_attention(q, k_cache, v_cache, mask, sink=None):
+    """Decode attention bounded per slot: where `ops.pallas.
+    kv_decode_kernel` takes the call (its conditions are the whole
+    choice: one float32 query a slot, a boolean mask shared by the
+    heads, no sink, a TPU), q `[B, 1, H, D]` against the leaves WHOLE —
+    the kernel bounds its own reading by the mask, whose columns may be
+    fewer than the leaves' rows, and a slice of a leaf is a copy to it
+    — through ONE kernel that walks each slot's row tiles from the
+    first row the mask shows to the last and reads each once for the
+    scores and the values; a slot that is not decoding (`active_rows`)
+    is shown nothing — its output is discarded — and walks one tile.
+    Logits over `sqrt(D)`, as `_attention_xla`'s. -> `[B, 1, H, Dv]`, or
+    None: the call is `attended_rows`' and `_attention_xla`'s."""
+    from ..ops import pallas as _pallas
+    from ..tensor import apply_op as _apply
+    kernel = _pallas.kv_decode_kernel(q, k_cache, v_cache, mask, sink)
+    if kernel is None:
+        return None
+
+    def f(qv, kc, vc, m, *active):
+        seen = jnp.broadcast_to(m[:, 0, 0], (qv.shape[0], m.shape[-1]))
+        if active:
+            seen = seen & active[0][:, None]
+        out = kernel(qv[:, 0], kc, vc, seen, qv.shape[-1] ** -0.5)
+        return out[:, None].astype(qv.dtype)
+    active = active_rows()
+    return _apply(f, q, k_cache, v_cache, mask,
+                  *(() if active is None else (Tensor(active),)),
+                  _name='kv_decode_attention')
+
+
+def bounded_decode_tile(heads, entry, slots, rows, sink=None):
+    """The row tile `bounded_decode_attention` walks by in a decode
+    sub-step of `slots` float32 queries of `heads` heads over the cache
+    `entry` (K, V: arrays or their specs) under a mask of `rows`
+    columns, None where it gives the call back: the same dispatch,
+    asked with that call's shapes. What a model's `decode_tiles` tells
+    the serving engine, layer by layer."""
+    from ..ops import pallas as _pallas
+    k, v = entry
+    spec = jax.ShapeDtypeStruct
+    kernel = _pallas.kv_decode_kernel(
+        spec((slots, 1, heads, k.shape[-1]), jnp.float32), k, v,
+        spec((slots, 1, 1, rows), jnp.bool_), sink)
+    return kernel and kernel.keywords['tile']
+
+
 def padded_decode_mask(keep, cache_len, cache_offset, sq):
     """[B, 1, Sq, L] boolean mask for decode over a static cache holding a
     left/right-PADDED prompt: slot-causal AND key slot not a pad slot.

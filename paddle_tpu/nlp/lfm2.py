@@ -48,7 +48,8 @@ from ..nn.norm import RMSNorm
 from ..tensor import Tensor, apply_op, to_jax
 from .afmoe import (ACTIVATION_PRECISION, FULL, AfmoeAttention,
                     AfmoeSparseMLP)
-from .generation import GenerationMixin, folded_tokens
+from .generation import (GenerationMixin, bounded_decode_tile,
+                         folded_tokens)
 from .llama import LlamaMLP, _col_linear, _row_linear
 
 CONV = 'conv'
@@ -357,6 +358,14 @@ class Lfm2MoeForCausalLM(Lfm2MoePretrainedModel, GenerationMixin):
 
     def init_cache(self, batch_size, max_length, dtype=None):
         return self.model.init_cache(batch_size, max_length, dtype)
+
+    def decode_tiles(self, cache, slots, rows):
+        """`AfmoeForCausalLM.decode_tiles`; a conv layer attends over
+        nothing."""
+        return tuple(bounded_decode_tile(l.self_attn.num_heads, entry,
+                                         slots, rows)
+                     if l.is_attention else None
+                     for l, entry in zip(self.model.layers, cache))
 
     def generate(self, input_ids, *args, attention_mask=None, **kwargs):
         if attention_mask is not None and \

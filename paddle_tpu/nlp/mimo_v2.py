@@ -46,6 +46,12 @@ from the slot it is given.
 Activations are float32 and products three bf16 passes
 (`afmoe.ACTIVATION_PRECISION`, and why, at `AfmoeForCausalLM.forward`:
 this router is that one, and picks 8 of 256). Served, not trained.
+
+A FULL layer's decode sub-step on a TPU is `nlp/afmoe.py`'s kernel
+bounded per slot (`generation.bounded_decode_attention`: K 192 wide
+beside V 128, sixteen query heads a KV head) unless the layer has a
+sink, which the kernel does not know; a ring is 128 rows read whole and
+keeps `_attention_xla`, whatever it has.
 """
 from __future__ import annotations
 
@@ -65,6 +71,7 @@ from .afmoe import (ACTIVATION_PRECISION, AfmoeSparseMLP, _narrow,
                     _window_mask)
 from .generation import (GenerationMixin, as_offset as _as_offset,
                          attended_rows as _attended_rows,
+                         bounded_decode_attention, bounded_decode_tile,
                          decode_mask as _decode_mask,
                          offset_grid as _offset_grid, ring_mask,
                          update_kv_cache as _update_kv_cache,
@@ -292,8 +299,14 @@ class MiMoV2Attention(Layer):
                                                     k, v, slot)
             mask = attn_mask if attn_mask is not None \
                 else _decode_mask(q, k_cache, slot)
-            out = _attend(q, *_attended_rows(k_cache, v_cache, mask), mask,
-                          self.sink)
+            # a decode sub-step of a layer without a sink on a TPU is
+            # ONE kernel over the leaves whole, bounded per slot by the
+            # mask (`afmoe.AfmoeAttention`); every other call XLA's
+            out = bounded_decode_attention(q, k_cache, v_cache, mask,
+                                           self.sink)
+            if out is None:
+                out = _attend(q, *_attended_rows(k_cache, v_cache, mask),
+                              mask, self.sink)
         else:
             # the ring: what is visible follows from the slot alone, so
             # the caller's mask of positions is not read (its rows are
@@ -472,6 +485,14 @@ class MiMoV2ForCausalLM(MiMoV2PretrainedModel, GenerationMixin):
         window layer, None for a full one. The serving engine counts
         the cache rows a round NEEDS from it."""
         return tuple(l.self_attn.window for l in self.model.layers)
+
+    def decode_tiles(self, cache, slots, rows):
+        """`AfmoeForCausalLM.decode_tiles`: a full layer without a
+        sink; a ring is read whole."""
+        return tuple(
+            None if l.self_attn.window is not None else bounded_decode_tile(
+                l.self_attn.num_heads, entry, slots, rows, l.self_attn.sink)
+            for l, entry in zip(self.model.layers, cache))
 
     def generate(self, input_ids, *args, attention_mask=None, **kwargs):
         if attention_mask is not None and \

@@ -4,14 +4,16 @@ Upstream analogue: the reference's hand-fused CUDA kernels
 (paddle/phi/kernels/fusion/gpu/*, flash-attn integration). Here the
 default path is plain jax — XLA already fuses normalization chains into
 adjacent matmuls — and the pallas kernels (ops/pallas_kernels.py) take
-over on TPU backends for three inner loops where a hand-written schedule
+over on TPU backends for four inner loops where a hand-written schedule
 beats the XLA-generated one: attention over a call's own tokens
 (`flash_attention`: manual VMEM blocking), the routed experts of a
 decode batch (`expert_kernel`: one weight stream across the experts,
-where XLA's `while` fetches each expert cold), and decode attention over
+where XLA's `while` fetches each expert cold), decode attention over
 latent rows (`latent_decode_kernel`: a slot's row tiles up to that
 slot's length, each read once for the scores and the values, where
-XLA's two einsums stream every row of every slot twice).
+XLA's two einsums stream every row of every slot twice), and decode
+attention of float32 queries over K and V held by head
+(`kv_decode_kernel`: the same walk, bounded per slot at both ends).
 
 Which path runs is decided by explicit conditions on the backend and
 the shapes, never by a caught exception: on a TPU a kernel that fails
@@ -203,6 +205,15 @@ def expert_kernel(tokens, block_rows, weight_dtype, interpret=False):
     return None
 
 
+def _one_query_a_slot(q, mask):
+    """What both decode attention kernels take: ONE query a slot (`q`
+    `[B, 1, ...]`) and hidden rows named by a boolean mask `[B or 1, 1,
+    1, n]` shared by the heads."""
+    return (q.shape[1] == 1 and mask.dtype == jnp.bool_
+            and len(mask.shape) == 4 and mask.shape[1] == 1
+            and mask.shape[2] == 1 and mask.shape[0] in (1, q.shape[0]))
+
+
 def latent_decode_kernel(q, rows, mask, interpret=False):
     """Dispatch for absorbed attention over latent rows
     (`nlp/deepseek_v3.py::_latent_attention`): the pallas kernel
@@ -222,12 +233,49 @@ def latent_decode_kernel(q, rows, mask, interpret=False):
     from . import pallas_kernels
     n = mask.shape[-1]
     tile = pallas_kernels._mla_row_tile(math.gcd(n, rows.shape[1]))
-    if ((interpret or _pallas_enabled()) and q.shape[1] == 1
-            and mask.dtype == jnp.bool_ and len(mask.shape) == 4
-            and mask.shape[1] == 1 and mask.shape[2] == 1
-            and mask.shape[0] in (1, q.shape[0])
+    if ((interpret or _pallas_enabled()) and _one_query_a_slot(q, mask)
             and rows.dtype in (jnp.float32, jnp.bfloat16)
             and rows.shape[-1] % 128 == 0 and tile is not None):
         return functools.partial(pallas_kernels.mla_decode_attention,
+                                 tile=tile, interpret=interpret)
+    return None
+
+
+def kv_decode_kernel(q, k, v, mask, sink=None, interpret=False):
+    """Dispatch for decode attention over K and V held by head (the
+    cached branch of `nlp/afmoe.py`, `nlp/lfm2.py` through it, and
+    `nlp/mimo_v2.py`'s full layers; `nlp/generation.py::
+    bounded_decode_attention` makes the call): the pallas kernel
+    `pallas_kernels.kv_decode_attention`, its row tile bound (`.keywords
+    ['tile']`: what the serving engine counts a round's `read_rows`
+    by), where it applies, None where `_attention_xla` runs. Read from
+    the call alone — `q` `[B, Sq, H, D]`, the leaves `k` `[B, L, H_kv,
+    D]` and `v` `[B, L, H_kv, Dv]` as they are held, `mask` `[B or 1, 1,
+    Sq, n]`, `sink` (anything with a `.shape` and a `.dtype`): the
+    kernel takes ONE float32 query a slot (a decode sub-step), hidden
+    rows named by a boolean mask shared by the heads, no sink, on a TPU
+    (or anywhere with interpret=True), leaves float32 or bf16, and `n`
+    and `L` a whole number of tiles. Speculation's k+1 rows, a prefill
+    or a chunk, an additive or per-head mask, a layer with a sink and
+    every other backend keep `_attention_xla`: there it is the tier-1
+    path and the parity ground truth.
+
+    **The query's dtype is what keeps `nlp/gpt.py` and `nlp/llama.py`
+    out**, and it is an honest condition: their decode attention is a
+    single-pass bf16 product with bf16 probabilities, another
+    arithmetic than the three-pass float32 one the kernel spells out —
+    serving them from it would change what they compute. A sibling for
+    bf16 queries waits for the `benchmark` issue that re-sweeps
+    serve-docs' backlog (ROADMAP, "the gate"). The conditions are the
+    whole selection: a kernel error on a TPU propagates."""
+    from . import pallas_kernels
+    n = mask.shape[-1]
+    tile = pallas_kernels._mla_row_tile(math.gcd(n, k.shape[1]))
+    if ((interpret or _pallas_enabled()) and _one_query_a_slot(q, mask)
+            and q.dtype == jnp.float32 and sink is None
+            and len(k.shape) == 4 and k.dtype == v.dtype
+            and k.dtype in (jnp.float32, jnp.bfloat16)
+            and q.shape[2] % k.shape[2] == 0 and tile is not None):
+        return functools.partial(pallas_kernels.kv_decode_attention,
                                  tile=tile, interpret=interpret)
     return None

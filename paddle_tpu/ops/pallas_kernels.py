@@ -21,12 +21,12 @@ Contents:
   layer's routed experts for a decode batch: one weight stream over the
   distinct experts the batch picked (dispatch: `ops.pallas.expert_kernel`).
 - `mla_decode_attention(q_lat, q_rope, c, r, seen, scale)` — decode
-  attention over latent rows, absorbed: a slot's row tiles up to that
-  slot's length, each read once for the scores and the values
-  (dispatch: `ops.pallas.latent_decode_kernel`).
-
-All kernels keep stats/accumulators in fp32 VMEM scratch and feed the
-MXU with `preferred_element_type=float32` per the TPU tiling rules.
+  attention over latent rows bounded per slot (`latent_decode_kernel`).
+- `kv_decode_attention(q, k, v, seen, scale)` — its sibling for float32
+  queries over K and V by head (`kv_decode_kernel`; `decode_walk`).
+All keep stats/accumulators in fp32 VMEM scratch and feed the MXU with
+`preferred_element_type=float32`. (Add no line above the last kernel
+but one: a Mosaic call's source lines are in its program's digest.)
 """
 from __future__ import annotations
 
@@ -1267,3 +1267,213 @@ def mla_decode_attention(q_lat, q_rope, c, r, seen, scale, *, tile=None,
         name='mla_decode_attention',
     )(slot, at, last, q_lat, q_rope, c, r,
       seen.astype(jnp.int32)[:, None, :])
+
+
+# ---------------------------------------------------------------------------
+# decode attention over K and V held by head (ISSUE 40; upstream
+# analogues: vLLM's and jax's ragged paged attention kernels, whose
+# strided loads of a head's rows `by_stride` has). The sibling of the
+# latent kernel above for leaves `[slot, row, H_kv, D]`: the same flat
+# grid over the row tiles the slots hold, bounded per slot at BOTH ends (a
+# window layer's walk starts at its first seen row), a K tile and a V
+# tile each used once while they sit in VMEM. A tile is taken as the
+# leaf holds it, a row's KV heads side by side — its LINES `[tile x
+# H_kv, D]` — because every other arrangement is a copy of the leaf
+# (`serving/kv_pool.py` on layouts; CHANGES.md, PR 40, has the chip's
+# numbers for the forms tried).
+# ---------------------------------------------------------------------------
+
+def decode_walk(first, bound, tile):
+    """The row tiles a slot's decode attention walks -> (the tile it
+    starts at, how many): from the tile of the `first` row it sees to
+    the tile of the last (`bound` = that row + 1), ONE tile — tile 0 —
+    of a slot that sees nothing (`first` = `bound` = 0). Plain
+    arithmetic on whole numbers, numpy's or jax's: the kernel's table
+    and the serving engine's `read_rows` both come from here."""
+    start = first // tile
+    return start, (bound + (bound == 0) + tile - 1) // tile - start
+
+
+def _kv_lines(ref):
+    """A leaf's tile as its LINES `[tile * H_kv, D]`, line `j` row `j //
+    H_kv` of KV head `j % H_kv`: what a block of the leaf's view `[B, L
+    * H_kv, D]` is, and what a block `[1, tile, H_kv, D]` of the leaf
+    itself (a leaf too wide for that view) is reshaped to."""
+    return ref[0] if len(ref.shape) == 3 else ref[0].reshape(
+        -1, ref.shape[-1])
+
+
+def _kv_fold(s, v2, lines, n, m_ref, l_ref, acc_ref):
+    """Fold one tile's masked scores `s` `[n, lines]` and its values'
+    stacked parts `v2` into an online softmax's running max, sum and
+    accumulator (float32)."""
+    m_prev = m_ref[...]                                    # [n, 128]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev[:, :1] - m_new[:, :1])
+    p = jnp.exp(s - m_new[:, :1])
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + _dot_high(
+        _split2(p), n, v2, lines, ((1,), (0,)))
+    m_ref[...] = m_new
+
+
+def _kv_decode_kernel(slot_ref, tile_ref, edge_ref, q_ref, k_ref, v_ref,
+                      seen_ref, o_ref, m_s, l_s, acc_s, *, tile, hkv, scale,
+                      by_stride):
+    """`_mla_decode_kernel`'s grid (`slot_ref`, `tile_ref`: step -> the
+    slot, and which tile of its leaf; `edge_ref`: 1 on a slot's first
+    step, 2 on its last, 3 on both) with a V operand and KV heads. A
+    tile comes as its lines (`_kv_lines`), a row's heads side by side.
+    `by_stride`: a KV head at a time, its rows every `H_kv`-th line (a
+    strided load, which Mosaic has for float32 lines of at most 128
+    lanes), its `rep` queries folded into that head's online softmax:
+    the kernel at its DMA pace. Else every query head meets every line
+    — `H_kv` times the products on the MXU, the least loaded unit, and
+    of the softmax's element-wise work: an eighth slower a tile on the
+    v5e — and `seen_ref` names each line's KV head, -1 where the mask
+    hides its row: query head `h` sees the lines of KV head `h // rep`."""
+    i = pl.program_id(0)
+    h = q_ref.shape[1]
+    rep = h // hkv
+
+    @pl.when(edge_ref[i] % 2 == 1)
+    def _():
+        m_s[...] = jnp.full_like(m_s, _NEG_INF)
+        l_s[...] = jnp.zeros_like(l_s)
+        acc_s[...] = jnp.zeros_like(acc_s)
+
+    q = q_ref[0].astype(jnp.float32) * scale
+    nt = ((1,), (1,))               # contract the minor dim of both
+    if by_stride:
+        seen = seen_ref[0] != 0                                # [1, T]
+        for g in range(hkv):
+            rows, mine = pl.ds(g, tile, stride=hkv), pl.ds(g * rep, rep)
+            s = _dot_high(_split2(q[g * rep:(g + 1) * rep]), rep,
+                          _split2(k_ref[0, rows, :]), tile, nt)
+            _kv_fold(jnp.where(seen, s, _NEG_INF), _split2(v_ref[0, rows, :]),
+                     tile, rep, m_s.at[mine], l_s.at[mine], acc_s.at[mine])
+    else:
+        lines = tile * hkv
+        s = _dot_high(_split2(q), h, _split2(_kv_lines(k_ref)), lines, nt)
+        mine = jax.lax.broadcasted_iota(jnp.int32, (h, 1), 0) // rep
+        _kv_fold(jnp.where(seen_ref[0] == mine, s, _NEG_INF),
+                 _split2(_kv_lines(v_ref)), lines, h, m_s, l_s, acc_s)
+
+    @pl.when(edge_ref[i] >= 2)
+    def _():
+        o_ref[0] = (acc_s[...] / l_s[:, :1]).astype(o_ref.dtype)
+
+
+def kv_decode_attention(q, k, v, seen, scale, *, tile=None, interpret=False):
+    """Attention of ONE query a slot over K and V held by head: q `[B,
+    H, D]` float32 against k `[B, L, H_kv, D]` and v `[B, L, H_kv, Dv]`
+    (float32 or bf16, as the pool holds them; query head `j * rep + r`
+    reads KV head `j`, `rep = H // H_kv`), `seen` `[B, rows]` boolean,
+    `rows <= L`: `softmax_k(q . k_k * scale) . v` over the rows `seen`
+    shows -> `[B, H, Dv]` float32.
+
+    A slot's walk runs from the tile of the first row it sees to the
+    tile of the last (`decode_walk`): those tiles are read, each once
+    for the scores and the values, and a row `seen` hides between the
+    two stays hidden. A slot that sees no row walks one tile and gives
+    a finite, meaningless average of it (the caller discards an
+    inactive slot's output). The grid is as long as the tiles to walk.
+    Products are what `precision='high'` gives XLA (`_dot_high`), the
+    softmax float32.
+
+    A leaf of at most 128 lanes a head is taken as its lines `[B, L *
+    H_kv, D]`, a bitcast of a row-major leaf; a wider one (mimo's K,
+    192) as it is, and its tile is reshaped to lines in the kernel:
+    either way nothing of a leaf the serving pool holds is copied on
+    the way in (`serving/kv_pool.py` on layouts). Float32 leaves both
+    taken as lines are read a KV head at a time, by stride; any other
+    pair all heads at once (`_kv_decode_kernel`). `tile` (dividing
+    `rows` and `L`; on a TPU whole lanes) is the rows a grid step
+    takes; None is `_mla_row_tile` of both."""
+    bsz, h, d = q.shape
+    length, hkv, dv, rows = k.shape[1], k.shape[2], v.shape[3], seen.shape[1]
+    if k.shape != (bsz, length, hkv, d) or v.shape[:3] != k.shape[:3] \
+            or h % hkv or seen.shape[0] != bsz or rows > length \
+            or k.dtype != v.dtype:
+        raise ValueError(
+            f'kv_decode_attention: q {q.shape} against k {k.shape} '
+            f'{k.dtype} / v {v.shape} {v.dtype}, seen {seen.shape}')
+    if seen.dtype != jnp.bool_:
+        raise ValueError(f'kv_decode_attention: a boolean mask, not '
+                         f'{seen.dtype}')
+    if tile is None:
+        tile = _mla_row_tile(math.gcd(rows, length))
+    if not tile or rows % tile or length % tile:
+        raise ValueError(f'kv_decode_attention: tile {tile} must divide '
+                         f'the rows attended {rows} and held {length}')
+    parts = 1 if k.dtype == jnp.bfloat16 else 2
+    by_stride = max(d, dv) <= 128 and k.dtype == jnp.float32
+    # tiny, in XLA: a slot's first and last seen row, its walk, the
+    # table step -> (slot, its tile, whether its first or its last) —
+    # one entry more than the most steps, every entry past the walk the
+    # last real step's — and the mask as the kernel reads it
+    row = jnp.arange(rows, dtype=jnp.int32)
+    bound = jnp.max(jnp.where(seen, row + 1, 0), axis=1)
+    first = jnp.minimum(jnp.min(jnp.where(seen, row, rows), axis=1), bound)
+    start, tiles = decode_walk(first, bound, tile)
+    ends = jnp.cumsum(tiles)
+    step = jnp.minimum(jnp.arange(bsz * (rows // tile) + 1, dtype=jnp.int32),
+                       ends[-1] - 1)
+    slot = jnp.sum(ends[None, :] <= step[:, None], axis=1, dtype=jnp.int32)
+    at = step - (ends - tiles)[slot]
+    edge = (at == 0) + 2 * (at == tiles[slot] - 1)
+    if by_stride:
+        seen, per_row = seen.astype(jnp.int32), 1
+    else:           # by line: its KV head, -1 where its row is hidden
+        seen = jnp.where(seen[:, :, None], jnp.arange(hkv, dtype=jnp.int32),
+                         -1).reshape(bsz, rows * hkv)
+        per_row = hkv
+
+    def of_slot(i, slot_ref, tile_ref, edge_ref):
+        return slot_ref[i], 0, 0
+
+    def of_tile(i, slot_ref, tile_ref, edge_ref):
+        return slot_ref[i], tile_ref[i], 0
+
+    def of_tile4(i, slot_ref, tile_ref, edge_ref):
+        return slot_ref[i], tile_ref[i], 0, 0
+
+    def seen_tile(i, slot_ref, tile_ref, edge_ref):
+        return slot_ref[i], 0, tile_ref[i]
+
+    def leaf(c):
+        width = c.shape[-1]
+        if width <= 128:
+            return (c.reshape(bsz, length * hkv, width),
+                    pl.BlockSpec((1, tile * hkv, width), of_tile))
+        return c, pl.BlockSpec((1, tile, hkv, width), of_tile4)
+    (k, k_block), (v, v_block) = leaf(k), leaf(v)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(ends[-1],),
+        in_specs=[
+            pl.BlockSpec((1, h, d), of_slot), k_block, v_block,
+            pl.BlockSpec((1, 1, tile * per_row), seen_tile),
+        ],
+        out_specs=pl.BlockSpec((1, h, dv), of_slot),
+        scratch_shapes=[
+            pltpu.VMEM((h, 128), jnp.float32),          # running max
+            pltpu.VMEM((h, 128), jnp.float32),          # running sum
+            pltpu.VMEM((h, dv), jnp.float32),           # accumulator
+        ])
+    # two buffers of each tile (its lines padded to whole lanes), their
+    # parts, the products' float32 results; far under the chip's 128 MiB
+    lanes = -(-d // 128) * 128 + -(-dv // 128) * 128
+    vmem = tile * hkv * lanes * (2 * k.dtype.itemsize + 2 * parts) \
+        + 16 * h * (tile * per_row + dv) * 4 + (8 << 20)
+    return pl.pallas_call(
+        functools.partial(_kv_decode_kernel, tile=tile, hkv=hkv, scale=scale,
+                          by_stride=by_stride),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((bsz, h, dv), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=('arbitrary',), vmem_limit_bytes=vmem),
+        interpret=interpret,
+        name='kv_decode_attention',
+    )(slot, at + start[slot], edge.astype(jnp.int32), q, k, v,
+      seen[:, None, :])

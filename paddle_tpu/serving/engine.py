@@ -482,7 +482,6 @@ class InferenceEngine:
                                for i in attending], bool)
         self._ring_rows = sum(self.pool.row_spec[i][0].shape[1]
                               for i in self.pool.ring_layers)
-        self._full_layers = int(np.count_nonzero(~self._ring))
         # the decode block exists at two lengths of attention: every
         # row of a slot, and the first half — which `_decode_round`
         # picks while the batch's positions allow it. 0 where a block
@@ -495,11 +494,12 @@ class InferenceEngine:
         # and a decode round's span says nothing)
         self._residual_streams = int(
             getattr(model, 'residual_streams', 0) or 0)
-        # either program's rows -> the row tile by which a latent
-        # layer's attention is bounded per slot there, None where it
-        # reads every row: what `read_rows` counts such a layer by
-        self._latent_tiles = {
-            rows: self._latent_tile(rows)
+        # either program's rows -> per attending layer, the row tile by
+        # which its decode attention is bounded per slot there, 0 where
+        # it reads every row: what `read_rows` counts such a layer by
+        self._attending = attending
+        self._tiles = {
+            rows: self._bounded_tiles(rows)
             for rows in (self.pool.max_length, self._half_rows) if rows}
 
         self._trace_counts = collections.Counter()
@@ -1659,39 +1659,50 @@ class InferenceEngine:
         need = np.minimum(written[:, None], self._layer_rows[None, :])
         return int(need.sum()), int(need[:, self._ring].sum())
 
-    def _latent_tile(self, rows: int):
-        """The row tile of the kernel that runs a latent layer's decode
-        attention in the program that attends over `rows` rows, None
-        where XLA's einsums run it (no latent entry, another backend):
-        the model's own dispatch, asked with the call the decode scan
-        makes — one query a slot, the leaf as held, a boolean mask of
-        `rows` columns."""
-        if not self.pool.latent_layers:
-            return None
+    def _bounded_tiles(self, rows: int):
+        """Per attending layer, the row tile of the kernel that runs
+        its decode attention in the program that attends over `rows`
+        rows, 0 where XLA reads every row (another backend, a ring, a
+        call the kernels do not take): the model's own dispatch, asked
+        with the call the decode scan makes — one query a slot, the
+        leaves as held, a boolean mask of `rows` columns. A latent
+        entry is `ops.pallas.latent_decode_kernel`'s; K and V by head
+        the model's to say (`decode_tiles`: its layers know their
+        queries and their sinks)."""
         spec, slots = jax.ShapeDtypeStruct, self.pool.num_slots
-        kernel = _pallas.latent_decode_kernel(
-            spec((slots, 1), np.float32),
-            self.pool.row_spec[self.pool.latent_layers[0]][0],
-            spec((slots, 1, 1, rows), np.bool_))
-        return kernel and kernel.keywords['tile']
+        tiles = {}
+        for i in self.pool.latent_layers:
+            kernel = _pallas.latent_decode_kernel(
+                spec((slots, 1), np.float32), self.pool.row_spec[i][0],
+                spec((slots, 1, 1, rows), np.bool_))
+            tiles[i] = kernel and kernel.keywords['tile']
+        by_head = getattr(self.model, 'decode_tiles', None)
+        if by_head is not None:
+            tiles.update(enumerate(by_head(self.pool.row_spec, slots, rows)))
+        return np.array([tiles.get(i) or 0 for i in self._attending],
+                        np.int64)
 
     def _read_rows(self, rows: int) -> int:
         """Cache rows this round's attention READS, over slots and
         layers: every slot's first `rows` rows on every layer that keeps
-        the slot's length and the whole of every ring — but on a latent
-        layer whose attention is bounded per slot (`_latent_tile`) what
-        the kernel walks: each decoding slot's length rounded up to the
-        row tile, one tile of a slot that is not decoding."""
-        slots, full = self.pool.num_slots, self._full_layers
-        read = slots * self._ring_rows
-        tile = self._latent_tiles[rows]
-        if tile:
-            written = np.where(self._active,
-                               self._pos.astype(np.int64) + 1, 1)
-            walked = -(-np.minimum(written, rows) // tile) * tile
-            read += int(walked.sum()) * len(self.pool.latent_layers)
-            full -= len(self.pool.latent_layers)
-        return read + slots * rows * full
+        the slot's length and the whole of every ring — but on a layer
+        whose attention is bounded per slot (`_bounded_tiles`) what the
+        kernel walks (`pallas_kernels.decode_walk`, the kernel's own
+        arithmetic): the row tiles from the first row a decoding slot
+        sees (on a window layer the window's first) to its last, one
+        tile of a slot that is not decoding."""
+        slots, tiles = self.pool.num_slots, self._tiles[rows]
+        bounded = tiles > 0
+        read = slots * (self._ring_rows + rows * int(
+            np.count_nonzero(~bounded & ~self._ring)))
+        if bounded.any():
+            from ..ops.pallas_kernels import decode_walk
+            bound = np.where(self._active, np.minimum(
+                self._pos.astype(np.int64) + 1, rows), 0)[:, None]
+            first = np.maximum(bound - self._layer_rows[None, bounded], 0)
+            _, walked = decode_walk(first, bound, tiles[None, bounded])
+            read += int((walked * tiles[None, bounded]).sum())
+        return read
 
     def _note_routing(self, round_span, routing):
         """Book a round's routing counts (`[decode_block, 2, expert

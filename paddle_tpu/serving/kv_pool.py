@@ -58,6 +58,28 @@ program is the one it was. What a leaf SAYS of its layout
 (`leaf.format`) is not to be read once programs are loaded from jax's
 compile cache (`programs.store._LayoutsOnTrust`): `formats` is the book.
 
+Where the decode block's attention is a Mosaic kernel (PR 38 latent
+rows, PR 40 K and V by head: `ops/pallas_kernels.py`), the kernel takes
+the leaf AS IT IS HELD, so a K or V leaf ends in the ROW-MAJOR layout
+`0,1,2,3` with the heads in the sublanes and the head size in the lanes
+— which is also what the `kv_write` scatter wants — and nothing of a
+leaf is copied (`tests/test_aot_decode.py`). A leaf of at most 128 lanes
+a head goes in as its LINES `[slot, row x H_kv, D]`, a bitcast of that
+layout; a wider one (mimo's K) as the 4-D leaf, its tile reshaped to
+lines inside the kernel. What a row of ONE leaf costs on the device
+(AOT for a v5e, PR 40): whole lanes cost what they are — trinity-mini
+`T(4,128)`, the default, 4 x 128 x 4 = 2,048 B; a head size under a
+lane tile pads to it — lfm2 `T(8,128)`, 8 heads x 64 -> 128 lanes =
+4,096 B where 2,048 are logical; one over it pads to the next — mimo's
+full K `T(4,128)`, 4 heads x 192 -> 256 lanes = 4,096 B against 3,072
+(its V, 128 wide, 2,048 in the default): the same bytes the parent of
+PR 40 held. Views that would pad nothing do not exist here: `[slot,
+row, H_kv x D]` is no layout of the 4-D leaf, the lines of a 192-wide
+leaf are no bitcast of it (the block copied mimo's K whole every
+sub-step), and a heads-major leaf is relaid in and out by the scatter.
+The kernel walks these device bytes (`entry_bytes`); the benchmark's
+rooflines count the logical ones.
+
 Prefill shapes are length-bucketed: a prompt of length s runs at the
 smallest bucket >= s (right-padded; pad KV lands above the live
 position, where the slot-causal decode mask hides it until the slot's

@@ -71,6 +71,30 @@ def fresh_programs():
 
 
 @pytest.fixture
+def kv_interpreted(monkeypatch, fresh_programs):
+    """Decode attention over K and V held by head through the kernel,
+    interpreted, wherever `ops.pallas.kv_decode_kernel`'s conditions
+    hold but the backend's — for a model's cached branch and for the
+    engine's count alike — at tiles of 16 or 8 rows (toy lengths have
+    no whole lanes). -> what the kernel was handed, call by call (at
+    trace time: tracers under a jit)."""
+    import functools
+    from paddle_tpu.ops import pallas, pallas_kernels
+    calls = []
+    real = pallas_kernels.kv_decode_attention
+
+    def kernel(*args, **kw):
+        calls.append(args)
+        return real(*args, **kw)
+    monkeypatch.setattr(pallas_kernels, 'kv_decode_attention', kernel)
+    monkeypatch.setattr(pallas_kernels, '_mla_row_tile', lambda rows: next(
+        (n for n in (16, 8) if rows % n == 0), None))
+    monkeypatch.setattr(pallas, 'kv_decode_kernel', functools.partial(
+        pallas.kv_decode_kernel, interpret=True))
+    return calls
+
+
+@pytest.fixture
 def sanitizer_strict():
     """Run the test under the runtime concurrency sanitizer in STRICT
     mode (ISSUE 15): any lock-order cycle, non-reentrant re-entry, or

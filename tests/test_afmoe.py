@@ -509,6 +509,56 @@ def test_the_kernel_serves_the_loops_tokens_and_says_it_ran(
         == sum(a['expert_kernel_substeps'] for a in rounds)
 
 
+def _walked(length, window, tile=16):
+    """Rows a decoding slot of `length` rows walks on a layer that sees
+    the newest `window` (None: all) in tiles of `tile`, by hand."""
+    first = max(length - window, 0) if window else 0
+    return ((length - 1) // tile - first // tile + 1) * tile
+
+
+def test_decode_through_the_kernel_agrees_with_the_reference(
+        kv_interpreted, preset='tiny_rep4'):
+    """The decode block through `kv_decode_attention`, interpreted, 2
+    slots x 64 rows in tiles of 16, one request at a time so a round's
+    `read_rows` is exact: on the full layer the decoding slot's length
+    rounded up to the tile, on a window layer only the tiles its window
+    of 8 touches — one, or two across an edge, wherever the slot stands
+    — and ONE tile a layer of the slot that is not decoding."""
+    cfg = _cfg(preset)
+    w = _weights(cfg)
+    model = _model(cfg, w)
+    windows = model.attention_windows()
+    log = obs.get_event_log()
+    log.clear()
+    eng = InferenceEngine(model, num_slots=2, max_length=64, decode_block=4,
+                          buckets=[16, 32], eos_token_id=-1)
+    assert eng._bounded_tiles(64).tolist() == [16] * len(windows)
+    assert eng._bounded_tiles(32).tolist() == [16] * len(windows)
+    rs = np.random.RandomState(4)
+    for n_prompt, n_new in ((3, 14), (21, 34)):
+        prompt = rs.randint(3, 128, n_prompt).tolist()
+        h = eng.submit(prompt, SamplingParams(max_new_tokens=n_new,
+                                              eos_token_id=-1))
+        eng.run()
+        assert _served_gap(cfg, w, prompt, list(h.tokens)) < TOL
+    assert len(kv_interpreted) == 2 * len(windows)  # a call a layer, traced
+    rounds = _rounds(log)
+    assert {a['rows'] for a in rounds} == {32, 64}
+    spans = set()
+    for a in rounds:
+        assert a['active'] == 1
+        # needed: min(length, 8) on the window layers, length on the full
+        n_win = sum(w_ is not None for w_ in windows)
+        length = next(n for n in range(1, 65) if n_win * min(n, 8)
+                      + (len(windows) - n_win) * n == a['needed_rows'])
+        assert a['read_rows'] == sum(_walked(length, w_) + 16
+                                     for w_ in windows)
+        assert a['needed_rows'] <= a['read_rows'] \
+            < 2 * a['rows'] * len(windows)
+        spans.add(_walked(length, 8) // 16)
+    assert spans == {1, 2}      # a window inside a tile, and across an edge
+
+
 def test_a_model_without_experts_returns_what_it_returned():
     paddle.seed(5)
     model = LlamaForCausalLM(LlamaConfig.tiny()).eval()

@@ -243,11 +243,16 @@ def test_decode_block_writes_its_rows_in_place_on_v5e(workload, program,
     assert len(out) <= layers, f'more than a leaf a layer evicted: {out}'
     if program == 'half':
         # attention reads half of each leaf where it lies: nothing of a
-        # leaf's size moves, in or out
+        # leaf's size moves, in or out — serve-chat's bf16 queries
+        # through a slice fused into both contractions, serve-moe-docs'
+        # float32 ones through the kernel, which takes the leaf whole
+        # and walks under the mask's 2,048 columns (PR 40)
         assert not into and not out, (into, out)
         rows = leaves[0].shape[1] // 2
-        assert re.search(r'f32\[%d,%d,%d,%d\]\S* slice\(' % (
+        sliced = re.search(r'f32\[%d,%d,%d,%d\]\S* slice\(' % (
             leaves[0].shape[0], rows, *leaves[0].shape[2:]), text)
+        assert bool(sliced) == (workload == 'serve-chat')
+        assert ('kv_decode_attention' in text) == (workload != 'serve-chat')
     # whole lanes: the default layout pads nothing, so the bytes on the
     # device are the logical ones
     assert pool_bytes == sum(v.size * v.dtype.itemsize for v in leaves)
@@ -338,6 +343,63 @@ def _cut_to_three_layers(cfg):
     if 'hybrid_layer_pattern' in cfg:
         cfg['hybrid_layer_pattern'] = [0, 1, 0]
         cfg['moe_layer_freq'] = [0, 1, 1]
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize('program', ['whole', 'half'])
+@pytest.mark.parametrize('workload, attending, row_bytes', [
+    ('serve-moe-docs', 3, 2 * 4 * 128 * 4),
+    ('serve-hybrid-reason', 1, 2 * 8 * 128 * 4),
+    ('serve-swa-reason', 2, 4 * (256 + 128) * 4)])
+def test_attention_by_head_is_one_kernel_on_the_leaves_as_held_on_v5e(
+        workload, attending, row_bytes, program, one_chip):
+    """The three cells whose queries are float32 over K and V by head,
+    at their shapes and three layers (trinity-mini: three attending
+    layers; lfm2: conv, attention, conv; mimo: full, ring, full — the
+    ring has a sink and keeps XLA): a sub-step holds ONE
+    `kv_decode_attention` an attending layer under `attention`; its K
+    and V operands are the 4-D leaves the row's scatter has just
+    written, in the layout the pool holds them — nothing of such a
+    leaf's size is copied, transposed or staged anywhere in the
+    program — and a row costs `row_bytes` on the device: trinity-mini's
+    4 x 128 whole, lfm2's 64 lanes padded to 128 (twice the logical
+    row), mimo's K 192 padded to 256 (4/3) beside its whole V."""
+    cell = spec.Spec().cell(workload)
+    _cut_to_three_layers(cell['config'])
+    text, ma, leaves, pool_bytes = _compile_decode_block(cell, one_chip,
+                                                         program)
+    assert 'decode' in re.search(r'HloModule (\S+)', text).group(1)
+    held = [v for v in leaves if v.ndim == 4 and v.shape[1] == 4096]
+    assert len(held) == 2 * attending
+    calls = [ln for ln in text.splitlines() if 'tpu_custom_call' in ln]
+    attention = [ln for ln in calls if 'kv_decode_attention' in ln]
+    assert len(attention) == attending, [ln[:160] for ln in calls]
+    assert all('/attention/kv_decode_attention' in ln for ln in attention)
+    assert not moved_leaves(text, held)
+    # the kernel reads what the row's scatters return, leaf for leaf:
+    # its K and V operands are the fusions that wrote the leaves
+    written = {m.group(1) for ln in text.splitlines()
+               for m in [re.match(r'\s*(%\S+) = ' + _rows_of(held)
+                                  + r' fusion\(', ln)] if m}
+    assert any('kv_write' in ln for ln in text.splitlines()
+               if re.match(r'\s*%\S+ = ' + _rows_of(held) + r' fusion', ln))
+    # (a leaf at most 128 lanes a head goes in as its lines `[slot, row
+    # x H_kv, D]`: a bitcast of what was written, not a copy)
+    views = {m.group(1): m.group(2) for ln in text.splitlines()
+             for m in [re.match(r'\s*(%\S+) = \S+ bitcast\((%[^),]+)\)', ln)]
+             if m}
+    for ln in attention:
+        operands = re.sub(r'/\*[^*]*\*/', '', re.search(
+            r'custom-call\(([^)]*)\)', ln).group(1)).split(', ')
+        assert len(written & {views.get(o, o) for o in operands}) == 2, \
+            ln[:300]
+    slots, rows = held[0].shape[:2]
+    others = pool_bytes - attending * slots * rows * row_bytes
+    assert others == sum(
+        v.size // v.shape[-1] * -(-v.shape[-1] // 128) * 128 * 4
+        for v in leaves if v.ndim == 4 and v.shape[1] != 4096) + sum(
+        v.size * 4 for v in leaves if v.ndim != 4)
+    assert ma.alias_size_in_bytes == pool_bytes
 
 
 @pytest.mark.slow

@@ -678,6 +678,38 @@ def test_the_other_families_programs_are_the_parents(family):
         assert digest == _PARENT_PROGRAMS[family, name], (family, name)
 
 
+@pytest.mark.parametrize('family', sorted(_FAMILIES))
+def test_through_the_kv_kernel_only_the_decode_blocks_are_other_programs(
+        family, monkeypatch):
+    """`ops.pallas.kv_decode_kernel` lifted off its backend condition
+    (PR 40; tiles of 16 rows, the toy length has no whole lanes): the
+    families that ask it — float32 queries over K and V by head, in
+    their cached branch — get other decode blocks and the same prefill;
+    gpt and llama never ask, and every program of theirs is the
+    parent's. (Lowered here and not through the program store, whose
+    memory the module's `served` engines still need.)"""
+    from paddle_tpu.ops import pallas, pallas_kernels
+    kv_interpreted = []
+    real = pallas.kv_decode_kernel
+
+    def asked(*args, **kw):
+        kv_interpreted.append(args)
+        return real(*args, interpret=True, **kw)
+    monkeypatch.setattr(pallas, 'kv_decode_kernel', asked)
+    monkeypatch.setattr(pallas_kernels, '_mla_row_tile',
+                        lambda rows: 16 if rows % 16 == 0 else None)
+    cls, conf = _FAMILIES[family]
+    paddle.seed(0)
+    eng = InferenceEngine(cls(conf.tiny()).eval(), num_slots=2,
+                          max_length=64, decode_block=4, buckets=[16])
+    changed = {name for name, lowered in _program_texts(eng).items()
+               if hashlib.sha256(lowered.as_text().encode()).hexdigest()[:16]
+               != _PARENT_PROGRAMS[family, name]}
+    assert changed == ({'decode', 'decode_half'}
+                       if family in ('afmoe', 'lfm2', 'mimo_v2') else set())
+    assert bool(kv_interpreted) == bool(changed)
+
+
 # ---------------------------------------------------------------------------
 # (i) what a decode round's span and the pool's book carry
 # ---------------------------------------------------------------------------
@@ -1011,8 +1043,8 @@ def test_both_decode_programs_agree_with_the_reference_through_the_kernel(
     log = obs.get_event_log()
     log.clear()
     eng = _engine(model, max_length=WIDE_LEN, buckets=[16, 320, 640])
-    assert eng._latent_tile(WIDE_LEN) == 256
-    assert eng._latent_tile(WIDE_LEN // 2) == 128
+    assert eng._bounded_tiles(WIDE_LEN).tolist() == [256] * 3
+    assert eng._bounded_tiles(WIDE_LEN // 2).tolist() == [128] * 3
     for n_prompt, n_new in ((3, 12), (250, 24), (370, 16), (600, 12)):
         prompt = _prompts((n_prompt,), seed=n_prompt)[0]
         h = eng.submit(prompt, SamplingParams(max_new_tokens=n_new,
@@ -1053,7 +1085,7 @@ def test_through_router_and_engine_every_prompt_length_through_the_kernel(
     rounds = _rounds(log)
     assert any(a['active'] == 2 for a in rounds)
     for a in rounds:
-        tile = eng._latent_tile(a['rows'])
+        tile = int(eng._bounded_tiles(a['rows'])[0])
         assert a['needed_rows'] <= a['read_rows'] \
             <= a['needed_rows'] + 3 * 2 * tile
         assert a['read_rows'] % (3 * tile) == 0
@@ -1066,7 +1098,7 @@ def test_decode_round_reads_slots_x_rows_where_the_einsums_run(wide):
     log = obs.get_event_log()
     log.clear()
     eng = _engine(model, max_length=WIDE_LEN, buckets=[16])
-    assert eng._latent_tile(WIDE_LEN) is None
+    assert not eng._bounded_tiles(WIDE_LEN).any()
     eng.submit([5, 6, 7], SamplingParams(max_new_tokens=6, eos_token_id=-1))
     eng.run()
     rounds = _rounds(log)
@@ -1077,5 +1109,5 @@ def test_decode_round_reads_slots_x_rows_where_the_einsums_run(wide):
 def test_a_model_without_a_latent_entry_is_asked_nothing(interpreted):
     eng = InferenceEngine(_llama(), num_slots=2, max_length=256,
                           decode_block=BLOCK, buckets=[BUCKET])
-    assert eng._latent_tile(256) is None
+    assert not eng._bounded_tiles(256).any()
     assert eng._read_rows(256) == 2 * 256 * len(eng.pool.row_spec)

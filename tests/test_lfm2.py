@@ -587,6 +587,39 @@ def test_decode_round_carries_state_and_counts_one_attention_layer(tiny):
         == sum(a['state_bytes'] for a in rounds)
 
 
+def test_decode_through_the_kernel_agrees_with_the_reference(
+        tiny, kv_interpreted):
+    """The decode block through `kv_decode_attention`, interpreted (4
+    query heads a KV head, 2 slots x 64 rows in tiles of 16), one
+    request at a time so a round's `read_rows` is exact: on the ONE
+    attention layer the decoding slot's length rounded up to the tile,
+    and one tile of the slot that is not decoding; a conv layer reads
+    no row."""
+    cfg, w, model = tiny
+    log = obs.get_event_log()
+    log.clear()
+    eng = _engine(model)
+    assert eng._bounded_tiles(64).tolist() == [16]
+    assert eng._bounded_tiles(32).tolist() == [16]
+    for n_prompt, n_new in ((3, 14), (21, 34)):
+        prompt = _prompts((n_prompt,), seed=n_prompt)[0]
+        h = eng.submit(prompt, SamplingParams(max_new_tokens=n_new,
+                                              eos_token_id=-1))
+        eng.run()
+        assert _served_gap(cfg, w, prompt, list(h.tokens)) < TOL
+    assert len(kv_interpreted) == 2         # a call a program, traced
+    rounds = _rounds(log)
+    assert {a['rows'] for a in rounds} == {32, 64}
+    walked = set()
+    for a in rounds:
+        assert a['active'] == 1
+        tiles = -(-a['needed_rows'] // 16)
+        walked.add(tiles)
+        assert a['read_rows'] == tiles * 16 + 16
+        assert a['needed_rows'] <= a['read_rows'] <= 2 * a['rows']
+    assert walked == {1, 2, 3, 4}
+
+
 def test_the_expert_kernel_serves_the_loops_tokens(monkeypatch,
                                                    fresh_programs):
     """bf16 expert leaves, four picks of eight experts, a state beside

@@ -626,6 +626,47 @@ def test_both_decode_programs_agree_with_the_reference(built):
     assert eng._trace_counts['decode_step_half'] == 1
 
 
+def test_decode_through_the_kernel_agrees_with_the_reference(
+        built, kv_interpreted):
+    """The decode block through `kv_decode_attention`, interpreted (K
+    wider than V, 2 slots x 64 rows in tiles of 16), one request at a
+    time so a round's `read_rows` is exact: on a full layer WITHOUT a
+    sink the decoding slot's length rounded up to the tile and one tile
+    of the slot that is not decoding; a full layer with a sink
+    (`tiny_window_first`) and every ring keep XLA and are read whole."""
+    cfg, w, model = built
+    sinks = cfg['add_full_attention_sink_bias']
+    log = obs.get_event_log()
+    log.clear()
+    eng = _engine(model)
+    full = [i for i in range(len(eng.pool.row_spec))
+            if i not in eng.pool.ring_layers]
+    want = [0 if sinks or i in eng.pool.ring_layers else 16
+            for i in range(len(eng.pool.row_spec))]
+    assert eng._bounded_tiles(64).tolist() == want
+    assert eng._bounded_tiles(32).tolist() == want
+    for n_prompt, n_new in ((3, 14), (21, 34)):
+        prompt = _prompts((n_prompt,), seed=n_prompt)[0]
+        h = eng.submit(prompt, SamplingParams(max_new_tokens=n_new,
+                                              eos_token_id=-1))
+        eng.run()
+        assert _served_gap(cfg, w, prompt, list(h.tokens)) < TOL
+    assert len(kv_interpreted) == (0 if sinks else 2 * len(full))
+    rounds = [e['attrs'] for e in log.events()
+              if e['name'] == 'serving.decode_round']
+    assert {a['rows'] for a in rounds} == {32, 64}
+    rings = 2 * len(eng.pool.ring_layers) * WINDOW
+    for a in rounds:
+        assert a['active'] == 1
+        if sinks:
+            assert a['read_rows'] == 2 * len(full) * a['rows'] + rings
+            continue
+        length = (a['needed_rows'] - a['needed_rows_window']) // len(full)
+        assert a['read_rows'] \
+            == len(full) * (-(-length // 16) * 16 + 16) + rings
+        assert a['needed_rows'] <= a['read_rows']
+
+
 def test_attended_rows_leaves_a_ring_whole(tiny):
     """The half program's mask has 32 columns: a full layer's leaves are
     sliced to it, a ring's 4 rows are not its business."""
