@@ -49,8 +49,11 @@ the literal slot 0 and no mask, which is how a whole prefill arrives
 (`serving.engine._prefill_fn`, `GenerationMixin.generate`): every row a
 query may see is one the call itself brings, so it attends over its OWN
 tokens with `k_h` and `v_h` made from its `c` and `r`, in blocks of
-`PREFILL_QUERY_BLOCK` queries (no more than `[H, block, S]` scores
-live at once), and never reads the slot it writes;
+`PREFILL_QUERY_BLOCK` queries one after another, block `i` against the
+keys up to its own last row and no further (`[H, block, (i + 1) x
+block]` scores, one block's live at once; the half of bucket x bucket
+above the diagonal is never computed), and never reads the slot it
+writes;
 (ii) any call AGAINST ROWS HELD (a decode sub-step, speculation's k+1
 rows, a prefill chunk): absorbed. With `W_kvb = [W_UK_h ; W_UV_h]` a
 head, `q_nope_h . k_nope_jh = (q_nope_h W_UK_h^T) . c_j`, so `qL_h =
@@ -305,6 +308,17 @@ def _starts_empty_slot(cache_offset, attn_mask):
             and int(cache_offset) == 0)
 
 
+def own_tokens_pairs(tokens):
+    """Query-key pairs ONE layer's `_own_tokens_attention` computes for
+    a call of `tokens` tokens (a whole prefill's bucket): a block of
+    queries against the keys up to its own last row. What the serving
+    engine says on `serving.prefill` beside the pairs a causal mask
+    lets through."""
+    blk = PREFILL_QUERY_BLOCK
+    return sum((min(first + blk, tokens) - first) * min(first + blk, tokens)
+               for first in range(0, tokens, blk))
+
+
 def _own_tokens_attention(q, k, v, mask, gain=1.0):
     """Causal attention of a call over its OWN S tokens: q, k `[B, S, H,
     qk]`, v `[B, S, H, v]` -> `[B, S, H, v]`; `mask` None or a caller's
@@ -312,29 +326,29 @@ def _own_tokens_attention(q, k, v, mask, gain=1.0):
     `sqrt(qk)` times `gain` — `ops.pallas.flash_attention` scales by
     `1/sqrt(qk)` and takes no other, so what a configuration has beside
     that (`softmax_gain`) is put on the queries. More than
-    `PREFILL_QUERY_BLOCK` queries go block after block (`lax.map`), so
-    that one block's `[B, H, block, S]` scores are all that live."""
+    `PREFILL_QUERY_BLOCK` queries go block after block, and a block is
+    scored against the keys UP TO ITS OWN LAST ROW and no further (a
+    static prefix of k, v and the mask: what lies above the diagonal is
+    never computed; `own_tokens_pairs` counts what is). Each block
+    waits for the one before it, so that one block's `[B, H, block,
+    keys]` scores are all that live."""
     if gain != 1.0:
         q = q * jnp.asarray(gain, q.dtype)
     s, blk = q.shape[1], PREFILL_QUERY_BLOCK
     if s <= blk:
         return _pallas.flash_attention(q, k, v, mask=mask, causal=True)
-    pad = -s % blk
-    qb = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    qb = jnp.moveaxis(qb.reshape(q.shape[0], -1, blk, *q.shape[2:]), 1, 0)
-    j = jnp.arange(s, dtype=jnp.int32)
-
-    def one(args):
-        qi, first = args
-        i = first + jnp.arange(blk, dtype=jnp.int32)
-        seen = (j[None, :] <= i[:, None])[None, None]     # [1, 1, blk, S]
-        if mask is not None:
-            seen = seen & mask
-        return _pallas.flash_attention(qi, k, v, mask=seen)
-    out = jax.lax.map(one, (qb, jnp.arange(qb.shape[0],
-                                           dtype=jnp.int32) * blk))
-    out = jnp.moveaxis(out, 0, 1).reshape(q.shape[0], -1, *out.shape[3:])
-    return out[:, :s]
+    outs = []
+    for first in range(0, s, blk):
+        last = min(first + blk, s)
+        qi = q[:, first:last]
+        if outs:        # after the block before it, not beside it
+            qi, outs[-1] = jax.lax.optimization_barrier((qi, outs[-1]))
+        # causal with fewer queries than keys: the last query sees the
+        # last key (`_attention_xla`'s alignment)
+        outs.append(_pallas.flash_attention(
+            qi, k[:, :last], v[:, :last],
+            mask=None if mask is None else mask[..., :last], causal=True))
+    return jnp.concatenate(outs, axis=1)
 
 
 def _latent_attention(q_nope, q_rope, c, r, w_kvb, mask, scale):
@@ -642,3 +656,7 @@ class DeepseekV3ForCausalLM(DeepseekV3PretrainedModel, GenerationMixin):
 
     def init_cache(self, batch_size, max_length, dtype=None):
         return self.model.init_cache(batch_size, max_length, dtype)
+
+    # what a whole prefill's attention computes a layer, for the serving
+    # engine to say on `serving.prefill`
+    own_tokens_pairs = staticmethod(own_tokens_pairs)

@@ -494,6 +494,10 @@ class InferenceEngine:
         # and a decode round's span says nothing)
         self._residual_streams = int(
             getattr(model, 'residual_streams', 0) or 0)
+        # bucket -> the query-key pairs ONE layer's attention computes
+        # in a whole prefill, where that prefill attends over its own
+        # tokens (the model's to say; None: the span says nothing)
+        self._own_tokens_pairs = getattr(model, 'own_tokens_pairs', None)
         # either program's rows -> per attending layer, the row tile by
         # which its decode attention is bounded per slot there, 0 where
         # it reads every row: what `read_rows` counts such a layer by
@@ -2218,7 +2222,10 @@ class InferenceEngine:
         bucket = self.pool.bucket_for(s)
         t_pf0 = time.perf_counter()
         with _obs.span('serving.prefill', request_id=h.request_id,
-                       bucket=bucket, slot=slot, prompt_len=s):
+                       bucket=bucket, slot=slot, prompt_len=s) as span:
+            if self._own_tokens_pairs is not None:
+                span.set(attn_pairs_scored=self._own_tokens_pairs(bucket),
+                         attn_pairs_causal=s * (s + 1) // 2)
             ids = np.zeros((1, bucket), np.int32)
             ids[0, :s] = h.prompt_tokens
             ids_dev = call_with_retry(_to_device, ids, policy=self._retry,

@@ -45,6 +45,8 @@ from benchmarks.models import adapter, fill
 from benchmarks.reference import common as C
 from benchmarks.reference import deepseek_v3 as R
 
+from test_own_tokens_attention import _shapes
+
 TOL = 2e-4
 AD = adapter('DeepseekV3ForCausalLM')
 PRESETS = ('tiny', 'tiny_wide_v')
@@ -462,9 +464,20 @@ def test_the_other_preset_through_the_router():
     cfg = _cfg('tiny_wide_v')
     w = _weights(cfg)
     prompts = _prompts((3, BUCKET + 5), seed=4)
+    log = obs.get_event_log()
+    log.clear()
     toks, _ = _through_the_router(_model(cfg, w), prompts, N_NEW)
     for prompt, got in zip(prompts, toks):
         assert _served_gap(cfg, w, prompt, got) < TOL
+    # a whole prefill over its own tokens says what its attention
+    # computes a layer beside what a causal mask lets through (PR 41):
+    # a bucket under one block of queries is scored whole
+    prefills = [e['attrs'] for e in log.events()
+                if e['name'] == 'serving.prefill']
+    assert [(a['attn_pairs_scored'], a['attn_pairs_causal'])
+            for a in prefills] == [
+        (a['bucket'] ** 2, len(p) * (len(p) + 1) // 2)
+        for a, p in zip(prefills, prompts)]
 
 
 def test_generate_gives_the_references_greedy_tokens(tiny):
@@ -753,6 +766,9 @@ def test_a_model_without_a_latent_entry_carries_what_it_carried():
     a = [e['attrs'] for e in log.events()
          if e['name'] == 'serving.decode_round'][-1]
     assert not {'latent_layers', 'latent_row_bytes'} & set(a)
+    assert [set(e['attrs']) for e in log.events()
+            if e['name'] == 'serving.prefill'] == [
+        {'request_id', 'bucket', 'slot', 'prompt_len'}]
     stats = eng.pool.stats()
     assert stats['latent_layers'] == 0 and stats['latent_row_bytes'] == 0
 
@@ -787,29 +803,19 @@ def test_a_latent_leaf_asks_for_a_layout_by_its_width(what, shape, backend,
 # ---------------------------------------------------------------------------
 # (j) what a prefill may build
 # ---------------------------------------------------------------------------
-def _shapes(jaxpr, out):
-    for eqn in jaxpr.eqns:
-        out.extend(tuple(v.aval.shape) for v in eqn.outvars
-                   if hasattr(v.aval, 'shape'))
-        for p in eqn.params.values():
-            for sub in (p if isinstance(p, (list, tuple)) else [p]):
-                sub = getattr(sub, 'jaxpr', sub)
-                if hasattr(sub, 'eqns'):
-                    _shapes(sub, out)
-    return out
-
-
 @pytest.mark.parametrize('as_draft', [False, True],
                          ids=['the_model', 'a_latent_draft'])
-def test_a_prefill_scores_one_block_of_queries_against_its_bucket(
+def test_a_prefill_scores_a_block_of_queries_against_the_keys_up_to_its_end(
         tiny, monkeypatch, as_draft):
     """max_length 256, bucket 48, blocks of 16 queries: the three
-    differ (and differ from the hidden size, 64). The largest array the
-    prefill builds with the bucket's keys in its last axis is heads x
-    block x bucket; nothing is bucket x max_length (the absorbed path
-    over the slab) nor bucket x bucket (the own-tokens path unblocked).
-    So for the draft's whole prefill, where the draft keeps latent rows:
-    every whole prefill has one body (`engine._whole_prefill`)."""
+    differ (and differ from the hidden size, 64). Block `i` of the
+    prefill is scored against the keys up to its own last row, `(i + 1)
+    x 16` of them (PR 41), so the largest array with keys in its last
+    axis is heads x block x bucket, the last block's; nothing is bucket
+    x max_length (the absorbed path over the slab) nor bucket x bucket
+    (the own-tokens path unblocked). So for the draft's whole prefill,
+    where the draft keeps latent rows: every whole prefill has one body
+    (`engine._whole_prefill`)."""
     _, _, model = tiny
     ids = jnp.zeros((1, 48), jnp.int32)
     if as_draft:
@@ -829,8 +835,9 @@ def test_a_prefill_scores_one_block_of_queries_against_its_bucket(
         return _shapes(jax.make_jaxpr(lambda *args: prefill(*args))(
             *state, ids).jaxpr, [])
     blocked = shapes(16)
-    scores = [s for s in blocked if len(s) == 4 and s[-1] in (48, 256)]
-    assert (1, 4, 16, 48) in scores
+    scores = [s for s in blocked
+              if len(s) == 4 and s[-1] in (16, 32, 48, 256)]
+    assert {(1, 4, 16, 16), (1, 4, 16, 32), (1, 4, 16, 48)} <= set(scores)
     assert max(math.prod(s) for s in scores) == 4 * 16 * 48
     assert not [s for s in blocked
                 if len(s) >= 4 and s[-2:] in ((48, 256), (48, 48))]
@@ -841,7 +848,7 @@ def test_a_prefill_scores_one_block_of_queries_against_its_bucket(
 def test_blocks_of_queries_give_the_unblocked_result(tiny, monkeypatch,
                                                      fresh_dispatch):
     cfg, w, model = tiny
-    ids = _ids((2, 27), 12)             # 27: the last block is padded
+    ids = _ids((2, 27), 12)             # 27: the last block is short
     ref = _ref_logits(cfg, w, ids)
     monkeypatch.setattr(deepseek_v3, 'PREFILL_QUERY_BLOCK', 8)
     assert np.abs(model(paddle.to_tensor(ids)).numpy() - ref).max() < TOL

@@ -179,6 +179,12 @@ def test_serving_spans_nest_and_carry_scalar_counts(gpt, log):
     assert all(a['real_rows'] >= 5 * a['active'] for a in rounds)
     assert sum(e['attrs']['admitted'] for e in spans
                if e['name'] == 'serving.admit') == 3
+    # a gpt prefill attends against the row it writes: no pairs of an
+    # own-tokens attention to say (a latent engine's carry both:
+    # `tests/test_deepseek_v3.py`)
+    assert all(set(e['attrs']) == {'request_id', 'bucket', 'slot',
+                                   'prompt_len'}
+               for e in spans if e['name'] == 'serving.prefill')
     # a count rides a span only where a metric reads it
     for name in ('serving.emit', 'serving.reap', 'serving.router_step',
                  'serving.step'):
