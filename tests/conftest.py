@@ -58,6 +58,16 @@ def _reset_span_state():
 
 
 @pytest.fixture
+def fresh_dispatch():
+    """The eager dispatch cache keys an op by its code, not by the
+    module globals a departure patches: empty it around such a test."""
+    from paddle_tpu import _dispatch
+    _dispatch.clear()
+    yield
+    _dispatch.clear()
+
+
+@pytest.fixture
 def fresh_programs():
     """Empty the program store's memory around a test that serves ONE
     model on two paths a trace picks (the expert loop, then the

@@ -1,0 +1,128 @@
+"""The programs of the families a new family must leave alone: a tiny
+engine of each (2 slots x 64, block 4, bucket 16) lowers to the
+StableHLO it lowered to before the family that asks came — pinned when
+`nlp/mimo_v2.py` brought a ring (PR 32) and `nlp/deepseek_v3.py` a
+latent entry (PR 37) — and a model that keeps K and V only takes none
+of `nlp/lfm2.py`'s state path (PR 30). One place: a new family adds its
+tiny engine to `_FAMILIES`, its pins, and what its pool must not book for
+the others."""
+import hashlib
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import paddle_tpu as paddle
+from paddle_tpu import programs
+from paddle_tpu.nlp import generation
+from paddle_tpu.nlp.afmoe import AfmoeConfig, AfmoeForCausalLM
+from paddle_tpu.nlp.gpt import GPTConfig, GPTForCausalLM
+from paddle_tpu.nlp.lfm2 import Lfm2MoeConfig, Lfm2MoeForCausalLM
+from paddle_tpu.nlp.llama import LlamaConfig, LlamaForCausalLM
+from paddle_tpu.nlp.mimo_v2 import MiMoV2Config, MiMoV2ForCausalLM
+from paddle_tpu.serving import InferenceEngine
+
+from family_harness import program_texts
+
+_FAMILIES = {'gpt': (GPTForCausalLM, GPTConfig),
+             'llama': (LlamaForCausalLM, LlamaConfig),
+             'afmoe': (AfmoeForCausalLM, AfmoeConfig),
+             'lfm2': (Lfm2MoeForCausalLM, Lfm2MoeConfig),
+             'mimo_v2': (MiMoV2ForCausalLM, MiMoV2Config)}
+
+# sha256 (first 16 hex digits) of the StableHLO text of each program, by
+# the very code of `family_harness.program_texts`: the first twelve
+# prefills' taken on the PARENT of PR 32 (commit 25ee4df), mimo_v2's on
+# the PARENT of PR 37 (commit bbd1fb5); the decode programs' re-taken AT
+# PR 36, which made the slot state one buffer that they unpack (they are
+# the engine's own functions on `_decode_args()`, the pins of
+# `tests/test_pool_layout.py`); jax 0.9.0, which the repository is
+# written for (the verify skill)
+_PARENT_PROGRAMS = {
+    ('afmoe', 'decode'): '81008fe4d4edb6d9',
+    ('afmoe', 'decode_half'): '8e312056c151a0a2',
+    ('afmoe', 'prefill'): '6782a117cd64283e',
+    ('gpt', 'decode'): '5e706a44cb430fe1',
+    ('gpt', 'decode_half'): '4a4e6ee67293bb7c',
+    ('gpt', 'prefill'): '365eec42133d1ab2',
+    ('lfm2', 'decode'): '611c2975c6cfa539',
+    ('lfm2', 'decode_half'): '6df5d3a5564cc3bd',
+    ('lfm2', 'prefill'): '1a02dff7d8263eae',
+    ('llama', 'decode'): '0b25e1d31f4c9b75',
+    ('llama', 'decode_half'): '8a7f5153ef78c81d',
+    ('llama', 'prefill'): '8b4c79aa8dc443ef',
+    ('mimo_v2', 'decode'): '8ea7c6f267237b5a',
+    ('mimo_v2', 'decode_half'): 'cf1b7410b8762beb',
+    ('mimo_v2', 'prefill'): '83c5267b24fdfe6b',
+}
+
+
+def _tiny_engine(family):
+    cls, conf = _FAMILIES[family]
+    paddle.seed(0)
+    return InferenceEngine(cls(conf.tiny()).eval(), num_slots=2,
+                           max_length=64, decode_block=4, buckets=[16])
+
+
+def _digests(eng):
+    return {name: hashlib.sha256(lowered.as_text().encode()).hexdigest()[:16]
+            for name, lowered in program_texts(eng).items()}
+
+
+@pytest.mark.parametrize('family, without', [
+    # what mimo_v2's ring (PR 32) must leave alone ...
+    ('afmoe', 'ring_layers'), ('gpt', 'ring_layers'),
+    ('lfm2', 'ring_layers'), ('llama', 'ring_layers'),
+    # ... and what deepseek_v3's latent entry (PR 37)
+    ('afmoe', 'latent_layers'), ('gpt', 'latent_layers'),
+    ('lfm2', 'latent_layers'), ('llama', 'latent_layers'),
+    ('mimo_v2', 'latent_layers')])
+def test_the_other_families_programs_are_the_parents(family, without):
+    eng = _tiny_engine(family)
+    assert getattr(eng.pool, without) == ()
+    for name, digest in _digests(eng).items():
+        assert digest == _PARENT_PROGRAMS[family, name], (family, name)
+
+
+@pytest.mark.parametrize('family', sorted(_FAMILIES))
+def test_through_the_kv_kernel_only_the_decode_blocks_are_other_programs(
+        family, monkeypatch):
+    """`ops.pallas.kv_decode_kernel` lifted off its backend condition
+    (PR 40; tiles of 16 rows, the toy length has no whole lanes): the
+    families that ask it — float32 queries over K and V by head, in
+    their cached branch — get other decode blocks and the same prefill;
+    gpt and llama never ask, and every program of theirs is the
+    parent's. (Lowered here and not through the program store.)"""
+    from paddle_tpu.ops import pallas, pallas_kernels
+    kv_interpreted = []
+    real = pallas.kv_decode_kernel
+
+    def asked(*args, **kw):
+        kv_interpreted.append(args)
+        return real(*args, interpret=True, **kw)
+    monkeypatch.setattr(pallas, 'kv_decode_kernel', asked)
+    monkeypatch.setattr(pallas_kernels, '_mla_row_tile',
+                        lambda rows: 16 if rows % 16 == 0 else None)
+    changed = {name for name, digest in _digests(_tiny_engine(family)).items()
+               if digest != _PARENT_PROGRAMS[family, name]}
+    assert changed == ({'decode', 'decode_half'}
+                       if family in ('afmoe', 'lfm2', 'mimo_v2') else set())
+    assert bool(kv_interpreted) == bool(changed)
+
+
+@pytest.mark.parametrize('family', ['afmoe', 'gpt', 'llama'])
+def test_a_model_without_state_keeps_the_programs_it_had(family):
+    """The state path is picked by what the cache holds: a model with K
+    and V only has no state booked, every layer counted as attending,
+    and the plain prefill — ids alone, no length."""
+    eng = _tiny_engine(family)
+    assert eng.pool.state_layers == () and eng.pool.state_bytes == 0
+    assert len(eng._layer_rows) == len(eng.pool.row_spec)
+    assert eng._prefill_jit._fn_token \
+        == programs.code_token(eng._prefill_fn) \
+        != programs.code_token(eng._state_prefill_fn)
+    state = (eng._params, eng._frozen, eng._buffers)
+    slab = jax.eval_shape(eng._prefill_fn, *state,
+                          jnp.zeros((1, 16), jnp.int32))
+    assert not generation.state_layers(slab)

@@ -317,12 +317,15 @@ def test_a_slow_router_step_keeps_a_record_of_what_it_fell_under(
     assert all(f'{k}={v}' in lines[0] for k, v in rec.items())
     assert obs.get_registry().value('paddle_serving_slow_steps_total',
                                     under='serving.emit') >= 1
+    # (No CPU time is held against the wall clock: beside five other
+    # workers a spin of 0.61 s got 0.43 s of a core, and the collector's
+    # pause, timed on the wall, read 7.2 s against 4.3 s of CPU.)
     if stall is time.sleep:         # the thread was not running
         assert rec['cpu_s'] < 0.1 and rec['gc_s'] < 0.1
-    elif stall is _spin:            # Python held it
-        assert abs(rec['cpu_s'] - rec['dur_s']) <= 0.2 * rec['dur_s']
+    elif stall is _spin:            # Python held it: many times a sleep's
+        assert rec['cpu_s'] > 0.2
     else:                           # the collector's part is told apart
-        assert 0 < rec['gc_s'] <= rec['cpu_s'] + 0.1
+        assert rec['gc_s'] > 0
         snap = {m['name']: m for m in obs.get_registry().snapshot()['metrics']}
         assert snap['paddle_gc_pause_seconds_total']['samples'][0]['value'] \
             >= rec['gc_s']

@@ -5,7 +5,9 @@ with a compressed query under YaRN positions, Sinkhorn a plain loop) at
 the tiny preset: YaRN stretches 16 original positions 8 times and every
 test below runs past them; the hyper-connection leaves are drawn at std
 1 (every map far from its start) and, in one case, at a published-like
-start (gates 0.01, no bias).
+start (gates 0.01, no bias). The served half is
+`tests/test_xing4_serving.py`, the shared cases and helpers
+`tests/family_harness.py`'s.
 
 TOL: both sides compute in float32 on the CPU and differ only in the
 order of their sums (the flat norm after the product against before it;
@@ -21,12 +23,9 @@ import math
 import numpy as np
 import pytest
 
-import jax
 import jax.numpy as jnp
 
 import paddle_tpu as paddle
-from paddle_tpu import _dispatch
-from paddle_tpu.jit import functional_call, functional_state
 from paddle_tpu.nlp import deepseek_v3, llama, xing4
 from paddle_tpu.nlp.deepseek_v3 import (DeepseekV3Config,
                                         DeepseekV3DecoderLayer,
@@ -34,41 +33,27 @@ from paddle_tpu.nlp.deepseek_v3 import (DeepseekV3Config,
 from paddle_tpu.nlp.xing4 import (HyperConnection, Xing4Config,
                                   Xing4DecoderLayer, Xing4ForCausalLM)
 
-from benchmarks.models import adapter, fill
-from benchmarks.reference import common as C
-from benchmarks.reference import xing4 as R
+import family_harness as H
+from family_harness import TOL
 
-TOL = 2e-4
-AD = adapter('Xing4ForCausalLM')
-BUCKET, BLOCK, MAX_LEN = 16, 4, 64
 ORIGINAL = 16       # positions YaRN stretches from, in the tiny preset
-
-
-def _cfg(**over):
-    conf = Xing4Config.tiny(**over)
-    cfg = {k: getattr(conf, k, None) for k in AD._KEYS}
-    cfg.update(moe_layer_freq=1, scoring_func='sigmoid',
-               topk_method='noaux_tc', n_group=1, topk_group=1,
-               attention_bias=False, tie_word_embeddings=False,
-               num_key_value_heads=conf.num_attention_heads)
-    return cfg
 
 
 def _is_hc(name, leaf=None):
     return '.hc_' in name and (leaf is None or name.endswith('.' + leaf))
 
 
-def _weights(cfg, seed=7, start=False):
-    """std 0.3: logits of a few units. The selection bias at 0.3 changes
-    picks; the two attention norms' weights are 1 + what the generator
-    drew. The hyper-connections: `Phi` at 0.3 (arguments of deviation
-    4.8 before the gates); gates and biases at std 1 — or, `start`, the
-    gates 0.01 and no bias: a published-like start."""
-    w = C.make_weights(R.param_shapes(cfg), seed, 'float32', std=0.3)
+def _draw(R, cfg, seed, start=False):
+    """The selection bias at 0.3 changes picks; the two attention norms'
+    weights are 1 + what the generator drew. The hyper-connections:
+    `Phi` at 0.3 (arguments of deviation 4.8 before the gates); gates
+    and biases at std 1 — or, `start`, the gates 0.01 and no bias: a
+    published-like start."""
+    w = H.draw(R.param_shapes(cfg), seed)
     noisy = {k: (v.shape, 'normal') for k, v in w.items()
              if k.endswith(('.kv_norm', '.q_norm'))
              or (_is_hc(k) and not _is_hc(k, 'phi'))}
-    noise = C.make_weights(noisy, seed + 1, 'float32', std=1.0)
+    noise = H.draw(noisy, seed + 1, std=1.0)
     out = {}
     for k, v in w.items():
         if k.endswith(('.kv_norm', '.q_norm')):
@@ -82,83 +67,24 @@ def _weights(cfg, seed=7, start=False):
     return out
 
 
-def _model(cfg, w):
-    return fill(AD.build(cfg), w, AD.name_map(cfg)).eval()
-
-
-REF_LEN = 64
-_REF = {}
-
-
-def _ref_logits(cfg, w, ids, ref_len=REF_LEN):
-    """The reference's logits, one compile a set of weights: every row
-    goes through alone, right-padded to `ref_len` (the reference is
-    causal: what follows a position does not reach it)."""
-    if id(w) not in _REF:
-        _REF[id(w)] = (w, jax.jit(lambda wt, row: R.logits_of(
-            cfg, wt, R.hidden_states(cfg, wt, row))))
-    fn = _REF[id(w)][1]
-    ids = np.atleast_2d(np.asarray(ids, 'int32'))
-    padded = np.zeros((ids.shape[0], ref_len), 'int32')
-    padded[:, :ids.shape[1]] = ids
-    return np.stack([np.asarray(fn(w, jnp.asarray(row[None])))[0]
-                     for row in padded])[:, :ids.shape[1]]
-
-
-def _ids(shape, seed=0):
-    return np.random.RandomState(seed).randint(3, 128, shape).astype('int32')
-
-
-@pytest.fixture(scope='module')
-def tiny():
-    """One dense and one expert layer, four sublayers: the suite's time
-    is short (every compile here is paid in every run of it)."""
-    cfg = _cfg(num_hidden_layers=2)
-    w = _weights(cfg)
-    return cfg, w, _model(cfg, w)
-
-
-@pytest.fixture
-def fresh_dispatch():
-    """The eager dispatch cache keys an op by its code, not by the
-    module globals a departure patches: empty it around such a test."""
-    _dispatch.clear()
-    yield
-    _dispatch.clear()
-
-
-def _both_paths(model, ids):
-    """-> (logits of a plain forward: attention over the call's own
-    tokens; logits of the whole sequence in ONE call against rows held:
-    a traced slot, so the absorbed path over the cache it has just
-    written), one compile for the two."""
-    state = functional_state(model)
-    cache = model.init_cache(ids.shape[0], ids.shape[1] + 8)
-
-    def both(ids, cache, zero):
-        own, _ = functional_call(model, *state, (ids,), {})
-        (held, _), _ = functional_call(
-            model, *state, (ids,),
-            dict(cache=cache, use_cache=True, position_offset=zero,
-                 cache_offset=zero))
-        return own, held
-    own, held = jax.jit(both)(jnp.asarray(ids), cache,
-                              jnp.zeros((), jnp.int32))
-    return np.asarray(own), np.asarray(held)
+# one dense and one expert layer, four sublayers: the suite's time is
+# short (every compile here is paid in every run of it)
+FAM = H.Family(
+    'Xing4ForCausalLM', Xing4Config, ('tiny',),
+    cfg_adds=lambda conf: dict(
+        moe_layer_freq=1, scoring_func='sigmoid', topk_method='noaux_tc',
+        n_group=1, topk_group=1, attention_bias=False,
+        tie_word_embeddings=False,
+        num_key_value_heads=conf.num_attention_heads),
+    draw=_draw, over=dict(num_hidden_layers=2))
+AD, R = FAM.adapter, FAM.R
 
 
 # ---------------------------------------------------------------------------
 # (a) the whole forward, both attention paths, past the original positions
 # ---------------------------------------------------------------------------
-def test_full_forward_agrees_with_the_reference_on_both_paths(tiny):
-    cfg, w, model = tiny
-    ids = _ids((1, 40))
-    assert ids.shape[1] > 2 * ORIGINAL
-    ref = _ref_logits(cfg, w, ids)
-    assert np.abs(ref).max() > 3
-    own, held = _both_paths(model, ids)
-    assert np.abs(own - ref).max() < TOL
-    assert np.abs(held - ref).max() < TOL
+test_full_forward_agrees_with_the_reference_on_both_paths = \
+    H.full_forward(FAM, H.paths, shape=(1, 2 * ORIGINAL + 8))
 
 
 # ---------------------------------------------------------------------------
@@ -361,19 +287,6 @@ def _res_map_transposed(model, mp):
     mp.setattr(xing4, 'connection_maps', transposed)
 
 
-def _bf16_operands(model, mp):
-    """What a single bf16 pass makes of the float32 activations the
-    configuration states: every norm's output — the operand of every
-    projection — rounded."""
-    def rounded(norm):
-        real = norm.forward
-        norm.forward = lambda x: real(x).astype('bfloat16').astype('float32')
-    for layer in model.model.layers:
-        rounded(layer.input_layernorm)
-        rounded(layer.post_attention_layernorm)
-    rounded(model.model.norm)
-
-
 def _bf16_maps(model, mp):
     """The residual path in bfloat16: the streams rounded as a
     sublayer reads them."""
@@ -382,68 +295,32 @@ def _bf16_maps(model, mp):
         x.astype(jnp.bfloat16).astype(jnp.float32), *a, **k))
 
 
-DEPARTURES = [_no_sinkhorn, _no_mscale_on_the_logits, _no_query_norm,
-              _plain_positions, _res_map_transposed, _bf16_operands,
-              _bf16_maps]
+def _held(model, ids):
+    return H.paths(model, ids, own=False)
 
 
-@pytest.fixture(scope='module')
-def one_layer():
-    cfg = _cfg(num_hidden_layers=1)      # two sublayers, attention and MLP
-    w = _weights(cfg, seed=11)
-    ids = _ids((1, 40), 5)
-    return cfg, w, ids, _ref_logits(cfg, w, ids)
-
-
-def _held_path(model, ids):
-    """The whole sequence in ONE call against rows held (a traced slot):
-    the absorbed path over the cache it has just written."""
-    state = functional_state(model)
-    cache = model.init_cache(1, ids.shape[1] + 8)
-
-    def held(ids, cache, zero):
-        (out, _), _ = functional_call(
-            model, *state, (ids,),
-            dict(cache=cache, use_cache=True, position_offset=zero,
-                 cache_offset=zero))
-        return out
-    return np.asarray(jax.jit(held)(jnp.asarray(ids), cache,
-                                    jnp.zeros((), jnp.int32)))
-
-
-@pytest.mark.parametrize('departure', DEPARTURES,
-                         ids=lambda d: d.__name__.strip('_'))
-def test_each_departure_fails_the_tolerance_the_sound_model_passes(
-        departure, one_layer, monkeypatch, fresh_dispatch):
-    """One layer, 40 positions (the YaRN of the preset stretches 16),
-    the absorbed path; the sound model passes TOL on the same weights in
-    the last test of the group. The first three are the three faulty
-    programs the chip's limit has to refuse; the last two
-    are the precision test: computing in bfloat16 what the configuration
-    states in float32 fails TOL by more than an order of magnitude."""
-    cfg, w, ids, ref = one_layer
-    model = _model(cfg, w)
-    departure(model, monkeypatch)
-    err = np.abs(_held_path(model, ids) - ref).max()
-    assert err > 50 * TOL, (departure.__name__, err)
-
-
-def test_the_sound_model_passes_on_the_departures_weights(one_layer):
-    cfg, w, ids, ref = one_layer
-    assert np.abs(ref).max() > 3
-    assert np.abs(_held_path(_model(cfg, w), ids) - ref).max() < TOL
+# One layer (two sublayers, attention and MLP), 40 positions (the YaRN
+# of the preset stretches 16), the absorbed path. The first three are
+# the three faulty programs the chip's limit has to refuse; the last two
+# are the precision test: computing in bfloat16 what the configuration
+# states in float32 fails TOL by more than an order of magnitude.
+test_each_departure_fails_the_tolerance_the_sound_model_passes = \
+    H.each_departure(
+        FAM, [_no_sinkhorn, _no_mscale_on_the_logits, _no_query_norm,
+              _plain_positions, _res_map_transposed, H.bf16_operands,
+              _bf16_maps], _held, shape=(1, 40), num_hidden_layers=1)
 
 
 def test_a_published_like_start_agrees_too_and_its_maps_are_constants():
     """Gates 0.01 and no bias: the maps are within a few hundredths of
     1/2, 1 and 1/4 for every token, which is why the benchmark's
     weights do NOT start there (a dropped Sinkhorn would not show)."""
-    cfg = _cfg(num_hidden_layers=1)
-    w = _weights(cfg, seed=9, start=True)
-    ids = _ids((1, 24), 6)
-    model = _model(cfg, w)
-    assert np.abs(_held_path(model, ids)
-                  - _ref_logits(cfg, w, ids)).max() < TOL
+    cfg = FAM.cfg(num_hidden_layers=1)
+    w = FAM.weights(cfg, seed=9, start=True)
+    ids = H.ids((1, 24), 6)
+    model = FAM.model(cfg, w)
+    assert np.abs(_held(model, ids)[0]
+                  - FAM.ref_logits(cfg, w, ids)).max() < TOL
     hc = model.model.layers[0].hc_mlp
     x = paddle.to_tensor(np.random.RandomState(0).standard_normal(
         (1, 5, 4, 64)).astype('float32'))
@@ -471,9 +348,8 @@ def test_config_presets_and_refusals():
         == [(1,), (1,), (1,), (4,), (4,), (4, 4)]
     assert Xing4ForCausalLM(Xing4Config.tiny(
         num_hidden_layers=1)).residual_streams == 4
-    for bad, what in ((dict(hc_mult=0), 'hc_mult'),
-                      (dict(rope_scaling={'type': 'dynamic', 'factor': 2}),
-                       "rope_scaling type 'dynamic'"),
-                      (dict(n_group=8), 'n_group')):
-        with pytest.raises(ValueError, match=what):
-            Xing4Config.tiny(**bad)
+    H.refused(Xing4Config.tiny, (
+        (dict(hc_mult=0), 'hc_mult'),
+        (dict(rope_scaling={'type': 'dynamic', 'factor': 2}),
+         "rope_scaling type 'dynamic'"),
+        (dict(n_group=8), 'n_group')))
