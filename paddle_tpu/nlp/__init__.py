@@ -17,6 +17,7 @@ from .ernie import (ErnieConfig, ErnieForMaskedLM,
 from .generation import GenerationMixin, Seq2SeqGenerationMixin
 from .gpt import GPTConfig, GPTForCausalLM, GPTModel
 from .lfm2 import Lfm2MoeConfig, Lfm2MoeForCausalLM, Lfm2MoeModel
+from .ling3 import Ling3Config, Ling3ForCausalLM, Ling3Model
 from .llama import (LlamaConfig, LlamaForCausalLM, LlamaModel)
 from .mimo_v2 import MiMoV2Config, MiMoV2ForCausalLM, MiMoV2Model
 from .t5 import T5Config, T5ForConditionalGeneration, T5Model
@@ -32,7 +33,8 @@ __all__ = [
     'DeepseekV3Model', 'ErnieConfig', 'ErnieForMaskedLM',
     'ErnieForSequenceClassification', 'ErnieModel', 'GenerationMixin',
     'GPTConfig', 'GPTForCausalLM', 'GPTModel', 'Lfm2MoeConfig',
-    'Lfm2MoeForCausalLM', 'Lfm2MoeModel', 'LlamaConfig',
+    'Lfm2MoeForCausalLM', 'Lfm2MoeModel', 'Ling3Config',
+    'Ling3ForCausalLM', 'Ling3Model', 'LlamaConfig',
     'LlamaForCausalLM', 'LlamaModel', 'MiMoV2Config', 'MiMoV2ForCausalLM',
     'MiMoV2Model', 'Seq2SeqGenerationMixin',
     'T5Config', 'T5ForConditionalGeneration', 'T5Model', 'BPETokenizer',

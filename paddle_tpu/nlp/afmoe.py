@@ -296,12 +296,27 @@ class AfmoeAttention(Layer):
         return out
 
 
-def route(scores, bias, k, route_norm, route_scale, eps):
+def route(scores, bias, k, route_norm, route_scale, eps, n_group=1,
+          topk_group=1):
     """The router's choice: `scores` [..., E] float32 (sigmoid), `bias`
     [E]. The bias SELECTS and is not in the weight; `eps` stands beside
     the sum the weights are normalised by (1e-20 in AFMoE, 1e-6 in
-    LFM2). -> (selected [..., k] int32, weights [..., k] float32)."""
-    _, sel = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
+    LFM2). With `n_group` > 1 the choice is GROUP-LIMITED (DeepSeek-V3's
+    `noaux_tc`): the E experts are `n_group` groups of consecutive
+    experts, a group's score is the sum of its two largest `scores +
+    bias`, and the k are taken inside the `topk_group` best groups —
+    with a group a chip, a token's picks land on at most that many
+    chips. One group is no limit, and the program it ever was.
+    -> (selected [..., k] int32, weights [..., k] float32)."""
+    choice = scores + bias.astype(jnp.float32)
+    if n_group > 1:
+        by_group = choice.reshape(choice.shape[:-1] + (n_group, -1))
+        best_two, _ = jax.lax.top_k(by_group, 2)
+        _, groups = jax.lax.top_k(jnp.sum(best_two, axis=-1), topk_group)
+        kept = jnp.any(groups[..., None] == jnp.arange(n_group), axis=-2)
+        choice = jnp.where(kept[..., None], by_group,
+                           -jnp.inf).reshape(choice.shape)
+    _, sel = jax.lax.top_k(choice, k)
     w = jnp.take_along_axis(scores, sel, axis=-1)
     if route_norm:
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + eps)
@@ -425,6 +440,10 @@ class AfmoeSparseMLP(Layer):
         cfg = self.config
         k, norm, scale, eps = (cfg.num_experts_per_tok, cfg.route_norm,
                                float(cfg.route_scale), self.route_norm_eps)
+        # a configuration that names no groups has one: no limit, and
+        # `route` is called as it always was
+        n_group = int(getattr(cfg, 'n_group', 1) or 1)
+        groups = (n_group, int(cfg.topk_group)) if n_group > 1 else ()
 
         def router(xv, wr, bias):
             # float32 and exact: which experts a token gets must not
@@ -432,7 +451,7 @@ class AfmoeSparseMLP(Layer):
             scores = jax.nn.sigmoid(jnp.matmul(
                 xv.astype(jnp.float32), wr.astype(jnp.float32),
                 precision=jax.lax.Precision.HIGHEST))
-            return route(scores, bias, k, norm, scale, eps)
+            return route(scores, bias, k, norm, scale, eps, *groups)
 
         # by backend and shape, nothing else: one kernel for a call one
         # block wide on a TPU, the loop over blocks everywhere else

@@ -30,10 +30,14 @@ What it is made of, and where that lives:
   `rope_scaling.type` is refused by name.
 - `f` of the first `first_k_dense_replace` layers is `nlp/llama.py`'s
   SwiGLU; of the others `nlp/afmoe.py`'s expert layer: `s = sigmoid(m
-  W_r)` in float32, `sel = top_k(s + bias)` (the bias selects only), `w
-  = s[sel] / (sum s[sel] + 1e-20) * routed_scaling_factor`, plus the
+  W_r)` in float32, `sel = top_k(s + bias)` (the bias selects only;
+  with `n_group` > 1 inside the `topk_group` best groups, `afmoe.route`),
+  `w = s[sel] / (sum s[sel] + 1e-20) * routed_scaling_factor`, plus the
   `n_shared_experts` shared experts as ONE unweighted MLP of their
   summed width; no token dropped.
+- where the configuration asks (`attention_output_gate: head_wise`;
+  `nlp/ling3.py`), each head's output is multiplied by one sigmoid of
+  the layer's input before `o_proj`, on every path below.
 
 **The cache is latent** (`init_cache`, `generation.latent_layers`): an
 entry is `(c [B, L, kv_lora_rank], r [B, L, qk_rope_head_dim])` — rows
@@ -127,6 +131,7 @@ class DeepseekV3Config:
                  num_experts_per_tok=6, norm_topk_prob=True,
                  routed_scaling_factor=2.448, scoring_func='sigmoid',
                  topk_method='noaux_tc', n_group=1, topk_group=1,
+                 attention_output_gate=None,
                  rms_norm_eps=1e-6, attention_bias=False,
                  max_position_embeddings=32768, tie_word_embeddings=False,
                  pad_token_id=0, bos_token_id=1, eos_token_id=2,
@@ -146,9 +151,12 @@ class DeepseekV3Config:
                 f'scoring_func {scoring_func!r} / topk_method '
                 f'{topk_method!r}: only the sigmoid router with a '
                 'selection bias (noaux_tc) is implemented')
-        if n_group != 1 or topk_group != 1:
-            raise ValueError('n_group / topk_group: no group limit is '
-                             'implemented')
+        check_route_groups(n_routed_experts, num_experts_per_tok, n_group,
+                           topk_group)
+        if attention_output_gate not in (None, 'head_wise'):
+            raise ValueError(
+                f'attention_output_gate {attention_output_gate!r}: no gate '
+                '(null) and one sigmoid a head (head_wise) are implemented')
         if moe_layer_freq != 1:
             raise ValueError('moe_layer_freq: every layer after the dense '
                              'ones is an expert layer (1), nothing else is '
@@ -188,6 +196,8 @@ class DeepseekV3Config:
         self.n_routed_experts = n_routed_experts
         self.n_shared_experts = n_shared_experts
         self.num_experts_per_tok = num_experts_per_tok
+        self.n_group, self.topk_group = int(n_group), int(topk_group)
+        self.attention_output_gate = attention_output_gate
         self.norm_topk_prob = norm_topk_prob
         self.routed_scaling_factor = routed_scaling_factor
         self.rms_norm_eps = rms_norm_eps
@@ -248,6 +258,17 @@ class DeepseekV3Config:
         kw.setdefault('n_shared_experts', 1)
         kw.setdefault('first_k_dense_replace', 2)
         return cls.tiny(**kw)
+
+
+def check_route_groups(routed, k, n_group, topk_group):
+    """A group limit the router can keep (`afmoe.route`): whole groups,
+    no more kept than there are, and room for the k picks inside them."""
+    if n_group < 1 or routed % n_group or not 1 <= topk_group <= n_group \
+            or routed // n_group < 2 or topk_group * (routed // n_group) < k:
+        raise ValueError(
+            f'n_group {n_group} / topk_group {topk_group}: {routed} experts '
+            f'do not divide into groups of two or more of which '
+            f'{topk_group} hold {k} picks')
 
 
 def _yarn_mscale(factor, mscale):
@@ -416,6 +437,19 @@ class DeepseekV3Attention(Layer):
             config, config.kv_lora_rank,
             nh * (config.qk_nope_head_dim + config.v_head_dim))
         self.o_proj = _row_linear(config, nh * config.v_head_dim, h)
+        # Gated Attention (arXiv:2505.06708), head-wise: one sigmoid a
+        # head of the layer's input on the softmax-weighted sum, before
+        # `o_proj`. Absent unless the configuration asks
+        self.gate_proj = Linear(h, nh, bias_attr=False) if getattr(
+            config, 'attention_output_gate', None) else None
+
+    def _gated(self, out, hidden):
+        """`out` [B, S, H, v] times `sigmoid(hidden W_gate)` [B, S, H]."""
+        if self.gate_proj is None:
+            return out
+        return apply_op(lambda o, g: o * jax.nn.sigmoid(g)[..., None]
+                        .astype(o.dtype), out, self.gate_proj(hidden),
+                        _name='mla_head_gate')
 
     def forward(self, hidden, position_offset=None, attn_mask=None,
                 cache=None, cache_offset=None):
@@ -487,7 +521,7 @@ class DeepseekV3Attention(Layer):
                            self.kv_b_proj.weight, mask, _name='mla_latent')
         out = apply_op(
             lambda t: t.reshape(t.shape[0], t.shape[1], nh * vd),
-            out, _name='merge_heads')
+            self._gated(out, hidden), _name='merge_heads')
         out = self.o_proj(out)
         if cache is not None:
             return out, (c_cache, r_cache)
@@ -498,7 +532,7 @@ class DeepseekV3DecoderLayer(Layer):
     def __init__(self, config: DeepseekV3Config, layer_idx: int):
         super().__init__()
         eps = config.rms_norm_eps
-        self.self_attn = DeepseekV3Attention(config)
+        self.self_attn = self.mixer(config, layer_idx)
         self.moe_enabled = layer_idx >= config.first_k_dense_replace
         self.mlp = AfmoeSparseMLP(config) if self.moe_enabled \
             else LlamaMLP(types.SimpleNamespace(
@@ -508,6 +542,12 @@ class DeepseekV3DecoderLayer(Layer):
         self.input_layernorm = RMSNorm(config.hidden_size, epsilon=eps)
         self.post_attention_layernorm = RMSNorm(config.hidden_size,
                                                 epsilon=eps)
+
+    def mixer(self, config, layer_idx):
+        """What stands between the layer's first norm and the residual
+        path: latent attention; a family that mixes kinds of layer
+        (`nlp/ling3.py`) gives its own by `layer_idx`."""
+        return DeepseekV3Attention(config)
 
     def attention_block(self, hidden, position_offset=None, attn_mask=None,
                         cache=None, cache_offset=None):

@@ -297,10 +297,12 @@ def experts_touched(picks, active):
 
 
 def state_layers(cache):
-    """The indices of a cache's entries that are not a (K, V) pair but
-    one leaf of recurrent slot state, `[B, ...]` with no row axis (a
-    short convolution's last inputs). Empty for a model that keeps K
-    and V only."""
+    """The indices of a cache's entries that are not a pair of row
+    leaves but recurrent slot STATE: one leaf `[B, ...]` with no row
+    axis (a short convolution's last inputs, `nlp/lfm2.py`), or several
+    such leaves in a pytree that is no tuple or list (`nlp/ling3.py`'s
+    `{'S': the matrix state, 'conv': the convolutions' inputs}`). Empty
+    for a model that keeps K and V only."""
     return tuple(i for i, entry in enumerate(cache)
                  if not isinstance(entry, (tuple, list)))
 

@@ -10,6 +10,8 @@ from .generation import GenerationMixin  # noqa: F401
 from .gpt import GPTConfig, GPTForCausalLM, GPTModel  # noqa: F401
 from .lfm2 import (Lfm2MoeConfig, Lfm2MoeForCausalLM,  # noqa: F401
                    Lfm2MoeModel)
+from .ling3 import (Ling3Config, Ling3ForCausalLM,  # noqa: F401
+                    Ling3Model)
 from .llama import LlamaConfig, LlamaForCausalLM, LlamaModel  # noqa: F401
 from .mimo_v2 import (MiMoV2Config, MiMoV2ForCausalLM,  # noqa: F401
                       MiMoV2Model)

@@ -498,6 +498,9 @@ class InferenceEngine:
         # in a whole prefill, where that prefill attends over its own
         # tokens (the model's to say; None: the span says nothing)
         self._own_tokens_pairs = getattr(model, 'own_tokens_pairs', None)
+        # bucket -> the chunks ONE layer's scan walks in a whole prefill,
+        # where a layer's prefill is a chunked scan (likewise)
+        self._kda_chunks = getattr(model, 'kda_chunks', None)
         # either program's rows -> per attending layer, the row tile by
         # which its decode attention is bounded per slot there, 0 where
         # it reads every row: what `read_rows` counts such a layer by
@@ -1734,7 +1737,7 @@ class InferenceEngine:
     def _note_state(self, round_span):
         """Book what a round does to slot state that is not K and V, on
         its span and on `paddle_serving_slot_state_bytes_total`: every
-        sub-step reads and writes the state leaf of every state layer
+        sub-step reads and writes every leaf of every state layer's entry
         for each active slot (an inactive slot's is garbage nobody
         needs)."""
         n = (int(np.count_nonzero(self._active)) * self.pool.state_bytes
@@ -2226,6 +2229,8 @@ class InferenceEngine:
             if self._own_tokens_pairs is not None:
                 span.set(attn_pairs_scored=self._own_tokens_pairs(bucket),
                          attn_pairs_causal=s * (s + 1) // 2)
+            if self._kda_chunks is not None:
+                span.set(kda_chunks=self._kda_chunks(bucket))
             ids = np.zeros((1, bucket), np.int32)
             ids[0, :s] = h.prompt_tokens
             ids_dev = call_with_retry(_to_device, ids, policy=self._retry,

@@ -232,9 +232,10 @@ class SlotPool:
 
     `rows` is whatever `model.init_cache(num_slots, max_length)` returns,
     one entry a layer: a (K, V) pair of `[num_slots, max_length, H, D]`
-    where the layer attends, or ONE leaf of recurrent slot state,
-    `[num_slots, ...]` with no row axis, where it keeps its past some
-    other way (`nlp/lfm2.py`'s conv layers: `state_layers`). Seating,
+    where the layer attends, or recurrent slot STATE — one leaf
+    `[num_slots, ...]` with no row axis, or a pytree of such leaves that
+    is no tuple — where it keeps its past some other way (`nlp/lfm2.py`'s
+    conv layers, `nlp/ling3.py`'s KDA layers: `state_layers`). Seating,
     copying and slicing a slot map over axis 0 of any leaf; what the
     pool says of ROWS (`written_rows`, buckets, `max_length`) is about
     the (K, V) entries alone. A state is not a row that a mask can hide
@@ -518,11 +519,13 @@ class SlotPool:
         + V width)` of a (K, V) entry, `rows x latent(widths)` of a
         latent one (its leaves `c`, the latent, and `r`, the shared
         rotary key), `state` of a state leaf: one name for layers that
-        keep the same."""
+        keep the same (whatever leaves it is made of)."""
         formats = iter(self.formats)
         for i, entry in enumerate(self._pool_spec):
             if i in self.state_layers:
-                yield 'state', ('state',), (entry,), (next(formats),)
+                leaves = _tree.tree_leaves(entry)
+                yield ('state', tuple(map(str, range(len(leaves)))), leaves,
+                       [next(formats) for _ in leaves])
                 continue
             a, b = entry
             if i in self.latent_layers:

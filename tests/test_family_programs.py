@@ -3,7 +3,10 @@ engine of each (2 slots x 64, block 4, bucket 16) lowers to the
 StableHLO it lowered to before the family that asks came — pinned when
 `nlp/mimo_v2.py` brought a ring (PR 32) and `nlp/deepseek_v3.py` a
 latent entry (PR 37) — and a model that keeps K and V only takes none
-of `nlp/lfm2.py`'s state path (PR 30). One place: a new family adds its
+of `nlp/lfm2.py`'s state path (PR 30); `nlp/ling3.py` (PR 43: a state
+entry of two leaves, a group-limited router, a gate on latent attention)
+left all of them as they were, and its own tiny engine's programs are
+pinned here for the family after it. One place: a new family adds its
 tiny engine to `_FAMILIES`, its pins, and what its pool must not book for
 the others."""
 import hashlib
@@ -19,6 +22,7 @@ from paddle_tpu.nlp import generation
 from paddle_tpu.nlp.afmoe import AfmoeConfig, AfmoeForCausalLM
 from paddle_tpu.nlp.gpt import GPTConfig, GPTForCausalLM
 from paddle_tpu.nlp.lfm2 import Lfm2MoeConfig, Lfm2MoeForCausalLM
+from paddle_tpu.nlp.ling3 import Ling3Config, Ling3ForCausalLM
 from paddle_tpu.nlp.llama import LlamaConfig, LlamaForCausalLM
 from paddle_tpu.nlp.mimo_v2 import MiMoV2Config, MiMoV2ForCausalLM
 from paddle_tpu.serving import InferenceEngine
@@ -29,6 +33,7 @@ _FAMILIES = {'gpt': (GPTForCausalLM, GPTConfig),
              'llama': (LlamaForCausalLM, LlamaConfig),
              'afmoe': (AfmoeForCausalLM, AfmoeConfig),
              'lfm2': (Lfm2MoeForCausalLM, Lfm2MoeConfig),
+             'ling3': (Ling3ForCausalLM, Ling3Config),
              'mimo_v2': (MiMoV2ForCausalLM, MiMoV2Config)}
 
 # sha256 (first 16 hex digits) of the StableHLO text of each program, by
@@ -38,7 +43,7 @@ _FAMILIES = {'gpt': (GPTForCausalLM, GPTConfig),
 # PR 36, which made the slot state one buffer that they unpack (they are
 # the engine's own functions on `_decode_args()`, the pins of
 # `tests/test_pool_layout.py`); jax 0.9.0, which the repository is
-# written for (the verify skill)
+# written for (the verify skill); ling3's taken AT PR 43, which brought it
 _PARENT_PROGRAMS = {
     ('afmoe', 'decode'): '81008fe4d4edb6d9',
     ('afmoe', 'decode_half'): '8e312056c151a0a2',
@@ -49,6 +54,9 @@ _PARENT_PROGRAMS = {
     ('lfm2', 'decode'): '611c2975c6cfa539',
     ('lfm2', 'decode_half'): '6df5d3a5564cc3bd',
     ('lfm2', 'prefill'): '1a02dff7d8263eae',
+    ('ling3', 'decode'): 'd539579323cc4e28',
+    ('ling3', 'decode_half'): '41dfd760bdbde01e',
+    ('ling3', 'prefill'): '5d6e70a47ddf9ca9',
     ('llama', 'decode'): '0b25e1d31f4c9b75',
     ('llama', 'decode_half'): '8a7f5153ef78c81d',
     ('llama', 'prefill'): '8b4c79aa8dc443ef',
@@ -77,7 +85,10 @@ def _digests(eng):
     # ... and what deepseek_v3's latent entry (PR 37)
     ('afmoe', 'latent_layers'), ('gpt', 'latent_layers'),
     ('lfm2', 'latent_layers'), ('llama', 'latent_layers'),
-    ('mimo_v2', 'latent_layers')])
+    ('mimo_v2', 'latent_layers'),
+    # ... and what ling3's state of two leaves beside latent rows (PR 43):
+    # its own programs, for the family after it
+    ('ling3', 'ring_layers')])
 def test_the_other_families_programs_are_the_parents(family, without):
     eng = _tiny_engine(family)
     assert getattr(eng.pool, without) == ()
@@ -106,6 +117,7 @@ def test_through_the_kv_kernel_only_the_decode_blocks_are_other_programs(
                         lambda rows: 16 if rows % 16 == 0 else None)
     changed = {name for name, digest in _digests(_tiny_engine(family)).items()
                if digest != _PARENT_PROGRAMS[family, name]}
+    # (ling3's one attending layer is latent: never K and V by head)
     assert changed == ({'decode', 'decode_half'}
                        if family in ('afmoe', 'lfm2', 'mimo_v2') else set())
     assert bool(kv_interpreted) == bool(changed)

@@ -144,6 +144,9 @@ def test_pool_books_state_apart_from_rows(tiny):
     kv = 2 * 64 * 2 * 8 * 4             # K and V, 64 rows, 2 heads x 8
     assert pool.row_bytes == kv + pool.state_bytes
     assert pool.stats()['state_bytes'] == pool.state_bytes
+    # (booked by entry whatever leaves an entry of state is made of: PR 43)
+    assert pool.stats()['entry_bytes']['state'] == 2 * pool.state_bytes
+    assert pool.stats()['entry_layouts']['state'] == 'default'
     assert list(eng._layer_rows) == [64]
     # a bf16 pool keeps its state leaves float32
     half = H.engine(model, dtype='bfloat16').pool.rows
