@@ -41,7 +41,10 @@ What it is made of, and where that lives:
 
 **One token and many** (`kda_step`, `kda_chunked`). A call of one token
 (a decode sub-step) is the recurrence itself, elementwise in float32: a
-read and a write of the state. A longer call (a prefill) goes CHUNK by
+read and a write of the state — as ONE kernel where `ops.pallas.
+kda_step_kernel` takes the call (a TPU, a float32 state of whole-lane
+heads: `pallas_kernels.kda_decode_step`, in place), else as XLA fuses
+`kda_step` (two reads and a write). A longer call (a prefill) goes CHUNK by
 chunk of `KDA_CHUNK` tokens, the chunks one after another and a
 chunk's tokens at once: with `G_i = sum_{j<=i} g_j` inside a chunk that
 starts at `S_0`, `A_ij = beta_i (k_i * exp(G_i - G_j)) . k_j` (j < i),
@@ -80,11 +83,12 @@ from ..nn import initializer as I
 from ..nn.common_layers import Linear
 from ..nn.layer import Layer
 from ..nn.norm import RMSNorm
+from ..ops import pallas as _pallas
 from ..tensor import Tensor, apply_op, to_jax
 from .deepseek_v3 import (DeepseekV3Attention, DeepseekV3DecoderLayer,
                           DeepseekV3ForCausalLM, DeepseekV3Model,
                           check_route_groups)
-from .generation import folded_tokens
+from .generation import folded_tokens, state_layers
 from .llama import _col_linear, _row_linear
 
 MLA, KDA = 'mla', 'kda'
@@ -429,8 +433,13 @@ def kda_mix(xq, xk, xv, f, b, wq, wk, wv, a_log, dt_bias, state, conv,
         g = jnp.where(enters[None, :, None, None], g, 0.0)
         beta = jnp.where(enters[None, :, None], beta, 0.0)
     if s == 1:
-        o, state = kda_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
-                            state)
+        step = q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], state
+        kernel = _pallas.kda_step_kernel(state)
+        if kernel is None:
+            o, state = kda_step(*step)
+        else:       # one pass over the state, in place
+            with jax.named_scope('state_write'):
+                o, state = kernel(*step)
         return o[:, None], state, conv
     o, state = kda_chunked(q, k, v, g, beta, state,
                            min(chunk, -(-s // KDA_BLOCK) * KDA_BLOCK))
@@ -595,6 +604,18 @@ class Ling3ForCausalLM(DeepseekV3ForCausalLM):
         `tokens` tokens (a whole prefill's bucket): what the serving
         engine says on `serving.prefill`."""
         return -(-tokens // KDA_CHUNK)
+
+    def state_kernel_layers(self, cache, slots):
+        """How many of `cache`'s state entries a decode sub-step of
+        `slots` sequences updates as ONE kernel (`ops.pallas.
+        kda_step_kernel`, asked with the call `kda_mix` makes: the leaf
+        `S` as held): what the serving engine says on
+        `serving.decode_round`."""
+        return sum(
+            _pallas.kda_step_kernel(jax.ShapeDtypeStruct(
+                (slots,) + tuple(cache[i]['S'].shape[1:]),
+                cache[i]['S'].dtype)) is not None
+            for i in state_layers(cache))
 
     def generate(self, input_ids, *args, attention_mask=None, **kwargs):
         if attention_mask is not None and \

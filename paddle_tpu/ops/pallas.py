@@ -4,16 +4,16 @@ Upstream analogue: the reference's hand-fused CUDA kernels
 (paddle/phi/kernels/fusion/gpu/*, flash-attn integration). Here the
 default path is plain jax — XLA already fuses normalization chains into
 adjacent matmuls — and the pallas kernels (ops/pallas_kernels.py) take
-over on TPU backends for four inner loops where a hand-written schedule
+over on TPU backends for five inner loops where a hand-written schedule
 beats the XLA-generated one: attention over a call's own tokens
-(`flash_attention`: manual VMEM blocking), the routed experts of a
-decode batch (`expert_kernel`: one weight stream across the experts,
-where XLA's `while` fetches each expert cold), decode attention over
-latent rows (`latent_decode_kernel`: a slot's row tiles up to that
-slot's length, each read once for the scores and the values, where
-XLA's two einsums stream every row of every slot twice), and decode
-attention of float32 queries over K and V held by head
-(`kv_decode_kernel`: the same walk, bounded per slot at both ends).
+(`flash_attention`), the routed experts of a decode batch
+(`expert_kernel`: one weight stream, where XLA's `while` fetches each
+expert cold), decode attention over latent rows (`latent_decode_kernel`:
+a slot's row tiles up to its length, each read once where XLA's einsums
+stream every row twice), its sibling for float32 queries over K and V
+by head (`kv_decode_kernel`), and a KDA layer's recurrence of one token
+(`kda_step_kernel`: the state read once and written in place, where
+XLA's two fusions read it twice).
 
 Which path runs is decided by explicit conditions on the backend and
 the shapes, never by a caught exception: on a TPU a kernel that fails
@@ -278,4 +278,30 @@ def kv_decode_kernel(q, k, v, mask, sink=None, interpret=False):
             and q.shape[2] % k.shape[2] == 0 and tile is not None):
         return functools.partial(pallas_kernels.kv_decode_attention,
                                  tile=tile, interpret=interpret)
+    return None
+
+
+def kda_step_kernel(state, interpret=False):
+    """Dispatch for a KDA layer's recurrence of ONE token (`nlp/ling3.py
+    ::kda_mix`, a call one token long: a decode sub-step): the pallas
+    kernel `pallas_kernels.kda_decode_step` (same arguments as `nlp/
+    ling3.py::kda_step`; `.keywords['heads']`: the heads a grid step
+    takes) where it applies, None where `kda_step` runs. Read from the
+    call alone — the leaf `state` `[B, H, d_k, d_v]` as it is held
+    (anything with a `.shape` and a `.dtype`): the kernel takes a
+    float32 state whose d_k and d_v are whole lanes (a head's tile
+    then stands in VMEM as the leaf holds it) and whose heads divide
+    into blocks (`_kda_head_block`), on a TPU (or anywhere with
+    interpret=True). A head of another size, a state in fewer bits and
+    every other backend keep `kda_step`'s two passes: there it is the
+    tier-1 path and the parity ground truth. The conditions are the
+    whole selection: a kernel error on a TPU propagates."""
+    from . import pallas_kernels
+    if ((interpret or _pallas_enabled()) and len(state.shape) == 4
+            and state.dtype == jnp.float32
+            and state.shape[2] % 128 == 0 and state.shape[3] % 128 == 0):
+        heads = pallas_kernels._kda_head_block(*state.shape[1:])
+        if heads:
+            return functools.partial(pallas_kernels.kda_decode_step,
+                                     heads=heads, interpret=interpret)
     return None

@@ -24,9 +24,9 @@ Contents:
   attention over latent rows bounded per slot (`latent_decode_kernel`).
 - `kv_decode_attention(q, k, v, seen, scale)` — its sibling for float32
   queries over K and V by head (`kv_decode_kernel`; `decode_walk`).
-All keep stats/accumulators in fp32 VMEM scratch and feed the MXU with
-`preferred_element_type=float32`. (Add no line above the last kernel
-but one: a Mosaic call's source lines are in its program's digest.)
+- `kda_decode_step(q, k, v, g, beta, state)` — a KDA layer's one-token
+  recurrence, the state read once, written in place (`kda_step_kernel`).
+(fp32 accumulators; add no line ABOVE a kernel: its lines are in digests.)
 """
 from __future__ import annotations
 
@@ -1477,3 +1477,113 @@ def kv_decode_attention(q, k, v, seen, scale, *, tile=None, interpret=False):
         name='kv_decode_attention',
     )(slot, at + start[slot], edge.astype(jnp.int32), q, k, v,
       seen[:, None, :])
+
+
+# ---------------------------------------------------------------------------
+# a KDA layer's recurrence of one token (ISSUE 44; `nlp/ling3.py::
+# kda_step` is the plain form and the parity ground truth). XLA makes
+# two fusions of it — both sums over d_k read the decayed state, then
+# the update reads it again — because `u` needs the first sum before
+# the update can start: two reads and a write of `[B, H, d_k, d_v]`
+# float32 a layer a sub-step. Here a block of heads' tiles sits in VMEM
+# between the sums and the update: one read, one write, in place.
+# ---------------------------------------------------------------------------
+_KDA_TILE_BYTES = 2 << 20       # a grid step's state block, each way
+_KDA_COLUMNS = 128              # q, k and the decay of a block's heads
+
+
+def _kda_head_block(heads, dk, dv):
+    """The heads a grid step of `kda_decode_step` takes, None where
+    none will do: the most that divide `heads`, keep the block of their
+    `[d_k, d_v]` float32 tiles within `_KDA_TILE_BYTES` and their q, k
+    and decay rows within `_KDA_COLUMNS` — whole sublanes (8) of them,
+    or every head. (On the v5e blocks of 8, 16 and 32 heads of 64 KiB
+    ran alike, each at the pace of a kernel that only copies the tiles:
+    PERF.md, PR 44.)"""
+    most = min(heads, _KDA_COLUMNS // 3,
+               max(_KDA_TILE_BYTES // (4 * dk * dv), 1))
+    for n in range(most, 0, -1):
+        if heads % n == 0 and (n % 8 == 0 or n == heads):
+            return n
+    return None
+
+
+def _kda_step_kernel(q_ref, k_ref, g_ref, v_ref, beta_ref, s_ref, o_ref,
+                     out_ref, *, scale):
+    """One block of heads of one slot. q, k and g come by ROWS (a
+    head's vector along lanes) and the state wants them along d_k, its
+    sublane axis: the block's rows are stacked `[q ; k ; exp(g) ; 0]`
+    and turned ONCE (a 2-D float32 transpose), so head `j`'s vectors
+    are columns `j`, `n + j`, `2 n + j`. Then a head at a time,
+    elementwise float32 as `kda_step` spells it: the decayed tile, both
+    sums over d_k from it, `u`, `o`, and the tile written back."""
+    n = q_ref.shape[1]
+    q = q_ref[0].astype(jnp.float32) * scale                   # [n, d_k]
+    k = k_ref[0].astype(jnp.float32)
+    kq = jnp.sum(k * q, axis=-1, keepdims=True)                # [n, 1]
+    rows = jnp.concatenate(
+        [q, k, jnp.exp(g_ref[0].astype(jnp.float32)),
+         jnp.zeros((_KDA_COLUMNS - 3 * n, q.shape[1]), jnp.float32)], axis=0)
+    cols = rows.T                                           # [d_k, 128]
+    for j in range(n):
+        qc, kc, decay = (cols[:, i * n + j:i * n + j + 1] for i in range(3))
+        decayed = s_ref[0, j] * decay                       # [d_k, d_v]
+        u = beta_ref[0, j:j + 1] * (v_ref[0, j:j + 1].astype(jnp.float32)
+                                    - jnp.sum(decayed * kc, axis=0,
+                                              keepdims=True))  # [1, d_v]
+        o_ref[0, j:j + 1] = jnp.sum(decayed * qc, axis=0, keepdims=True) \
+            + kq[j:j + 1] * u
+        out_ref[0, j] = decayed + kc * u
+
+
+def kda_decode_step(q, k, v, g, beta, state, *, heads=None, interpret=False):
+    """`nlp/ling3.py::kda_step` as ONE kernel: q, k, g `[B, H, d_k]`, v
+    `[B, H, d_v]`, beta `[B, H]`, state `[B, H, d_k, d_v]` float32 ->
+    (o `[B, H, d_v]` float32, the state after the token). A grid over
+    (slot, block of `heads` heads): a step brings its block of `[d_k,
+    d_v]` tiles into VMEM once and writes it back once, and the state
+    is ALIASED input to output — donated to a caller that carries it
+    (the decode scan), the update is in place and no second buffer of
+    the state's size exists. The state, both sums and the update are
+    float32 multiply-adds on the vector unit; nothing of the state
+    meets the matrix unit. `beta = 0, g = 0` leaves a tile bit for bit
+    (the identity update a folded-out token relies on). `heads`
+    (dividing H; on a TPU whole sublanes or all of them) is the heads a
+    grid step takes; None is `_kda_head_block`'s."""
+    bsz, h, dk = q.shape
+    dv = v.shape[-1]
+    if k.shape != q.shape or g.shape != q.shape or v.shape != (bsz, h, dv) \
+            or beta.shape != (bsz, h) or state.shape != (bsz, h, dk, dv):
+        raise ValueError(
+            f'kda_decode_step: q {q.shape}, k {k.shape}, g {g.shape}, '
+            f'v {v.shape}, beta {beta.shape} against state {state.shape}')
+    if state.dtype != jnp.float32:
+        raise ValueError(f'kda_decode_step: a float32 state, not '
+                         f'{state.dtype}')
+    if heads is None:
+        heads = _kda_head_block(h, dk, dv)
+    if not heads or h % heads or 3 * heads > _KDA_COLUMNS:
+        raise ValueError(f'kda_decode_step: a block of {heads} heads must '
+                         f'divide {h} and be at most {_KDA_COLUMNS // 3}')
+
+    def rows(width):
+        return pl.BlockSpec((1, heads, width), lambda b, i: (b, i, 0))
+    tiles = pl.BlockSpec((1, heads, dk, dv), lambda b, i: (b, i, 0, 0))
+    # two buffers of the block each way, a head's temporaries, the rows
+    vmem = 4 * heads * dk * dv * 4 + (16 << 20)
+    return pl.pallas_call(
+        functools.partial(_kda_step_kernel, scale=1.0 / math.sqrt(dk)),
+        grid=(bsz, h // heads),
+        in_specs=[rows(dk), rows(dk), rows(dk), rows(dv), rows(dv), tiles],
+        out_specs=[rows(dv), tiles],
+        out_shape=[jax.ShapeDtypeStruct((bsz, h, dv), jnp.float32),
+                   jax.ShapeDtypeStruct(state.shape, jnp.float32)],
+        input_output_aliases={5: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=('arbitrary', 'arbitrary'),
+            vmem_limit_bytes=vmem),
+        interpret=interpret,
+        name='kda_decode_step',
+    )(q, k, g, v,
+      jnp.broadcast_to(beta.astype(jnp.float32)[..., None], (bsz, h, dv)),
+      state)

@@ -501,6 +501,12 @@ class InferenceEngine:
         # bucket -> the chunks ONE layer's scan walks in a whole prefill,
         # where a layer's prefill is a chunked scan (likewise)
         self._kda_chunks = getattr(model, 'kda_chunks', None)
+        # how many state layers' recurrences a decode sub-step runs as a
+        # kernel, where the model has one to ask (its own dispatch, with
+        # the leaves as the pool holds them; None: the span says nothing)
+        by_kernel = getattr(model, 'state_kernel_layers', None)
+        self._state_kernel_layers = None if by_kernel is None else int(
+            by_kernel(self.pool.row_spec, self.pool.num_slots))
         # either program's rows -> per attending layer, the row tile by
         # which its decode attention is bounded per slot there, 0 where
         # it reads every row: what `read_rows` counts such a layer by
@@ -1745,6 +1751,8 @@ class InferenceEngine:
         round_span.set(attn_layers=len(self._layer_rows),
                        state_layers=len(self.pool.state_layers),
                        state_bytes=n)
+        if self._state_kernel_layers is not None:
+            round_span.set(state_kernel_layers=self._state_kernel_layers)
         if _obs.enabled():
             self._m_state_bytes.inc(n)
 

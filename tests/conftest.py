@@ -105,6 +105,25 @@ def kv_interpreted(monkeypatch, fresh_programs):
 
 
 @pytest.fixture
+def kda_interpreted(monkeypatch, fresh_programs):
+    """A KDA layer's one-token recurrence through `kda_decode_step`,
+    interpreted, with `ops.pallas.kda_step_kernel` lifted off its
+    backend and whole-lane conditions (the toy head of 8 has no whole
+    lanes) — for `kda_mix` and for the engine's count alike. -> the
+    shapes of the states the dispatch was asked with."""
+    import functools
+    from paddle_tpu.ops import pallas, pallas_kernels
+    asked = []
+
+    def lifted(state, interpret=False):
+        asked.append(tuple(state.shape))
+        return functools.partial(pallas_kernels.kda_decode_step,
+                                 interpret=True)
+    monkeypatch.setattr(pallas, 'kda_step_kernel', lifted)
+    return asked
+
+
+@pytest.fixture
 def sanitizer_strict():
     """Run the test under the runtime concurrency sanitizer in STRICT
     mode (ISSUE 15): any lock-order cycle, non-reentrant re-entry, or

@@ -20,7 +20,9 @@ cells' leaves keep the default, which is what AUTO would give them. Since
 PR 38: serve-mla-long's decode programs hold ONE Mosaic attention call a
 latent layer (`mla_decode_attention`, under `attention`), which takes both
 leaves of the layer where the row's write left them: nothing of a leaf's
-size is copied, transposed or staged. Every compile here goes through the
+size is copied, transposed or staged. Since PR 44: serve-kda-reason's hold
+ONE `kda_decode_step` a KDA layer under `kda/state_write`, and no fusion or
+copy of the state's shape beside it. Every compile here goes through the
 store's compile site with the formats the pool holds, as the engine's do.
 
 The topology is described inside a fixture, never at import: every xdist
@@ -331,6 +333,36 @@ def test_latent_attention_is_one_kernel_on_the_leaves_as_held_on_v5e(
         v.size // v.shape[-1] * max(v.shape[-1], 128) * 4 for v in leaves)
     assert ma.alias_size_in_bytes == pool_bytes
     assert ma.temp_size_in_bytes <= 0.18 * 2 ** 30
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize('program', ['whole', 'half'])
+def test_a_kda_layers_recurrence_is_one_kernel_in_place_on_v5e(
+        program, one_chip):
+    """serve-kda-reason at 48 slots, its dense KDA layer, one expert KDA
+    layer and the latent layer (PR 44): a sub-step holds ONE
+    `kda_decode_step` a KDA layer, under `kda/state_write`, beside the
+    expert kernels and the latent layer's; nothing else in the program
+    makes, copies or moves an array of the state's `[48,32,128,128]` —
+    no fusion reads the state beside the kernel — and the pool, state
+    leaves and latent rows, is aliased whole."""
+    cell = spec.Spec().cell('serve-kda-reason')
+    cell['config'].update(num_hidden_layers=3, kept_layers=[0, 6, 11],
+                          layer_types=['kda', 'kda', 'mla'])
+    text, ma, leaves, pool_bytes = _compile_decode_block(cell, one_chip,
+                                                         program)
+    calls = [ln for ln in text.splitlines() if 'tpu_custom_call' in ln]
+    steps = [ln for ln in calls if 'kda_decode_step' in ln]
+    assert len(steps) == 2, [ln[:160] for ln in calls]
+    assert all('/kda/state_write/kda_decode_step' in ln for ln in steps)
+    assert sum('/attention/mla_decode_attention' in ln for ln in calls) == 1
+    assert sum('moe/experts/moe_decode_experts' in ln for ln in calls) == 2
+    state = r' = \(*f32\[48,32,128,128\]\{[^}]*\}[^=]* (\S+)\('
+    made_by = {m.group(1) for m in (re.search(state, ln) for ln in
+                                    text.splitlines()) if m}
+    assert made_by <= {'custom-call', 'parameter', 'get-tuple-element',
+                       'while', 'tuple'}, made_by
+    assert ma.alias_size_in_bytes == pool_bytes
 
 
 def _cut_to_three_layers(cfg):

@@ -6,7 +6,9 @@ latent entry (PR 37) — and a model that keeps K and V only takes none
 of `nlp/lfm2.py`'s state path (PR 30); `nlp/ling3.py` (PR 43: a state
 entry of two leaves, a group-limited router, a gate on latent attention)
 left all of them as they were, and its own tiny engine's programs are
-pinned here for the family after it. One place: a new family adds its
+pinned here for the family after it; the kernel for its one-token
+recurrence (PR 44) is asked by `kda_mix` alone and leaves its prefill and
+every other family's programs as they were. One place: a new family adds its
 tiny engine to `_FAMILIES`, its pins, and what its pool must not book for
 the others."""
 import hashlib
@@ -121,6 +123,25 @@ def test_through_the_kv_kernel_only_the_decode_blocks_are_other_programs(
     assert changed == ({'decode', 'decode_half'}
                        if family in ('afmoe', 'lfm2', 'mimo_v2') else set())
     assert bool(kv_interpreted) == bool(changed)
+
+
+@pytest.mark.parametrize('family', sorted(_FAMILIES))
+def test_through_the_kda_kernel_only_ling3s_decode_blocks_are_other_programs(
+        family, kda_interpreted):
+    """`ops.pallas.kda_step_kernel` lifted off its backend and
+    whole-lane conditions (PR 44; interpreted: the toy head of 8 has no
+    whole lanes): `kda_mix` alone asks it, for a call of one token — so
+    ling3 gets other decode blocks and the same prefill (more tokens:
+    the chunked scan), and every program of the five other families is
+    the parent's. (Lowered here and not through the program store.)"""
+    eng = _tiny_engine(family)
+    changed = {name for name, digest in _digests(eng).items()
+               if digest != _PARENT_PROGRAMS[family, name]}
+    assert changed == ({'decode', 'decode_half'} if family == 'ling3'
+                       else set())
+    # each decode block asks once a KDA layer: its whole leaf, every slot
+    assert set(kda_interpreted) == ({(2, 4, 8, 8)} if changed else set())
+    assert eng._state_kernel_layers == (2 if family == 'ling3' else None)
 
 
 @pytest.mark.parametrize('family', ['afmoe', 'gpt', 'llama'])

@@ -1,8 +1,9 @@
 """`nlp/ling3.py` served: the engine's own prefill program and the
 hand-off of a state entry of two leaves beside a latent entry,
 continuous batching over reseated slots, both decode programs, what an
-engine refuses a state AND a latent entry, and what a decode round's
-span, a prefill's span and the pool's book carry — against the plain
+engine refuses a state AND a latent entry, what a decode round's
+span, a prefill's span and the pool's book carry, and the decode block
+with the one-token recurrence as the kernel (PR 44) — against the plain
 float32 reference. The family, its tolerance and its reason are
 `tests/test_ling3.py`'s, the shared cases `tests/family_harness.py`'s (a
 file of its own so that no worker of the suite carries both)."""
@@ -140,6 +141,8 @@ def test_decode_round_carries_state_latent_and_held_share_counts(tiny):
     assert rounds
     for a in rounds:
         assert (a['attn_layers'], a['state_layers']) == (1, 2)
+        # on the CPU `kda_step` runs both recurrences: none is the kernel
+        assert a['state_kernel_layers'] == 0
         # BOTH leaves of every KDA layer's entry, read and written
         assert a['state_bytes'] == a['active'] * 2 \
             * (STATE_LEAF + CONV_LEAF) * 2 * BLOCK
@@ -201,7 +204,30 @@ def test_pool_books_a_state_of_two_leaves_beside_latent_rows(tiny):
         assert float(leaf[1].min()) == 1.0
 
 
-def test_kda_scopes_are_on_the_decode_and_prefill_programs(tiny):
+def test_through_the_kernel_the_round_says_so_and_the_tokens_hold(
+        tiny, kda_interpreted):
+    """The decode block with both KDA layers' recurrences as the
+    kernel: the served tokens are the reference's, and every round says
+    2 of its 2 state layers ran it."""
+    cfg, w, model = tiny
+    served = H.prompts((5, 19, 11))
+    log = H.cleared_log()
+    toks, eng = H.through_the_router(model, served, 14)
+    H.within_tol(FAM, cfg, w, served, toks)
+    # asked by the engine for its count, then by each decode program as
+    # it is traced: every slot's leaf, never a prefill's
+    assert set(kda_interpreted) == {(2, 4, 8, 8)}
+    rounds = H.rounds(log)
+    assert rounds and all(
+        (a['state_layers'], a['state_kernel_layers']) == (2, 2)
+        for a in rounds)
+
+
+@pytest.mark.parametrize('kernel', [False, True])
+def test_kda_scopes_are_on_the_decode_and_prefill_programs(
+        tiny, kernel, request):
+    if kernel:
+        request.getfixturevalue('kda_interpreted')
     _, _, model = tiny
     H.through_the_router(model, H.prompts((5,)), 6)
     # (a prefill returns rows and state, no logits: no `lm_head` there,
@@ -219,5 +245,11 @@ def test_kda_scopes_are_on_the_decode_and_prefill_programs(tiny):
         # `attention`
         assert all(p[0] == 'kda' for p in paths if 'state_write' in p)
         assert all(p[0] == 'attention' for p in paths if 'kv_write' in p)
+        kernels = [p for (op, *_), p in zip(table.values(), paths)
+                   if 'kda_decode_step' in op]
+        # the kernel (interpreted: many ops under its name) is the
+        # decode block's alone, under `kda` with the state's write
+        assert bool(kernels) == (kernel and 'decode' in prog)
+        assert all(p == ('kda', 'state_write') for p in kernels)
     assert programs.scope_path(
         'jit(f)/while/body/kda/state_write/add') == ('kda', 'state_write')
