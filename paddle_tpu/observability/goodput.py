@@ -107,6 +107,9 @@ SPAN_CATEGORIES: Dict[str, str] = {
     'serving.draft_prefill': 'serving_prefill',
     'serving.decode_round': 'serving_decode',
     'serving.spec_round': 'serving_decode',
+    # a step that only fetches the block in flight (the engine ran
+    # ahead of it and can no longer): the wait is that block's
+    'serving.settle': 'serving_decode',
     'step.data_wait': 'host_wait',
     'step.host_wait': 'host_wait',
 }

@@ -12,6 +12,13 @@ params carried as arrays — so heterogeneous requests (different prompt
 lengths, token budgets, temperatures, eos ids) share a single XLA
 program and admission/retirement never recompiles anything.
 
+A decode round RUNS AHEAD of the host whenever nothing could be seated
+or retired between it and the next (`InferenceEngine.step`): the next
+block is dispatched before the tokens of the one in flight are fetched,
+so the device never waits for the fetch, the emission and the dispatch.
+Its slot state is advanced on the host at dispatch time and its pending
+tokens stay on the device (`SlotState.carried`).
+
 Compiled-program inventory (asserted by the zero-recompile tests):
 - the decode-block step (shapes fixed by num_slots/max_length/block)
   at two lengths of attention: over every row of a slot, and over the
@@ -79,6 +86,14 @@ from .kv_pool import (PagePoolExhausted, PagedSlotPool, PoolLostError,
 from .prefix_cache import PagedPrefixCache, RadixPrefixCache
 from .scheduler import FCFSScheduler
 from .slot_state import SlotState
+
+#: A decode round in flight: dispatched, its tokens not fetched yet.
+#: `toks` ([num_slots, block]) and `routing` (the routing counts, or
+#: None) are still the device's; `parts` is the (slot, handle) of every
+#: slot that decoded in it AS DISPATCHED, so its tokens reach nobody
+#: seated in a slot since and nobody retired since; `t0` its dispatch
+#: instant on the host clock.
+_Round = collections.namedtuple('_Round', 'toks routing parts t0')
 
 # occupancy is a ratio; the latency-shaped default buckets are wrong here
 _OCCUPANCY_BUCKETS = (0.125, 0.25, 0.375, 0.5, 0.625, 0.75, 0.875, 1.0)
@@ -461,7 +476,21 @@ class InferenceEngine:
         self._keys = slots.keys
         self._eos_arr = slots.eos       # spec accept stop
         self._adapter_rows = slots.adapter_rows   # 0 = base adapter
+        self._carried = slots.carried   # the pending token is the device's
         self._slot_req: dict = {}               # slot -> RequestHandle
+        # host only: each slot's token budget (`max_new_tokens`), so
+        # that "nobody ends by length inside the round in flight" is
+        # `_steps < _budget` — arithmetic, no token value needed
+        self._budget = np.zeros(n, np.int32)
+        # decode rounds dispatched and not yet fetched, oldest first:
+        # none in the serial order, one while the engine runs ahead (two
+        # between an ahead dispatch and the fetch that follows it)
+        self._rounds: collections.deque = collections.deque()
+        # the tokens of the block dispatched last, on the device: every
+        # decode program takes them beside the slot state and reads the
+        # `carried` slots' pending tokens off their last column
+        self._prev_toks = jnp.zeros((n, self.decode_block), jnp.int32)
+        self._t_emitted = 0.0           # the last emission's instant
 
         # per layer, the most rows a query can see (a window layer's
         # window, else the slot): what `needed_rows` is counted from
@@ -685,6 +714,11 @@ class InferenceEngine:
         self._m_rounds = reg.counter(
             'paddle_serving_decode_rounds_total',
             'compiled decode-block invocations')
+        self._m_rounds_ahead = reg.counter(
+            'paddle_serving_decode_rounds_ahead_total',
+            'decode blocks dispatched before the tokens of the block in '
+            'flight were fetched (nothing could be seated or retired '
+            'between the two)')
         self._m_slots = reg.gauge(
             'paddle_serving_slots', 'KV slot capacity')
         self._m_active = reg.gauge(
@@ -767,7 +801,7 @@ class InferenceEngine:
     # ------------------------------------------------------------------
     # compiled programs
     # ------------------------------------------------------------------
-    def _decode_block_fn(self, params, frozen, buffers, pool, state,
+    def _decode_block_fn(self, params, frozen, buffers, pool, state, prev,
                          adapters=None):
         """One compiled program: `decode_block` single-token steps over
         ALL slots (lax.scan), per-slot positions/masks/sampling. `pool`
@@ -775,32 +809,40 @@ class InferenceEngine:
         is the scan's carry and comes back as the second result, so
         the program, which takes it donated, updates it in place.
         `state` is the slot state's one buffer (`SlotState`), unpacked
-        here into the values the scan takes. `adapters` (bank-attached
-        engines only) is the packed LoRA banks; the per-slot bank rows
-        ride `state` — traced inputs all, so any adapter mix replays
-        this same program."""
+        here into the values the scan takes; `prev` the tokens of the
+        block before ([num_slots, block], the device's own result handed
+        back), whose last column is the pending token of every `carried`
+        slot. `adapters` (bank-attached engines only) is the packed LoRA
+        banks; the per-slot bank rows ride `state` — traced inputs all,
+        so any adapter mix replays this same program."""
         self._trace_counts['decode_step'] += 1   # python-level trace count
         fwd = cached_forward(self.model, params, frozen, buffers)
-        return self._scan_packed(fwd, pool, state, adapters)
+        return self._scan_slots(fwd, pool, self._slot_state.unpack(state),
+                                prev, adapters)
 
     def _decode_block_half_fn(self, params, frozen, buffers, pool, state,
-                              adapters=None):
+                              prev, adapters=None):
         """`_decode_block_fn` with attention over the first
         `max_length // 2` rows of every slot: a program of its own,
         which `_decode_round` runs while no active slot comes near that
         row."""
         self._trace_counts['decode_step_half'] += 1
         fwd = cached_forward(self.model, params, frozen, buffers)
-        return self._scan_packed(fwd, pool, state, adapters,
-                                 rows=self._half_rows)
+        return self._scan_slots(fwd, pool, self._slot_state.unpack(state),
+                                prev, adapters, rows=self._half_rows)
 
-    def _scan_packed(self, fwd, pool, state, adapters, rows=None):
+    def _scan_slots(self, fwd, pool, slots, prev, adapters, rows=None):
         """`_decode_scan` over the slot state as the programs receive
-        it: one buffer, unpacked on the device (a handful of slices and
-        bitcasts, once a block, outside the scan) into the nine values
-        the scan has always taken, and the adapter rows."""
-        slots = self._slot_state.unpack(state)
-        return self._decode_scan(fwd, pool, *slots[:9], adapters,
+        it: one buffer, unpacked on the device (`SlotState.unpack`: a
+        handful of slices and bitcasts, once a block, outside the scan)
+        into `slots` — the nine values the scan has always taken, the
+        adapter rows and `carried`. A `carried` slot's pending token is
+        the last token of `prev`, the block before — ONE `where`,
+        outside the scan: the same program runs whether the host had
+        fetched that block before it dispatched this one or not, so
+        neither order compiles anything of its own."""
+        tok = jnp.where(slots.carried, prev[:, -1], slots.tok)
+        return self._decode_scan(fwd, pool, tok, *slots[1:9], adapters,
                                  slots.adapter_rows, rows)
 
     def _decode_scan(self, fwd, pool, tok, pos, steps, active, temp, topk,
@@ -951,7 +993,7 @@ class InferenceEngine:
         k = self.spec_k
         self._trace_counts[f'spec_decode_k{k}'] += 1
         (tok, pos, steps, active, temp, topk, topp, greedy, keys, eos,
-         adapter_rows) = self._slot_state.unpack(state)
+         adapter_rows, _) = self._slot_state.unpack(state)
         fwd_t = cached_forward(self.model, params, frozen, buffers)
         fwd_d = cached_forward(self.draft_model, d_params, d_frozen,
                                d_buffers)
@@ -1007,7 +1049,7 @@ class InferenceEngine:
     # compiled programs: PAGED layout
     # ------------------------------------------------------------------
     def _paged_decode_fn(self, params, frozen, buffers, pages, scales,
-                         table, state, adapters=None, rows=None):
+                         table, state, prev, adapters=None, rows=None):
         """The decode block over the PAGE-TABLE pool: gather every
         slot's pages into the contiguous [N, max_length, H, D] view the
         row-pool scan already consumes (dequantizing int8 pages in the
@@ -1019,7 +1061,8 @@ class InferenceEngine:
         have their table row redirected to the null page so their junk
         token-0 writes can land nowhere real. `pages`/`scales` are
         donated (argnums 3, 4) so the pool aliases in place. `rows` is
-        the scan's: how much of the gathered view attention reads."""
+        the scan's: how much of the gathered view attention reads;
+        `prev` the block before's tokens (`_decode_block_fn`)."""
         self._trace_counts['paged_decode_step' if rows is None
                            else 'paged_decode_step_half'] += 1
         fwd = cached_forward(self.model, params, frozen, buffers)
@@ -1028,20 +1071,20 @@ class InferenceEngine:
         table = jnp.where(slots.active[:, None], table, 0)
         contig = gather_pages(pages, table, sc,
                               out_dtype=self.pool.compute_dtype)
-        toks, contig, *touched = self._decode_scan(
-            fwd, contig, *slots[:9], adapters, slots.adapter_rows, rows)
+        toks, contig, *touched = self._scan_slots(
+            fwd, contig, slots, prev, adapters, rows)
         pages, sc = scatter_pages(pages, table, contig, slots.pos,
                                   self.decode_block,
                                   self.pool.page_size, sc)
         return (toks, pages, sc if sc is not None else (), *touched)
 
     def _paged_decode_half_fn(self, params, frozen, buffers, pages,
-                              scales, table, state, adapters=None):
+                              scales, table, state, prev, adapters=None):
         """`_paged_decode_fn` with the scan's attention over the first
         `max_length // 2` rows of the gathered view: the paged pool's
         second decode program (`_decode_block_half_fn`)."""
         return self._paged_decode_fn(params, frozen, buffers, pages, scales,
-                                     table, state, adapters,
+                                     table, state, prev, adapters,
                                      rows=self._half_rows)
 
     def _paged_prefill_fn(self, params, frozen, buffers, pages, scales,
@@ -1104,7 +1147,7 @@ class InferenceEngine:
         k = self.spec_k
         self._trace_counts[f'paged_spec_decode_k{k}'] += 1
         (tok, pos, steps, active, temp, topk, topp, greedy, keys, eos,
-         adapter_rows) = self._slot_state.unpack(state)
+         adapter_rows, _) = self._slot_state.unpack(state)
         fwd_t = cached_forward(self.model, params, frozen, buffers)
         fwd_d = cached_forward(self.draft_model, d_params, d_frozen,
                                d_buffers)
@@ -1279,6 +1322,7 @@ class InferenceEngine:
     def _begin_drain(self):
         if self._draining:
             return
+        self._settle()      # what is in flight is counted as it ended
         self._draining = True
         self._drain_t0 = time.monotonic()
         info = {'queued': self.scheduler.queue_depth,
@@ -1321,6 +1365,7 @@ class InferenceEngine:
         return (h.adapter_id, h.adapter_version)
 
     def _fail_remaining(self, exc: BaseException):
+        self._rounds.clear()        # nobody is left to take their tokens
         for h in self.scheduler.drain():
             h._fail(exc)
             self._counts['failed'] += 1
@@ -1344,7 +1389,12 @@ class InferenceEngine:
         declares this replica dead, the orphans are resubmitted
         elsewhere, so their handles must leave this engine untouched.
         Slots free, actives clear; the engine itself stays serviceable
-        (a transient device blip doesn't scrap the pool)."""
+        (a transient device blip doesn't scrap the pool). A round in
+        flight is DROPPED, not fetched (the replica may be dying, and
+        its requests start over elsewhere): whatever it still writes
+        stays in rows of the slots it was dispatched for, and the next
+        request's seat is ordered behind it by the pool."""
+        self._rounds.clear()
         out = self.scheduler.drain()
         for slot, h in list(self._slot_req.items()):
             self._detach_slot(slot, h)
@@ -1409,6 +1459,7 @@ class InferenceEngine:
         `restore_weights`, which the updater holds for the rollback
         path (the old device arrays stay alive by reference, so a
         revert is a pointer swap, not a reload)."""
+        self._settle()
         if self._slot_req or self.scheduler.queue_depth > 0:
             raise RuntimeError(
                 f'swap_weights requires a drained engine, but '
@@ -1431,6 +1482,7 @@ class InferenceEngine:
         failed-health-gate path). Same drained-engine requirement; the
         prefix cache's entries for the restored version re-validate for
         free (they were never flushed, only version-shadowed)."""
+        self._settle()
         if self._slot_req or self.scheduler.queue_depth > 0:
             raise RuntimeError(
                 'restore_weights requires a drained engine')
@@ -1484,56 +1536,127 @@ class InferenceEngine:
     # ------------------------------------------------------------------
     @property
     def has_work(self) -> bool:
-        return bool(self._slot_req) or self.scheduler.queue_depth > 0
+        return bool(self._slot_req or self._rounds) \
+            or self.scheduler.queue_depth > 0
 
     def step(self) -> int:
         """ONE scheduler iteration: admit queued requests into free
         slots, advance every mid-prefill slot one chunk, then advance
         every ACTIVE slot one decode round (a plain block, or one
         speculation round when a draft model is configured). Returns
-        the number of requests that progressed."""
+        the number of requests that progressed.
+
+        A plain round is two halves, dispatch and settle (fetch its
+        tokens, emit them, retire who ended), and a step runs them in
+        the order that what the engine can observe allows:
+
+        - SERIAL — admit, dispatch round N, settle N — whenever
+          something could be seated or retired before round N+1: a slot
+          is free or parked mid chunked prefill, a participant reaches
+          `max_new_tokens` inside N, or a draft model speculates (its
+          accepted counts are data). Nothing is in flight when the step
+          returns, and a request that could be seated never waits behind
+          a queued block.
+        - AHEAD otherwise (`_may_run_ahead`): N stays in flight when the
+          step returns, and the next step dispatches N+1 — its slot
+          state advanced on the host when N was dispatched, its pending
+          tokens the device's (`SlotState.carried`) — BEFORE it settles
+          N. The device always has the next block queued behind the one
+          it runs, and the fetch, the emission, the caller's bookkeeping
+          and the dispatch all happen under a running block. A step that
+          finds a round in flight and the rule no longer holding settles
+          it (`serving.settle`) and returns: the serial order's second
+          half.
+
+        An end the host could not foresee (an EOS inside N) costs that
+        slot the one block already queued behind N: its share of N+1 is
+        dropped, not emitted (`blocks_discarded`), and its writes stay
+        in the slot's own rows, above everything the next request there
+        will read before it has overwritten it."""
         with _obs.span('serving.step'):
             self._check_drain()
             if not self._pool_layout_settled:
                 self._settle_pool_layout()
+            if self._rounds and (len(self._rounds) > 1
+                                 or not self._may_run_ahead()):
+                n = len(self._slot_req)
+                self._settle_oldest()
+                return n
             with _obs.span('serving.admit') as sp:
                 sp.set(admitted=self._admit())
             self._advance_prefills()
             n = len(self._slot_req)
             if not np.any(self._active):
                 return n        # chunk-prefill-only progress this round
-            t_round0 = time.perf_counter()
             if self.draft_model is not None:
+                t_round0 = time.perf_counter()
                 toks, counts = self._spec_round()
+                with _obs.span('serving.emit'):
+                    self._emit_round(
+                        [(slot, h) for slot, h in self._slot_req.items()
+                         if self._active[slot]], toks, counts, t_round0)
             else:
-                toks, counts = self._decode_round()
-            with _obs.span('serving.emit'):
-                self._emit_round(toks, counts, t_round0)
+                self._decode_round()
             return n
 
-    def _emit_round(self, toks, counts, t_round0: float):
-        """Hand the round's tokens to their requests: ledger booking,
-        emission, retirement and the slots' next-round state."""
+    def _may_run_ahead(self) -> bool:
+        """Whether the next decode block may be dispatched BEFORE the
+        newest one dispatched is settled — from what the engine holds,
+        no token value: no draft model, every slot holds a DECODING
+        request (none free, none parked mid chunked prefill: nothing can
+        be seated), and no participant reaches its `max_new_tokens`
+        inside the round in flight (`_steps` already counts it: nothing
+        ends by length). An EOS is the one end this cannot see."""
+        return (self.draft_model is None and bool(self._active.all())
+                and bool((self._steps < self._budget).all()))
+
+    def _settle_oldest(self):
+        """Fetch the oldest round in flight and hand its tokens out,
+        with no dispatch before it: the serial order's second half.
+        Under `serving.settle`, not `serving.decode_round` — there is
+        one of those a block DISPATCHED, with the block's counts — which
+        the goodput ledger books as decode like it: the wait is a whole
+        block's."""
+        with _obs.span('serving.settle') as settle_span:
+            fetched = self._fetch(settle_span)
+        with _obs.span('serving.emit'):
+            self._emit_round(*fetched)
+
+    def _settle(self):
+        """Settle every round in flight: what assumes a quiet engine
+        (a weight swap, the start of a drain, `stats`) calls this
+        first."""
+        while self._rounds:
+            self._settle_oldest()
+
+    def _emit_round(self, live, toks, counts, t_round0: float):
+        """Hand a round's tokens to the requests that decoded in it and
+        are still seated where they were (`live`: (slot, handle);
+        `counts` None: a plain block, `decode_block` tokens each):
+        ledger booking, emission and retirement. A plain block's slots
+        were advanced when it was dispatched (`_advance_slots`); a
+        speculation round's accepted counts are data, so its slots
+        advance here."""
         now = time.perf_counter()
         # ledger BEFORE the emission loop, so the round that produced a
         # request's first token still lands in its TTFT sub-book
         # (mark_first fires inside _emit below). Waterfall book: every
         # active participant waited the full round wall; fair-share
         # book: the wall splits evenly, closing to the engine decode
-        # wall.
-        round_recs = [h._ledger_rec for slot, h in self._slot_req.items()
-                      if self._active[slot]]
+        # wall. The wall runs from the LATER of the round's dispatch and
+        # the emission before it: a round dispatched ahead overlaps the
+        # one before it, and those seconds are booked once.
         _reqledger.get_ledger().note_round(
-            now - t_round0, round_recs,
+            now - max(t_round0, self._t_emitted),
+            [h._ledger_rec for _, h in live],
             'spec_verify' if self.draft_model is not None else 'decode',
             now=now, absorb=True)
+        self._t_emitted = now
         self._counts['decode_rounds'] += 1
         if _obs.enabled():
             self._m_rounds.inc()
             self._m_occupancy.observe(self.pool.occupancy)
-        for slot, h in list(self._slot_req.items()):
-            if not self._active[slot]:
-                continue            # mid-chunked-prefill: no tokens yet
+        for slot, h in live:
             c = self.decode_block if counts is None else \
                 int(counts[slot])  # paddle-lint: disable=host-sync -- spec accept counts gate the emission loop; one d2h per round, already materialized by toks
             if self.draft_model is not None and self._greedy[slot]:
@@ -1564,12 +1687,31 @@ class InferenceEngine:
                     self._m_ttft.observe(h.ttft)
             if done:
                 self._retire(slot, h, now)
-            else:
+            elif counts is not None:
                 self._tok[slot] = toks[slot, c - 1]
                 self._pos[slot] += c
-                self._steps[slot] += (1 if counts is not None else c)
+                self._steps[slot] += 1
                 # stranded-capacity accounting: rows actually written
                 self.pool.note_written(slot, self._pos[slot] + 1)
+
+    def _advance_slots(self):
+        """A plain block has just been dispatched: move every decoding
+        slot to where the block leaves it — position and sample index
+        `decode_block` on, the pending token the device's — so that the
+        buffer is the NEXT block's state before this one's tokens have
+        been fetched. Arithmetic on what the host holds; who ends inside
+        the block is found when its tokens are emitted. -> the block's
+        participants."""
+        c = self.decode_block
+        self._pos[self._active] += c
+        self._steps[self._active] += c
+        self._carried[self._active] = True
+        parts = [(slot, h) for slot, h in self._slot_req.items()
+                 if self._active[slot]]
+        for slot, _ in parts:
+            # stranded-capacity accounting: rows actually written
+            self.pool.note_written(slot, self._pos[slot] + 1)
+        return parts
 
     def _settle_pool_layout(self):
         """Before the first program touches the pool: compile (or load)
@@ -1592,18 +1734,27 @@ class InferenceEngine:
     def _decode_args(self) -> tuple:
         """The row pool's decode programs' arguments, as they stand."""
         return (self._params, self._frozen, self._buffers, self.pool.cache,
-                *self._state_args())
+                *self._state_args(self._prev_toks))
 
-    def _state_args(self) -> tuple:
+    def _state_args(self, *prev) -> tuple:
         """What every decode and speculation program takes last: the
         slot state — the ONE host buffer, as it stands: one argument,
-        one transfer a call, nothing packed or copied here — and, on an
-        engine with a bank, the bank's arrays (the per-slot bank rows
-        ride the buffer). A bank-less engine's signatures and
-        program-store keys carry no trace of adapters."""
+        one transfer a call, nothing packed per round — then, for a
+        decode block, `prev` (the tokens of the block before, the
+        device's) and, on an engine with a bank, the bank's arrays (the
+        per-slot bank rows ride the buffer). A bank-less engine's
+        signatures and program-store keys carry no trace of adapters.
+
+        The call gets a COPY of the buffer (a few hundred words): the
+        engine writes the buffer for the block after as soon as this
+        call returns, while the program may not have started, and a
+        backend may read a numpy argument where it lies instead of
+        copying it during the call (jax's CPU client does, whenever the
+        array happens to start on a 64-byte boundary)."""
+        state = self._slot_state.buffer.copy()
         if self.adapter_bank is None:
-            return (self._slot_state.buffer,)
-        return (self._slot_state.buffer, self.adapter_bank.device_arrays())
+            return (state, *prev)
+        return (state, *prev, self.adapter_bank.device_arrays())
 
     def _recover_pool(self):
         """A DONATED program (decode, spec, seat, copy) failed mid-call:
@@ -1611,7 +1762,12 @@ class InferenceEngine:
         retained buffer is suspect. Rebuild a zero pool and force-clear
         the prefix cache (its KV floors are gone) BEFORE re-raising —
         the error still classifies and fails over normally, but the
-        engine itself stays serviceable for the next admission."""
+        engine itself stays serviceable for the next admission. Every
+        round in flight goes with the pool (a block dispatched ahead ran
+        on what the failed one was to return), and the pool is rebuilt
+        once."""
+        self._rounds.clear()
+        self._prev_toks = jnp.zeros_like(self._prev_toks)
         if self._paged:
             self.pool.reset_pages()
         else:
@@ -1782,17 +1938,30 @@ class InferenceEngine:
             else self._decode_half_jit
 
     def _decode_round(self):
-        """The plain compiled decode block (no draft model): every
-        active slot advances `decode_block` tokens. Its span carries,
-        as scalars, the slots decoding (`active`), the slots there are,
-        the rows that hold a real token by the pool's own book, the
-        rows of a slot this round's program attends over (`rows`:
-        `max_length` or half of it, `_round_rows`) and, over slots and
-        layers, the rows it therefore reads and the rows it needed; its
-        children are `serving.decode_dispatch` (staging the host arrays
-        and the page table, and the jitted call until it returns) and
-        `serving.d2h` (the blocking fetch of the round's tokens)."""
-        with _obs.span('serving.decode_round',
+        """The plain compiled decode block (no draft model): dispatch
+        one — every active slot advances `decode_block` tokens — and
+        settle one, in the order `step` describes. With a round in
+        flight (`ahead` 1 on the span) the dispatch is the block AFTER
+        it and the fetch is the one in flight's, which stays behind; with
+        none, the block dispatched is fetched here too, unless the next
+        step may dispatch ahead of it (`_may_run_ahead`): then it stays
+        in flight and this span has no `serving.d2h` (a later step that
+        cannot go ahead of it fetches it under `serving.settle`). There
+        is ONE such span a block dispatched. The span carries,
+        as scalars, `ahead`, the slots decoding (`active`), the slots
+        there are, the rows that hold a real token by the pool's own
+        book, the rows of a slot the dispatched program attends over
+        (`rows`: `max_length` or half of it, `_round_rows`) and, over
+        slots and layers, the rows it therefore reads and the rows it
+        needed — all of the block DISPATCHED, from the positions as
+        advanced — and of the block FETCHED the routing counts and
+        `discarded` (slot-blocks dropped: their request ended in the
+        round before, unforeseen); its children are
+        `serving.decode_dispatch` (staging the host arrays and the page
+        table, and the jitted call until it returns) and `serving.d2h`
+        (the blocking fetch of a round's tokens)."""
+        ahead = int(bool(self._rounds))
+        with _obs.span('serving.decode_round', ahead=ahead,
                        active=int(np.count_nonzero(self._active)),
                        slots=self.pool.num_slots,
                        real_rows=self.pool.written_rows) as round_span:
@@ -1810,15 +1979,19 @@ class InferenceEngine:
                     latent_row_bytes=self.pool.latent_row_bytes)
             if self._residual_streams:
                 round_span.set(residual_streams=self._residual_streams)
+            t_round0 = time.perf_counter()
             try:
                 with _obs.span('serving.decode_dispatch'):
                     if self._paged:
                         pages, scales = self.pool.device_state()
+                        # a copy, as of the slot state: a retirement
+                        # rewrites the table while this block may run
                         table = call_with_retry(
-                            _to_device, self.pool.page_table,
+                            _to_device, self.pool.page_table.copy(),
                             policy=self._retry, site='serving.h2d')
                         args = (self._params, self._frozen, self._buffers,
-                                pages, scales, table, *self._state_args())
+                                pages, scales, table,
+                                *self._state_args(self._prev_toks))
                         toks_dev, new_pages, new_scales, *touched = \
                             self._decode_program(rows, args)(*args)
                         self.pool.set_device_state(new_pages, new_scales)
@@ -1830,23 +2003,51 @@ class InferenceEngine:
             except Exception:
                 self._recover_pool()
                 raise
-            with _obs.span('serving.d2h'):
-                toks = call_with_retry(_from_device, toks_dev,
-                                       policy=self._retry,
-                                       site='serving.d2h')
-                if touched:
-                    # the routing counts left the device with the
-                    # tokens: ready when they are, no further wait
-                    self._note_routing(round_span, call_with_retry(
-                        _from_device, touched[0], policy=self._retry,
-                        site='serving.d2h'))
+            self._prev_toks = toks_dev
+            self._rounds.append(_Round(
+                toks_dev, touched[0] if touched else None,
+                self._advance_slots(), t_round0))
+            self._counts['decode_steps'] += self.decode_block
+            self._counts['rounds_ahead'] += ahead
+            if _obs.enabled():
+                self._m_decode_steps.inc(self.decode_block)
+                self._m_rounds_ahead.inc(ahead)
+                self._m_rows_read.inc(
+                    self.pool.num_slots * rows * self.decode_block)
+            if not ahead and self._may_run_ahead():
+                return      # in flight: the next step dispatches first
+            fetched = self._fetch(round_span)
+        with _obs.span('serving.emit'):
+            self._emit_round(*fetched)
+
+    def _fetch(self, span):
+        """Block until the tokens of the OLDEST round in flight are on
+        the host (`serving.d2h`) and take it off the list; a failed
+        fetch leaves it there. Its routing counts and `discarded` go on
+        `span`, the `serving.decode_round` or `serving.settle` open. -> what `_emit_round` takes: of its participants
+        AS DISPATCHED those still seated where they were (a request
+        retired since — an EOS in the round before — or evicted takes
+        nothing more, and whoever holds its slot now was seated after
+        the dispatch), its tokens, None, its dispatch instant."""
+        rnd = self._rounds[0]
+        with _obs.span('serving.d2h'):
+            toks = call_with_retry(_from_device, rnd.toks,
+                                   policy=self._retry,
+                                   site='serving.d2h')
+            if rnd.routing is not None:
+                # the routing counts left the device with the
+                # tokens: ready when they are, no further wait
+                self._note_routing(span, call_with_retry(
+                    _from_device, rnd.routing, policy=self._retry,
+                    site='serving.d2h'))
+        self._rounds.popleft()
+        live = [(slot, h) for slot, h in rnd.parts
+                if self._slot_req.get(slot) is h]
+        dropped = len(rnd.parts) - len(live)
+        span.set(discarded=dropped)
+        self._counts['blocks_discarded'] += dropped
         _obs.note_progress('decode')   # /healthz decode liveness beat
-        self._counts['decode_steps'] += self.decode_block
-        if _obs.enabled():
-            self._m_decode_steps.inc(self.decode_block)
-            self._m_rows_read.inc(
-                self.pool.num_slots * rows * self.decode_block)
-        return toks, None
+        return live, toks, None, rnd.t0
 
     def _spec_round(self):
         """One compiled speculation round: k draft proposals + one
@@ -2353,7 +2554,9 @@ class InferenceEngine:
         for decode. The pending token is the LAST prompt token at
         position s-1 — the next decode round re-forwards it (identical
         KV overwrite) and its sampled output is the request's first
-        generated token."""
+        generated token. That token is the HOST's (`carried` cleared):
+        whatever block the slot's last request left on the device is
+        not this one's."""
         p = h.params
         s = len(h.prompt_tokens)
         if self.draft_model is not None:
@@ -2363,8 +2566,10 @@ class InferenceEngine:
             jax.random.PRNGKey(h.request_id if p.seed is None
                                else p.seed), np.uint32))
         self._tok[slot] = h.prompt_tokens[-1]
+        self._carried[slot] = False
         self._pos[slot] = s - 1
         self._steps[slot] = 0
+        self._budget[slot] = p.max_new_tokens
         self._active[slot] = True
         self._temp[slot] = p.temperature
         self._topk[slot] = p.top_k
@@ -2421,7 +2626,9 @@ class InferenceEngine:
     def stats(self) -> dict:
         """Host-side counters + compile-trace counts (the zero-recompile
         assertions read `traces`: after warmup it must stop growing
-        across admissions)."""
+        across admissions). A round in flight is settled first, so the
+        counters are those of a quiet engine."""
+        self._settle()
         traces = collections.Counter(self._trace_counts)
         for pool in (self.pool, self.draft_pool):
             if isinstance(pool, SlotPool):   # its seat/copy/slice programs
@@ -2435,6 +2642,8 @@ class InferenceEngine:
             'prefill_tokens': self._counts['prefill_tokens'],
             'decode_rounds': self._counts['decode_rounds'],
             'decode_steps': self._counts['decode_steps'],
+            'rounds_ahead': self._counts['rounds_ahead'],
+            'blocks_discarded': self._counts['blocks_discarded'],
             'chunked_prefills': self._counts['chunked_prefills'],
             'chunk_rounds': self._counts['chunk_rounds'],
             'queue_depth': self.scheduler.queue_depth,

@@ -2,8 +2,12 @@
 
 A decode round hands its program, per slot, the pending token and its
 position, the sample index, whether the slot decodes, the sampling
-parameters and key, the stop token and the adapter row. They used to be
-nine to eleven loose numpy arrays, and jax made a transfer of each on
+parameters and key, the stop token, the adapter row and whether the
+pending token is the host's or the device's (`carried`: a slot that
+decoded in the block before takes that block's last token, which never
+left the device, so the engine can dispatch a block before it has
+fetched the one before it). They used to be nine to eleven loose numpy
+arrays, and jax made a transfer of each on
 every call: 121 us apiece on a TPU's host whatever the size, 1.09 ms a
 round for under 2 KB (PERF.md, PR 35). Here they are views into one
 int32 buffer with the dtypes they always had, so the engine writes
@@ -45,6 +49,11 @@ _FIELDS = (
     ('keys', np.uint32, 2, 0),          # the request's sampling key
     ('eos', np.int32, 1, -1),           # speculation's accept stop
     ('adapter_rows', np.int32, 1, 0),   # 0 = the base adapter
+    # the pending token is the DEVICE's: the last token of the decode
+    # block before this one (which the program is handed beside the
+    # buffer), not `tok` — set when a block is dispatched for the slot,
+    # cleared when a request is seated (its `tok`: the last prompt token)
+    ('carried', np.bool_, 0, False),
 )
 
 #: what `unpack` returns: a device value a field, in `_FIELDS`' order
@@ -53,8 +62,8 @@ Slots = namedtuple('Slots', [name for name, *_ in _FIELDS])
 
 class SlotState:
     """`buffer` (int32, 1-D) and, as attributes, a view into it for every
-    field: `[n]` of the field's dtype (`keys`: `[n, 2]` uint32; `active`
-    and `greedy`: numpy `bool`, so they index as masks)."""
+    field: `[n]` of the field's dtype (`keys`: `[n, 2]` uint32; `active`,
+    `greedy` and `carried`: numpy `bool`, so they index as masks)."""
 
     def __init__(self, num_slots: int):
         n = self.num_slots = int(num_slots)

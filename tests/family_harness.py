@@ -24,7 +24,8 @@ ITS files: what builds no engine in `test_<family>.py`, what serves in
 rows held), `left_padded_forward`, `each_departure`, `generate_greedy`,
 `generate_refuses`; `prefill_then_decode`,
 `through_router_shorter_than_bucket`, `faulty_hand_off`,
-`more_requests_than_slots`, `reseated_slot`, `both_decode_programs`,
+`more_requests_than_slots`, `ahead_serves_the_serial_tokens`,
+`reseated_slot`, `both_decode_programs`,
 `decode_through_the_kernel`, `expert_kernel_serves_the_loops_tokens`,
 `modes_refused`, `as_a_draft_refused`. `tests/test_family_programs.py`
 pins the programs of the families it must leave alone: it adds its tiny
@@ -241,9 +242,28 @@ def one_at_a_time(fam, cfg, w, eng, requests, ref_len=REF_LEN):
         assert fam.served_gap(cfg, w, prompt, h.tokens, ref_len) < TOL
 
 
+#: what a `serving.decode_round` or `serving.settle` says of the block
+#: it FETCHED
+_FETCHED = {'discarded', 'experts_touched', 'expert_layer_substeps',
+            'expert_kernel_substeps', 'experts', 'picks', 'picks_held'}
+
+
 def rounds(log):
-    return [e['attrs'] for e in log.events()
-            if e['name'] == 'serving.decode_round']
+    """Per decode ROUND, the counts its spans carried. A round's block
+    is dispatched under ONE `serving.decode_round` span (the block's own
+    counts: `rows`, `read_rows`, ...) and fetched under that one, a later
+    one or a `serving.settle` (the routing counts, `discarded`) — not
+    its own whenever the engine ran ahead (ISSUE 45) — and rounds are
+    fetched in the order they were dispatched: the k-th dispatch's
+    counts and the k-th fetch's are one round's."""
+    spans = [e for e in log.events() if e.get('ph') == 'X' and e['name']
+             in ('serving.decode_round', 'serving.settle')]
+    dispatched = [{k: v for k, v in e['attrs'].items() if k not in _FETCHED}
+                  for e in spans if e['name'] == 'serving.decode_round']
+    fetched = [{k: v for k, v in e['attrs'].items() if k in _FETCHED}
+               for e in spans if 'discarded' in e['attrs']]
+    assert len(dispatched) == len(fetched), (len(dispatched), len(fetched))
+    return [dict(d, **f) for d, f in zip(dispatched, fetched)]
 
 
 def cleared_log():
@@ -567,6 +587,49 @@ def more_requests_than_slots(fam):
         assert all(h.error is None for h in hs)
         within_tol(fam, cfg, w, served, [h.tokens for h in hs])
         assert eng._counts['prefills'] == 7 and eng.pool.num_slots == 2
+    return test
+
+
+class SerialEngine(InferenceEngine):
+    """The engine held to the serial order, every round settled before
+    the next is dispatched: what a run-ahead engine's tokens are compared
+    with (test-only; production has no switch, ISSUE 45)."""
+
+    def _may_run_ahead(self):
+        return False
+
+
+def ahead_serves_the_serial_tokens(fam):
+    """Two slots kept busy by five requests: whenever nobody can be
+    seated or ends by length the engine dispatches a block BEFORE it has
+    fetched the one in flight, its pending tokens never leaving the
+    device — and every request's tokens are those of the same engine
+    settling each round first, and the reference's to TOL."""
+    def test():
+        cfg, w, model = fam.build()
+        served = prompts((5, 19, 3, 11, 16), seed=4)
+        news = (24, 17, 8, 13, 20)
+
+        def serve(cls):
+            eng = cls(model, **_geometry({}))
+            hs = [eng.submit(p, greedy(n)) for p, n in zip(served, news)]
+            eng.run()
+            assert all(h.error is None and len(h.tokens) == n
+                       for h, n in zip(hs, news))
+            assert not eng._rounds and not eng.has_work
+            return [list(h.tokens) for h in hs], eng.stats()
+        log = cleared_log()
+        toks, stats = serve(InferenceEngine)
+        flags = [a['ahead'] for a in rounds(log)]
+        serial, serial_stats = serve(SerialEngine)
+        assert toks == serial
+        within_tol(fam, cfg, w, served, toks)
+        # the rounds are the same rounds, in another order of the calls
+        assert stats['decode_rounds'] == serial_stats['decode_rounds']
+        assert stats['tokens'] == serial_stats['tokens'] == sum(news)
+        assert serial_stats['rounds_ahead'] == 0
+        assert stats['rounds_ahead'] == sum(flags) >= 6
+        assert stats['blocks_discarded'] == 0       # nobody's EOS
     return test
 
 

@@ -112,6 +112,12 @@ def test_adapters_on_the_attention_projections(tiny, base):
     assert toks[1] != base[1] and toks[1] == alone[0]
 
 
+# every slot busy: a block is dispatched before the one in flight is
+# fetched (ISSUE 45), and the tokens are the serial order's
+test_ahead_of_the_fetch_the_engine_serves_the_serial_orders_tokens = \
+    H.ahead_serves_the_serial_tokens(FAM)
+
+
 # ---------------------------------------------------------------------------
 # what the engine says about itself
 # ---------------------------------------------------------------------------

@@ -85,6 +85,8 @@ def test_the_other_preset_through_the_router():
 # ---------------------------------------------------------------------------
 test_more_requests_than_slots_every_one_against_the_reference = \
     H.more_requests_than_slots(FAM)
+test_ahead_of_the_fetch_the_engine_serves_the_serial_orders_tokens = \
+    H.ahead_serves_the_serial_tokens(FAM)
 
 
 def _every_layers_rows_latent_as_k_and_v(eng, rounds):
@@ -268,14 +270,17 @@ def test_scopes_are_on_the_decode_and_prefill_programs(served):
 # ---------------------------------------------------------------------------
 # sha256 (first 16 hex digits) of the StableHLO text of this family's own
 # programs at the tiny presets (2 slots x 64, block 4, bucket 16), taken
-# on the PARENT of PR 38 (commit 90423b8) by `_own_program_texts` below
+# on the PARENT of PR 38 (commit 90423b8) by `_own_program_texts` below;
+# the four decode blocks' re-taken AT PR 45, which handed every decode
+# program the tokens of the block before and one more flag a slot (one
+# `where` outside the scan, `tests/test_family_programs.py`)
 _PARENT_OWN_PROGRAMS = {
-    ('tiny', 'decode'): '7f1fc5826be26a90',
-    ('tiny', 'decode_half'): 'cf4185e6d5cf00af',
+    ('tiny', 'decode'): '4891dea3bdafb776',
+    ('tiny', 'decode_half'): '0f4381c8005777c6',
     ('tiny', 'prefill'): 'feba32510e9fd5d1',
     ('tiny', 'chunk'): '338c89c25e5b4dce',
-    ('tiny_wide_v', 'decode'): '79b7ba536389d5cb',
-    ('tiny_wide_v', 'decode_half'): '8464664a0954bc96',
+    ('tiny_wide_v', 'decode'): 'ac6607434d99a455',
+    ('tiny_wide_v', 'decode_half'): 'f7d46387584dc6b7',
     ('tiny_wide_v', 'prefill'): '683ac209cf3b7b56',
     ('tiny_wide_v', 'chunk'): '0af2fdcdd9919cab',
 }

@@ -42,28 +42,30 @@ _FAMILIES = {'gpt': (GPTForCausalLM, GPTConfig),
 # the very code of `family_harness.program_texts`: the first twelve
 # prefills' taken on the PARENT of PR 32 (commit 25ee4df), mimo_v2's on
 # the PARENT of PR 37 (commit bbd1fb5); the decode programs' re-taken AT
-# PR 36, which made the slot state one buffer that they unpack (they are
-# the engine's own functions on `_decode_args()`, the pins of
-# `tests/test_pool_layout.py`); jax 0.9.0, which the repository is
+# PR 36, which made the slot state one buffer that they unpack, and AT
+# PR 45, which handed them the tokens of the block before and one more
+# flag a slot (one `where` outside the scan: `tests/test_serving.py`
+# spells it; they are the engine's own functions on `_decode_args()`,
+# the pins of `tests/test_pool_layout.py`); jax 0.9.0, which the repository is
 # written for (the verify skill); ling3's taken AT PR 43, which brought it
 _PARENT_PROGRAMS = {
-    ('afmoe', 'decode'): '81008fe4d4edb6d9',
-    ('afmoe', 'decode_half'): '8e312056c151a0a2',
+    ('afmoe', 'decode'): '06d4c6cd626f8f1c',
+    ('afmoe', 'decode_half'): '9ff2fd3dad3881d1',
     ('afmoe', 'prefill'): '6782a117cd64283e',
-    ('gpt', 'decode'): '5e706a44cb430fe1',
-    ('gpt', 'decode_half'): '4a4e6ee67293bb7c',
+    ('gpt', 'decode'): '26d1a9e871109e8a',
+    ('gpt', 'decode_half'): '43ad4e7e3a35cce5',
     ('gpt', 'prefill'): '365eec42133d1ab2',
-    ('lfm2', 'decode'): '611c2975c6cfa539',
-    ('lfm2', 'decode_half'): '6df5d3a5564cc3bd',
+    ('lfm2', 'decode'): '2b23d551b6d5dcf5',
+    ('lfm2', 'decode_half'): '0f8bfc49e25b9946',
     ('lfm2', 'prefill'): '1a02dff7d8263eae',
-    ('ling3', 'decode'): 'd539579323cc4e28',
-    ('ling3', 'decode_half'): '41dfd760bdbde01e',
+    ('ling3', 'decode'): '859ac4250da37164',
+    ('ling3', 'decode_half'): 'd6c532db63081802',
     ('ling3', 'prefill'): '5d6e70a47ddf9ca9',
-    ('llama', 'decode'): '0b25e1d31f4c9b75',
-    ('llama', 'decode_half'): '8a7f5153ef78c81d',
+    ('llama', 'decode'): '0b50d9ed7ed665f2',
+    ('llama', 'decode_half'): 'b5a72e2c6a853df8',
     ('llama', 'prefill'): '8b4c79aa8dc443ef',
-    ('mimo_v2', 'decode'): '8ea7c6f267237b5a',
-    ('mimo_v2', 'decode_half'): 'cf1b7410b8762beb',
+    ('mimo_v2', 'decode'): '6ac560b685af1312',
+    ('mimo_v2', 'decode_half'): '497fca16f53523e5',
     ('mimo_v2', 'prefill'): '83c5267b24fdfe6b',
 }
 

@@ -62,6 +62,16 @@ class TestLedgerMechanics:
         assert r['overcount_seconds'] == 0.0
         assert abs(sum(r['fractions'].values()) - 1.0) < 1e-9
 
+    def test_a_step_that_only_settles_a_block_books_as_decode(self):
+        """ISSUE 45: an engine that ran ahead and can no longer fetches
+        the block in flight under `serving.settle`, with no dispatch:
+        the wait is that block's, decode like `serving.decode_round`."""
+        log, led = _fresh_ledger()
+        _sleep_span(log, 'serving.settle', 0.02)
+        r = led.report()
+        assert r['categories']['serving_decode'] >= 0.015
+        assert r['overcount_seconds'] == 0.0
+
     def test_nested_span_counts_once(self):
         log, led = _fresh_ledger()
         # a compile inside a train step: the step keeps only its surplus

@@ -78,6 +78,8 @@ test_a_faulty_hand_off_fails_the_tolerance = H.faulty_hand_off(
 # ---------------------------------------------------------------------------
 test_more_requests_than_slots_every_one_against_the_reference = \
     H.more_requests_than_slots(FAM)
+test_ahead_of_the_fetch_the_engine_serves_the_serial_orders_tokens = \
+    H.ahead_serves_the_serial_tokens(FAM)
 test_a_reseated_slot_holds_the_new_requests_ring_whole = \
     H.reseated_slot(FAM, 'ring_layers')
 

@@ -92,18 +92,21 @@ _FAMILIES = {'gpt': (GPTForCausalLM, GPTConfig),
 # as one buffer and unpack it (the parents' digests, PR 32's and PR
 # 33's, were of programs over nine loose arrays; that the packed
 # program returns the loose one's tokens and pool, bit for bit, is
-# `tests/test_slot_state.py`'s); jax 0.9.0
+# `tests/test_slot_state.py`'s); re-taken AT PR 45, whose programs also
+# take the tokens of the block before and read a `carried` slot's
+# pending token off them (ONE `where` outside the scan, spelled in
+# `tests/test_serving.py`); jax 0.9.0
 _PARENT_DECODE = {
-    ('afmoe', 'decode'): '81008fe4d4edb6d9',
-    ('afmoe', 'decode_half'): '8e312056c151a0a2',
-    ('gpt', 'decode'): '5e706a44cb430fe1',
-    ('gpt', 'decode_half'): '4a4e6ee67293bb7c',
-    ('lfm2', 'decode'): '611c2975c6cfa539',
-    ('lfm2', 'decode_half'): '6df5d3a5564cc3bd',
-    ('llama', 'decode'): '0b25e1d31f4c9b75',
-    ('llama', 'decode_half'): '8a7f5153ef78c81d',
-    ('mimo', 'decode'): '8ea7c6f267237b5a',
-    ('mimo', 'decode_half'): 'cf1b7410b8762beb',
+    ('afmoe', 'decode'): '06d4c6cd626f8f1c',
+    ('afmoe', 'decode_half'): '9ff2fd3dad3881d1',
+    ('gpt', 'decode'): '26d1a9e871109e8a',
+    ('gpt', 'decode_half'): '43ad4e7e3a35cce5',
+    ('lfm2', 'decode'): '2b23d551b6d5dcf5',
+    ('lfm2', 'decode_half'): '0f8bfc49e25b9946',
+    ('llama', 'decode'): '0b50d9ed7ed665f2',
+    ('llama', 'decode_half'): 'b5a72e2c6a853df8',
+    ('mimo', 'decode'): '6ac560b685af1312',
+    ('mimo', 'decode_half'): '497fca16f53523e5',
 }
 
 

@@ -565,10 +565,16 @@ def _slot_args(eng):
     return (eng._slot_state.buffer,)
 
 
+def _decode_slot_args(eng):
+    """A decode block takes, after the buffer, the tokens of the block
+    before (ISSUE 45): the device's, never donated."""
+    return (eng._slot_state.buffer, eng._prev_toks)
+
+
 def _prog_decode(gpt):
     eng = _engine(gpt)
     return eng._decode_jit, (eng._params, eng._frozen, eng._buffers,
-                             eng.pool.cache, *_slot_args(eng))
+                             eng.pool.cache, *_decode_slot_args(eng))
 
 
 def _prog_paged_decode(gpt):
@@ -576,7 +582,7 @@ def _prog_paged_decode(gpt):
     pages, scales = eng.pool.device_state()
     return eng._decode_jit, (eng._params, eng._frozen, eng._buffers,
                              pages, scales, jnp.asarray(eng.pool.page_table),
-                             *_slot_args(eng))
+                             *_decode_slot_args(eng))
 
 
 def _prog_spec(gpt):

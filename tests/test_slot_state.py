@@ -1,5 +1,7 @@
 """ISSUE 36 (a): the per-slot decode state is ONE host buffer and one
-argument of every decode and speculation program. The engine's names
+argument of every decode and speculation program (ISSUE 45: with one
+more flag a slot, `carried`, and a decode block takes the tokens of the
+block before beside it). The engine's names
 are views into it; the programs unpack it on the device into the values
 they always took; the served tokens are the parent's, token for token,
 greedy and sampled."""
@@ -20,7 +22,7 @@ NO_EOS = -1
 _DTYPES = {'tok': np.int32, 'pos': np.int32, 'steps': np.int32,
            'active': np.bool_, 'temp': np.float32, 'topk': np.int32,
            'topp': np.float32, 'greedy': np.bool_, 'keys': np.uint32,
-           'eos': np.int32, 'adapter_rows': np.int32}
+           'eos': np.int32, 'adapter_rows': np.int32, 'carried': np.bool_}
 
 
 @pytest.fixture(scope='module')
@@ -57,13 +59,14 @@ def loose_decode_fns(eng):
     slot state nine loose values after the pool: -> (the whole-length
     block, the half-length one), each `(params, frozen, buffers, pool,
     *nine)`."""
-    def whole(params, frozen, buffers, pool, *state):
+    def whole(params, frozen, buffers, pool, tok, *state):
         fwd = cached_forward(eng.model, params, frozen, buffers)
-        return eng._decode_scan(fwd, pool, *state)
+        return eng._decode_scan(fwd, pool, tok, *state)
 
-    def half(params, frozen, buffers, pool, *state):
+    def half(params, frozen, buffers, pool, tok, *state):
         fwd = cached_forward(eng.model, params, frozen, buffers)
-        return eng._decode_scan(fwd, pool, *state, rows=eng._half_rows)
+        return eng._decode_scan(fwd, pool, tok, *state,
+                                rows=eng._half_rows)
     return whole, half
 
 
@@ -83,6 +86,7 @@ def _scramble(state, seed):
     state.adapter_rows[:] = rng.integers(0, 4, n)
     state.active[:] = rng.random(n) > 0.4
     state.greedy[:] = rng.random(n) > 0.5
+    state.carried[:] = rng.random(n) > 0.5
 
 
 # ---------------------------------------------------------------------------
@@ -98,6 +102,7 @@ def test_fields_are_views_and_unpack_returns_them(n):
     # what a free slot holds: what the loose arrays were built with
     assert state.temp.tolist() == [1.0] * n == state.topp.tolist()
     assert state.greedy.all() and not state.active.any()
+    assert not state.carried.any()
     assert state.eos.tolist() == [-1] * n and not state.keys.any()
     _scramble(state, n)
     views = {name: getattr(state, name) for name in _DTYPES}
@@ -108,7 +113,7 @@ def test_fields_are_views_and_unpack_returns_them(n):
     # no two fields overlap: together they fill the buffer (less the
     # flags' padding)
     assert sum(v.nbytes for v in views.values()) \
-        == state.buffer.nbytes - 2 * (-n % 4)
+        == state.buffer.nbytes - 3 * (-n % 4)
     assert state.pos[state.active].tolist() == [
         p for p, a in zip(state.pos.tolist(), state.active.tolist()) if a]
     out = jax.jit(state.unpack)(state.buffer)
@@ -127,11 +132,15 @@ def test_the_engines_names_are_the_buffers_views(gpt):
     buf = eng._slot_state.buffer
     names = dict(zip(('tok', 'pos', 'steps', 'active', 'temp', 'topk',
                       'topp', 'greedy', 'keys'), nine(eng)),
-                 eos=eng._eos_arr, adapter_rows=eng._adapter_rows)
+                 eos=eng._eos_arr, adapter_rows=eng._adapter_rows,
+                 carried=eng._carried)
     for name, view in names.items():
         assert view.dtype == _DTYPES[name], name
         assert np.shares_memory(view, buf), name
-    assert eng._decode_args()[4] is buf
+    # the call gets a COPY (ISSUE 45: the engine writes the buffer for
+    # the next block while this one may not have started)
+    handed = eng._decode_args()[4]
+    assert handed is not buf and handed.tobytes() == buf.tobytes()
     before = buf.copy()
     eng._pos[1] = 17
     eng._active[2] = True
@@ -154,18 +163,26 @@ def test_the_engines_names_are_the_buffers_views(gpt):
 def test_the_packed_program_returns_what_the_loose_one_returns(gpt, program):
     """One call of the engine's program on the buffer against the same
     scan on the nine loose arrays (the parent's program, spelled): the
-    tokens of every slot, greedy and sampled, and every pool leaf."""
+    tokens of every slot, greedy and sampled, and every pool leaf. A
+    `carried` slot's pending token is the last of the block before's
+    (ISSUE 45), every other slot's the buffer's."""
     eng = InferenceEngine(gpt, num_slots=4, max_length=64, decode_block=4)
     _scramble(eng._slot_state, 5)
     eng._active[:] = [True, True, False, True]
     eng._greedy[:] = [True, False, False, False]
+    eng._carried[:] = [True, False, True, False]
+    eng._prev_toks = jax.numpy.asarray(
+        np.arange(100, 116, dtype=np.int32).reshape(4, 4))
+    pending = np.where(eng._carried, [103, 107, 111, 115], eng._tok)
     packed = {'decode': eng._decode_block_fn,
               'decode_half': eng._decode_block_half_fn}[program]
     loose = dict(zip(('decode', 'decode_half'),
                      loose_decode_fns(eng)))[program]
     args = eng._decode_args()
     toks, pool = jax.jit(packed)(*args)
-    want, want_pool = jax.jit(loose)(*args[:4], *nine(eng))
+    assert args[5] is eng._prev_toks and len(args) == 6
+    want, want_pool = jax.jit(loose)(*args[:4], pending.astype(np.int32),
+                                     *nine(eng)[1:])
     assert np.asarray(toks).tolist() == np.asarray(want).tolist()
     assert np.asarray(toks)[2].tolist() == [0] * 4      # inactive
     for a, b in zip(jax.tree_util.tree_leaves(pool),

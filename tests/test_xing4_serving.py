@@ -33,6 +33,10 @@ test_prefill_program_then_decode_logits_at_every_position = \
 # ---------------------------------------------------------------------------
 # (b) through InferenceEngine: both decode programs, the span, the scopes
 # ---------------------------------------------------------------------------
+test_ahead_of_the_fetch_the_engine_serves_the_serial_orders_tokens = \
+    H.ahead_serves_the_serial_tokens(FAM)
+
+
 @pytest.fixture(scope='module')
 def served(tiny):
     """max_length 64: rounds attend over 32 latent rows while every
