@@ -16,6 +16,7 @@ from .ernie import (ErnieConfig, ErnieForMaskedLM,
                     ErnieForSequenceClassification, ErnieModel)
 from .generation import GenerationMixin, Seq2SeqGenerationMixin
 from .gpt import GPTConfig, GPTForCausalLM, GPTModel
+from .jamba import JambaConfig, JambaForCausalLM, JambaModel
 from .lfm2 import Lfm2MoeConfig, Lfm2MoeForCausalLM, Lfm2MoeModel
 from .ling3 import Ling3Config, Ling3ForCausalLM, Ling3Model
 from .llama import (LlamaConfig, LlamaForCausalLM, LlamaModel)
@@ -32,7 +33,8 @@ __all__ = [
     'BertModel', 'DeepseekV3Config', 'DeepseekV3ForCausalLM',
     'DeepseekV3Model', 'ErnieConfig', 'ErnieForMaskedLM',
     'ErnieForSequenceClassification', 'ErnieModel', 'GenerationMixin',
-    'GPTConfig', 'GPTForCausalLM', 'GPTModel', 'Lfm2MoeConfig',
+    'GPTConfig', 'GPTForCausalLM', 'GPTModel', 'JambaConfig',
+    'JambaForCausalLM', 'JambaModel', 'Lfm2MoeConfig',
     'Lfm2MoeForCausalLM', 'Lfm2MoeModel', 'Ling3Config',
     'Ling3ForCausalLM', 'Ling3Model', 'LlamaConfig',
     'LlamaForCausalLM', 'LlamaModel', 'MiMoV2Config', 'MiMoV2ForCausalLM',

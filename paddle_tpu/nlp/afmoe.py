@@ -195,12 +195,13 @@ def _narrow(mask, win):
 
 
 class AfmoeAttention(Layer):
-    """`rotary` / `gated`: what another family's attention layer of
-    this shape has or lacks (`nlp/lfm2.py`: rotary on a full layer, no
-    gate); the defaults are this family's."""
+    """`rotary` / `gated` / `qk_norm`: what another family's attention
+    layer of this shape has or lacks (`nlp/lfm2.py`: rotary on a full
+    layer, no gate; `nlp/jamba.py`: no positions, no gate, no norm on q
+    and k); the defaults are this family's."""
 
     def __init__(self, config: AfmoeConfig, layer_idx: int,
-                 rotary=None, gated=True):
+                 rotary=None, gated=True, qk_norm=True):
         super().__init__()
         self.config = config
         h, hd = config.hidden_size, config.head_dim
@@ -217,8 +218,10 @@ class AfmoeAttention(Layer):
         self.gate_proj = _col_linear(config, h, self.num_heads * hd) \
             if gated else None
         self.o_proj = _row_linear(config, self.num_heads * hd, h)
-        self.q_norm = RMSNorm(hd, epsilon=config.rms_norm_eps)
-        self.k_norm = RMSNorm(hd, epsilon=config.rms_norm_eps)
+        self.q_norm = RMSNorm(hd, epsilon=config.rms_norm_eps) \
+            if qk_norm else None
+        self.k_norm = RMSNorm(hd, epsilon=config.rms_norm_eps) \
+            if qk_norm else None
 
     def _gated(self, out, hidden):
         if self.gate_proj is None:
@@ -239,8 +242,10 @@ class AfmoeAttention(Layer):
             return apply_op(
                 lambda v: v.reshape(v.shape[0], v.shape[1], n, hd), t,
                 _name='split_heads')
-        q = self.q_norm(heads(self.q_proj(hidden), nh))
-        k = self.k_norm(heads(self.k_proj(hidden), nkv))
+        def normed(norm, t):
+            return t if norm is None else norm(t)
+        q = normed(self.q_norm, heads(self.q_proj(hidden), nh))
+        k = normed(self.k_norm, heads(self.k_proj(hidden), nkv))
         v = heads(self.v_proj(hidden), nkv)
 
         if self.rotary:

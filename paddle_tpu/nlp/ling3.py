@@ -291,16 +291,19 @@ def l2norm(x, eps=1e-6):
     return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), -1, keepdims=True) + eps)
 
 
-def short_conv_silu(u, w, state, folded):
+def short_conv_silu(u, w, state, folded, bias=None):
     """`silu(conv(u))`: u [B, S, C], one depthwise causal filter of L
-    taps a channel `w` [C, L] (tap L-1 on the token itself), `state` [B,
-    L-1, C] the last inputs before the call (zeros before a sequence).
-    -> (the activations [B, S, C], the state after the first `folded`
-    tokens of the call)."""
+    taps a channel `w` [C, L] (tap L-1 on the token itself) and, where a
+    family has one (`nlp/jamba.py`), a `bias` [C] inside the SiLU,
+    `state` [B, L-1, C] the last inputs before the call (zeros before a
+    sequence). -> (the activations [B, S, C], the state after the first
+    `folded` tokens of the call)."""
     s, taps = u.shape[1], w.shape[1]
     past = jnp.concatenate([state.astype(u.dtype), u], axis=1)
     # token t's taps are u_{t-L+1} .. u_t: rows t .. t+L-1 of `past`
     c = sum(past[:, j:j + s] * w[:, j].astype(u.dtype) for j in range(taps))
+    if bias is not None:
+        c = c + bias.astype(u.dtype)
     with jax.named_scope('state_write'):
         new_state = jax.lax.dynamic_slice_in_dim(
             past, folded, taps - 1, axis=1).astype(state.dtype)
@@ -599,11 +602,11 @@ class Ling3ForCausalLM(DeepseekV3ForCausalLM):
     config_class = Ling3Config
     model_class = Ling3Model
 
-    def kda_chunks(self, tokens):
+    def scan_chunks(self, tokens):
         """Chunks ONE KDA layer scans, one after another, in a call of
-        `tokens` tokens (a whole prefill's bucket): what the serving
-        engine says on `serving.prefill`."""
-        return -(-tokens // KDA_CHUNK)
+        `tokens` tokens (a whole prefill's bucket), under the name the
+        serving engine says it by on `serving.prefill`."""
+        return {'kda_chunks': -(-tokens // KDA_CHUNK)}
 
     def state_kernel_layers(self, cache, slots):
         """How many of `cache`'s state entries a decode sub-step of

@@ -8,6 +8,8 @@ from .ernie import (ErnieConfig, ErnieForMaskedLM,  # noqa: F401
                     ErnieForSequenceClassification, ErnieModel)
 from .generation import GenerationMixin  # noqa: F401
 from .gpt import GPTConfig, GPTForCausalLM, GPTModel  # noqa: F401
+from .jamba import (JambaConfig, JambaForCausalLM,  # noqa: F401
+                    JambaModel)
 from .lfm2 import (Lfm2MoeConfig, Lfm2MoeForCausalLM,  # noqa: F401
                    Lfm2MoeModel)
 from .ling3 import (Ling3Config, Ling3ForCausalLM,  # noqa: F401

@@ -3,8 +3,9 @@
 The layers of what is compiled are traced under `jax.named_scope`s of
 one fixed vocabulary (`SCOPES`: nlp/gpt.py, nlp/llama.py, nlp/afmoe.py's
 expert layer, nlp/lfm2.py's short convolution and the update of its
-state, nlp/ling3.py's KDA layers, the loss, the optimizer's functional
-update, the engine's sampling and KV writes), so
+state, nlp/ling3.py's KDA layers, nlp/jamba.py's state-space layers,
+the loss, the optimizer's functional update, the engine's sampling and
+KV writes), so
 every HLO instruction's `op_name` metadata says where it came from:
 `jit(step_fn)/jvp(mlp)/dot_general` is the forward pass of an MLP,
 `.../transpose(jvp(attention))/...` the backward pass of attention,
@@ -27,7 +28,7 @@ from .store import get_store
 # carry
 SCOPES = ('embed', 'attention', 'mlp', 'norm', 'lm_head', 'loss', 'sample',
           'kv_write', 'optimizer', 'moe/router', 'moe/experts', 'moe/shared',
-          'conv', 'state_write', 'latent_absorb', 'mhc', 'kda')
+          'conv', 'state_write', 'latent_absorb', 'mhc', 'kda', 'ssm')
 
 _INSTR = re.compile(r'^\s*(?:ROOT\s+)?%?([\w.\-]+) = ')
 _OP_NAME = re.compile(r'op_name="([^"]*)"')

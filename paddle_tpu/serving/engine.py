@@ -528,8 +528,10 @@ class InferenceEngine:
         # tokens (the model's to say; None: the span says nothing)
         self._own_tokens_pairs = getattr(model, 'own_tokens_pairs', None)
         # bucket -> the chunks ONE layer's scan walks in a whole prefill,
-        # where a layer's prefill is a chunked scan (likewise)
-        self._kda_chunks = getattr(model, 'kda_chunks', None)
+        # where a layer's prefill is a chunked scan (likewise; a dict,
+        # the count under the attribute's name: `kda_chunks` for a delta
+        # rule's chunk, `ssm_chunks` for a state-space layer's)
+        self._scan_chunks = getattr(model, 'scan_chunks', None)
         # how many state layers' recurrences a decode sub-step runs as a
         # kernel, where the model has one to ask (its own dispatch, with
         # the leaves as the pool holds them; None: the span says nothing)
@@ -2438,8 +2440,8 @@ class InferenceEngine:
             if self._own_tokens_pairs is not None:
                 span.set(attn_pairs_scored=self._own_tokens_pairs(bucket),
                          attn_pairs_causal=s * (s + 1) // 2)
-            if self._kda_chunks is not None:
-                span.set(kda_chunks=self._kda_chunks(bucket))
+            if self._scan_chunks is not None:
+                span.set(**self._scan_chunks(bucket))
             ids = np.zeros((1, bucket), np.int32)
             ids[0, :s] = h.prompt_tokens
             ids_dev = call_with_retry(_to_device, ids, policy=self._retry,

@@ -22,7 +22,10 @@ latent layer (`mla_decode_attention`, under `attention`), which takes both
 leaves of the layer where the row's write left them: nothing of a leaf's
 size is copied, transposed or staged. Since PR 44: serve-kda-reason's hold
 ONE `kda_decode_step` a KDA layer under `kda/state_write`, and no fusion or
-copy of the state's shape beside it. Every compile here goes through the
+copy of the state's shape beside it. Since PR 46: serve-ssm-reason's make
+the state ONCE a Mamba layer, the update and the sum over the states in one
+fusion, and hold one `kv_decode_attention` for twenty query heads on one
+K,V head. Every compile here goes through the
 store's compile site with the formats the pool holds, as the engine's do.
 
 The topology is described inside a fixture, never at import: every xdist
@@ -362,6 +365,41 @@ def test_a_kda_layers_recurrence_is_one_kernel_in_place_on_v5e(
                                     text.splitlines()) if m}
     assert made_by <= {'custom-call', 'parameter', 'get-tuple-element',
                        'while', 'tuple'}, made_by
+    assert ma.alias_size_in_bytes == pool_bytes
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize('program', ['whole', 'half'])
+def test_a_state_space_layers_update_is_one_pass_in_place_on_v5e(
+        program, one_chip):
+    """serve-ssm-reason at its slots and widths, a Mamba layer, an
+    attention layer and a Mamba layer (PR 46; XLA's step, no kernel): a
+    sub-step makes the state's `[slots, 16, 5120]` ONCE a Mamba layer —
+    one fusion that updates it and sums over its states in the same
+    pass — and nothing copies or moves it; the attention layer's twenty
+    query heads on ONE K,V head are one `kv_decode_attention`; the
+    pool, both state leaves and the rows, is aliased whole. What a
+    kernel for the update (ROADMAP A4k) has to beat is this."""
+    cell = spec.Spec().cell('serve-ssm-reason')
+    cell['config'].update(num_hidden_layers=3, attn_layer_offset=1,
+                          attn_layer_period=3)
+    slots = cell['traffic']['slots']
+    text, ma, leaves, pool_bytes = _compile_decode_block(cell, one_chip,
+                                                         program)
+    calls = [ln for ln in text.splitlines() if 'tpu_custom_call' in ln]
+    assert len(calls) == 1 and '/attention/kv_decode_attention' in calls[0]
+    # what MAKES an array of the state's shape, outside the fused
+    # computations (inside one nothing is materialised)
+    made_by, fused = [], False
+    for ln in text.splitlines():
+        if ln and not ln[0].isspace():          # a computation opens, or ends
+            fused = 'fused_computation' in ln
+        m = re.match(r'\s*(?:ROOT )?%\S+ = (.*?) ([\w\-]+)\(', ln)
+        if m and not fused and f'f32[{slots},16,5120]' in m.group(1):
+            made_by.append(m.group(2))
+    assert set(made_by) <= {'fusion', 'parameter', 'get-tuple-element',
+                            'while', 'tuple'}, set(made_by)
+    assert made_by.count('fusion') == 2
     assert ma.alias_size_in_bytes == pool_bytes
 
 

@@ -8,7 +8,11 @@ entry of two leaves, a group-limited router, a gate on latent attention)
 left all of them as they were, and its own tiny engine's programs are
 pinned here for the family after it; the kernel for its one-token
 recurrence (PR 44) is asked by `kda_mix` alone and leaves its prefill and
-every other family's programs as they were. One place: a new family adds its
+every other family's programs as they were; `nlp/jamba.py` (PR 46: a
+diagonal state of two leaves beside K and V of ONE head, and `qk_norm=`
+on `AfmoeAttention`, which it alone turns off: afmoe and lfm2 build that
+class with the default) left afmoe's, lfm2's, mimo_v2's and everyone
+else's programs at their digests, and its own are pinned here. One place: a new family adds its
 tiny engine to `_FAMILIES`, its pins, and what its pool must not book for
 the others."""
 import hashlib
@@ -23,6 +27,7 @@ from paddle_tpu import programs
 from paddle_tpu.nlp import generation
 from paddle_tpu.nlp.afmoe import AfmoeConfig, AfmoeForCausalLM
 from paddle_tpu.nlp.gpt import GPTConfig, GPTForCausalLM
+from paddle_tpu.nlp.jamba import JambaConfig, JambaForCausalLM
 from paddle_tpu.nlp.lfm2 import Lfm2MoeConfig, Lfm2MoeForCausalLM
 from paddle_tpu.nlp.ling3 import Ling3Config, Ling3ForCausalLM
 from paddle_tpu.nlp.llama import LlamaConfig, LlamaForCausalLM
@@ -34,6 +39,7 @@ from family_harness import program_texts
 _FAMILIES = {'gpt': (GPTForCausalLM, GPTConfig),
              'llama': (LlamaForCausalLM, LlamaConfig),
              'afmoe': (AfmoeForCausalLM, AfmoeConfig),
+             'jamba': (JambaForCausalLM, JambaConfig),
              'lfm2': (Lfm2MoeForCausalLM, Lfm2MoeConfig),
              'ling3': (Ling3ForCausalLM, Ling3Config),
              'mimo_v2': (MiMoV2ForCausalLM, MiMoV2Config)}
@@ -47,7 +53,8 @@ _FAMILIES = {'gpt': (GPTForCausalLM, GPTConfig),
 # flag a slot (one `where` outside the scan: `tests/test_serving.py`
 # spells it; they are the engine's own functions on `_decode_args()`,
 # the pins of `tests/test_pool_layout.py`); jax 0.9.0, which the repository is
-# written for (the verify skill); ling3's taken AT PR 43, which brought it
+# written for (the verify skill); ling3's taken AT PR 43, which brought
+# it, jamba's AT PR 46
 _PARENT_PROGRAMS = {
     ('afmoe', 'decode'): '06d4c6cd626f8f1c',
     ('afmoe', 'decode_half'): '9ff2fd3dad3881d1',
@@ -55,6 +62,9 @@ _PARENT_PROGRAMS = {
     ('gpt', 'decode'): '26d1a9e871109e8a',
     ('gpt', 'decode_half'): '43ad4e7e3a35cce5',
     ('gpt', 'prefill'): '365eec42133d1ab2',
+    ('jamba', 'decode'): 'fc2f502b42e211f1',
+    ('jamba', 'decode_half'): '4d36c83e4ab82144',
+    ('jamba', 'prefill'): '8e3724ef9358419d',
     ('lfm2', 'decode'): '2b23d551b6d5dcf5',
     ('lfm2', 'decode_half'): '0f8bfc49e25b9946',
     ('lfm2', 'prefill'): '1a02dff7d8263eae',
@@ -92,7 +102,10 @@ def _digests(eng):
     ('mimo_v2', 'latent_layers'),
     # ... and what ling3's state of two leaves beside latent rows (PR 43):
     # its own programs, for the family after it
-    ('ling3', 'ring_layers')])
+    ('ling3', 'ring_layers'),
+    # ... and what jamba's `qk_norm=` and `ssm_chunks` (PR 46): every
+    # program above; its own, for the family after it
+    ('jamba', 'ring_layers'), ('jamba', 'latent_layers')])
 def test_the_other_families_programs_are_the_parents(family, without):
     eng = _tiny_engine(family)
     assert getattr(eng.pool, without) == ()
@@ -121,9 +134,11 @@ def test_through_the_kv_kernel_only_the_decode_blocks_are_other_programs(
                         lambda rows: 16 if rows % 16 == 0 else None)
     changed = {name for name, digest in _digests(_tiny_engine(family)).items()
                if digest != _PARENT_PROGRAMS[family, name]}
-    # (ling3's one attending layer is latent: never K and V by head)
+    # (ling3's one attending layer is latent: never K and V by head;
+    # jamba's four query heads on ONE K,V head are the kernel's)
     assert changed == ({'decode', 'decode_half'}
-                       if family in ('afmoe', 'lfm2', 'mimo_v2') else set())
+                       if family in ('afmoe', 'jamba', 'lfm2', 'mimo_v2')
+                       else set())
     assert bool(kv_interpreted) == bool(changed)
 
 

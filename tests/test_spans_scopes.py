@@ -465,16 +465,17 @@ def test_scope_vocabulary_is_pinned(toy_step, gpt):
     assert scopes_mod.SCOPES == (
         'embed', 'attention', 'mlp', 'norm', 'lm_head', 'loss', 'sample',
         'kv_write', 'optimizer', 'moe/router', 'moe/experts', 'moe/shared',
-        'conv', 'state_write', 'latent_absorb', 'mhc', 'kda')
+        'conv', 'state_write', 'latent_absorb', 'mhc', 'kda', 'ssm')
     # the expert layer's three are on an expert model's decode program
     # (tests/test_afmoe.py::test_expert_scopes_are_on_the_decode_program),
     # the short convolution's two on a hybrid's (below), the absorbed
     # products' on a latent-attention model's (tests/test_deepseek_v3.py),
     # the hyper-connections' on a model with a residual path of several
     # streams (tests/test_xing4.py), the KDA layers' on a model with a
-    # matrix state (tests/test_ling3_serving.py)
+    # matrix state (tests/test_ling3_serving.py), the state-space layers'
+    # on a model with a diagonal one (tests/test_jamba_serving.py)
     moe = {s for s in scopes_mod.SCOPES if s.startswith('moe/')} \
-        | {'conv', 'state_write', 'latent_absorb', 'mhc', 'kda'}
+        | {'conv', 'state_write', 'latent_absorb', 'mhc', 'kda', 'ssm'}
     _serve(gpt, n_requests=1)
     table = programs.scope_table()
     assert 'train_step' in table and 'serving.decode_block' in table
