@@ -77,7 +77,7 @@ SLIDING, FULL = 'sliding_attention', 'full_attention'
 # more than that (prefill); a batch of fewer tokens is one block wide
 BLOCK_ROWS = 256
 
-# how float32 activations are multiplied: three bf16 passes on the MXU
+# float32 activations' products: bf16 passes, 3 (2 against a bf16 weight)
 ACTIVATION_PRECISION = 'high'
 
 
@@ -610,19 +610,19 @@ class AfmoeForCausalLM(AfmoePretrainedModel, GenerationMixin):
     def forward(self, input_ids, position_offset=None, attention_mask=None,
                 cache=None, use_cache=False, labels=None,
                 cache_offset=None):
-        # Activations are float32 and every product three bf16 passes
-        # (`ACTIVATION_PRECISION`), with the parameters stored as they
-        # come (bf16 when served): which 8 of 128 experts a token gets
-        # hangs on the 8th and 9th score, 0.009 apart on average, and in
-        # single-pass bf16 one token-layer in thirty picks another
-        # expert than the float32 reference does — a tenth of the
-        # hidden state, and more flips in every layer after it. The
+        # Activations are float32 and every product bf16 passes on the
+        # MXU (`ACTIVATION_PRECISION`): THREE where both sides are
+        # float32 (scores, a state's products), TWO against a weight
+        # stored in bf16, which has no low part (PERF.md, PR 47). Why:
+        # which 8 of 128 experts a token gets hangs on the 8th and 9th
+        # score, 0.009 apart on average, and in single-pass bf16 one
+        # token-layer in thirty picks another expert than the float32
+        # reference does, and more flips in every layer after it. The
         # passes are NOT free where a product is a few rows against a
         # large weight: XLA pushes every weight tile through the MXU once
-        # a pass, and the expert loop was bound by that, not by its
-        # bytes (PERF.md section 6, PR 31). The expert kernel stacks the
-        # activations' bf16 parts by rows and pushes each tile once;
-        # attention's and the dense products' passes remain.
+        # a pass, and the expert loop was bound by that, not its bytes
+        # (PR 31). The expert kernel stacks the activations' bf16 parts
+        # by rows and pushes each tile once; the other passes remain.
         with jax.default_matmul_precision(ACTIVATION_PRECISION):
             out = self.model(input_ids, position_offset=position_offset,
                              attention_mask=attention_mask, cache=cache,

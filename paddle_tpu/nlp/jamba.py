@@ -39,29 +39,29 @@ What it is made of, and where that lives:
   times the bytes in memory and on every read. `A_log` keeps its
   published shape and is turned once a call.
 
-**One token and many** (`mamba_step`, `mamba_scan`). A call of one token
-(a decode sub-step) is the recurrence itself, elementwise in float32: a
-read and a write of the state. A longer call (a prefill) goes CHUNK by
-chunk of `SSM_CHUNK` tokens, the chunks one after another in a
-`lax.scan` that carries `h`, and a chunk's tokens as an associative scan
-of the pairs `(a, b) -> (a2 a1, a2 b1 + b2)` — every `a` lies in (0, 1],
-so nothing grows and nothing has to be bounded (`nlp/ling3.py`'s chunk
-is another algebra: its decay is one number a channel and its chunk five
-matrix products). `y` is made inside the chunk; `[S, d_inner, N]` never
-exists for more than a chunk's tokens.
+**One token and many** (`mamba_step`; `prefill_scan`). A call of one
+token (a decode sub-step) is the recurrence itself, elementwise in
+float32: a read and a write of the state. A longer call (a prefill) is
+the same sum by one of TWO schedules, picked by `ops.pallas.
+ssm_scan_kernel` from the call alone (backend, the state's dtype and
+shape; no flag). On a TPU, for a float32 state of whole lanes and
+sublanes: ONE Mosaic kernel a layer (`ssm_prefill_scan`), a block of
+channels' `h` in VMEM, the tokens walked in order — `mamba_step`'s own
+products; u, dt, B, C read and y written, nothing else. Everywhere else
+`mamba_scan`: chunks of `SSM_CHUNK` tokens one after another in a
+`lax.scan` carrying `h`, a chunk's tokens an associative scan of the
+pairs `(a, b) -> (a2 a1, a2 b1 + b2)`, every `a` in (0, 1] — the tier-1
+path, the parity ground truth, and the one a gradient can take.
 
 A caller that forwards a right-padded prompt says how many of its
 tokens may enter the state (`generation.state_scope`); a token past
-that is folded as `dt = 0` — decay one, input nothing: the state passes
-it bit for bit — and the convolution's inputs are cut there
-(`nlp/ling3.py::short_conv_silu`, which this family shares). A pad of a
-left-padded batch is folded the same way.
+that, like a pad of a left-padded batch, is folded as `dt = 0` — decay
+one, input nothing: the state passes it bit for bit, either schedule —
+and the convolution's inputs are cut there (`ling3.short_conv_silu`).
 
-Refused by name, because not built: experts (`num_experts` > 1: Jamba
-Mini and Large), a sliding window, a bias on the Mamba projections, an
-untied head. Activations and state are float32 and products three bf16
-passes (`afmoe.ACTIVATION_PRECISION`). XLA's step and scan are the
-program on every backend. Served, not trained.
+Refused by name, because not built: experts, a sliding window, a Mamba
+projection's bias, an untied head. Float32 activations and state; two
+bf16 passes against a bf16 weight, three float32 by float32 (`afmoe`).
 """
 from __future__ import annotations
 
@@ -72,10 +72,10 @@ from ..nn import initializer as I
 from ..nn.common_layers import Embedding, Linear
 from ..nn.layer import Layer
 from ..nn.norm import RMSNorm
+from ..ops import pallas as _pallas
 from ..tensor import Tensor, apply_op, to_jax
 from .afmoe import ACTIVATION_PRECISION, FULL, AfmoeAttention
-from .generation import (GenerationMixin, bounded_decode_tile,
-                         folded_tokens)
+from .generation import GenerationMixin, bounded_decode_tile, folded_tokens
 from .ling3 import short_conv_silu
 from .llama import LlamaMLP, _col_linear, _row_linear
 
@@ -278,9 +278,9 @@ def mamba_mix(u, z, dt, b, c, a_log, d, h, folded, *keep, chunk, fold_all):
         y, h = mamba_step(u[:, 0], dt[:, 0], b[:, 0], c[:, 0], a, d, h)
         y = y[:, None]
     else:
-        y, h = mamba_scan(u, dt, b, c, a, d, h,
-                          None if fold_all else folded,
-                          min(chunk, u.shape[1]))
+        y, h = prefill_scan(u, dt, b, c, a, d, h,
+                            None if fold_all else folded,
+                            min(chunk, u.shape[1]))
     return y * jax.nn.silu(z), h
 
 
@@ -498,10 +498,19 @@ class JambaForCausalLM(JambaPretrainedModel, GenerationMixin):
                      for l, entry in zip(self.model.layers, cache))
 
     def scan_chunks(self, tokens):
-        """Chunks ONE Mamba layer scans, one after another, in a call of
-        `tokens` tokens (a whole prefill's bucket), under the name the
-        serving engine says it by on `serving.prefill`."""
-        return {'ssm_chunks': -(-tokens // SSM_CHUNK)}
+        """What the serving engine says on `serving.prefill` of a call
+        of `tokens` tokens (a whole prefill's bucket): `ssm_kernel_layers`,
+        the Mamba layers whose recurrence the program runs as ONE kernel
+        (`ops.pallas.ssm_scan_kernel`, asked as `prefill_scan` asks it),
+        and `ssm_chunks`, the chunks `mamba_scan` walks one after
+        another in each of the others (none where there are no others)."""
+        cfg = self.config
+        state = jax.ShapeDtypeStruct(
+            (1, cfg.mamba_d_state, cfg.mamba_d_inner), jnp.float32)
+        mamba = sum(not layer.is_attention for layer in self.model.layers)
+        by_kernel = _pallas.ssm_scan_kernel(state, tokens) is not None
+        return {'ssm_chunks': 0 if by_kernel else -(-tokens // SSM_CHUNK),
+                'ssm_kernel_layers': mamba if by_kernel else 0}
 
     def generate(self, input_ids, *args, attention_mask=None, **kwargs):
         if attention_mask is not None and \
@@ -520,3 +529,23 @@ class JambaForCausalLM(JambaPretrainedModel, GenerationMixin):
             'speculative decoding rejects a draft by moving the position '
             'back, and a Mamba layer\'s state cannot be moved back: it '
             'needs a snapshot of the state per proposed token (ROADMAP)')
+
+
+# below the classes: the lines above them are in the digests of the
+# decode programs, whose kernels' calls carry this file's line numbers
+def prefill_scan(u, dt, b, c, a, d, h0, folded, chunk):
+    """`mamba_scan`'s arguments and results, by the schedule the call
+    admits: ONE kernel where `ops.pallas.ssm_scan_kernel` gives one (a
+    TPU, a float32 state of whole lanes and sublanes, more than one
+    token) — `folded` applied to `dt` here, the identity update —, else
+    `mamba_scan`. The same recurrence either way; the kernel makes
+    `mamba_step`'s products in its order and is forward only (nothing
+    differentiates through a served model; `jax.grad` through it
+    raises, and off a TPU meets `mamba_scan`)."""
+    kernel = _pallas.ssm_scan_kernel(h0, u.shape[1])
+    if kernel is None:
+        return mamba_scan(u, dt, b, c, a, d, h0, folded, chunk)
+    if folded is not None:
+        dt = jnp.where((jnp.arange(u.shape[1]) < folded)[None, :, None],
+                       dt, 0.0)
+    return kernel(u, dt, b, c, a, d, h0)

@@ -4,16 +4,16 @@ Upstream analogue: the reference's hand-fused CUDA kernels
 (paddle/phi/kernels/fusion/gpu/*, flash-attn integration). Here the
 default path is plain jax — XLA already fuses normalization chains into
 adjacent matmuls — and the pallas kernels (ops/pallas_kernels.py) take
-over on TPU backends for five inner loops where a hand-written schedule
+over on TPU backends for six inner loops where a hand-written schedule
 beats the XLA-generated one: attention over a call's own tokens
 (`flash_attention`), the routed experts of a decode batch
 (`expert_kernel`: one weight stream, where XLA's `while` fetches each
 expert cold), decode attention over latent rows (`latent_decode_kernel`:
-a slot's row tiles up to its length, each read once where XLA's einsums
-stream every row twice), its sibling for float32 queries over K and V
-by head (`kv_decode_kernel`), and a KDA layer's recurrence of one token
-(`kda_step_kernel`: the state read once and written in place, where
-XLA's two fusions read it twice).
+a slot's row tiles read once where XLA's einsums stream every row
+twice), its sibling for float32 queries over K and V by head
+(`kv_decode_kernel`), a KDA layer's recurrence of one token
+(`kda_step_kernel`: the state read once, written in place), a Mamba
+layer's over a prompt (`ssm_scan_kernel`: the state stays in VMEM).
 
 Which path runs is decided by explicit conditions on the backend and
 the shapes, never by a caught exception: on a TPU a kernel that fails
@@ -304,4 +304,30 @@ def kda_step_kernel(state, interpret=False):
         if heads:
             return functools.partial(pallas_kernels.kda_decode_step,
                                      heads=heads, interpret=interpret)
+    return None
+
+
+def ssm_scan_kernel(h, tokens, interpret=False):
+    """Dispatch for a Mamba layer's recurrence over MORE than one token
+    (`nlp/jamba.py::mamba_mix`, a prefill, a chunk of one, a batch's
+    prompts): the pallas kernel `pallas_kernels.ssm_prefill_scan` (the
+    arguments of `nlp/jamba.py::mamba_scan` after `folded` has been
+    applied to `dt`) where it applies, None where `mamba_scan` runs.
+    Read from the call alone — the leaf `h` `[B, N, Di]` as it is held
+    (anything with a `.shape` and a `.dtype`) and the call's `tokens`:
+    the kernel takes a float32 state whose `Di` is whole lanes and `N`
+    whole sublanes (a block of channels' state then stands in vregs as
+    the leaf holds it) and more than one token, on a TPU (or anywhere
+    with interpret=True). ONE token keeps `mamba_step`, whatever this
+    says; a state of another shape or in fewer bits and every other
+    backend keep `mamba_scan`'s associative scan: there it is the
+    tier-1 path, the parity ground truth and what a gradient meets.
+    The conditions are the whole selection: a kernel error on a TPU
+    propagates."""
+    if ((interpret or _pallas_enabled()) and tokens > 1
+            and len(h.shape) == 3 and h.dtype == jnp.float32
+            and h.shape[1] % 8 == 0 and h.shape[2] % 128 == 0):
+        from . import pallas_kernels
+        return functools.partial(pallas_kernels.ssm_prefill_scan,
+                                 interpret=interpret)
     return None

@@ -24,8 +24,8 @@ Contents:
   attention over latent rows bounded per slot (`latent_decode_kernel`).
 - `kv_decode_attention(q, k, v, seen, scale)` — its sibling for float32
   queries over K and V by head (`kv_decode_kernel`; `decode_walk`).
-- `kda_decode_step(q, k, v, g, beta, state)` — a KDA layer's one-token
-  recurrence, the state read once, written in place (`kda_step_kernel`).
+- `kda_decode_step`, `ssm_prefill_scan` — a KDA layer's one-token update
+  in place; a Mamba layer's prompt, `h` in VMEM (`kda_step_kernel`, ...).
 (fp32 accumulators; add no line ABOVE a kernel: its lines are in digests.)
 """
 from __future__ import annotations
@@ -1587,3 +1587,130 @@ def kda_decode_step(q, k, v, g, beta, state, *, heads=None, interpret=False):
     )(q, k, g, v,
       jnp.broadcast_to(beta.astype(jnp.float32)[..., None], (bsz, h, dv)),
       state)
+
+
+# ---------------------------------------------------------------------------
+# a Mamba layer's recurrence over a prompt (ISSUE 47; `nlp/jamba.py::
+# mamba_step` token by token is the plain form and the parity ground
+# truth). XLA's schedule (`mamba_scan`) composes a chunk's affine
+# updates by an associative scan: every level of it a pass over `[C, N,
+# Di]` float32 pairs through HBM. The decay is one number a channel a
+# STATE, so a chunk has no matrix form; but the state of a block of
+# channels is a few vregs, so here it never leaves the core: the tokens
+# are walked in order and only `u`, `dt`, `b`, `c` come and `y` goes.
+# ---------------------------------------------------------------------------
+_SSM_TOKENS = 128               # tokens a grid step walks
+_SSM_LANES = 8192               # channels a grid step holds, at most
+_SSM_VREGS = 8                  # of the state, carried through a walk
+
+
+def _ssm_blocks(n, di):
+    """(channels a grid step holds, channels a walk carries in vregs):
+    the most whole lanes that divide `di` up to `_SSM_LANES`; of those,
+    the most whose `[n, lanes]` float32 state is `_SSM_VREGS` vregs."""
+    lanes = max(m for m in range(128, min(di, _SSM_LANES) + 1, 128)
+                if di % m == 0)
+    walk = max(m for m in range(128, lanes + 1, 128) if lanes % m == 0
+               and (m == 128 or n * m <= _SSM_VREGS * 1024))
+    return lanes, walk
+
+
+def _ssm_scan_kernel(u_ref, dt_ref, b_ref, c_ref, at_ref, d_ref, h0_ref,
+                     y_ref, h_ref, *, walk):
+    """One block of tokens of one block of channels of one sequence.
+    `h_ref` is the output's block and stays in VMEM over the token axis:
+    the state as the tokens before left it. A walk takes `walk` channels
+    — their `[N, walk]` state in vregs — through the block's tokens, the
+    recurrence as `mamba_step` spells it, elementwise float32; `b` and
+    `c` come with their N values down the sublanes of 128 equal lanes."""
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        h_ref[...] = h0_ref[...]
+    tokens, lanes = u_ref.shape[1], u_ref.shape[2]
+    for at in range(0, lanes, walk):
+        cols = pl.ds(at, walk)
+        a_t, skip = at_ref[:, cols], d_ref[:, cols]        # [N, w], [1, w]
+
+        def token(t, h):
+            row = pl.ds(t, 1)
+            u, dt = u_ref[0, row, cols], dt_ref[0, row, cols]   # [1, w]
+            b = jnp.tile(b_ref[0, t], (1, walk // 128))         # [N, w]
+            c = jnp.tile(c_ref[0, t], (1, walk // 128))
+            h = jnp.exp(dt * a_t) * h + (dt * u) * b
+            y_ref[0, row, cols] = jnp.sum(h * c, axis=0, keepdims=True) \
+                + skip * u
+            return h
+
+        def eight(i, h):        # Mosaic unrolls all of a loop or none
+            for j in range(8):
+                h = token(8 * i + j, h)
+            return h
+        h_ref[0, :, cols] = jax.lax.fori_loop(0, tokens // 8, eight,
+                                              h_ref[0, :, cols])
+
+
+@functools.partial(jax.jit, static_argnames='interpret')
+def ssm_prefill_scan(u, dt, b, c, a, d, h0, *, interpret=False):
+    """`nlp/jamba.py::mamba_scan` as ONE kernel, `folded` already
+    applied to `dt`: u, dt `[B, S, Di]`, b, c `[B, S, N]`, `a` `[Di, N]`
+    (negative), `d` `[Di]`, h0 `[B, N, Di]` float32 -> (y `[B, S, Di]`
+    float32, the state after the S tokens). A grid over (sequence, block
+    of channels, block of `_SSM_TOKENS` tokens, in order): the block's
+    state `[N, lanes]` lives in VMEM across the token axis (the output's
+    own block, aliased to `h0`), and a token is `decay = exp(dt_t A^T)`,
+    `h = decay h + (dt_t u_t) b_t`, `y_t = sum_n h c_t + d u_t` on the
+    vector unit, in `mamba_step`'s order: the products of decays an
+    associative scan rounds are not made. `b` and `c` reach the sublanes
+    broadcast over 128 lanes by XLA (8 KB a token each). A token with
+    `dt = 0` — past `folded`, a pad, the tail that fills the last block
+    — is decay one and input nothing: the state passes it bit for bit.
+    Forward only: nothing differentiates through a served model's scan,
+    and `jax.grad` through this call raises (`mamba_scan` is the path a
+    gradient takes). Jitted, so that a program's 26 layers trace and
+    lower the walk's unrolled body ONCE (0.8 s of host time a call
+    otherwise: 39 s of serve-ssm-reason's set-up, PERF.md, PR 47)."""
+    bsz, s, di = u.shape
+    n = b.shape[-1]
+    if dt.shape != u.shape or b.shape != (bsz, s, n) or c.shape != b.shape \
+            or a.shape != (di, n) or d.shape != (di,) \
+            or h0.shape != (bsz, n, di):
+        raise ValueError(
+            f'ssm_prefill_scan: u {u.shape}, dt {dt.shape}, b {b.shape}, c '
+            f'{c.shape}, a {a.shape}, d {d.shape} against state {h0.shape}')
+    if h0.dtype != jnp.float32 or n % 8 or di % 128:
+        raise ValueError(f'ssm_prefill_scan: a float32 state of whole '
+                         f'sublanes and lanes, not {h0.dtype} {h0.shape}')
+    lanes, walk = _ssm_blocks(n, di)
+    tokens = min(_SSM_TOKENS, -(-s // 8) * 8)
+    pad = -s % tokens
+
+    def rows(t, wide=False):        # dt = 0 over the tail: the identity
+        t = jnp.pad(t.astype(jnp.float32), ((0, 0), (0, pad), (0, 0)))
+        return jnp.broadcast_to(t[..., None], t.shape + (128,)) if wide \
+            else t
+    by_token = pl.BlockSpec((1, tokens, lanes), lambda i, j, k: (i, k, j))
+    by_state = pl.BlockSpec((1, tokens, n, 128), lambda i, j, k: (i, k, 0, 0))
+    state = pl.BlockSpec((1, n, lanes), lambda i, j, k: (i, 0, j))
+
+    def of_channels(r):
+        return pl.BlockSpec((r, lanes), lambda i, j, k: (0, j))
+    # two buffers of every block, the state's twice over, and room
+    vmem = 8 * tokens * (3 * lanes + 2 * n * 128) + 16 * n * lanes \
+        + (16 << 20)
+    y, h = pl.pallas_call(
+        functools.partial(_ssm_scan_kernel, walk=walk),
+        grid=(bsz, di // lanes, (s + pad) // tokens),
+        in_specs=[by_token, by_token, by_state, by_state, of_channels(n),
+                  of_channels(1), state],
+        out_specs=[by_token, state],
+        out_shape=[jax.ShapeDtypeStruct((bsz, s + pad, di), jnp.float32),
+                   jax.ShapeDtypeStruct(h0.shape, jnp.float32)],
+        input_output_aliases={6: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=('parallel', 'parallel', 'arbitrary'),
+            vmem_limit_bytes=vmem),
+        interpret=interpret,
+        name='ssm_prefill_scan',
+    )(rows(u), rows(dt), rows(b, True), rows(c, True),
+      a.T.astype(jnp.float32), d.astype(jnp.float32)[None], h0)
+    return y[:, :s], h

@@ -25,7 +25,8 @@ ONE `kda_decode_step` a KDA layer under `kda/state_write`, and no fusion or
 copy of the state's shape beside it. Since PR 46: serve-ssm-reason's make
 the state ONCE a Mamba layer, the update and the sum over the states in one
 fusion, and hold one `kv_decode_attention` for twenty query heads on one
-K,V head. Every compile here goes through the
+K,V head. Since PR 47: its largest PREFILL holds one `ssm_prefill_scan` a
+Mamba layer under `ssm` and no `while` there. Every compile here goes through the
 store's compile site with the formats the pool holds, as the engine's do.
 
 The topology is described inside a fixture, never at import: every xdist
@@ -401,6 +402,42 @@ def test_a_state_space_layers_update_is_one_pass_in_place_on_v5e(
                             'while', 'tuple'}, set(made_by)
     assert made_by.count('fusion') == 2
     assert ma.alias_size_in_bytes == pool_bytes
+
+
+@pytest.mark.slow
+def test_a_state_space_layers_prefill_is_one_kernel_a_layer_on_v5e(one_chip):
+    """serve-ssm-reason's largest prefill at its widths, a Mamba layer,
+    an attention layer and a Mamba layer (PR 47): the recurrence over
+    the bucket's tokens is ONE `ssm_prefill_scan` a Mamba layer, under
+    `ssm`; no `while` is left under `ssm` (the chunks' `lax.scan` and
+    the associative scan inside it are gone) and nothing outside the
+    kernel makes the pairs `[1, tokens, 16, 5120]` an associative scan
+    composes."""
+    import numpy as np
+    cell = spec.Spec().cell('serve-ssm-reason')
+    cell['config'].update(num_hidden_layers=3, attn_layer_offset=1,
+                          attn_layer_period=3)
+    cell['traffic']['slots'] = 2        # the prefill never holds the pool
+    bucket = max(cell['traffic']['buckets'])
+    with _engine_for_the_chip(cell) as eng:
+        assert eng._scan_chunks(bucket) == {'ssm_chunks': 0,
+                                            'ssm_kernel_layers': 2}
+        text = _compile(
+            eng._prefill_jit,
+            (eng._params, eng._frozen, eng._buffers,
+             np.zeros((1, bucket), np.int32), np.int32(bucket - 3)),
+            one_chip).as_text()
+    calls = [ln for ln in text.splitlines() if 'tpu_custom_call' in ln]
+    scans = [ln for ln in calls if 'ssm_prefill_scan' in ln]
+    assert len(scans) == 2, [ln[:160] for ln in calls]
+    assert all(re.search(r'/ssm/(jit\(ssm_prefill_scan\)/)?ssm_prefill_scan', ln)
+               for ln in scans)
+    loops = [ln for ln in text.splitlines()
+             if re.search(r'op_name="[^"]*/ssm/[^"]*while', ln)]
+    assert not loops, loops[:3]
+    pairs = [ln for ln in text.splitlines()
+             if re.match(r'\s*(?:ROOT )?%\S+ = \(*f32\[1,\d+,16,5120\]', ln)]
+    assert not pairs, [ln[:160] for ln in pairs[:3]]
 
 
 def _cut_to_three_layers(cfg):

@@ -11,10 +11,12 @@ import copy
 
 import jax
 import jax.numpy as jnp
+import pytest
 
 from paddle_tpu import observability as obs
 from paddle_tpu import programs
 from paddle_tpu.nlp import generation, jamba
+from paddle_tpu.nlp.jamba import JambaConfig, JambaForCausalLM
 
 import family_harness as H
 from family_harness import BLOCK, BUCKET, MAX_LEN
@@ -162,14 +164,38 @@ def test_decode_round_carries_state_and_counts_one_attention_layer(tiny):
     # chunks of 16 tokens scanned a Mamba layer
     assert [(a['bucket'], a['ssm_chunks']) for a in prefills] \
         == [(16, 1), (32, 2), (16, 1)]
+    # ... by XLA's scan in every one of them: no kernel on the CPU
+    assert [a['ssm_kernel_layers'] for a in prefills] == [0, 0, 0]
     assert all('kda_chunks' not in a for a in prefills)
+
+
+@pytest.mark.parametrize('preset,widths,layers', [
+    ('tiny', dict(hidden_size=64), 4),              # d_inner 128, 8 states
+    ('tiny_attention_last', dict(hidden_size=128), 2),
+    ('tiny', dict(), 0),                            # d_inner 64: half a lane
+    ('tiny', dict(hidden_size=64, mamba_d_state=4), 0)])
+def test_the_prefill_span_counts_the_layers_the_kernel_takes(
+        monkeypatch, preset, widths, layers):
+    """With the gate forced as `benchmarks/aot.py::force_kernels_on`
+    forces it, `serving.prefill` would say every Mamba layer of a model
+    whose state is whole lanes and sublanes, and no chunk of XLA's scan;
+    a state of another shape stays `mamba_scan`'s, gate or no gate, and
+    a call of one token is no scan at all."""
+    from paddle_tpu.ops import pallas
+    model = JambaForCausalLM(getattr(JambaConfig, preset)(**widths))
+    assert model.scan_chunks(32) == {'ssm_chunks': 2, 'ssm_kernel_layers': 0}
+    monkeypatch.setattr(pallas, '_pallas_enabled', lambda: True)
+    assert model.scan_chunks(32) == {
+        'ssm_chunks': 0 if layers else 2, 'ssm_kernel_layers': layers}
+    assert model.scan_chunks(1) == {'ssm_chunks': 1, 'ssm_kernel_layers': 0}
 
 
 def test_a_model_without_such_a_scan_says_nothing_of_its_chunks():
     _, a, log = H.llama_round()
     assert 'state_bytes' not in a
-    assert all('ssm_chunks' not in e['attrs'] for e in log.events()
-               if e['name'] == 'serving.prefill')
+    assert all('ssm_chunks' not in e['attrs']
+               and 'ssm_kernel_layers' not in e['attrs']
+               for e in log.events() if e['name'] == 'serving.prefill')
 
 
 def test_pool_books_a_state_of_two_leaves_lanes_whole_beside_k_and_v(tiny):
