@@ -7,6 +7,10 @@ jax.sharding Mesh + XLA collectives over ICI.
 """
 from __future__ import annotations
 
+import time as _time
+
+_T_IMPORT = _time.perf_counter()   # set-up's first phase counts from here
+
 from . import autograd, dtype as _dtype_module, framework
 from .autograd import enable_grad, no_grad, set_grad_enabled, grad
 from .dtype import (bfloat16, bool_, complex64, complex128, finfo, float16,
@@ -104,3 +108,9 @@ def get_flags(flags=None):
 def set_flags(flags):
     from . import flags as _flags
     return _flags.set_flags(flags)
+
+
+# the package's own body and whatever it was first to import, as
+# `paddle_setup_seconds_total{phase="import"}`: the last line
+observability.telemetry.note_setup('import',
+                                   _time.perf_counter() - _T_IMPORT)

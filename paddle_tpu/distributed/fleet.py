@@ -381,6 +381,7 @@ class DistTrainStep:
     jax.jit with donation — GSPMD inserts all collectives.
     """
 
+    @_obs.telemetry.constructing('train.step_init')
     def __init__(self, layer: Layer, loss_fn, optimizer,
                  strategy: Optional[DistributedStrategy] = None,
                  retry_policy=None):

@@ -232,6 +232,7 @@ class TrainStep:
     shape; params/opt_state/buffers are donated so XLA aliases them in HBM.
     """
 
+    @_obs.telemetry.constructing('train.step_init')
     def __init__(self, layer: Layer, loss_fn: Callable, optimizer,
                  extra_metrics: Optional[Callable] = None):
         self.layer = layer

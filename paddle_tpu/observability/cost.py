@@ -203,10 +203,20 @@ class MfuWindow:
                              wall_seconds=wall, baseline=self._before)
 
 
+# what a build of a program cost, by phase (`telemetry.ProgramBuild`):
+# the wall of `StoredJit._build`; inside it jax's trace, lowering and
+# compile-or-fetch-and-load (the cache's retrieval lies inside that);
+# then the first execution until the call returned
+BUILD_FIELDS = ('build_seconds', 'trace_seconds', 'lower_seconds',
+                'backend_seconds', 'cache_retrieval_seconds',
+                'first_call_seconds')
+
+
 class ProgramRecord:
     """One named compiled program's cumulative accounting."""
 
     __slots__ = ('name', 'kind', 'compile_count', 'compile_seconds',
+                 *BUILD_FIELDS,
                  'invocations', 'host_seconds', 'flops', 'bytes_accessed',
                  'peak_memory_bytes', 'argument_bytes', 'output_bytes',
                  'temp_bytes', 'analyzed', 'note')
@@ -216,6 +226,8 @@ class ProgramRecord:
         self.kind = kind
         self.compile_count = 0
         self.compile_seconds = 0.0
+        for field in BUILD_FIELDS:
+            setattr(self, field, 0.0)
         self.invocations = 0
         self.host_seconds = 0.0
         self.flops = 0.0
@@ -232,6 +244,7 @@ class ProgramRecord:
             'name': self.name, 'kind': self.kind,
             'compile_count': self.compile_count,
             'compile_seconds': self.compile_seconds,
+            **{f: getattr(self, f) for f in BUILD_FIELDS},
             'invocations': self.invocations,
             'host_seconds': self.host_seconds,
             'flops': self.flops, 'bytes_accessed': self.bytes_accessed,
@@ -334,7 +347,8 @@ class ProgramCatalog:
                      kind: Optional[str] = None) -> List[Dict[str, Any]]:
         """The attribution report: programs ranked by `sort_by`
         ('host_seconds', 'flops', 'bytes_accessed', 'invocations',
-        'compile_seconds', 'mfu'). Every row carries the roofline view
+        'compile_seconds', a build phase of `BUILD_FIELDS`, 'mfu').
+        Every row carries the roofline view
         — 'mfu', 'roofline_bound' ('compute'|'bandwidth'), and
         'arithmetic_intensity' — None where the device peaks are
         unknown or the program has no cost analysis. Pure dict reads —
@@ -373,7 +387,9 @@ class ProgramCatalog:
                      f'not in peak table; set PADDLE_PEAK_FLOPS)')
         lines = [head,
                  f'  {"program":<28}{"kind":<10}{"calls":>8}'
-                 f'{"host s":>10}{"compile s":>10}{"GFLOPs":>10}'
+                 f'{"host s":>10}{"compile s":>10}{"build s":>9}'
+                 f'{"trace s":>9}{"lower s":>9}{"backend s":>10}'
+                 f'{"1st call s":>11}{"GFLOPs":>10}'
                  f'{"GB moved":>10}{"peak MiB":>10}{"mfu":>7}'
                  f'{"bound":>11}']
         for r in rows:
@@ -384,6 +400,9 @@ class ProgramCatalog:
                 f'{r["invocations"]:>8}'
                 f'{r["host_seconds"]:>10.3f}'
                 f'{r["compile_seconds"]:>10.3f}'
+                f'{r["build_seconds"]:>9.3f}{r["trace_seconds"]:>9.3f}'
+                f'{r["lower_seconds"]:>9.3f}{r["backend_seconds"]:>10.3f}'
+                f'{r["first_call_seconds"]:>11.3f}'
                 f'{r["flops"] / 1e9:>10.3f}'
                 f'{r["bytes_accessed"] / 1e9:>10.3f}'
                 f'{r["peak_memory_bytes"] / 2**20:>10.1f}'

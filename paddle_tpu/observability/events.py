@@ -69,6 +69,10 @@ EVENT_SCHEMA: Dict[str, str] = {
     'program_store_preload': 'bulk preload completed',
     'program_store_invalidate': 'fingerprint refresh dropped entries',
     'program_store_wipe': 'persistent tier deleted on disk',
+    'program_built': 'a program came into being and has run once: '
+                     'program, kind, source (compile|disk|memory) and '
+                     'the seconds of its build by phase (wall, trace, '
+                     'lower, backend, cache_retrieval, first_call)',
     'serving_pool_recovered': 'donated decode failed mid-call; pool '
                               'rows rebuilt',
     # serving engine / router / tenancy

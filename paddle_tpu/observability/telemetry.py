@@ -7,6 +7,13 @@ Wires the passive sources into the registry:
   the host-side view of "where did my step go" that xprof's device
   traces assume the framework provides (upstream analogue: the
   to_static program-cache hit logs).
+- while the program store has a build open (`ProgramBuild`, around
+  `StoredJit._build`), the same listener books jax's own split of it —
+  trace, lowering, compile-or-fetch, the cache's retrieval — to that
+  build: `paddle_program_build_seconds_total{phase}`. With
+  `paddle_setup_seconds_total{phase}` (`import`, `construct`) these are
+  the counters a process's set-up is read from once its spans have
+  left the event ring.
 - a registry collector mirrors the eager dispatch cache's raw counters
   (paddle_tpu._dispatch) into `paddle_dispatch_*` metrics at snapshot
   time — zero per-op cost, `debug.dispatch_stats()` stays the raw view.
@@ -18,9 +25,11 @@ Wires the passive sources into the registry:
 from __future__ import annotations
 
 import collections
+import functools
 import gc
+import threading
 import time
-from typing import Optional
+from typing import Callable, List, Optional
 
 from . import metrics as _metrics
 
@@ -41,20 +50,200 @@ def _synthetic_span(name: str, secs: float):
     get_ledger().note_span(name, _events._now() - secs, secs)
 
 
+# jax's duration events that make up a program's coming into being, by
+# the last part of their names -> the phase they are booked under
+_PHASE_OF = {
+    'jaxpr_trace_duration': 'trace',
+    'jaxpr_to_mlir_module_duration': 'lower',
+    'backend_compile_duration': 'backend',
+    'cache_retrieval_time_sec': 'cache_retrieval',
+}
+
+BUILD_PHASES = ('wall', 'trace', 'lower', 'backend', 'cache_retrieval',
+                'first_call')
+
+
 def _on_jax_duration(name: str, secs: float, **kw):
     if not _metrics.enabled():
         return
+    phase = _PHASE_OF.get(name.rsplit('/', 1)[-1])
+    if phase is None:
+        return
     reg = _metrics.get_registry()
-    if name.endswith('backend_compile_duration'):
+    if phase == 'backend':
         reg.counter('paddle_jit_compiles_total',
                     'XLA backend compiles').inc()
         reg.counter('paddle_jit_compile_seconds_total',
                     'seconds spent in XLA backend compile').inc(secs)
         _synthetic_span('jit.compile', secs)
-    elif name.endswith('jaxpr_trace_duration'):
+    elif phase == 'trace':
         reg.counter('paddle_jit_trace_seconds_total',
                     'seconds spent tracing python to jaxpr').inc(secs)
         _synthetic_span('jit.trace', secs)
+    build = _build_of_this_event()
+    if build is not None:
+        build.book(phase, secs)
+
+
+# ---------------------------------------------------------------------------
+# a program's build, booked by phase
+# ---------------------------------------------------------------------------
+_open_builds: List['ProgramBuild'] = []   # append / remove under the GIL
+
+
+def _build_of_this_event() -> Optional['ProgramBuild']:
+    """The build a duration event belongs to: the one this thread has
+    open, else the newest open one — the attribution follows the build,
+    not the thread, so a compile the store hands to a helper thread is
+    still the build's. None outside any build (the harness's own jits,
+    an eager op): such an event moves the process-wide counters alone."""
+    if not _open_builds:
+        return None
+    tid = threading.get_ident()
+    for build in reversed(_open_builds):
+        if build.tid == tid:
+            return build
+    return _open_builds[-1]
+
+
+def _build_counter(family: str, help_: str, label: str, value: str):
+    return _metrics.get_registry().counter(
+        family, help_, (label,)).labels(**{label: value})
+
+
+def note_build_seconds(phase: str, secs: float):
+    _build_counter('paddle_program_build_seconds_total',
+                   'seconds the program store spent bringing programs '
+                   'into being, by phase: wall, and inside it trace, '
+                   'lower, backend (compile, or fetch from jax\'s cache '
+                   'and load; cache_retrieval lies inside it), then '
+                   'first_call', 'phase', phase).inc(secs)
+
+
+class ProgramBuild:
+    """One program coming into being, `with`-ed around
+    `StoredJit._build`: its wall time and, inside it, jax's own split,
+    which `_on_jax_duration` books here while the build is open. On exit
+    the numbers go to the program's `ProgramRecord`, to
+    `paddle_program_build_seconds_total{phase}` and to
+    `paddle_program_builds_total{source}`.
+
+    jax fires a duration for every region as it ENDS, so for every
+    jitted function traced inside another's trace before the outer one:
+    an interval that lies inside a later one is taken back out when the
+    later one arrives, and the phases of a build never sum past its
+    wall. `cache_retrieval` lies inside `backend` by jax's own nesting;
+    it is kept beside the split, not in it.
+
+    A build opened on a thread that already has one open (a stored
+    program called while another's function is being traced) is not a
+    build of its own: it books nothing, the outer one's trace covers it
+    (`live` False, as with observability off). The record's `wall` is
+    the catalog's and is kept either way, like `host_seconds`."""
+
+    __slots__ = ('record', 'source', 'seconds', 'live', 'tid', '_t0',
+                 '_top')
+
+    def __init__(self, record):
+        self.record = record
+        self.source = 'compile'       # the store says: compile|disk|memory
+        self.seconds = dict.fromkeys(BUILD_PHASES, 0.0)
+        self.live = False
+        self.tid = threading.get_ident()
+        self._top: list = []          # [start, end, phase]: not nested
+
+    def __enter__(self) -> 'ProgramBuild':
+        self.live = _metrics.enabled() and not any(
+            b.tid == self.tid for b in _open_builds)
+        if self.live:
+            _open_builds.append(self)
+        self._t0 = time.perf_counter()
+        return self
+
+    def book(self, phase: str, secs: float):
+        if phase == 'cache_retrieval':
+            self.seconds[phase] += secs
+            return
+        end = time.perf_counter()
+        start = end - secs
+        top = self._top
+        # an earlier interval whose middle lies in this one is nested in
+        # it (the two clocks' jitter is far below half a region)
+        while top and (top[-1][0] + top[-1][1]) / 2 >= start:
+            was = top.pop()
+            self.seconds[was[2]] -= was[1] - was[0]
+        top.append((start, end, phase))
+        self.seconds[phase] += secs
+
+    def __exit__(self, *exc):
+        wall = time.perf_counter() - self._t0
+        rec, s = self.record, self.seconds
+        s['wall'] = wall
+        rec.build_seconds += wall
+        if self.source != 'memory':
+            rec.compile_seconds += wall
+        if not self.live:
+            return
+        _open_builds.remove(self)
+        rec.trace_seconds += s['trace']
+        rec.lower_seconds += s['lower']
+        rec.backend_seconds += s['backend']
+        rec.cache_retrieval_seconds += s['cache_retrieval']
+        for phase in BUILD_PHASES[:-1]:
+            note_build_seconds(phase, s[phase])
+        _build_counter('paddle_program_builds_total',
+                       'programs the store brought into being, by where '
+                       'the executable came from', 'source',
+                       self.source).inc()
+
+    def first_call(self, secs: float):
+        """The first execution of the program this build made has
+        returned after `secs`: the last phase, and the build's one
+        `program_built` event with all six."""
+        from . import events as _events
+        s = self.seconds
+        s['first_call'] = secs
+        self.record.first_call_seconds += secs
+        if not _metrics.enabled():      # switched off since the build
+            return
+        note_build_seconds('first_call', secs)
+        _events.emit('program_built', program=self.record.name,
+                     kind=self.record.kind, source=self.source,
+                     **{f'{p}_seconds': round(s[p], 6)
+                        for p in BUILD_PHASES})
+
+
+# ---------------------------------------------------------------------------
+# the set-up outside the builds: the import and the constructors
+# ---------------------------------------------------------------------------
+def note_setup(phase: str, secs: float):
+    """`paddle_setup_seconds_total{phase}`: `import` (the package's own
+    body and whatever it is first to import) and `construct`
+    (`InferenceEngine.__init__`, `TrainStep.__init__`); no others."""
+    if _metrics.enabled():
+        _metrics.get_registry().counter(
+            'paddle_setup_seconds_total',
+            'seconds of a process\'s set-up outside its programs\' '
+            'builds, by phase', ('phase',)).labels(phase=phase).inc(secs)
+
+
+def constructing(span_name: str,
+                 attrs: Optional[Callable[[object], dict]] = None):
+    """Decorator for a constructor that is part of set-up: one span of
+    `span_name` around it (with `attrs(self)` once it has run), booked
+    to `paddle_setup_seconds_total{phase="construct"}`."""
+    def wrap(init):
+        @functools.wraps(init)
+        def timed(self, *args, **kwargs):
+            from . import events as _events
+            with _events.span(span_name) as sp:
+                init(self, *args, **kwargs)
+                if attrs is not None and _metrics.enabled():
+                    sp.set(**attrs(self))
+            if sp.dur:
+                note_setup('construct', sp.dur)
+        return timed
+    return wrap
 
 
 def _on_jax_event(name: str, **kw):

@@ -68,6 +68,7 @@ from .. import flags as _flags
 from .. import observability as _obs
 from ..analysis.runtime import concurrency as _concurrency
 from ..observability import cost as _cost
+from ..observability import telemetry as _telemetry
 
 _MANIFEST_VERSION = 1
 
@@ -759,7 +760,8 @@ class ProgramStore:
     # -- the acquisition path ------------------------------------------------
     def acquire(self, key: str, name: str, kind: str,
                 record: _cost.ProgramRecord, jitted, args,
-                persist: bool = True, donate_argnums=(), formats=()):
+                persist: bool = True, donate_argnums=(), formats=(),
+                build: Optional[_telemetry.ProgramBuild] = None):
         """Resolve one program key to an executable: memory tier, then
         the integrity-verified disk tier, then a fresh AOT compile of
         `jitted` at `args`.
@@ -775,28 +777,31 @@ class ProgramStore:
         that cannot be exported and compiled to them goes the
         'aot_noexport' way too). Returns the resolved `_StoreEntry`,
         or None when no AOT path works at all — callers fall back to
-        their plain jitted call."""
+        their plain jitted call. `build`, the caller's open
+        `ProgramBuild`, is told which of the three it was; the build is
+        the one timer (`ProgramRecord.compile_seconds` and the phases
+        beside it)."""
         with self._lock:
             ent = self._mem.get(key)
         if ent is not None:
+            if build is not None:
+                build.source = 'memory'
             self._note_hit(name, 'memory', ent.format)
             if ent.source == 'disk':
                 record.note = record.note or f'loaded:{ent.format}'
             return ent
         ent = self._load_disk(key, formats)
         if ent is not None:
-            t0 = time.perf_counter()
+            if build is not None:
+                build.source = 'disk'
             _cost._read_analysis(ent.callable, record)
             record.note = f'loaded:{ent.format}'
             with self._lock:
                 self._mem[key] = ent
-            with self.catalog._lock:
-                record.compile_seconds += time.perf_counter() - t0
             self._note_hit(name, 'disk', ent.format)
             return ent
         # cold: compile fresh
         persisting = persist and self.persistent
-        t0 = time.perf_counter()
         compiled = payload = None
         fmt = ''
         if persisting:
@@ -817,10 +822,8 @@ class ProgramStore:
                 return None   # no AOT path; caller serves the plain call
             if persisting:
                 record.note = 'aot_noexport'
-        dt = time.perf_counter() - t0
         with self.catalog._lock:
             record.compile_count += 1
-            record.compile_seconds += dt
         _cost._read_analysis(compiled, record)
         self._note_miss(name)
         ent = _StoreEntry(key, name, kind, compiled, 'compile', fmt,
@@ -1023,6 +1026,31 @@ class ProgramStore:
             self._preload = None
 
 
+class _FirstCall:
+    """What `StoredJit._build` stores as the callable of a program it
+    has just built: the program, for ONE call. That call is timed until
+    it returns (the executable's first execution: its load, its first
+    transfer), booked as the build's `first_call` phase with the
+    build's one `program_built` event, and the entry is put back as the
+    program itself — so a call of a signature already built finds the
+    executable and pays nothing for this."""
+
+    __slots__ = ('program', '_entries', '_key', '_build')
+
+    def __init__(self, program, entries, key, build):
+        self.program = program
+        self._entries, self._key, self._build = entries, key, build
+
+    def __call__(self, *args):
+        t0 = time.perf_counter()
+        try:
+            return self.program(*args)
+        finally:
+            secs = time.perf_counter() - t0
+            self._entries[self._key] = (self._build.record, self.program)
+            self._build.first_call(secs)
+
+
 _ON_HOST: Dict[type, bool] = {}   # leaf type -> not a device array
 _DTYPE_NAME: Dict[Any, str] = {}  # a leaf's dtype -> its name in a key
 
@@ -1111,8 +1139,10 @@ class StoredJit:
             except Exception:  # paddle-lint: disable=swallowed-exception -- naming must never fail a call; kind:unnamed IS the visible trace
                 name = f'{self._kind}:unnamed'   # naming must never fail
         record = self._store.catalog.record(name, kind=self._kind)
-        call = self._fn
-        if key is not None:
+        if key is None:
+            return record, self._fn
+        # the one place a program comes into being: booked by phase
+        with _telemetry.ProgramBuild(record) as build:
             ent = None
             if hasattr(self._fn, 'lower'):   # an opaque one has no AOT path
                 formats = pool_formats(self._pool_io)
@@ -1127,12 +1157,16 @@ class StoredJit:
                     ent = self._store.acquire(
                         skey, name, self._kind, record, self._fn, args,
                         persist=self._persist,
-                        donate_argnums=self._donate, formats=formats)
+                        donate_argnums=self._donate, formats=formats,
+                        build=build)
             if ent is not None:
                 call = ent.callable
             else:
+                call = self._fn
                 record.note = 'aot_unavailable'
-            self._entries[key] = (record, call)
+        if build.live:
+            call = _FirstCall(call, self._entries, key, build)
+        self._entries[key] = (record, call)
         return record, call
 
     def _resolve(self, args):
@@ -1158,7 +1192,10 @@ class StoredJit:
         wrapper has not met their signature — and not called: a caller
         with several programs over one set of arguments has them all
         compiled by the time it first runs one."""
-        return self._resolve(args)[:2]
+        record, call = self._resolve(args)[:2]
+        if isinstance(call, _FirstCall):    # built, and not yet run
+            call = call.program
+        return record, call
 
     def __call__(self, *args):
         """The host's part of a call by cause, as two spans named by the

@@ -317,6 +317,10 @@ class InferenceEngine:
     `step()`, `run()`, `stream()`, or `generate_many()`.
     """
 
+    @_obs.telemetry.constructing('serving.engine_init', lambda self: {
+        'slots': self.pool.num_slots, 'max_length': self.pool.max_length,
+        'pool_bytes': self.pool.pool_bytes,
+        'programs_resolved': self.programs_preloaded})
     def __init__(self, model, num_slots: int = 8, max_length: int = 256,
                  decode_block: int = 4,
                  buckets: Optional[Sequence[int]] = None,
@@ -679,12 +683,15 @@ class InferenceEngine:
                                      f'{args[3].shape[1]}',
                 kind='serving', statics=spec_statics)
         self._init_metrics()
+        # programs the construction itself resolved (the span's
+        # `programs_resolved`): those a persistent store held
+        self.programs_preloaded = 0
         if store.persistent:
             # cold-replica warm start: materialize persisted serving
             # executables BEFORE the first request (holds the
             # ref-counted /healthz `warming` state while loading);
             # idempotent, so sibling replicas after the first skip it
-            self.preload_programs()
+            self.programs_preloaded = self.preload_programs()['loaded']
 
     def preload_programs(self) -> dict:
         """Bulk-load this engine's persisted executables (decode block,

@@ -117,6 +117,29 @@ def test_zero_sharded_step_equals_unsharded():
     assert base[2] < base[0]  # actually learning
 
 
+def test_a_dist_train_steps_construction_is_one_span_booked_as_set_up():
+    """`train.step_init`, as around `jit.TrainStep.__init__` (ISSUE 48):
+    the placement of the parameters is part of what it times."""
+    from paddle_tpu import observability as obs
+    strategy = fleet.DistributedStrategy()
+    strategy.hybrid_configs = {'dp_degree': 8, 'mp_degree': 1,
+                               'pp_degree': 1, 'sep_degree': 1}
+    fleet.init(is_collective=True, strategy=strategy)
+    m = _Mlp()
+    fleet.distributed_model(m)
+    opt = paddle.optimizer.Adam(learning_rate=1e-2,
+                                parameters=m.parameters())
+    reg = obs.get_registry()
+    before = reg.value('paddle_setup_seconds_total', phase='construct')
+    seq = max((e['seq'] for e in obs.get_event_log().events()), default=0)
+    fleet.DistTrainStep(m, lambda out, lab: F.cross_entropy(out, lab), opt,
+                        strategy=strategy)
+    (ev,) = [e for e in obs.get_event_log().events()
+             if e['seq'] > seq and e['name'] == 'train.step_init']
+    assert reg.value('paddle_setup_seconds_total', phase='construct') \
+        - before == pytest.approx(ev['dur'])
+
+
 @pytest.mark.parametrize('causal', [True, False])
 def test_ring_attention_matches_full(causal):
     env.init_parallel_env((1, 1, 8, 1), ('pp', 'dp', 'sp', 'mp'))

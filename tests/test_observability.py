@@ -410,6 +410,70 @@ class TestRuntimeInstrumentation:
         assert reg.value('paddle_jit_compiles_total') >= before + 1
         assert reg.value('paddle_jit_compile_seconds_total') > 0
 
+    @pytest.mark.parametrize('case', ['flat', 'nested', 'outside'])
+    def test_a_builds_phases_from_jaxs_four_durations(self, case,
+                                                      monkeypatch):
+        """`_on_jax_duration` books trace, lowering, compile-or-fetch
+        and the cache's retrieval to the build that is open — an
+        interval that lies inside a later one once, with the outer — and
+        to nothing new when none is (ISSUE 48). Made-up events on a
+        made-up clock."""
+        from paddle_tpu.observability import cost, telemetry
+        now = [100.0]
+        import types
+        monkeypatch.setattr(telemetry, 'time', types.SimpleNamespace(
+            perf_counter=lambda: now[0]))   # this module's clock alone
+
+        def fire(event, at, secs):
+            now[0] = at                 # jax fires a region as it ends
+            telemetry._on_jax_duration(f'/jax/core/compile/{event}', secs,
+                                       fun_name='f')
+
+        reg = obs.get_registry()
+        rec = cost.ProgramRecord('made.up', 'serving')
+        if case == 'outside':
+            telemetry.note_build_seconds('trace', 0.0)      # declared
+            fam = reg.get('paddle_program_build_seconds_total')
+            before = dict((k, c.value) for k, c in fam.children())
+            traced = reg.value('paddle_jit_trace_seconds_total')
+            fire('jaxpr_trace_duration', 101.0, 1.0)
+            fire('jaxpr_to_mlir_module_duration', 102.0, 1.0)
+            fire('an_event_of_no_phase', 103.0, 1.0)
+            assert dict((k, c.value) for k, c in fam.children()) == before
+            assert reg.value('paddle_jit_trace_seconds_total') \
+                == traced + 1.0
+            return
+        with telemetry.ProgramBuild(rec) as build:
+            assert build.live
+            if case == 'nested':
+                # two layers traced inside the outer trace, and an eager
+                # op compiled while it ran: 0.5 + 0.25 + 0.125 s inside
+                fire('jaxpr_trace_duration', 100.75, 0.5)
+                fire('backend_compile_duration', 101.0, 0.125)
+                fire('jaxpr_trace_duration', 101.5, 0.25)
+            fire('jaxpr_trace_duration', 102.0, 1.75)
+            fire('jaxpr_to_mlir_module_duration', 103.0, 1.0)
+            now[0] = 104.5
+            telemetry._on_jax_duration(
+                '/jax/compilation_cache/cache_retrieval_time_sec', 1.25)
+            fire('backend_compile_duration', 105.0, 1.5)
+            now[0] = 106.0
+        assert build.seconds == {
+            'wall': 6.0, 'trace': 1.75, 'lower': 1.0, 'backend': 1.5,
+            'cache_retrieval': 1.25, 'first_call': 0.0}
+        assert (rec.build_seconds, rec.trace_seconds, rec.lower_seconds,
+                rec.backend_seconds, rec.cache_retrieval_seconds) \
+            == (6.0, 1.75, 1.0, 1.5, 1.25)
+        assert rec.compile_seconds == 6.0 and not telemetry._open_builds
+        # a build opened inside another on the same thread is the outer
+        # one's: it books nothing of its own
+        with telemetry.ProgramBuild(rec) as outer:
+            with telemetry.ProgramBuild(
+                    cost.ProgramRecord('inner', 'jit')) as inner:
+                fire('jaxpr_trace_duration', 107.0, 0.5)
+            assert not inner.live and inner.seconds['trace'] == 0.0
+        assert outer.seconds['trace'] == 0.5
+
     def test_observability_summary_sections(self):
         text = debug.observability_summary()
         for field in ('dispatch:', 'hit_rate', 'jit:', 'compiles',

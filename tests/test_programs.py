@@ -838,6 +838,215 @@ def test_every_route_compiles_through_one_function(route, open_store,
 
 
 # ---------------------------------------------------------------------------
+# a build books itself by phase (ISSUE 48)
+# ---------------------------------------------------------------------------
+
+_BUILD_FAMILY = 'paddle_program_build_seconds_total'
+
+
+def _build_seconds():
+    """phase -> the counter family's running seconds ({} undeclared)."""
+    fam = obs.get_registry().get(_BUILD_FAMILY)
+    return {} if fam is None else {
+        key[0]: child.value for key, child in fam.children()}
+
+
+def _builds(source):
+    return obs.get_registry().value('paddle_program_builds_total',
+                                    source=source)
+
+
+def _moved(before):
+    now = _build_seconds()
+    return {p: now.get(p, 0.0) - before.get(p, 0.0)
+            for p in obs.telemetry.BUILD_PHASES}
+
+
+def _phases_of(record):
+    return {f: getattr(record, f) for f in obs.cost.BUILD_FIELDS}
+
+
+def _layered():
+    """-> a NEW function (jax keeps what it traced and lowered by the
+    function) whose layers are jitted functions of their own: jax times
+    each inner trace, and the outer one around them all."""
+    def layered(x, y):
+        layer = jax.jit(lambda h, w: jnp.tanh(h @ w))
+        for _ in range(6):
+            x = layer(x, y)
+        return jnp.sin(x) @ y
+    return layered
+
+
+@pytest.mark.parametrize('route', ['direct', 'cold_export', 'warm_load',
+                                   'memory', 'helper_thread'])
+def test_a_build_books_itself_by_phase(route, open_store, monkeypatch,
+                                       tmp_path):
+    """One `StoredJit._build` is one build: its wall, jax's own split
+    inside it (never past the wall, a nested trace booked once), one
+    `program_built` event once it has run, one `first_call` — on every
+    route to an executable, and with the compile on another thread than
+    the build's."""
+    directory = str(tmp_path / 'store') if route in (
+        'cold_export', 'warm_load') else None
+    store = open_store(directory)
+
+    def build():
+        return store_mod.get_store().wrap_jit(
+            _layered(), name=f'test.build_{route}', kind='serving',
+            statics={'route': route})
+
+    x, y = _args()
+    if route == 'warm_load':
+        build()(x, y)
+        store = open_store(directory)
+    elif route == 'memory':
+        build()(x, y)           # a sibling wrapper compiled it
+    elif route == 'helper_thread':
+        room = store_mod._with_stack_room
+
+        def on_a_thread(fn, *args):
+            out = []
+            t = threading.Thread(
+                target=lambda: out.append(room(fn, *args)))
+            t.start()
+            t.join()
+            return out[0]
+        monkeypatch.setattr(store_mod, '_with_stack_room', on_a_thread)
+    source = {'warm_load': 'disk', 'memory': 'memory'}.get(route, 'compile')
+    before, n_before = _build_seconds(), _builds(source)
+    events = len(_recent_events('program_built'))
+    w = build()
+    had = _phases_of(store_mod.get_store().catalog.record(
+        f'test.build_{route}', kind='serving'))
+    record, call = w.resolve(x, y)          # built, and not run
+    got = _moved(before)
+    mine = {f: v - had[f] for f, v in _phases_of(record).items()}
+    assert _builds(source) == n_before + 1
+    assert got['first_call'] == 0.0 and not hasattr(call, 'program')
+    assert len(_recent_events('program_built')) == events
+    split = got['trace'] + got['lower'] + got['backend']
+    assert got['wall'] >= split
+    if route == 'memory':
+        assert split == 0.0
+    else:
+        assert got['lower'] > 0 and got['backend'] > 0
+        # the exported module is traced as one call; the function
+        # itself, with its six inner traces inside the outer one, on
+        # the routes that trace it
+        assert got['trace'] > 0
+    assert mine['build_seconds'] == pytest.approx(got['wall']) \
+        and got['wall'] > 0
+    assert mine['trace_seconds'] + mine['lower_seconds'] \
+        + mine['backend_seconds'] == pytest.approx(split)
+    w(x, y)
+    got = _moved(before)
+    assert got['first_call'] > 0
+    assert record.first_call_seconds - had['first_call_seconds'] \
+        == pytest.approx(got['first_call'])
+    (ev,) = _recent_events('program_built')[events:]
+    assert ev['attrs']['program'] == f'test.build_{route}'
+    assert (ev['attrs']['kind'], ev['attrs']['source']) \
+        == ('serving', source)
+    assert {f'{p}_seconds' for p in obs.telemetry.BUILD_PHASES} \
+        <= set(ev['attrs'])
+    assert ev['attrs']['wall_seconds'] == pytest.approx(got['wall'],
+                                                        abs=1e-5)
+    # a second call of the same signature: nothing booked, no event,
+    # and no span beside the two of every call
+    log = obs.get_event_log()
+    seq = max(e['seq'] for e in log.events())
+    settled = _build_seconds()
+    w(x, y)
+    assert _build_seconds() == settled
+    assert _builds(source) == n_before + 1
+    assert [e['name'] for e in log.events() if e['seq'] > seq] \
+        == ['serving.program_resolve', 'serving.program_call']
+    assert record.invocations >= 2
+
+
+def test_a_nested_trace_is_booked_once(open_store):
+    """jax fires a trace duration for every jitted function traced
+    inside another's trace, inner ones first: the process-wide counter
+    takes them all, the build only the outermost."""
+    open_store(None)
+    reg = obs.get_registry()
+    traced = reg.value('paddle_jit_trace_seconds_total')
+    before = _build_seconds()
+    w = store_mod.get_store().wrap_jit(
+        _layered(), name='test.nested', statics={})
+    w.resolve(*_args())
+    got = _moved(before)
+    every = reg.value('paddle_jit_trace_seconds_total') - traced
+    assert 0 < got['trace'] < every
+    assert got['trace'] + got['lower'] + got['backend'] <= got['wall']
+
+
+def test_a_jit_outside_any_build_moves_only_the_process_counters():
+    reg = obs.get_registry()
+    store_mod.get_store().wrap_jit(
+        lambda x: x + 1.0, name='test.declares', statics={})(
+            jnp.float32(1.0))           # the families exist
+    before = _build_seconds()
+    built = reg.get('paddle_program_builds_total').total()
+    marks = (reg.value('paddle_jit_compiles_total'),
+             reg.value('paddle_jit_compile_seconds_total'),
+             reg.value('paddle_jit_trace_seconds_total'))
+    events = len(_recent_events('program_built'))
+    out = jax.jit(lambda a: jnp.cos(a) * 3.0 + 0.125)(jnp.ones((5, 3)))
+    assert out.shape == (5, 3)
+    assert reg.value('paddle_jit_compiles_total') > marks[0]
+    assert reg.value('paddle_jit_compile_seconds_total') > marks[1]
+    assert reg.value('paddle_jit_trace_seconds_total') > marks[2]
+    assert _build_seconds() == before
+    assert reg.get('paddle_program_builds_total').total() == built
+    assert len(_recent_events('program_built')) == events
+
+
+def test_with_observability_off_a_build_books_nothing(open_store):
+    open_store(None)
+    before = _build_seconds()
+    built = obs.get_registry().get('paddle_program_builds_total')
+    built = built.total() if built is not None else 0.0
+    events = len(obs.get_event_log())
+    obs.disable()
+    try:
+        w = store_mod.get_store().wrap_jit(
+            _layered(), name='test.dark', statics={})
+        x, y = _args()
+        record, call = w.resolve(x, y)
+        assert (np.asarray(w(x, y)) == np.asarray(call(x, y))).all()
+        paddle.jit.TrainStep       # the decorated constructors still run
+        _mlp_step()
+    finally:
+        obs.enable()
+    assert _build_seconds() == before
+    fam = obs.get_registry().get('paddle_program_builds_total')
+    assert (fam.total() if fam is not None else 0.0) == built
+    assert len(obs.get_event_log()) == events
+    # the catalog's own wall is kept, like `host_seconds`
+    assert record.build_seconds > 0 and record.compile_seconds > 0
+    assert record.trace_seconds == record.first_call_seconds == 0.0
+    assert record.invocations == 1
+
+
+def test_top_programs_carries_the_phases(open_store):
+    open_store(None)
+    w = store_mod.get_store().wrap_jit(
+        _layered(), name='test.table', statics={})
+    w(*_args())
+    row, = [r for r in store_mod.get_store().catalog.top_programs(n=1000)
+            if r['name'] == 'test.table']
+    assert row['build_seconds'] >= row['trace_seconds'] \
+        + row['lower_seconds'] + row['backend_seconds'] > 0
+    assert row['first_call_seconds'] > 0
+    head = store_mod.get_store().catalog.report().splitlines()[1]
+    for column in ('compile s', 'build s', 'trace s', 'lower s',
+                   'backend s', '1st call s'):
+        assert column in head
+
+
+# ---------------------------------------------------------------------------
 # warm restart: serving replica
 # ---------------------------------------------------------------------------
 

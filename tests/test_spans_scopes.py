@@ -459,6 +459,71 @@ def test_train_step_spans(toy_step, log):
             >= 0.95 * ev['train.step']['dur'])
 
 
+def _construct_seconds():
+    return obs.get_registry().value('paddle_setup_seconds_total',
+                                    phase='construct')
+
+
+def test_an_engines_construction_is_one_span_booked_as_set_up(gpt, log):
+    """`serving.engine_init` around `InferenceEngine.__init__` — the
+    pool's allocation, the slot state, the wrappers — with what it made
+    as scalars, and its wall under `phase="construct"`."""
+    before = _construct_seconds()
+    eng = InferenceEngine(gpt, num_slots=3, max_length=64, decode_block=2)
+    (ev,) = _spans(log, 'serving.engine_init')
+    assert ev['attrs'] == {'slots': 3, 'max_length': 64,
+                           'pool_bytes': eng.pool.pool_bytes,
+                           'programs_resolved': 0}
+    assert eng.pool.pool_bytes > 0 and ev['parent'] == 0
+    assert _construct_seconds() - before == pytest.approx(ev['dur'])
+    # nothing is built by constructing: the programs come with the
+    # first step, inside `serving.router_step`
+    assert not _spans(log, 'serving.program_')
+    # every replica of a set constructs its own engine
+    Router(ReplicaSet(gpt, 2, num_slots=2, max_length=64, decode_block=2))
+    assert len(_spans(log, 'serving.engine_init')) == 3
+    assert _construct_seconds() - before == pytest.approx(
+        sum(e['dur'] for e in _spans(log, 'serving.engine_init')))
+
+
+def test_a_train_steps_construction_is_one_span_booked_as_set_up(log):
+    before = _construct_seconds()
+    paddle.seed(3)
+    model = GPTForCausalLM(GPTConfig.tiny())
+    opt = paddle.optimizer.AdamW(learning_rate=1e-3,
+                                 parameters=model.parameters())
+    TrainStep(model, lambda out, lab: out.mean(), opt)
+    (ev,) = _spans(log, 'train.step_init')
+    assert ev['parent'] == 0 and 'attrs' not in ev
+    assert _construct_seconds() - before == pytest.approx(ev['dur'])
+    assert _spans(log, 'train.') == [ev]        # constructing runs nothing
+
+
+def test_a_disabled_constructor_span_books_nothing(gpt, log):
+    before = _construct_seconds()
+    obs.disable()
+    try:
+        eng = InferenceEngine(gpt, num_slots=2, max_length=64,
+                              decode_block=2)
+    finally:
+        obs.enable()
+    assert eng.pool.num_slots == 2
+    assert not _spans(log, 'serving.engine_init')
+    assert _construct_seconds() == before
+
+
+def test_the_import_is_booked_once_as_set_up():
+    """`paddle_tpu/__init__.py` reads the clock at its first and last
+    line: the package's own body and whatever it was first to import."""
+    fam = obs.get_registry().get('paddle_setup_seconds_total')
+    assert fam is not None and fam.labelnames == ('phase',)
+    phases = {key[0]: child.value for key, child in fam.children()}
+    assert set(phases) <= {'import', 'construct'}
+    assert 0 < phases['import'] < 600
+    src = open(os.path.join(PKG, '__init__.py')).read().strip().splitlines()
+    assert '_T_IMPORT' in src[-1] and "'import'" in src[-2]
+
+
 def test_scope_vocabulary_is_pinned(toy_step, gpt):
     """Every name of the vocabulary is on the toy train step's or the
     decode program's HLO, and no scope of the models' is outside it."""
