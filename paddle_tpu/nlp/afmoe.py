@@ -41,12 +41,12 @@ backend) is `_attention_xla` over the rows the mask has columns for.
 
 `expert_bias` is a buffer upstream; here it is a frozen parameter (it is
 state a checkpoint fills, and `named_parameters()` is how weights reach
-a model in this repo). The expert layer SERVES, on one of two schedules
+a model in this repo). The expert layer SERVES, on one of three schedules
 of the same sum, picked by backend and shape (`ops.pallas.expert_kernel`):
-a call one block wide on a TPU — a decode sub-step — is ONE pallas kernel
-that streams the touched experts' weights; every other call is
-`grouped_experts`, a `lax.while_loop` over blocks. Neither has a
-reverse-mode derivative — training an expert layer is ROADMAP's.
+on a TPU a call one block wide — a decode sub-step — is ONE pallas kernel
+that streams the touched experts' weights and a wider one — a prefill —
+ONE grouped matmul over the sorted picks; every other call is the loop
+`grouped_experts`. None has a reverse mode — training one is ROADMAP's.
 """
 from __future__ import annotations
 
@@ -351,13 +351,13 @@ def grouped_experts(x, sel, w, gate_w, up_w, down_w):
     expert and walked by no block; what its row of `ys` holds is some
     block's overhang, so its weight must be zero, and it adds nothing.
 
-    This is the path of every backend but a TPU, of a prefill
-    everywhere, and the parity ground truth of the kernel that takes a
-    one-block call on a TPU (`ops.pallas.expert_kernel`): there an
-    iteration starts its three products' weight streams cold, nothing
-    fetches the next expert meanwhile, and `precision='high'` pushes
-    every weight tile through the MXU three times — 29 and 40 us an
-    expert where the bytes are 15 and 23 (PERF.md section 6, PR 31)."""
+    This is the path of every backend but a TPU and of leaves that are
+    not bf16, and the parity ground truth of the two kernels that take
+    a TPU's calls (`ops.pallas.expert_kernel`: one for a call one block
+    wide, PR 31, one for a wider, PR 49): there
+    an iteration starts its three products' weight streams cold, nothing
+    fetches the next expert meanwhile, `g` and `u` go through HBM, and
+    every expert's last block is walked whole (PERF.md section 6)."""
     t, h = x.shape
     k, e = sel.shape[1], gate_w.shape[0]
     n = t * k
@@ -458,8 +458,8 @@ class AfmoeSparseMLP(Layer):
                 precision=jax.lax.Precision.HIGHEST))
             return route(scores, bias, k, norm, scale, eps, *groups)
 
-        # by backend and shape, nothing else: one kernel for a call one
-        # block wide on a TPU, the loop over blocks everywhere else
+        # by backend, leaf dtype and shape, nothing else: on a TPU one of
+        # two kernels by the call's width, else the loop over blocks
         kernel = expert_kernel(math.prod(x.shape[:-1]), BLOCK_ROWS,
                                to_jax(self.gate_w).dtype)
         routed_experts = kernel or grouped_experts
@@ -658,3 +658,30 @@ class AfmoeForCausalLM(AfmoePretrainedModel, GenerationMixin):
         return tuple(bounded_decode_tile(l.self_attn.num_heads, entry,
                                          slots, rows)
                      for l, entry in zip(self.model.layers, cache))
+
+    def scan_chunks(self, tokens):
+        """`expert_kernel_layers`, for the serving engine to say on
+        `serving.prefill` of a bucket of `tokens` tokens."""
+        return expert_kernel_layers(self, tokens)
+
+
+def expert_kernel_layers(model, tokens):
+    """`{'expert_kernel_layers': n}`: the expert layers of `model` whose
+    routed experts a call of `tokens` tokens — a whole prefill's bucket
+    — is dispatched to the grouped kernel (`ops.pallas.expert_kernel`
+    asked as each layer's `forward` asks it, and what it answers read):
+    all of them on a TPU over bf16 leaves where the bucket is more than
+    one block wide, none where the loop or the one-block kernel runs.
+    The DISPATCH's answer, not a count of the program's kernels: a
+    prefill's program holds one fewer, because nothing a prefill returns
+    is fed by the last layer's MLP and the compiler drops it, loop or
+    kernel. What a family's `scan_chunks` says, and the serving engine
+    on `serving.prefill` after it."""
+    def grouped(layer):
+        kernel = expert_kernel(tokens, BLOCK_ROWS,
+                               to_jax(layer.gate_w).dtype)
+        return kernel is not None \
+            and kernel.func.__name__ == 'moe_grouped_experts'
+    return {'expert_kernel_layers': sum(
+        grouped(layer) for layer in model.sublayers()
+        if isinstance(layer, AfmoeSparseMLP))}

@@ -94,7 +94,7 @@ from ..nn.common_layers import Embedding, Linear
 from ..nn.norm import RMSNorm
 from ..ops import pallas as _pallas
 from ..tensor import Tensor, apply_op, to_jax
-from .afmoe import ACTIVATION_PRECISION, AfmoeSparseMLP
+from .afmoe import ACTIVATION_PRECISION, AfmoeSparseMLP, expert_kernel_layers
 from .generation import (GenerationMixin, active_rows as _active_rows,
                          as_offset as _as_offset,
                          attended_rows as _attended_rows,
@@ -700,3 +700,8 @@ class DeepseekV3ForCausalLM(DeepseekV3PretrainedModel, GenerationMixin):
     # what a whole prefill's attention computes a layer, for the serving
     # engine to say on `serving.prefill`
     own_tokens_pairs = staticmethod(own_tokens_pairs)
+
+    # the expert layers whose routed experts a whole prefill's program
+    # runs as the grouped kernel, for the serving engine to say on
+    # `serving.prefill` (`model.scan_chunks(bucket)`)
+    scan_chunks = expert_kernel_layers

@@ -47,7 +47,7 @@ from ..nn.common_layers import Embedding
 from ..nn.norm import RMSNorm
 from ..tensor import Tensor, apply_op, to_jax
 from .afmoe import (ACTIVATION_PRECISION, FULL, AfmoeAttention,
-                    AfmoeSparseMLP)
+                    AfmoeSparseMLP, expert_kernel_layers)
 from .generation import (GenerationMixin, bounded_decode_tile,
                          folded_tokens)
 from .llama import LlamaMLP, _col_linear, _row_linear
@@ -384,3 +384,8 @@ class Lfm2MoeForCausalLM(Lfm2MoePretrainedModel, GenerationMixin):
             'speculative decoding rejects a draft by moving the position '
             'back, and a conv layer\'s state cannot be moved back: it '
             'needs a snapshot of the state per proposed token (ROADMAP)')
+
+    # the expert layers whose routed experts a whole prefill's program
+    # runs as the grouped kernel, for the serving engine to say on
+    # `serving.prefill` (`model.scan_chunks(bucket)`)
+    scan_chunks = expert_kernel_layers

@@ -605,8 +605,10 @@ class Ling3ForCausalLM(DeepseekV3ForCausalLM):
     def scan_chunks(self, tokens):
         """Chunks ONE KDA layer scans, one after another, in a call of
         `tokens` tokens (a whole prefill's bucket), under the name the
-        serving engine says it by on `serving.prefill`."""
-        return {'kda_chunks': -(-tokens // KDA_CHUNK)}
+        serving engine says it by on `serving.prefill`, beside what the
+        expert layers say (`afmoe.expert_kernel_layers`)."""
+        return {'kda_chunks': -(-tokens // KDA_CHUNK),
+                **super().scan_chunks(tokens)}
 
     def state_kernel_layers(self, cache, slots):
         """How many of `cache`'s state entries a decode sub-step of

@@ -68,7 +68,7 @@ from ..nn.norm import RMSNorm
 from ..ops import pallas as _pallas
 from ..tensor import Tensor, apply_op, to_jax
 from .afmoe import (ACTIVATION_PRECISION, AfmoeSparseMLP, _narrow,
-                    _window_mask)
+                    _window_mask, expert_kernel_layers)
 from .generation import (GenerationMixin, as_offset as _as_offset,
                          attended_rows as _attended_rows,
                          bounded_decode_attention, bounded_decode_tile,
@@ -512,3 +512,8 @@ class MiMoV2ForCausalLM(MiMoV2PretrainedModel, GenerationMixin):
             'speculative decoding rejects a draft by moving the position '
             'back, and a ring cannot be moved back: the rejected tokens '
             'have already replaced the rows a window back (ROADMAP)')
+
+    # the expert layers whose routed experts a whole prefill's program
+    # runs as the grouped kernel, for the serving engine to say on
+    # `serving.prefill` (`model.scan_chunks(bucket)`)
+    scan_chunks = expert_kernel_layers

@@ -6,9 +6,9 @@ default path is plain jax — XLA already fuses normalization chains into
 adjacent matmuls — and the pallas kernels (ops/pallas_kernels.py) take
 over on TPU backends for six inner loops where a hand-written schedule
 beats the XLA-generated one: attention over a call's own tokens
-(`flash_attention`), the routed experts of a decode batch
-(`expert_kernel`: one weight stream, where XLA's `while` fetches each
-expert cold), decode attention over latent rows (`latent_decode_kernel`:
+(`flash_attention`), an expert layer's routed experts (`expert_kernel`:
+one weight stream, the next expert in flight, where XLA's `while`
+fetches each cold), decode attention over latent rows (`latent_decode_kernel`:
 a slot's row tiles read once where XLA's einsums stream every row
 twice), its sibling for float32 queries over K and V by head
 (`kv_decode_kernel`), a KDA layer's recurrence of one token
@@ -185,24 +185,35 @@ def flash_attention(q, k, v, mask=None, causal=False, dropout_p=0.0,
 
 
 def expert_kernel(tokens, block_rows, weight_dtype, interpret=False):
-    """Dispatch for an expert layer's routed experts: the pallas kernel
-    `pallas_kernels.moe_decode_experts` (same arguments as the caller's
-    loop over blocks, `nlp/afmoe.py::grouped_experts`) where it applies,
-    None where the loop runs. The kernel takes a call that is ONE block
-    wide — `tokens <= block_rows`: a decode sub-step, speculation's k+1
-    rows, where every touched expert multiplies every row either way —
-    on a TPU (or anywhere with interpret=True), over bf16 leaves (its
-    three-part product is exact against them alone). A prefill's blocks
-    of `block_rows` rows are compute, not bytes, and keep the loop; so
-    does every other backend, where the loop is the tier-1 path and the
-    parity ground truth. The conditions are the whole selection: a
-    kernel error on a TPU propagates."""
-    if ((interpret or _pallas_enabled()) and tokens <= block_rows
+    """Dispatch for an expert layer's routed experts: one of two pallas
+    kernels (same arguments as the caller's loop over blocks, `nlp/
+    afmoe.py::grouped_experts`) where one applies, None where the loop
+    runs. Both want a TPU (or interpret=True anywhere) and bf16 leaves
+    (their products of the activations' bf16 parts are exact against
+    them alone); the call's width picks between them, as the loop's
+    block does:
+
+    - ONE block wide, `tokens <= block_rows` — a decode sub-step,
+      speculation's k+1 rows, where every touched expert multiplies
+      every row either way: `pallas_kernels.moe_decode_experts`, one
+      weight stream over the distinct experts the batch picked.
+    - wider — a whole prefill, a chunk or a prefix attach of more than a
+      block: `pallas_kernels.moe_grouped_experts`, one grouped matmul
+      over the picks sorted by expert in tiles of 128 rows, the next
+      tile's expert in flight, `g` and `u` never in HBM.
+
+    Every other backend and every other leaf dtype keep the loop: there
+    it is the tier-1 path and the parity ground truth. The conditions
+    are the whole selection: a kernel error on a TPU propagates."""
+    if not ((interpret or _pallas_enabled())
             and weight_dtype == jnp.bfloat16):
-        from . import pallas_kernels
+        return None
+    from . import pallas_kernels
+    if tokens <= block_rows:
         return functools.partial(pallas_kernels.moe_decode_experts,
                                  interpret=interpret)
-    return None
+    return functools.partial(pallas_kernels.moe_grouped_experts,
+                             interpret=interpret)
 
 
 def _one_query_a_slot(q, mask):

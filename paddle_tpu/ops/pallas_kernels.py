@@ -17,9 +17,9 @@ Contents:
   analytic custom VJP.
 - `paged_attention`, `adapter_matmul` — scalar-prefetch kernels of the
   serving engine (a page table; a packed adapter bank).
-- `moe_decode_experts(x, sel, w, gate_w, up_w, down_w)` — an expert
-  layer's routed experts for a decode batch: one weight stream over the
-  distinct experts the batch picked (dispatch: `ops.pallas.expert_kernel`).
+- `moe_decode_experts`, `moe_grouped_experts(x, sel, w, gate_w, up_w,
+  down_w)` — an expert layer's routed experts: a decode batch's, and a
+  prefill's sorted picks by tile (dispatch: `ops.pallas.expert_kernel`).
 - `mla_decode_attention(q_lat, q_rope, c, r, seen, scale)` — decode
   attention over latent rows bounded per slot (`latent_decode_kernel`).
 - `kv_decode_attention(q, k, v, seen, scale)` — its sibling for float32
@@ -1714,3 +1714,199 @@ def ssm_prefill_scan(u, dt, b, c, a, d, h0, *, interpret=False):
     )(rows(u), rows(dt), rows(b, True), rows(c, True),
       a.T.astype(jnp.float32), d.astype(jnp.float32)[None], h0)
     return y[:, :s], h
+
+
+# ---------------------------------------------------------------------------
+# routed experts of a call MORE than one block wide (ISSUE 49; upstream
+# analogue: megablox's grouped matmul, `jax.experimental.pallas.ops.tpu.
+# megablox`). The picks sorted by expert are cut into aligned tiles of
+# rows; a tile is visited once for every expert that has rows in it,
+# one after another, and a visit multiplies the whole tile by that
+# expert's weights and keeps the rows that are the expert's. The grid
+# walks (visit, tile of f): the weights' BlockSpecs read the visit's
+# expert from a prefetched table, so Pallas' double buffering has the
+# next expert in flight while this one multiplies, and the gate, up,
+# SwiGLU and down of a tile never leave VMEM. XLA's `while` over blocks
+# (`nlp/afmoe.py::grouped_experts`) starts every block's three weight
+# streams cold and sends `g` and `u` through HBM.
+# ---------------------------------------------------------------------------
+
+# an expert's three weight tiles, both buffers of each: what `f` is cut by
+_GROUPED_WEIGHT_VMEM = 56 << 20
+
+
+# rows of a tile (whole packed bf16 tiles of 16 rows). A tile that
+# straddles two experts is multiplied once for each, so a layer walks
+# a tile an expert more than its picks: on the chip
+# 128 and 64 level with each other and a sixth to a half faster than 256
+# at all six cells' shapes, from 24 rows an expert (mimo) to 640 (xing4)
+# — 64 walks fewer rows and feeds the MXU half-empty (CHANGES.md, PR 49)
+_GROUPED_ROW_TILE = 128
+
+
+def _grouped_f_tile(h, f):
+    """How much of an expert's `f` a grid step takes: all of it where the
+    three weight tiles fit VMEM twice over (then a tile of rows that
+    follows one of the same expert fetches nothing), else the most whole
+    lanes that divide it and do (mimo's expert is 50 MB)."""
+    fits = [n for n in range(128, f, 128)
+            if f % n == 0 and 12 * h * n <= _GROUPED_WEIGHT_VMEM]
+    return f if 12 * h * f <= _GROUPED_WEIGHT_VMEM or not fits else max(fits)
+
+
+def _moe_grouped_kernel(ex_ref, tile_ref, lo_ref, hi_ref, x_ref, g_ref,
+                        u_ref, d_ref, o_ref, xs_ref):
+    """Grid (visits, tiles of f), both `arbitrary`: visit `i` is tile
+    `tile[i]` of the sorted rows against expert `ex[i]`, whose rows are
+    `lo[i] <= row < hi[i]`. The output's tile stays in VMEM over a
+    tile's visits (they follow one another): the first zeroes it and
+    splits the rows, each adds `(silu(x G_j) * (x U_j)) D_j` on the
+    expert's own rows."""
+    i, j = pl.program_id(0), pl.program_id(1)
+    tm = o_ref.shape[0]
+
+    @pl.when((j == 0) & ((i == 0)
+                        | (tile_ref[i] != tile_ref[jnp.maximum(i - 1, 0)])))
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+        xs_ref[...] = _split2(x_ref[...])
+
+    def dot(parts, w):      # a bf16 tile has no low part: two products
+        return _dot_high(parts, tm, w, w.shape[0], ((1,), (0,)))
+    xs = xs_ref[...]
+    g, u = dot(xs, g_ref[0]), dot(xs, u_ref[0])
+    y = dot(_split2(jax.nn.silu(g) * u), d_ref[0])
+    row = tile_ref[i] * tm + jax.lax.broadcasted_iota(jnp.int32, (tm, 1), 0)
+    o_ref[...] += jnp.where((row >= lo_ref[i]) & (row < hi_ref[i]), y, 0.0)
+
+
+@functools.partial(jax.jit, static_argnames=('tile', 'f_tile', 'interpret'))
+def _moe_grouped(x, sel, w, gate_w, up_w, down_w, *, tile, f_tile,
+                 interpret):
+    t, h = x.shape
+    k, (e, _, f) = sel.shape[1], gate_w.shape
+    n, nf = t * k, f // f_tile
+    tiles = -(-n // tile)
+    # the sort is `grouped_experts`': a pick of no expert held here
+    # (`sel == e`) sorts behind every real one and is counted for none
+    flat = sel.reshape(n)
+    order = jnp.argsort(flat, stable=True)        # sorted row -> pick
+    counts = jnp.zeros(e, jnp.int32).at[flat].add(1)
+    last = jnp.cumsum(counts)
+    first = last - counts
+    # tiny, in XLA: the tiles an expert's rows lie in, and the table
+    # visit -> (expert, tile, the expert's rows); at most this many
+    visits = jnp.where(counts > 0, (last - 1) // tile - first // tile + 1, 0)
+    ends = jnp.cumsum(visits)
+    i = jnp.arange(tiles + min(e, n), dtype=jnp.int32)
+    ex = jnp.minimum(jnp.sum(ends[None, :] <= i[:, None], axis=1,
+                             dtype=jnp.int32), e - 1)
+    at = jnp.clip(first[ex] // tile + i - (ends - visits)[ex], 0, tiles - 1)
+    xs = jnp.pad(x[order // k], ((0, tiles * tile - n), (0, 0)))
+
+    def rows(i, j, ex_ref, tile_ref, lo_ref, hi_ref):
+        return tile_ref[i], 0
+
+    def columns(i, j, ex_ref, tile_ref, lo_ref, hi_ref):  # of gate_w, up_w
+        return ex_ref[i], 0, j
+
+    def down(i, j, ex_ref, tile_ref, lo_ref, hi_ref):
+        return ex_ref[i], j, 0
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        # a layer none of whose experts was picked walks one visit that
+        # keeps no row
+        grid=(jnp.maximum(ends[-1], 1), nf),
+        in_specs=[
+            pl.BlockSpec((tile, h), rows),
+            pl.BlockSpec((1, h, f_tile), columns),
+            pl.BlockSpec((1, h, f_tile), columns),
+            pl.BlockSpec((1, f_tile, h), down),
+        ],
+        out_specs=pl.BlockSpec((tile, h), rows),
+        scratch_shapes=[pltpu.VMEM((2 * tile, h), jnp.bfloat16)])
+    # two buffers of each weight tile and of the rows in and out, the
+    # rows' parts, the products' float32 results; under the chip's 128 MiB
+    vmem = 12 * h * f_tile + tile * (44 * h + 28 * f_tile) + (8 << 20)
+    ys = pl.pallas_call(
+        _moe_grouped_kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((tiles * tile, h), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=('arbitrary', 'arbitrary'),
+            vmem_limit_bytes=vmem),
+        interpret=interpret,
+        name='moe_grouped_experts',
+    )(ex, at, first[ex], last[ex], xs, gate_w, up_w, down_w)
+    where = jnp.zeros(n, jnp.int32).at[order].set(
+        jnp.arange(n, dtype=jnp.int32))           # pick -> sorted row
+    # a tile no expert has rows in is never written: what it holds is
+    # not zero, and no pick held here lies in it
+    picked = jnp.where((sel < e)[..., None], ys[where].reshape(t, k, h), 0.0)
+    return jnp.sum(picked * w.astype(jnp.float32)[..., None], axis=1)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+def _moe_grouped_taped(x, sel, w, gate_w, up_w, down_w, tile, f_tile,
+                       interpret):
+    return _moe_grouped(x, sel, w, gate_w, up_w, down_w, tile=tile,
+                        f_tile=f_tile, interpret=interpret)
+
+
+def _moe_grouped_fwd(x, sel, w, gate_w, up_w, down_w, tile, f_tile,
+                     interpret):
+    return _moe_grouped(x, sel, w, gate_w, up_w, down_w, tile=tile,
+                        f_tile=f_tile, interpret=interpret), None
+
+
+def _moe_grouped_bwd(tile, f_tile, interpret, saved, ct):
+    # as the loop it stands in for (a `lax.while_loop` has none either)
+    raise NotImplementedError(
+        'moe_grouped_experts has no reverse mode: an expert layer\'s '
+        'routed experts serve, on every schedule (training one is '
+        'ROADMAP\'s)')
+
+
+_moe_grouped_taped.defvjp(_moe_grouped_fwd, _moe_grouped_bwd)
+
+
+def moe_grouped_experts(x, sel, w, gate_w, up_w, down_w, *,
+                        interpret=False):
+    """`sum_k w[t, k] * SwiGLU_{sel[t, k]}(x[t])` for a call MORE than
+    one block wide, as ONE grouped matmul over the picks sorted by
+    expert: x [T, h] float32, sel / w [T, k], bf16 expert leaves [E, h,
+    f], [E, h, f], [E, f, h] -> [T, h] float32; `sel == E` is a pick of
+    no expert held here, and adds nothing.
+
+    The sort, the gather of the sorted rows and the weighted sum over
+    `k` are XLA's and `grouped_experts`' own; between them the T x k
+    sorted rows are cut into tiles of `_GROUPED_ROW_TILE` rows, and a
+    tile is multiplied once for every expert with rows in it: no pick is
+    dropped, an expert nobody picked is never read, a tile behind the
+    last held pick is never visited. Products are the activations' two
+    bf16 parts against the bf16 tile, summed in float32 (what
+    `precision='high'` gives XLA there); `silu(g) * u` is float32 and
+    split again for `down`. A grid step takes `_grouped_f_tile` of an
+    expert's `f`. Under a tape the forward is this kernel and the
+    pullback refuses by name, as the loop's does (`grouped_experts` is a
+    `while`: training an expert layer is ROADMAP's). Jitted, so that a
+    program's expert layers lower the body once."""
+    t, h = x.shape
+    k, (e, _, f) = sel.shape[1], gate_w.shape
+    if x.dtype != jnp.float32:
+        raise ValueError(f'moe_grouped_experts: float32 activations, not '
+                         f'{x.dtype}')
+    if not (gate_w.dtype == up_w.dtype == down_w.dtype == jnp.bfloat16):
+        raise ValueError(
+            'moe_grouped_experts: bf16 expert weights (the two-part '
+            f'product is exact only against them), not {gate_w.dtype}')
+    if up_w.shape != (e, h, f) or down_w.shape != (e, f, h) \
+            or sel.shape != (t, k) or w.shape != (t, k):
+        raise ValueError(
+            f'moe_grouped_experts: x {x.shape}, sel {sel.shape}, w '
+            f'{w.shape} against leaves {gate_w.shape}, {up_w.shape}, '
+            f'{down_w.shape}')
+    return _moe_grouped_taped(x, sel, w, gate_w, up_w, down_w,
+                              _GROUPED_ROW_TILE, _grouped_f_tile(h, f),
+                              interpret)

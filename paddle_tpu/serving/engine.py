@@ -531,10 +531,10 @@ class InferenceEngine:
         # in a whole prefill, where that prefill attends over its own
         # tokens (the model's to say; None: the span says nothing)
         self._own_tokens_pairs = getattr(model, 'own_tokens_pairs', None)
-        # bucket -> the chunks ONE layer's scan walks in a whole prefill,
-        # where a layer's prefill is a chunked scan (likewise; a dict,
-        # the count under the attribute's name: `kda_chunks` for a delta
-        # rule's chunk, `ssm_chunks` for a state-space layer's)
+        # bucket -> what a whole prefill's program runs, by the model's
+        # own dispatch (likewise; a dict of the span's attributes: the
+        # chunks ONE layer's scan walks, `kda_chunks`, `ssm_chunks`; the
+        # layers run as ONE kernel, `ssm_`, `expert_kernel_layers`)
         self._scan_chunks = getattr(model, 'scan_chunks', None)
         # how many state layers' recurrences a decode sub-step runs as a
         # kernel, where the model has one to ask (its own dispatch, with

@@ -691,22 +691,37 @@ def as_a_draft_refused(fam, why):
 
 
 def expert_kernel_serves_the_loops_tokens(fam):
-    """bf16 expert leaves: through the engine the interpreted
-    `moe_decode_experts` gives the greedy tokens the loop gives, and
-    every expert-layer sub-step of every round is booked as the
-    kernel's, in the span and in the counter; on the CPU as it is,
-    none."""
-    def test(monkeypatch, fresh_programs):
+    """bf16 expert leaves: through the engine the interpreted kernels
+    give the greedy tokens the loop gives. `decode`: at the model's own
+    block of 256 rows every call is one block wide — `moe_decode_experts`
+    in the rounds, every expert-layer sub-step of every round booked as
+    the kernel's, in the span and in the counter, the prefills on the
+    loop. `prefill`: at a block of 8 rows both buckets are wider than a
+    block — `moe_grouped_experts` in every prefill, and
+    `serving.prefill` reads `expert_kernel_layers` = the expert layers.
+    On the CPU as it is, none of either."""
+    @pytest.mark.parametrize('block_rows', [256, 8],
+                             ids=['decode', 'prefill'])
+    def test(block_rows, monkeypatch, fresh_programs):
+        monkeypatch.setattr(afmoe, 'BLOCK_ROWS', block_rows)
         cfg = fam.cfg()
         w = {name: v.astype(jnp.bfloat16) if 'experts_' in name else v
              for name, v in fam.weights(cfg).items()}
         served, log, reg = prompts((5, 19, 11)), cleared_log(), \
             obs.get_registry()
         family = 'paddle_serving_moe_expert_kernel_substeps_total'
+
+        def prefills():
+            return [e['attrs']['expert_kernel_layers'] for e in log.events()
+                    if e['name'] == 'serving.prefill']
         before = reg.value(family)
-        base, _ = through_the_router(fam.model(cfg, w), served, 14)
+        model = fam.model(cfg, w)
+        layers = sum(isinstance(l, afmoe.AfmoeSparseMLP)
+                     for l in model.sublayers())
+        base, _ = through_the_router(model, served, 14)
         assert all(a['expert_kernel_substeps'] == 0 for a in rounds(log))
         assert reg.value(family) == before
+        assert layers and prefills() == [0, 0, 0]
         monkeypatch.setattr(afmoe, 'expert_kernel', functools.partial(
             pallas.expert_kernel, interpret=True))
         fresh_programs.clear_memory()
@@ -717,6 +732,7 @@ def expert_kernel_serves_the_loops_tokens(fam):
         assert booked and booked == [a['expert_layer_substeps']
                                      for a in rounds(log)] and all(booked)
         assert reg.value(family) - before == sum(booked)
+        assert prefills() == [layers * (block_rows < BUCKET)] * 3
     return test
 
 

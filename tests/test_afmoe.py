@@ -182,15 +182,22 @@ def _picks(rs, tokens, e, k, routing):
     (8, 256, 'one_expert', (128, 8), True),
     (8, 256, 'distinct', (128, 8), True),    # the grid's bound, all of it real
     (32, 256, 'few', (64, 4), True),         # 5 of 64 experts touched
-    (5, 256, 'random', (8, 2), True)])       # rows padded to the kernel's 16
+    (5, 256, 'random', (8, 2), True),        # rows padded to the kernel's 16
+    # a call wider than a block: the grouped kernel, interpreted (PR 49)
+    (70, 16, 'random', (8, 2), True),        # tiles of 128 hold several experts
+    (70, 16, 'one_expert', (8, 2), True),    # half the picks one expert's
+    (17, 16, 'random', (8, 2), True),        # one row over a block
+    (70, 16, 'few', (64, 4), True)])         # 59 of 64 experts picked by nobody
 def test_grouped_experts_against_a_loop_over_picks(
         tokens, block_rows, routing, experts, kernel, monkeypatch,
         fresh_dispatch):
-    """The loop over blocks and the kernel against a float64 loop over
-    the picks. The kernel takes bf16 leaves under float32 activations,
-    and is held to the tolerance the loop meets — also against the loop
-    itself over the same leaves in float32 at `HIGHEST`."""
+    """The loop over blocks and the kernels against a float64 loop over
+    the picks. A kernel takes bf16 leaves under float32 activations,
+    and is held to the tolerance the loop meets (the grouped kernel's
+    two bf16 parts to 16 bits of it) — also against the loop itself
+    over the same leaves in float32 at `HIGHEST`."""
     monkeypatch.setattr(afmoe, 'BLOCK_ROWS', block_rows)
+    tol = 1e-4 if kernel and tokens > block_rows else 1e-5
     rs = np.random.RandomState(tokens)
     (e, k), h, f = experts, 16, 12
     x = rs.randn(tokens, h).astype('float32')
@@ -210,22 +217,26 @@ def test_grouped_experts_against_a_loop_over_picks(
         with jax.default_matmul_precision('highest'):
             loop = afmoe.grouped_experts(*args, *map(jnp.asarray,
                                                      (gw, uw, dw)))
-        assert np.abs(np.asarray(got) - np.asarray(loop)).max() < 1e-5
+        assert np.abs(np.asarray(got) - np.asarray(loop)).max() < tol
     want = _experts_by_loop(x, sel, w, gw, uw, dw)
-    assert np.abs(np.asarray(got) - want).max() < 1e-5
+    assert np.abs(np.asarray(got) - want).max() < tol
 
 
 @pytest.mark.parametrize('tokens,dtype,interpret,takes', [
-    (8, 'bfloat16', False, False),      # the CPU, not interpreted: the loop
-    (8, 'bfloat16', True, True),
-    (256, 'bfloat16', True, True),      # one block wide, to the row
-    (257, 'bfloat16', True, False),     # a prefill's blocks keep the loop
-    (8, 'float32', True, False)])       # the three parts want bf16 leaves
+    (8, 'bfloat16', False, None),       # the CPU, not interpreted: the loop
+    (8, 'bfloat16', True, 'moe_decode_experts'),
+    (256, 'bfloat16', True, 'moe_decode_experts'),  # one block, to the row
+    (8, 'float32', True, None),         # the parts want bf16 leaves
+    # wider than a block: the grouped kernel (PR 49)
+    (257, 'bfloat16', True, 'moe_grouped_experts'),
+    (10240, 'bfloat16', True, 'moe_grouped_experts'),
+    (257, 'float32', True, None),
+    (257, 'bfloat16', False, None)])    # another backend: the loop
 def test_expert_kernel_is_picked_by_backend_and_shape(tokens, dtype,
                                                       interpret, takes):
     fn = pallas.expert_kernel(tokens, afmoe.BLOCK_ROWS, jnp.dtype(dtype),
                               interpret=interpret)
-    assert (fn is not None) == takes
+    assert (fn and fn.func.__name__) == takes
 
 
 def _attention_repeated(q, k, v, mask, causal):

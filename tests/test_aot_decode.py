@@ -26,7 +26,9 @@ copy of the state's shape beside it. Since PR 46: serve-ssm-reason's make
 the state ONCE a Mamba layer, the update and the sum over the states in one
 fusion, and hold one `kv_decode_attention` for twenty query heads on one
 K,V head. Since PR 47: its largest PREFILL holds one `ssm_prefill_scan` a
-Mamba layer under `ssm` and no `while` there. Every compile here goes through the
+Mamba layer under `ssm` and no `while` there. Since PR 49: serve-moe-docs'
+largest PREFILL holds one `moe_grouped_experts` an expert layer under
+`moe/experts` and no `while` there. Every compile here goes through the
 store's compile site with the formats the pool holds, as the engine's do.
 
 The topology is described inside a fixture, never at import: every xdist
@@ -438,6 +440,40 @@ def test_a_state_space_layers_prefill_is_one_kernel_a_layer_on_v5e(one_chip):
     pairs = [ln for ln in text.splitlines()
              if re.match(r'\s*(?:ROOT )?%\S+ = \(*f32\[1,\d+,16,5120\]', ln)]
     assert not pairs, [ln[:160] for ln in pairs[:3]]
+
+
+@pytest.mark.slow
+def test_an_expert_layers_prefill_is_one_grouped_kernel_on_v5e(one_chip):
+    """serve-moe-docs' largest prefill at its widths, one dense layer and
+    three expert layers (PR 49): the routed experts of the bucket's
+    tokens are ONE `moe_grouped_experts` an expert layer, under
+    `moe/experts`, and no `while` is left there (the loop over blocks of
+    256 sorted rows is gone); the engine says so on `serving.prefill`.
+    The LAST layer's experts are in no prefill program, loop or kernel:
+    a prefill returns K and V, which nothing behind the last layer's
+    attention feeds, and the compiler drops the rest. The decode
+    programs keep `moe_decode_experts`
+    (`test_an_expert_layer_is_one_kernel_on_v5e`)."""
+    import numpy as np
+    cell = spec.Spec().cell('serve-moe-docs')
+    cfg = cell['config']
+    cfg['num_hidden_layers'] = 4
+    cfg['layer_types'] = cfg['layer_types'][:4]
+    cell['traffic']['slots'] = 2        # the prefill never holds the pool
+    bucket = max(cell['traffic']['buckets'])
+    with _engine_for_the_chip(cell) as eng:
+        assert eng._scan_chunks(bucket) == {'expert_kernel_layers': 3}
+        assert eng._scan_chunks(256) == {'expert_kernel_layers': 0}
+        text = _compile(
+            eng._prefill_jit,
+            (eng._params, eng._frozen, eng._buffers,
+             np.zeros((1, bucket), np.int32)), one_chip).as_text()
+    experts = [ln for ln in text.splitlines() if 'moe/experts' in ln]
+    kernels = [ln for ln in experts if 'tpu_custom_call' in ln]
+    assert len(kernels) == 2, [ln[:160] for ln in kernels]
+    assert all('moe_grouped_experts' in ln for ln in kernels)
+    loops = [ln for ln in experts if re.search(r' while\(', ln)]
+    assert not loops, f'a loop under moe/experts again: {loops[0][:200]}'
 
 
 def _cut_to_three_layers(cfg):

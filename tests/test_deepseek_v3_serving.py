@@ -419,3 +419,8 @@ def test_a_model_without_a_latent_entry_is_asked_nothing(interpreted):
                           decode_block=BLOCK, buckets=[BUCKET])
     assert not eng._bounded_tiles(256).any()
     assert eng._read_rows(256) == 2 * 256 * len(eng.pool.row_spec)
+
+
+# bf16 expert leaves through both expert kernels, interpreted (PR 49)
+test_the_expert_kernels_serve_the_loops_tokens = \
+    H.expert_kernel_serves_the_loops_tokens(FAM)

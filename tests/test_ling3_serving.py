@@ -255,3 +255,8 @@ def test_kda_scopes_are_on_the_decode_and_prefill_programs(
         assert all(p == ('kda', 'state_write') for p in kernels)
     assert programs.scope_path(
         'jit(f)/while/body/kda/state_write/add') == ('kda', 'state_write')
+
+
+# bf16 expert leaves through both expert kernels, interpreted (PR 49)
+test_the_expert_kernels_serve_the_loops_tokens = \
+    H.expert_kernel_serves_the_loops_tokens(FAM)

@@ -251,3 +251,8 @@ def test_scopes_are_on_the_decode_and_prefill_programs(tiny):
         assert any('kv_write' in programs.scope_path(op)
                    and op.endswith('/rem')
                    for op, *_ in table[prog].values())
+
+
+# bf16 expert leaves through both expert kernels, interpreted (PR 49)
+test_the_expert_kernels_serve_the_loops_tokens = \
+    H.expert_kernel_serves_the_loops_tokens(FAM)

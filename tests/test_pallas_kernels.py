@@ -416,6 +416,15 @@ class TestNoSilentFallback:
                 p._data = p._data.astype(jnp.bfloat16)
         with pytest.raises(RuntimeError, match='mosaic says no'):
             model(paddle.to_tensor(np.ones((1, 8), 'int32')))
-        # a call wider than one block is the loop's by the conditions
-        out = model(paddle.to_tensor(np.ones((1, BLOCK_ROWS + 1), 'int32')))
-        assert np.isfinite(out.numpy()).all()
+        # a call wider than one block is the grouped kernel's by the
+        # conditions (PR 49), and its error reaches the caller as well
+        wide = paddle.to_tensor(np.ones((1, BLOCK_ROWS + 1), 'int32'))
+        with pytest.raises(Exception) as refused:
+            model(wide)         # Mosaic, on the CPU: no stand-in steps in
+        assert 'mosaic says no' not in str(refused.value)
+
+        def boom_too(*a, **k):
+            raise RuntimeError('mosaic says no to the wide call too')
+        monkeypatch.setattr(pk, 'moe_grouped_experts', boom_too)
+        with pytest.raises(RuntimeError, match='to the wide call too'):
+            model(wide)

@@ -668,7 +668,8 @@ def test_every_pallas_kernel_states_its_name():
         'flash_fwd', 'flash_bwd_dq', 'flash_bwd_dkv', 'rms_norm_fwd',
         'ce_fwd', 'ce_bwd', 'paged_attention', 'adapter_matmul',
         'moe_decode_experts', 'mla_decode_attention',
-        'kv_decode_attention', 'kda_decode_step', 'ssm_prefill_scan']
+        'kv_decode_attention', 'kda_decode_step', 'ssm_prefill_scan',
+        'moe_grouped_experts']
 
 
 def test_named_kernel_reaches_the_lowered_program():

@@ -109,3 +109,8 @@ def test_a_model_with_one_stream_says_nothing_of_streams():
     other.run()
     last = H.rounds(log)[-1]
     assert 'residual_streams' not in last and 'latent_layers' in last
+
+
+# bf16 expert leaves through both expert kernels, interpreted (PR 49)
+test_the_expert_kernels_serve_the_loops_tokens = \
+    H.expert_kernel_serves_the_loops_tokens(FAM)
